@@ -30,7 +30,7 @@ import sys
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
-import bench  # noqa: E402  (the root bench module: probe + measure_row)
+import bench  # noqa: E402  (the root bench module: platform check + measure_row)
 
 ROWS = (
     ("fp32", dict(use_amp=False)),
@@ -48,24 +48,16 @@ def main() -> None:
     ap.add_argument("--image-size", type=int, default=224)
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--warmup", type=int, default=5)
-    ap.add_argument("--probe-timeout", type=float, default=90.0)
-    ap.add_argument("--probe-budget", type=float, default=600.0)
+    ap.add_argument("--platform", default="tpu", choices=("tpu", "cpu"),
+                    help="the backend this table is FOR: exits non-zero if "
+                         "jax initializes on another one")
     ap.add_argument("--out", default=os.path.join(
         _REPO, "benchmarks", "results", "recipe_table.json"))
     ap.add_argument("--rows", default=",".join(name for name, _ in ROWS),
                     help="comma-separated subset of rows to run")
     args = ap.parse_args()
 
-    if os.environ.get("TPUDIST_BENCH_CHILD") != "cpu" \
-            and os.environ.get("JAX_PLATFORMS") != "cpu":
-        # Reuse the bench's killable-subprocess probe, but without its stale/
-        # CPU fallback: a recipe table is only worth producing on a live
-        # backend the caller chose.
-        ok, detail = bench._probe_backend(args.probe_timeout)
-        if not ok:
-            print(f"recipe_table: backend probe failed: {detail}",
-                  file=sys.stderr)
-            sys.exit(3)
+    bench.require_platform(args.platform)
 
     want = set(args.rows.split(","))
     records = []

@@ -1,0 +1,603 @@
+"""Chip smoke: prove on the attached TPU that the trainer trains, that what
+it logs as running is what ran, and that the kernels match their references.
+
+    python3 chip_smoke.py              # one chip: device, train, input, kernels
+    python3 chip_smoke.py --multichip  # four chips: DP+SyncBN vs one device,
+                                       # one dp x tp 2x2 ViT-B/16 step
+
+One process, and it is the only one that touches jax (a chip belongs to one
+process at a time); the children it starts (``make``, the JPEG generator)
+never need the chip and have exited before the phase that used them ends.
+Each phase prints one JSON line; the last stdout line is the contract's
+``{"ok": true, "device": {...}}`` — printed only if every phase passed. On
+anything but a TPU the script exits non-zero before the first phase line.
+
+Everything under test goes through the entry points a user calls:
+``config.from_args`` -> ``trainer.run`` (what ``python -m tpudist`` runs),
+with ``--require-platform tpu`` on every trainer call.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import re
+import shutil
+import subprocess
+import sys
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+# Module constants, not options: the only other values they ever take are
+# the tiny CPU ones a rehearsal script assigns before calling main().
+PLATFORM = "tpu"
+INTERPRET = False            # Pallas kernels compiled by Mosaic, not interpreted
+MODEL = ["-a", "resnet18", "--num-classes", "1000", "--image-size", "224",
+         "--use_amp"]
+VIT = ["-a", "vit_b_16", "--num-classes", "1000", "--image-size", "224",
+       "--use_amp"]
+PER_CHIP_BATCH = 128
+STEPS_PER_EPOCH = 3          # x 4 epochs = a dozen steps over repeated data,
+EPOCHS = 4                   # so the train loss has something to fall on
+# resnet18 @224, per-chip batch 128: every BN epilogue workload of the model
+# (rows = 128 * hw^2, channels) — the widths tests/test_tpu_compile.py holds.
+RESNET18_BN_WIDTHS = ((112, 64), (56, 64), (28, 128), (14, 256), (7, 512))
+VIT_B16_ATTENTION = (128, 197, 12, 64)      # batch, tokens, heads, head_dim
+
+
+def say(phase: str, **fields) -> None:
+    print(json.dumps({"phase": phase, **fields}), flush=True)
+
+
+class CompileLog:
+    """Times of every backend compile request jax makes in this process
+    (persistent-cache hits included: a hit still means a program that the
+    in-memory cache did not hold)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax
+        self.times: list[float] = []
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, name, duration, **_):
+        if name == self.EVENT:
+            self.times.append(time.time())
+
+    def between(self, t0: float, t1: float) -> int:
+        return sum(1 for t in self.times if t0 < t <= t1)
+
+
+# -- device ------------------------------------------------------------------
+
+def phase_device(want_count: int | None) -> dict:
+    import jax
+    devs = jax.devices()
+    dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+           "count": len(devs)}
+    if dev["platform"] != PLATFORM:
+        print(f"chip_smoke: jax found no {PLATFORM} (platform "
+              f"'{dev['platform']}') — this script only runs on the chip",
+              file=sys.stderr)
+        raise SystemExit(2)
+    if want_count is not None and dev["count"] != want_count:
+        print(f"chip_smoke: --multichip needs {want_count} chips, found "
+              f"{dev['count']}", file=sys.stderr)
+        raise SystemExit(2)
+    from tpudist.telemetry import resolve_peak_flops, resolve_peak_hbm
+    peak = resolve_peak_flops(dev["kind"])
+    if peak is None:
+        raise AssertionError(
+            f"device_kind '{dev['kind']}' resolves to no peak FLOP/s in "
+            f"tpudist.telemetry.PEAK_FLOPS_BY_KIND — MFU would be silently "
+            f"unreported on this chip")
+    from tpudist.serve.cache import configure_compile_cache
+    cache_dir, cache_state = configure_compile_cache()
+    say("device", ok=True, **dev, peak_flops=peak,
+        peak_hbm_bytes_per_s=resolve_peak_hbm(dev["kind"]),
+        jax=jax.__version__, compile_cache=cache_dir,
+        compile_cache_state=cache_state)
+    return dev
+
+
+# -- kernels -----------------------------------------------------------------
+
+def _mismatch(got, want, rtol: float, atol: float, excuse=None):
+    """(#elements outside tolerance and not excused, #excused and outside,
+    largest error as a share of its tolerance among the rest), computed on
+    device."""
+    import jax.numpy as jnp
+    g, w = got.astype(jnp.float32), want.astype(jnp.float32)
+    share = jnp.abs(g - w) / (atol + rtol * jnp.abs(w))
+    share = jnp.where(jnp.isfinite(g), share, jnp.inf)
+    if excuse is None or excuse.shape != share.shape:
+        excuse = jnp.zeros(share.shape, bool)
+    out = share > 1.0
+    return (int(jnp.sum(out & ~excuse)), int(jnp.sum(out & excuse)),
+            float(jnp.max(jnp.where(excuse, 0.0, share))))
+
+
+def _check(name: str, pairs, tol_fn, excuse=None) -> dict:
+    """Compare each (label, got, want) elementwise. ``excuse`` marks the
+    elements whose ReLU input is within one storage-dtype ulp of zero in
+    the reference: there the two programs may legitimately disagree on the
+    ReLU's side, which flips that single gradient element and nothing
+    else. Every other element must be inside the tolerance."""
+    worst = {}
+    for label, got, want in pairs:
+        if got.shape != want.shape or got.dtype != want.dtype:
+            raise AssertionError(f"{name} {label}: got {got.shape} "
+                                 f"{got.dtype}, want {want.shape} "
+                                 f"{want.dtype}")
+        rtol, atol = tol_fn(want)
+        bad, flipped, share = _mismatch(got, want, rtol, atol, excuse)
+        if bad:
+            raise AssertionError(
+                f"{name} {label}: {bad} of {got.size} elements outside "
+                f"rtol={rtol:g} atol={atol:g} (worst error {share:g}x its "
+                f"tolerance; {flipped} more at the ReLU boundary)")
+        worst[label] = {"err_over_tol": round(share, 4),
+                        "relu_boundary_flips": flipped}
+    return worst
+
+
+def _bn_case(key, rows: int, channels: int, residual: bool) -> dict:
+    """Fused BN epilogue fwd + every input gradient vs the XLA reference
+    (bf16 storage; tolerances of tests/test_fused_norm.py's parity matrix,
+    1e-2 forward and 1e-2 * 20 * (max|ref| + 1) on gradients; dx/dres may
+    differ outside it only where the ReLU input is within a bf16 ulp of
+    zero — see ``_check``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from tpudist.ops.pallas.fused_norm import fused_bn_act, reference_bn_act
+    dt, f32 = jnp.bfloat16, jnp.float32
+    kx, kr, kw = jax.random.split(key, 3)
+    x = jax.random.normal(kx, (rows, channels), dt)
+    res = jax.random.normal(kr, (rows, channels), dt) if residual else None
+    w = jax.random.normal(kw, (rows, channels), dt)
+    rng = np.random.default_rng(channels)
+    scale, bias, mean = (jnp.asarray(rng.standard_normal(channels), f32)
+                         for _ in range(3))
+    var = jnp.asarray(rng.random(channels) + 0.5, f32)
+
+    def loss(fn):
+        def f(x, scale, bias, mean, var, res, w):
+            y = fn(x, scale, bias, mean, var, residual=res)
+            return (y.astype(f32) * w.astype(f32)).sum(), y
+        return f
+
+    argnums = tuple(range(6 if residual else 5))
+    fused = lambda *a, **k: fused_bn_act(  # noqa: E731
+        *a, interpret=INTERPRET, **k)
+    run = lambda fn: jax.jit(jax.value_and_grad(  # noqa: E731
+        loss(fn), argnums=argnums, has_aux=True))(
+            x, scale, bias, mean, var, res, w)
+    (_, y1), g1 = run(fused)
+    (_, y2), g2 = run(reference_bn_act)
+
+    @jax.jit
+    def relu_boundary(x, scale, bias, mean, var, res):
+        """ReLU input within one bf16 ulp (2^-7 relative) of zero."""
+        q = ((x.astype(f32) - mean) * jax.lax.rsqrt(var + 1e-5) * scale
+             + bias)
+        r = 0.0 if res is None else res.astype(f32)
+        return jnp.abs(q + r) <= 2.0 ** -7 * jnp.maximum(jnp.abs(q),
+                                                         jnp.abs(r))
+
+    near0 = relu_boundary(x, scale, bias, mean, var, res)
+    names = ("dx", "dscale", "dbias", "dmean", "dvar", "dres")
+    tol = 1e-2
+    out = _check(f"fused_bn m{rows} c{channels}", [("y", y1, y2)],
+                 lambda want: (tol, tol))
+    out.update(_check(
+        f"fused_bn m{rows} c{channels}", list(zip(names, g1, g2)),
+        lambda want: (0.0, tol * 20 * (float(jnp.max(jnp.abs(
+            want.astype(f32)))) + 1.0)), excuse=near0))
+    return out
+
+
+def _flash_case(key) -> dict:
+    """Flash attention fwd + dQ/dK/dV at ViT-B/16 widths vs XLA attention
+    on the f32-widened inputs at highest matmul precision (tolerances of
+    tests/test_flash_attention.py: 2e-2 forward, 1e-2 relative to max|ref|
+    on gradients)."""
+    import jax
+    import jax.numpy as jnp
+    from tpudist.ops.pallas.flash_attention import flash_attention
+    from tpudist.parallel.ring_attention import attention
+    f32 = jnp.float32
+    kq, kk, kv, kg = jax.random.split(key, 4)
+    q, k, v = (jax.random.normal(kk_, VIT_B16_ATTENTION, jnp.bfloat16)
+               for kk_ in (kq, kk, kv))
+    g = jax.random.normal(kg, VIT_B16_ATTENTION, f32)
+
+    def loss(fn):
+        def f(q, k, v):
+            o = fn(q, k, v)
+            return (o.astype(f32) * g).sum(), o
+        return f
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, interpret=INTERPRET)
+    (_, o1), g1 = jax.jit(jax.value_and_grad(
+        loss(flash), argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        (_, o2), g2 = jax.jit(jax.value_and_grad(
+            loss(attention), argnums=(0, 1, 2), has_aux=True))(
+                q.astype(f32), k.astype(f32), v.astype(f32))
+    out = _check("flash", [("o", o1.astype(f32), o2)],
+                 lambda want: (2e-2, 2e-2))
+    out.update(_check(
+        "flash", [(n, a.astype(f32), b) for n, a, b in zip(
+            ("dq", "dk", "dv"), g1, g2)],
+        lambda want: (1e-2, 1e-2 * float(jnp.max(jnp.abs(want))))))
+    return out
+
+
+def phase_kernels(seed: int) -> None:
+    import jax
+    from tpudist.ops import attention_dispatch
+    from tpudist.ops.pallas.flash_attention import KERNEL_REV as flash_rev
+    from tpudist.ops.pallas.fused_norm import KERNEL_REV as fused_norm_rev
+    key = jax.random.PRNGKey(seed)
+    cases = {}
+    for hw, c in RESNET18_BN_WIDTHS:
+        rows = PER_CHIP_BATCH * hw * hw
+        for residual in (False, True):
+            name = f"bn_m{rows}_c{c}_{'res' if residual else 'plain'}"
+            key, sub = jax.random.split(key)
+            cases[name] = _bn_case(sub, rows, c, residual)
+    cases["flash_b{}_t{}_h{}_d{}".format(*VIT_B16_ATTENTION)] = \
+        _flash_case(key)
+    # What the default flag (--flash auto) resolves to for ViT-B/16 at the
+    # per-chip batch the trainer would run: the same decide() the Trainer
+    # calls, so a kernel the compiler refuses raises here too.
+    _, t, h, d = VIT_B16_ATTENTION
+    dec = attention_dispatch.decide(64, t, h, d, "bfloat16", train=True,
+                                    mode="auto")
+    say("kernels", ok=True, interpret=INTERPRET,
+        fused_norm_rev=fused_norm_rev, flash_rev=flash_rev,
+        cases=len(cases),
+        worst_err_over_tolerance={k: max(v[n]["err_over_tol"] for n in v)
+                                  for k, v in cases.items()},
+        relu_boundary_flips={k: sum(v[n]["relu_boundary_flips"] for n in v)
+                             for k, v in cases.items()},
+        attention_dispatch={k: dec.get(k) for k in (
+            "kernel", "mode", "source", "key", "flash_ms", "xla_ms",
+            "margin")})
+
+
+# -- trainer runs --------------------------------------------------------------
+
+def _events(outpath: str) -> list[dict]:
+    with open(os.path.join(outpath, "events.0.jsonl")) as f:
+        return [json.loads(ln) for ln in f if ln.strip()]
+
+
+_STEP_LOSS = re.compile(r"Epoch\[(\d+)\]:\t\[(\d+)/\d+\].*?Loss (\S+) \(")
+_EPOCH_LOSS = re.compile(r"\|\|==> (Train|Val): Epoch\[(\d+)\]\tLoss (\S+)")
+
+
+def _losses(outpath: str, since: int = 0):
+    """(per-step train losses, {epoch: train mean}, {epoch: val mean}) parsed
+    from experiment.log (bytes ``since`` onward). With the default async
+    metric drain console line i shows step i-1's loss; line 0 shows none."""
+    with open(os.path.join(outpath, "experiment.log")) as f:
+        f.seek(since)
+        text = f.read()
+    steps = [float(m.group(3)) for m in _STEP_LOSS.finditer(text)
+             if int(m.group(2)) > 0]
+    train = {int(m.group(2)): float(m.group(3))
+             for m in _EPOCH_LOSS.finditer(text) if m.group(1) == "Train"}
+    val = {int(m.group(2)): float(m.group(3))
+           for m in _EPOCH_LOSS.finditer(text) if m.group(1) == "Val"}
+    return steps, train, val
+
+
+def _cfg(argv: list[str]):
+    """``config.from_args`` plus what every trainer call here carries:
+    refuse any other backend, and keep only the live checkpoint file (the
+    run dirs come back through the chip tool, which carries 64 MiB)."""
+    from tpudist.config import from_args
+    return from_args(argv + ["--require-platform", PLATFORM,
+                             "--keep-checkpoints", "0"])
+
+
+def _run_trainer(argv: list[str]) -> float:
+    """What ``python -m tpudist <argv>`` runs, in this process."""
+    from tpudist.trainer import run
+    return run(_cfg(argv))
+
+
+def _drop_checkpoints(outpath: str) -> None:
+    """Delete a finished run's checkpoint files (logs, settings and
+    telemetry stay)."""
+    for name in os.listdir(outpath):
+        if name.endswith(".msgpack"):
+            os.remove(os.path.join(outpath, name))
+
+
+def _check_run(outpath: str, compiles: CompileLog, *, first_epoch: int,
+               n_epochs: int, steps_per_epoch: int, log_since: int = 0,
+               ev_since: int = 0) -> dict:
+    """The assertions every trainer run must meet; returns its record."""
+    evs = _events(outpath)[ev_since:]
+    start = next(e for e in evs if e["type"] == "run_start")
+    assert start["platform"] == PLATFORM, start
+    steps = [e for e in evs if e["type"] == "step"]
+    assert len(steps) == n_epochs * steps_per_epoch, \
+        (len(steps), n_epochs, steps_per_epoch)
+    # No compile after warm-up. The trainer's own compile events (first
+    # dispatch + cost analysis) must all precede the second step's end, and
+    # jax itself must not have been asked to compile anything between the
+    # end of the warm-up and the last train step — except in the first
+    # epoch's tail, where the eval program compiles once.
+    t_warm = steps[1]["t"]
+    tel_compiles = [e for e in evs if e["type"] == "compile"]
+    late = [e for e in tel_compiles if e["t"] > t_warm]
+    assert not late, f"compile telemetry events after warm-up: {late}"
+    epoch_ends = [e["t"] for e in evs if e["type"] == "epoch"]
+    assert len(epoch_ends) == n_epochs, (len(epoch_ends), n_epochs)
+    windows = [(t_warm, steps[steps_per_epoch - 1]["t"])]
+    if n_epochs > 1:
+        windows.append((epoch_ends[0], epoch_ends[-1]))
+    recompiles = sum(compiles.between(a, b) for a, b in windows)
+    assert recompiles == 0, \
+        f"{recompiles} compile(s) in the steady-state window(s) {windows}"
+    per_step, train, val = _losses(outpath, log_since)
+    epochs = list(range(first_epoch, first_epoch + n_epochs))
+    assert sorted(train) == epochs and sorted(val) == epochs, (train, val)
+    every = per_step + list(train.values()) + list(val.values())
+    assert every and all(math.isfinite(x) for x in every), every
+    peaks = [e["peak_hbm_gb"] for e in evs
+             if e["type"] == "epoch" and "peak_hbm_gb" in e]
+    assert peaks, "no epoch event reported peak_bytes_in_use"
+    end = next(e for e in evs if e["type"] == "run_end")
+    compiled = next(e for e in tel_compiles if e["phase"] == "cost_analysis")
+    return {
+        "steps": len(steps), "per_step_loss": per_step,
+        "train_loss_by_epoch": train, "val_loss_by_epoch": val,
+        "compile_events": [{k: e.get(k) for k in ("phase", "seconds",
+                                                  "cache")}
+                           for e in tel_compiles],
+        "recompiles_after_warmup": recompiles,
+        # The runtime's high-water mark (device.memory_stats()) beside the
+        # compiler's own account of the step program.
+        "peak_hbm_gb": max(peaks),
+        "compiled_step_hbm_gb": {
+            k: round(compiled[f] / 2**30, 3)
+            for k, f in (("total", "hbm_compiled_bytes"),
+                         ("temps", "temp_bytes"), ("args", "arg_bytes"))
+            if f in compiled},
+        "goodput": end.get("goodput"),
+    }
+
+
+def _dispatch_lines(evs: list[dict]) -> dict:
+    out = {}
+    for e in evs:
+        if e["type"] in ("fused_norm_dispatch", "attention_dispatch",
+                         "comm_dispatch"):
+            out[e["type"]] = {k: v for k, v in e.items()
+                              if k not in ("t", "type", "rank", "attempt")}
+    return out
+
+
+def phase_train(out: str, seed: int, n_dev: int,
+                compiles: CompileLog) -> None:
+    outpath = os.path.join(out, "train")
+    batch = PER_CHIP_BATCH * n_dev
+    argv = ["--synthetic", *MODEL, "-b", str(batch),
+            "--synthetic-size", str(batch * STEPS_PER_EPOCH),
+            "--seed", str(seed), "-p", "1", "-j", "8", "--telemetry",
+            "--outpath", outpath]
+    _run_trainer(argv + ["--epochs", str(EPOCHS), "--overwrite", "delete"])
+    first = _check_run(outpath, compiles, first_epoch=0, n_epochs=EPOCHS,
+                       steps_per_epoch=STEPS_PER_EPOCH)
+    tl = first["train_loss_by_epoch"]
+    assert tl[EPOCHS - 1] < tl[0], \
+        f"train loss did not fall over {EPOCHS} epochs: {tl}"
+    ckpt = os.path.join(outpath, "checkpoint.msgpack")
+    assert os.path.getsize(ckpt) > 0
+    evs = _events(outpath)
+    say("train", ok=True, model=" ".join(MODEL), global_batch=batch, **first,
+        checkpoint_bytes=os.path.getsize(ckpt),
+        dispatch=_dispatch_lines(evs))
+
+    # Resume from that checkpoint for one more epoch (--overwrite keep: the
+    # outpath holds the checkpoint being resumed).
+    log_since = os.path.getsize(os.path.join(outpath, "experiment.log"))
+    _run_trainer(argv + ["--epochs", str(EPOCHS + 1), "--overwrite", "keep",
+                         "--resume", ckpt])
+    resumed = _check_run(outpath, compiles, first_epoch=EPOCHS, n_epochs=1,
+                         steps_per_epoch=STEPS_PER_EPOCH,
+                         log_since=log_since, ev_since=len(evs))
+    with open(os.path.join(outpath, "experiment.log")) as f:
+        f.seek(log_since)
+        assert f"(epoch {EPOCHS}," in f.read(), "resume line not logged"
+    assert resumed["train_loss_by_epoch"][EPOCHS] < tl[0], (resumed, tl)
+    _drop_checkpoints(outpath)
+    say("resume", ok=True, from_epoch=EPOCHS, **resumed)
+
+
+def phase_input(out: str, seed: int, n_dev: int,
+                compiles: CompileLog) -> None:
+    """A few steps of the same model through the real input path: JPEG
+    ImageFolder -> (native) decode/crop/flip/normalize -> threaded loader ->
+    device prefetch. ``native/`` is rebuilt from source here: the checkout
+    holds no binary, and one built on another CPU must not be loaded."""
+    native_dir = os.path.join(REPO, "native")
+    t0 = time.time()
+    mk = subprocess.run(["make", "-C", native_dir, "clean", "all"],
+                        capture_output=True, text=True, timeout=600)
+    if mk.returncode != 0:
+        raise AssertionError(f"native build failed (exit {mk.returncode}):\n"
+                             f"{mk.stdout[-2000:]}\n{mk.stderr[-2000:]}")
+    build_s = time.time() - t0
+    root = os.path.join(out, "imagefolder")
+    shutil.rmtree(root, ignore_errors=True)
+    batch = PER_CHIP_BATCH * n_dev
+    classes, steps = 8, 4
+    t0 = time.time()
+    subprocess.run(
+        [sys.executable,
+         os.path.join(REPO, "benchmarks", "make_synth_imagefolder.py"),
+         "--root", root, "--classes", str(classes),
+         "--train-per-class", str(batch * steps // classes),
+         "--val-per-class", str(batch // classes), "--size", "256",
+         "--seed", str(seed)],
+        check=True, capture_output=True, text=True, timeout=600)
+    gen_s = time.time() - t0
+
+    from tpudist.data import native
+    decode = ("native-jpeg" if native.jpeg_available()
+              else "native-transform+PIL-decode" if native.available()
+              else "PIL")
+    assert decode == "native-jpeg", \
+        f"native library built but the loader would run '{decode}'"
+    outpath = os.path.join(out, "input")
+    _run_trainer(["--data", root, *MODEL, "-b", str(batch), "--epochs", "1", "--seed", str(seed), "-p", "1", "-j", "8",
+                  "--telemetry", "--outpath", outpath,
+                  "--overwrite", "delete"])
+    rec = _check_run(outpath, compiles, first_epoch=0, n_epochs=1,
+                     steps_per_epoch=steps)
+    epoch = next(e for e in _events(outpath) if e["type"] == "epoch")
+    assert not epoch.get("samples_skipped"), epoch
+    _drop_checkpoints(outpath)
+    shutil.rmtree(root)
+    say("input", ok=True, decode_path=decode,
+        native_build_s=round(build_s, 1), jpeg_gen_s=round(gen_s, 1),
+        jpegs=batch * steps + batch, **rec)
+
+
+# -- four chips --------------------------------------------------------------
+
+def _fit(argv: list[str], mesh=None):
+    """Trainer + fit (what ``trainer.run`` does), keeping the Trainer so
+    its placed state can be inspected."""
+    from tpudist.trainer import Trainer
+    t = Trainer(_cfg(argv), mesh=mesh)
+    t.fit()
+    _drop_checkpoints(t.cfg.outpath)
+    return t
+
+
+def _devices_of(tree) -> set:
+    import jax
+    devs = set()
+    for leaf in jax.tree_util.tree_leaves(tree):
+        devs |= {s.device for s in leaf.addressable_shards}
+    return devs
+
+
+def _census(outpath: str) -> dict:
+    ev = next(e for e in _events(outpath)
+              if e["type"] == "compile" and e.get("phase") == "cost_analysis")
+    return {k: v for k, v in ev.items()
+            if k.startswith(("all_reduce", "collective"))}
+
+
+def phase_multichip(out: str, seed: int, compiles: CompileLog) -> None:
+    import jax
+    import numpy as np
+    from tpudist.dist import make_mesh, shard_host_batch
+    devs = jax.devices()
+    batch, steps = 512, 4
+
+    def dp_argv(name):
+        return ["--synthetic", *MODEL, "--sync_batchnorm", "-b", str(batch), "--synthetic-size", str(batch * steps),
+                "--epochs", "1", "--seed", str(seed), "-p", "1", "-j", "8",
+                "--telemetry", "--outpath", os.path.join(out, name),
+                "--overwrite", "delete"]
+
+    # (a) DP + SyncBN over four chips vs the same global batch on one.
+    t4 = _fit(dp_argv("dp4"))
+    assert t4.mesh.devices.size == 4 and not t4.uses_gspmd_path
+    on = _devices_of(t4.state.params)
+    assert on == set(devs), f"params live on {on}, not all of {devs}"
+    host = (np.zeros((batch, 8, 8, 3), np.float32),
+            np.zeros((batch,), np.int32))
+    for arr in shard_host_batch(t4.mesh, host):
+        shards = arr.addressable_shards
+        assert {s.device for s in shards} == set(devs)
+        assert all(s.data.shape[0] == batch // 4 for s in shards)
+    census4 = _census(os.path.join(out, "dp4"))
+    assert census4.get("all_reduce_count", 0) > 0, census4
+    rec4 = _check_run(os.path.join(out, "dp4"), compiles, first_epoch=0,
+                      n_epochs=1, steps_per_epoch=steps)
+    _fit(dp_argv("dp1"), mesh=make_mesh((1,), ("data",), devs[:1]))
+    rec1 = _check_run(os.path.join(out, "dp1"), compiles, first_epoch=0,
+                      n_epochs=1, steps_per_epoch=steps)
+    l4 = rec4["per_step_loss"] + [rec4["train_loss_by_epoch"][0]]
+    l1 = rec1["per_step_loss"] + [rec1["train_loss_by_epoch"][0]]
+    # bf16 tolerance: 8 mantissa bits through an 18-layer forward; the two
+    # programs differ only in reduction order (SyncBN's pmean of shard
+    # statistics vs one batch-512 statistic, gradient all-reduce vs none).
+    assert len(l4) == len(l1) == steps and np.allclose(l4, l1, rtol=2e-2), \
+        (l4, l1)
+    say("multichip_dp", ok=True, global_batch=batch, sync_batchnorm=True,
+        loss_4chip=l4, loss_1chip=l1,
+        max_rel_diff=float(np.max(np.abs(np.subtract(l4, l1))
+                                  / np.abs(l1))),
+        param_devices=len(on), census=census4,
+        compiled_step_hbm_gb_4chip=rec4["compiled_step_hbm_gb"],
+        compiled_step_hbm_gb_1chip=rec1["compiled_step_hbm_gb"])
+
+    # (b) one dp x tp 2x2 GSPMD step of ViT-B/16.
+    name = os.path.join(out, "vit_dp_tp")
+    tv = _fit(["--synthetic", *VIT, "-b", "128",
+               "--synthetic-size", "256", "--epochs", "1",
+               "--mesh-shape", "2,2", "--mesh-axes", "data,model",
+               "--seed", str(seed), "-p", "1", "-j", "8", "--telemetry",
+               "--outpath", name, "--overwrite", "delete"])
+    assert tv.uses_gspmd_path and dict(tv.mesh.shape) == {"data": 2,
+                                                          "model": 2}
+    assert _devices_of(tv.state.params) == set(devs)
+    cut = [leaf for leaf in jax.tree_util.tree_leaves(tv.state.params)
+           if "model" in str(leaf.sharding.spec)]
+    assert cut, "no parameter is sharded over the model axis"
+    census = _census(name)
+    assert census.get("collective_ops", 0) > 0, census
+    recv = _check_run(name, compiles, first_epoch=0, n_epochs=1,
+                      steps_per_epoch=2)
+    say("multichip_vit_dp_tp", ok=True, mesh={"data": 2, "model": 2},
+        global_batch=128, tp_sharded_params=len(cut), census=census,
+        dispatch=_dispatch_lines(_events(name)), **recv)
+
+
+# -- driver ----------------------------------------------------------------------
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--multichip", action="store_true",
+                    help="run ONLY the four-chip paths (needs 4 chips)")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--out", default=os.path.join("chiprun_out",
+                                                  "chip_smoke"))
+    args = ap.parse_args()
+    if not __debug__:
+        raise SystemExit("chip_smoke.py checks with assert statements: "
+                         "do not run it under python -O")
+
+    dev = phase_device(4 if args.multichip else None)
+    os.makedirs(args.out, exist_ok=True)
+    compiles = CompileLog()
+    if args.multichip:
+        phase_multichip(args.out, args.seed, compiles)
+    else:
+        # Trainer first: peak_bytes_in_use is a process-wide high-water
+        # mark, and the kernel phase's 112x112x64 operands would own it.
+        phase_train(args.out, args.seed, dev["count"], compiles)
+        phase_input(args.out, args.seed, dev["count"], compiles)
+        phase_kernels(args.seed)
+    sys.stdout.flush()
+    print(json.dumps({"ok": True, "device": dev}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -18,7 +18,8 @@
 # jax.distributed.initialize when flags are omitted.
 
 set -euo pipefail
-# Pre-build the native data-transform kernels so the first training batch
-# never pays a compile (the import path itself never builds — it only loads).
-make -s -C "$(dirname "$0")/../native" || echo "native build failed; PIL fallback" >&2
+# Build the native data-transform kernels before jax starts (the import path
+# itself never builds — it only loads). A failed build stops the launch:
+# training on the PIL path is a different input pipeline, not a detail.
+make -s -C "$(dirname "$0")/../native"
 exec python -m tpudist "$@"

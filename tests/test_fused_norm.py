@@ -453,7 +453,7 @@ def test_trainer_forced_on_reports_actual_sites(tmp_path, monkeypatch):
         # simulated via the recording hook) reports no_sites, not pallas.
         monkeypatch.setattr(
             Trainer, "_record_fused_norm_requests",
-            lambda self, ndm: (set(), None))
+            lambda self, ndm: set())
         t = Trainer(_cfg(tmp_path / "b"), writer=None)
         dec = t.fused_norm_decision
         assert dec["kernel"] == "xla" and dec["source"] == "no_sites"
@@ -556,7 +556,7 @@ def test_shard_local_workload_is_local_inside_manual_regions():
     the ambient mesh context still entered (the GSPMD builders' set_mesh
     wraps calls, and a manual region can nest inside), the bound axes
     must NOT divide a second time and the wrapper must not try to rebind
-    them (ambient_auto_axes subtracts manual axes; _axis_is_bound)."""
+    them (ambient_auto_axes only returns Auto axes)."""
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh42()

@@ -359,7 +359,7 @@ def test_regress_passes_unchanged_and_flags_20pct_slowdown():
     assert analyze_history(hist + _rows(1, value=920.0))["status"] == "pass"
 
 
-def test_regress_grouping_min_history_and_stale(tmp_path):
+def test_regress_grouping_min_history_and_unparseable(tmp_path):
     # a different workload's rows never gate this one
     other = _rows(5, value=10.0, metric="vit_s_224_1chip")
     v = analyze_history(other + _rows(1, value=800.0))
@@ -376,12 +376,11 @@ def test_regress_grouping_min_history_and_stale(tmp_path):
     # median over the window ignores one noisy historical row
     hist = _rows(4) + _rows(1, value=5000.0)
     assert analyze_history(hist + _rows(1, value=980.0))["status"] == "pass"
-    # stale/provisional echoes are filtered at load time
+    # unparseable lines are skipped at load time
     h = tmp_path / "hist.jsonl"
     with open(h, "w") as f:
         for r in _rows(3):
             f.write(json.dumps(r) + "\n")
-        f.write(json.dumps(dict(_rows(1, value=1.0)[0], stale=True)) + "\n")
         f.write("not json\n")
     rows = load_history(str(h))
     assert len(rows) == 3

@@ -282,7 +282,7 @@ def test_manifest_validation_rejects(tmp_path, stages, err):
 
 def test_repo_manifest_is_valid():
     """The committed matrix must always pass its own arm-time validation
-    (what benchmarks/tpu_watch.sh runs before arming)."""
+    (what an unattended run checks first)."""
     man = perfci.load_manifest(perfci.DEFAULT_MANIFEST)
     names = [st["name"] for st in man["stages"]]
     assert "chaos" in names and "parity1000" in names

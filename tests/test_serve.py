@@ -38,6 +38,7 @@ from tpudist import telemetry as telemetry_lib
 from tpudist.serve.batching import (ContinuousBatcher, open_loop_load,
                                     pad_to_bucket, parse_buckets,
                                     pick_bucket)
+from tpudist.serve import cache as cache_lib
 from tpudist.serve.cache import cache_state, resolve_cache_dir
 
 pytestmark = pytest.mark.serve
@@ -75,12 +76,62 @@ def test_pick_bucket_and_padding():
 
 # -- compile-cache state resolution ------------------------------------------
 
-def test_cache_dir_resolution_and_state(tmp_path, monkeypatch):
+@pytest.fixture()
+def jax_cache_config():
+    """Snapshot/restore jax's process-global cache settings around a test
+    that re-points them."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    old_dir = jax.config.jax_compilation_cache_dir
+    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    yield
+    jax.config.update("jax_compilation_cache_dir", old_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", old_min)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("case", ["env_set", "env_unset", "flag"])
+def test_cache_resolver(case, tmp_path, monkeypatch, jax_cache_config):
+    """The one resolver (serve/cache.py): JAX_COMPILATION_CACHE_DIR set →
+    jax's own reading is the cache and no jax setting is touched; unset →
+    the fixed in-checkout path; the flag/TPUDIST env only when unset."""
+    import jax
+    # Fixed path inside the checkout: no temp dir, no pid/uid/time key.
+    assert cache_lib.DEFAULT_CACHE_DIR == os.path.join(REPO, ".jax_cache")
+    default = str(tmp_path / ".jax_cache")
+    monkeypatch.setattr(cache_lib, "DEFAULT_CACHE_DIR", default)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    flag, env = str(tmp_path / "flag"), str(tmp_path / "env")
+    if case == "env_set":
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        monkeypatch.setenv("TPUDIST_COMPILE_CACHE", env)
+        notes = []
+        assert cache_lib.configure_compile_cache(
+            flag, log=notes.append) == (outside, "cold")
+        assert updates == []                 # jax reads the variable itself
+        assert len(notes) == 1 and "ignoring" in notes[0]
+        return
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("TPUDIST_COMPILE_CACHE", raising=False)
-    assert resolve_cache_dir("") == ""
-    monkeypatch.setenv("TPUDIST_COMPILE_CACHE", str(tmp_path / "env"))
-    assert resolve_cache_dir("") == str(tmp_path / "env")
-    assert resolve_cache_dir("/explicit") == "/explicit"   # flag wins
+    if case == "env_unset":
+        assert resolve_cache_dir() == default
+        assert cache_lib.configure_compile_cache() == (default, "cold")
+        assert jax.config.jax_compilation_cache_dir == default
+        return
+    monkeypatch.setenv("TPUDIST_COMPILE_CACHE", env)
+    assert resolve_cache_dir() == env
+    assert resolve_cache_dir(flag) == flag                 # flag wins
+    assert cache_lib.configure_compile_cache(flag) == (flag, "cold")
+    assert "jax_compilation_cache_dir" in updates
+    assert jax.config.jax_compilation_cache_dir == flag
+
+
+def test_cache_state(tmp_path):
     d = tmp_path / "cache"
     assert cache_state(str(d)) == "cold"                   # absent dir
     d.mkdir()
@@ -409,7 +460,8 @@ def test_zero_recompile_mixed_stream(tmp_path, tiny_serve_parts):
     assert a["run_end"]["productive_s"] > 0
 
 
-def test_aot_warm_vs_cold_persistent_cache(tmp_path):
+def test_aot_warm_vs_cold_persistent_cache(tmp_path, monkeypatch,
+                                           jax_cache_config):
     """ISSUE 14 acceptance: against one fresh cache dir, a second
     engine's AOT XLA-compile slice is ≥3x faster than the first's —
     the measured cold-start kill. (The compile slice, not the total:
@@ -420,42 +472,33 @@ def test_aot_warm_vs_cold_persistent_cache(tmp_path):
     the "cold" side here is pure XLA compile — smaller numerator, same
     qualitative claim; standalone-vs-in-suite was a reproducible ~4.4x
     squeeze at clean PR 14 HEAD on this box.)"""
-    import jax
     from tpudist.serve.cache import configure_compile_cache
     from tpudist.serve.engine import ServeEngine
     from tpudist.serve.export import load_serve_state
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+    # The suite's own cache is placed from outside (conftest exports
+    # JAX_COMPILATION_CACHE_DIR); this test needs a fresh dir of its own,
+    # so it runs the env-unset branch. jax_cache_config re-binds the
+    # suite's cache afterwards.
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache_dir = str(tmp_path / "xla_cache")
-    try:
-        assert configure_compile_cache(cache_dir) == "cold"
-        model, variables = load_serve_state(
-            "vgg16", num_classes=8, image_size=64, max_batch=4)
-        cold = ServeEngine(model, variables, image_size=64,
-                           buckets=(1, 2, 4), cache="cold")
-        assert os.listdir(cache_dir), "cache dir stayed empty after AOT"
-        assert configure_compile_cache(cache_dir) == "warm"
-        # min-of-3 warm passes: CPU contention can only INFLATE a
-        # cache-hit measurement, so the minimum is the sound estimator
-        # (the cold side needs no such care — noise there only widens
-        # the ratio).
-        warms = [ServeEngine(model, variables, image_size=64,
-                             buckets=(1, 2, 4), cache="warm")
-                 for _ in range(3)]
-        warm_s = min(w.aot_compile_s for w in warms)
-        assert cold.aot_compile_s >= 3.0 * warm_s, \
-            (cold.aot_compile_s, warm_s)
-        assert warms[0].compiled_buckets() == (1, 2, 4)
-    finally:
-        # Re-bind the suite's own cache (configure resets jax's
-        # once-per-process cache object, so later tests don't keep
-        # writing into this tmp dir).
-        if old_dir:
-            configure_compile_cache(old_dir)
-        else:
-            jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          old_min)
+    assert configure_compile_cache(cache_dir) == (cache_dir, "cold")
+    model, variables = load_serve_state(
+        "vgg16", num_classes=8, image_size=64, max_batch=4)
+    cold = ServeEngine(model, variables, image_size=64,
+                       buckets=(1, 2, 4), cache="cold")
+    assert os.listdir(cache_dir), "cache dir stayed empty after AOT"
+    assert configure_compile_cache(cache_dir) == (cache_dir, "warm")
+    # min-of-3 warm passes: CPU contention can only INFLATE a
+    # cache-hit measurement, so the minimum is the sound estimator
+    # (the cold side needs no such care — noise there only widens
+    # the ratio).
+    warms = [ServeEngine(model, variables, image_size=64,
+                         buckets=(1, 2, 4), cache="warm")
+             for _ in range(3)]
+    warm_s = min(w.aot_compile_s for w in warms)
+    assert cold.aot_compile_s >= 3.0 * warm_s, \
+        (cold.aot_compile_s, warm_s)
+    assert warms[0].compiled_buckets() == (1, 2, 4)
 
 
 # -- bench_serve: curve artifact + history series ----------------------------

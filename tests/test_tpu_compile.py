@@ -1,0 +1,220 @@
+"""Compiles for the chip, without the chip.
+
+The TPU compiler installed beside jax compiles for a *described* v5e
+topology — no device attached, nothing runs — and raises exactly what the
+chip's compiler would raise. Interpret mode checks none of this: it never
+looks at Mosaic's tiling rules, its supported ops or VMEM limits, which is
+how a fused-BN backward that 21 interpret-mode tests passed was refused at
+every resnet18 width on the real target (fused_norm KERNEL_REV 2).
+
+- the two main-path Pallas kernels at real widths, forward and
+  forward+backward (tier-1, well under a second each);
+- the whole train-step programs the chip smoke runs (``slow``): resnet18
+  on one and on four described chips, ViT-B/16 with flash on one chip and
+  dp x tp over the 2x2.
+
+Model code that asks ``jax.default_backend()`` (``interpret=None`` in the
+kernels) would take its CPU branch here, so the whole-step tests steer it
+with a monkeypatch — in the test, not through an option of the program.
+A compile that passes is not a chip run: ``chip_smoke.py`` is that.
+"""
+
+from __future__ import annotations
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+# libtpu lets one process per host load it (a lockfile under /tmp) — the
+# rule that gives a chip to one process. Describing a topology attaches
+# nothing, and under pytest-xdist several workers reach this module at
+# once: without this every worker but the first would skip.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
+                          SingleDeviceSharding)
+
+# resnet18 @224, per-chip batch 128: (spatial edge, channels) of every BN
+# epilogue in the model — chip_smoke.py runs the same widths on the chip.
+BATCH = 128
+BN_WIDTHS = ((112, 64), (56, 64), (28, 128), (14, 256), (7, 512))
+FLASH_SHAPES = ((128, 197, 12, 64),      # ViT-B/16 @224
+                (8, 2048, 12, 64))       # exact-tiling long sequence
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu / unknown topology name
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without the chip (the next run would warn and
+    recompile), so the cache is off around this module."""
+    from jax.experimental.compilation_cache import compilation_cache
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    compilation_cache.reset_cache()
+
+
+def _bn_fn(residual: bool, bwd: bool):
+    from tpudist.ops.pallas.fused_norm import fused_bn_act
+
+    def f(x, scale, bias, mean, var, res=None):
+        return fused_bn_act(x, scale, bias, mean, var, residual=res,
+                            interpret=False).astype(jnp.float32).sum()
+
+    if not bwd:
+        return f
+    return jax.grad(f, argnums=(0, 1, 2, 3, 4) + ((5,) if residual else ()))
+
+
+def _flash_fn(bwd: bool):
+    from tpudist.ops.pallas.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return flash_attention(q, k, v,
+                               interpret=False).astype(jnp.float32).sum()
+
+    return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
+
+
+_KERNEL_CASES = (
+    [pytest.param(("bn", hw, c, residual), bwd,
+                  id=f"bn_{hw}x{hw}x{c}_{'res' if residual else 'plain'}_"
+                     f"{'fwdbwd' if bwd else 'fwd'}")
+     for hw, c in BN_WIDTHS for residual in (False, True)
+     for bwd in (False, True)]
+    + [pytest.param(("flash",) + shape, bwd,
+                    id=f"flash_b{shape[0]}_t{shape[1]}_"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for shape in FLASH_SHAPES for bwd in (False, True)])
+
+
+@pytest.mark.parametrize("case,bwd", _KERNEL_CASES)
+def test_kernel_compiles_for_v5e(topo, case, bwd):
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    if case[0] == "bn":
+        _, hw, c, residual = case
+        act = S((BATCH * hw * hw, c), jnp.bfloat16)
+        args = [act] + [S((c,), jnp.float32)] * 4 + ([act] if residual
+                                                     else [])
+        fn = _bn_fn(residual, bwd)
+    else:
+        args = [S(case[1:], jnp.bfloat16)] * 3
+        fn = _flash_fn(bwd)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- whole train steps (slow: ~15-30 s of TPU compiler each) -----------------
+
+def _abstract_state(model, cfg, mesh, specs_fn=None):
+    from tpudist.train import create_train_state
+    state = jax.eval_shape(
+        lambda: create_train_state(jax.random.PRNGKey(0), model, cfg))
+    specs = (specs_fn(state) if specs_fn is not None
+             else jax.tree_util.tree_map(lambda _: P(), state))
+    return jax.tree_util.tree_map(
+        lambda s, sp: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, sp)),
+        state, specs)
+
+
+def _compile_step(monkeypatch, step, state, cfg, mesh):
+    images = jax.ShapeDtypeStruct(
+        (cfg.batch_size, cfg.image_size, cfg.image_size, 3), jnp.float32,
+        sharding=NamedSharding(mesh, P("data")))
+    labels = jax.ShapeDtypeStruct((cfg.batch_size,), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    lr = jax.ShapeDtypeStruct((), jnp.float32,
+                              sharding=NamedSharding(mesh, P()))
+    # The kernels resolve interpret mode from the backend at trace time.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return step.lower(state, images, labels, lr).compile()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("n_dev,fused,sync_bn", [
+    (1, "off", False),      # the program chip_smoke's train phase may run
+    (1, "on", False),       # ... with every BN epilogue on the Pallas kernel
+    (4, "off", True),       # chip_smoke --multichip: DP + SyncBN
+], ids=["1chip_xla", "1chip_fused", "4chip_syncbn"])
+def test_resnet18_step_compiles_for_v5e(topo, monkeypatch, n_dev, fused,
+                                        sync_bn):
+    from tpudist.config import Config
+    from tpudist.models import create_model
+    from tpudist.ops import norm_dispatch
+    from tpudist.train import compute_dtype, make_train_step
+    mesh = Mesh(np.asarray(topo.devices[:n_dev]), ("data",))
+    cfg = Config(arch="resnet18", num_classes=1000, image_size=224,
+                 batch_size=BATCH * n_dev, use_amp=True,
+                 sync_batchnorm=sync_bn, seed=0).finalize(n_dev)
+    model = create_model("resnet18", num_classes=1000,
+                         dtype=compute_dtype(cfg), sync_batchnorm=sync_bn,
+                         bn_axis_name="data")
+    norm_dispatch.set_mode(fused)
+    try:
+        compiled = _compile_step(
+            monkeypatch, make_train_step(mesh, model, cfg),
+            _abstract_state(model, cfg, mesh), cfg, mesh)
+    finally:
+        norm_dispatch.set_mode(None)
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (fused == "on")
+    assert ("all-reduce" in text) == (n_dev > 1)
+    # Fits one v5e chip (16 GB) with room for the prefetch double buffer.
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes + ma.argument_size_in_bytes < 8 * 2**30
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("tp", [False, True], ids=["1chip", "dp2_tp2"])
+def test_vit_b16_flash_step_compiles_for_v5e(topo, monkeypatch, tp):
+    from tpudist.config import Config
+    from tpudist.models import create_model
+    from tpudist.train import compute_dtype, make_train_step
+    model_kw = dict(num_classes=1000, flash=True)
+    if tp:
+        from tpudist.parallel import make_gspmd_train_step, plane
+        mesh = Mesh(np.asarray(topo.devices).reshape(2, 2),
+                    ("data", "model"))
+        cfg = Config(arch="vit_b_16", num_classes=1000, image_size=224,
+                     batch_size=128, use_amp=True, seed=0,
+                     mesh_shape=[2, 2],
+                     mesh_axes=["data", "model"]).finalize(4)
+        model = create_model("vit_b_16", dtype=compute_dtype(cfg),
+                             **model_kw)
+        rules = plane.rules_for_mesh("vit_b_16", mesh)
+        step = make_gspmd_train_step(mesh, model, cfg, rules,
+                                     data_axis="data")
+        state = _abstract_state(
+            model, cfg, mesh,
+            specs_fn=lambda s: plane.state_specs(mesh, s, rules))
+    else:
+        mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+        cfg = Config(arch="vit_b_16", num_classes=1000, image_size=224,
+                     batch_size=64, use_amp=True, seed=0).finalize(1)
+        model = create_model("vit_b_16", dtype=compute_dtype(cfg),
+                             **model_kw)
+        step = make_train_step(mesh, model, cfg)
+        state = _abstract_state(model, cfg, mesh)
+    text = _compile_step(monkeypatch, step, state, cfg, mesh).as_text()
+    assert "tpu_custom_call" in text           # flash really is in the step
+    assert ("all-reduce" in text) == tp

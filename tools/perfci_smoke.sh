@@ -2,8 +2,8 @@
 # Perf-console smoke: one command proves the unattended perf-CI chain on CPU.
 #
 #   1. the COMMITTED matrix (benchmarks/perfci.json) must validate and plan
-#      under `tpudist-perfci --dry-run` — what tpu_watch.sh checks at arm
-#      time;
+#      under `tpudist-perfci --dry-run` — the arm-time check an
+#      unattended run makes;
 #   2. a tiny CPU matrix runs end to end: a row-producing stage appends to
 #      a scratch history through regress.append_history, a platform-guarded
 #      stage is skipped, the report/exit contract is 0;

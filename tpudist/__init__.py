@@ -34,7 +34,5 @@ __version__ = "0.1.0"
 
 # NOTE: keep this module jax-free — the launcher/supervisor process
 # (tpudist.launch) imports the package but must not pay a jax import (or
-# die on a broken jax install) just to supervise ranks. The jax-facing
-# modules (dist/train/parallel/models/ops) each import tpudist._jaxshim,
-# which backfills the jax>=0.8 surface on older installs.
+# die on a broken jax install) just to supervise ranks.
 from tpudist.config import Config  # noqa: F401

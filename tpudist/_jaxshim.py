@@ -1,147 +1,23 @@
-"""Version shim: expose the jax>=0.8 surface this package codes against on
-older jax installs (no new deps — ROADMAP environments pin different jax
-versions and the container cannot pip install).
-
-The one load-bearing gap today is top-level ``jax.shard_map`` (jax 0.8
-promoted ``jax.experimental.shard_map.shard_map`` and renamed two kwargs:
-``check_rep`` → ``check_vma``, and the *auto* axis set became its complement
-``axis_names`` — the axes the body IS manual over). Everything else this
-repo uses (``jax.distributed.initialize(initialization_timeout=...)``,
-``NamedSharding``, ``multihost_utils``) exists back to 0.4.x.
-
-Imported for its side effect from ``tpudist/__init__.py`` so every
-``from jax import shard_map`` / ``jax.shard_map(...)`` site in the package
-and its tests works unchanged on either version. On jax>=0.8 this module is
-a no-op.
-"""
+"""Ambient-mesh helper for the trace-time kernel wrappers
+(``flash_attention_spmd``, ``fused_bn_act_spmd``,
+``norm_dispatch.epilogue_shard_axes``)."""
 
 from __future__ import annotations
 
 import jax
 
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True,
-                  axis_names=None):
-        kwargs = {}
-        if axis_names is not None:
-            # New API names the MANUAL axes; the old one names the AUTO
-            # (complement) set.
-            kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-        return _experimental_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs,
-                                       check_rep=check_vma, **kwargs)
-
-    jax.shard_map = shard_map
-
-if not hasattr(jax.lax, "axis_size"):
-    # jax<0.6 spells "static size of a bound axis" as core.axis_frame(name)
-    # (an int on 0.4.x; earlier versions return a frame with .size).
-    def _axis_size(axis_name):
-        frame = jax.core.axis_frame(axis_name)
-        return getattr(frame, "size", frame)
-
-    jax.lax.axis_size = _axis_size
-
-if not hasattr(jax.sharding, "set_mesh"):
-    # jax<0.8 has no jax.sharding.set_mesh; the GSPMD step builders use it
-    # to provide the ambient mesh for trace-time consumers (the Pallas
-    # flash kernel's nested manual region). On these versions entering the
-    # Mesh itself is the ambient-mesh context manager.
-    jax.sharding.set_mesh = lambda mesh: mesh
-
-if not hasattr(jax.sharding, "AxisType"):
-    # jax<0.8 spells mesh axis kinds jax._src.mesh.AxisTypes with different
-    # members (Auto/User/Collective vs the new Auto/Explicit/Manual). The
-    # shim only needs identity semantics for `t == AxisType.Auto` checks,
-    # so expose a tiny enum-alike with the one member the package compares
-    # against.
-    class _AxisType:
-        class Auto:
-            pass
-
-        class Explicit:
-            pass
-
-        class Manual:
-            pass
-
-    jax.sharding.AxisType = _AxisType
-
-
-class _AbstractMeshShim:
-    """jax<0.8 stand-in for ``jax.sharding.get_abstract_mesh()``'s result:
-    wraps the thread-resources physical mesh (the ``with mesh:`` context
-    that ``set_mesh`` resolves to on these versions) and reports every axis
-    as Auto — on old jax the ambient-context mesh IS the partitioner-managed
-    (GSPMD) mesh; manual (shard_map-bound) axes never appear here because
-    they live in the axis environment, not the context mesh (see
-    ``ambient_auto_axes``, which subtracts them). ``physical_mesh`` is the
-    real ``Mesh`` a nested ``shard_map`` needs."""
-
-    def __init__(self, mesh):
-        self.physical_mesh = mesh
-
-    @property
-    def empty(self):
-        return self.physical_mesh.empty
-
-    @property
-    def axis_names(self):
-        return self.physical_mesh.axis_names
-
-    @property
-    def shape(self):
-        return self.physical_mesh.shape
-
-    @property
-    def axis_types(self):
-        return (jax.sharding.AxisType.Auto,) * len(
-            self.physical_mesh.axis_names)
-
-
-if not hasattr(jax.sharding, "get_abstract_mesh"):
-    # jax<0.8: the ambient mesh is the entered-Mesh thread resource (what
-    # the shimmed set_mesh provides). Exposing it under the jax>=0.8 name
-    # lets flash_attention_spmd / fused_bn_act_spmd compose with the GSPMD
-    # path on old jax instead of standing down to gather-and-replicate —
-    # the off-TPU environment-reason failure of
-    # test_gspmd_step_composes_with_flash at clean HEAD since PR 5.
-    def _get_abstract_mesh():
-        from jax._src import mesh as _mesh_lib
-        return _AbstractMeshShim(_mesh_lib.thread_resources.env.physical_mesh)
-
-    jax.sharding.get_abstract_mesh = _get_abstract_mesh
-
-
-def _axis_is_bound(name: str) -> bool:
-    """True when ``name`` is currently bound as a MANUAL axis (we are
-    tracing inside a shard_map/pmap body over it)."""
-    try:
-        jax.lax.axis_size(name)
-        return True
-    except Exception:
-        return False
-
 
 def ambient_auto_axes(axes=("data", "model")):
-    """``(mesh, auto)``: the ambient mesh usable for a nested manual
-    ``shard_map`` and the subset of ``axes`` that are partitioner-managed
-    (Auto) in it — i.e. the axes a trace-time kernel wrapper may claim.
-    ``mesh`` is a concrete ``Mesh`` on jax<0.8 and the abstract mesh on
-    jax>=0.8 (both accepted by ``jax.shard_map``). Returns
-    ``(None, frozenset())`` when there is no ambient mesh (eager, plain
-    jit) or every candidate axis is already manual (inside a shard_map
-    body — the DP/SP/EP/PP step paths), so callers degrade to the plain
-    kernel exactly where wrapping would be wrong."""
+    """``(mesh, auto)``: the ambient abstract mesh usable for a nested
+    manual ``shard_map`` and the subset of ``axes`` that are
+    partitioner-managed (Auto) in it — i.e. the axes a trace-time kernel
+    wrapper may claim. Returns ``(None, frozenset())`` when there is no
+    ambient mesh (eager, plain jit); inside a shard_map body (the
+    DP/SP/EP/PP step paths) the bound axes read as Manual, so callers
+    degrade to the plain kernel exactly where wrapping would be wrong."""
     am = jax.sharding.get_abstract_mesh()
     if am.empty:
         return None, frozenset()
-    if isinstance(am, _AbstractMeshShim):
-        auto = frozenset(a for a in am.axis_names
-                         if a in axes and not _axis_is_bound(a))
-        return am.physical_mesh, auto
     auto = frozenset(
         a for a, t in zip(am.axis_names, am.axis_types)
         if t == jax.sharding.AxisType.Auto and a in axes)

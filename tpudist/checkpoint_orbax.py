@@ -29,7 +29,10 @@ def _digest_path(ckpt_dir: str) -> str:
 
 
 def _write_digest(ckpt_dir: str, digest: str) -> None:
-    tmp = _digest_path(ckpt_dir) + ".tmp"
+    # Every rank of a collective save writes this (identical) sidecar: a
+    # shared tmp name lets one rank's replace() take the file another is
+    # about to replace.
+    tmp = f"{_digest_path(ckpt_dir)}.tmp.{os.getpid()}"
     with open(tmp, "w") as f:
         f.write(f"{digest}  {os.path.basename(os.path.normpath(ckpt_dir))}\n")
     os.replace(tmp, _digest_path(ckpt_dir))

@@ -114,13 +114,15 @@ class Config:
                                         # step; booked as the overlapped
                                         # drain_ovl bucket, like prefetch
     compile_cache: str = ""             # persistent XLA compilation cache
-                                        # dir (env TPUDIST_COMPILE_CACHE):
-                                        # an elastic restart/reform re-pays
-                                        # cache-hit seconds instead of the
-                                        # full 25-45s compile; provenance
+                                        # dir (env TPUDIST_COMPILE_CACHE;
+                                        # default <checkout>/.jax_cache;
+                                        # ignored when
+                                        # JAX_COMPILATION_CACHE_DIR is
+                                        # set — serve/cache.py): a restart
+                                        # re-pays cache-hit seconds, not
+                                        # the full compile; provenance
                                         # (warm/cold) stamped on compile
-                                        # telemetry events. Shared with
-                                        # tpudist.serve (docs/SERVING.md)
+                                        # telemetry events
 
     # misc (reference -p/--print-freq, -e/--evaluate, --seed, --outpath)
     print_freq: int = 10
@@ -192,9 +194,9 @@ class Config:
     replica_check_freq: int = 0         # check replica consistency every N epochs
     stall_timeout: float = 0.0          # abort if no step completes in N sec (0 = off)
     require_platform: str = "any"       # refuse to run unless jax landed on
-                                        # this backend ("tpu"): unattended
-                                        # captures must not silently fall
-                                        # back to CPU when the plugin dies
+                                        # this backend ("tpu"): a run meant
+                                        # for the chip must not complete on
+                                        # the CPU
 
     # mesh (TPU-native; no reference equivalent — NCCL topology was implicit)
     mesh_shape: Sequence[int] | None = None   # default: (num_devices,)
@@ -514,11 +516,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--compile-cache", default=d.compile_cache,
                    dest="compile_cache", metavar="DIR",
                    help="persistent XLA compilation cache dir (env "
-                        "TPUDIST_COMPILE_CACHE): restarts, elastic "
-                        "reforms, and serving replicas pay cache-hit "
-                        "seconds instead of recompiling; warm/cold "
-                        "provenance lands on compile telemetry events. "
-                        "See docs/SERVING.md for format/invalidation")
+                        "TPUDIST_COMPILE_CACHE; default "
+                        "<checkout>/.jax_cache; ignored when "
+                        "JAX_COMPILATION_CACHE_DIR is set): restarts, "
+                        "elastic reforms, and serving replicas pay "
+                        "cache-hit seconds instead of recompiling; "
+                        "warm/cold provenance lands on compile telemetry "
+                        "events. See docs/SERVING.md")
     _bool_flag(p, "synthetic", d.synthetic, "use synthetic data")
     p.add_argument("--seed", default=d.seed, type=int, help="seed for initializing training")
     p.add_argument("--outpath", metavar="DIR", default=d.outpath, help="path to output")
@@ -601,8 +605,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--require-platform", default=d.require_platform,
                    dest="require_platform", choices=("any", "tpu", "cpu"),
                    help="refuse to run unless jax initialized on this "
-                        "backend (unattended on-chip captures must not "
-                        "silently fall back to CPU)")
+                        "backend (a run meant for the chip must not "
+                        "complete on the CPU)")
     p.add_argument("--overwrite", default=d.overwrite, choices=["prompt", "delete", "quit", "keep"], help="what to do if outpath exists (keep = reuse untouched, for elastic restarts)")
     p.add_argument("--num-classes", default=d.num_classes, type=int, dest="num_classes")
     p.add_argument("--image-size", default=d.image_size, type=int, dest="image_size")

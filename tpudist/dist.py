@@ -30,7 +30,6 @@ import os
 import time
 from typing import Sequence
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P

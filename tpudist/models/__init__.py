@@ -9,7 +9,6 @@ for argparse choices (``distributed.py:39-40``).
 
 from __future__ import annotations
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 
 from typing import Any, Callable, Dict
 

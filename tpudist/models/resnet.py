@@ -32,8 +32,7 @@ class _StemConvS2D(nn.Module):
     """The 7x7/stride-2 stem conv, computed via space-to-depth.
 
     A 3-channel 7x7 stem feeds the 128-lane MXU at ~2% input utilization —
-    the dominant MFU headroom in the roofline analysis
-    (benchmarks/results/README.md). The MLPerf-style fix: pack 2x2 pixel
+    the dominant MFU headroom in a roofline estimate. The MLPerf-style fix: pack 2x2 pixel
     blocks into channels (H,W,3 -> H/2,W/2,12) and run the mathematically
     identical 4x4/stride-1 conv there (output rows i of the original conv
     read input rows 2i-3..2i+3, i.e. pixel-blocks i-2..i+1 — four
@@ -149,12 +148,11 @@ class ResNet(nn.Module):
     sync_batchnorm: bool = False
     bn_axis_name: str = "data"
     remat: bool = False                       # jax.checkpoint each block
-    # Stem policy (VERDICT r4 weak #2): the DEFAULT must be a program that
-    # was actually measured on chip. Every persisted TPU record to date ran
-    # the direct 7x7/s2 conv; the s2d rewrite's entire purpose is MXU
-    # utilization, which only an on-chip A/B can confirm — so s2d stays an
-    # opt-in lever (bench.py --s2d, watcher stage `s2d`) until that A/B
-    # lands, at which point the winner becomes the default WITH its number.
+    # Stem policy: the DEFAULT is the direct 7x7/s2 conv — the program
+    # chip_smoke.py runs on the chip. The s2d rewrite's entire purpose is
+    # MXU utilization, which only an on-chip A/B can confirm — so s2d
+    # stays an opt-in lever (bench.py --s2d) until that A/B lands, at
+    # which point the winner becomes the default WITH its number.
     s2d_stem: bool = False                    # bench A/B lever; same params
 
     @nn.compact

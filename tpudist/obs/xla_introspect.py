@@ -146,8 +146,8 @@ def hlo_op_census(hlo_text: str) -> dict:
 
 
 # HLO op kind → coarse execution-unit category, for the summarize
-# time-attribution table (VERDICT r5 weak #4: MFU 0.429 with nothing naming
-# where the other 57% goes). Categories are chosen by which hardware
+# time-attribution table (an MFU figure alone names nothing about where the
+# rest of the time goes). Categories are chosen by which hardware
 # resource the op *occupies*: MXU (systolic matmuls), VPU elementwise,
 # reductions, pure data movement (layout/copy — zero arithmetic, pure
 # HBM/VMEM traffic), collectives (ICI/DCN), and control/bookkeeping ops

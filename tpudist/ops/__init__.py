@@ -1,5 +1,4 @@
 """Numerics that fold into the compiled step (reference C14 + loss math)."""
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 from tpudist.ops.metrics import accuracy            # noqa: F401
 from tpudist.ops.loss import cross_entropy_loss     # noqa: F401

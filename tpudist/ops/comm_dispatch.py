@@ -77,7 +77,6 @@ def build_measure_fns(n_grads: int, mesh, data_axis: str, chunk: int):
     definition, ONE timing harness)."""
     import numpy as np
 
-    from tpudist import _jaxshim  # noqa: F401
     import jax
     import jax.numpy as jnp
     from jax import shard_map

@@ -113,7 +113,7 @@ def epilogue_shard_axes(shape):
     which ambient Auto mesh axes cut an ``(..., C)`` epilogue activation:
     the batch (leading) dim over ``data`` and the channel (trailing) dim
     over ``model``, each only when the axis exists, is Auto
-    (partitioner-managed — inside a shard_map body both read as bound and
+    (partitioner-managed — inside a shard_map body both read as Manual and
     nothing cuts, see _jaxshim.ambient_auto_axes), has size > 1, and
     divides the dim. Shared by the dispatch key
     (``shard_local_workload``) and the kernel wrapper
@@ -138,8 +138,7 @@ def shard_local_workload(shape) -> tuple[int, int, bool]:
     Outside any ambient Auto mesh (eager, the shard_map DP path — where
     the traced shapes are already local) this is the plain
     ``(prod(shape[:-1]), shape[-1], False)``. Under a GSPMD trace (the
-    step builders' ``set_mesh`` ambient mesh, jax<0.8 via the _jaxshim
-    backfill) the batch dim divides by the ``data`` axis and the channel
+    step builders' ``set_mesh`` ambient mesh) the batch dim divides by the ``data`` axis and the channel
     dim by the ``model`` axis exactly as ``fused_bn_act_spmd`` will shard
     them (both read ``epilogue_shard_axes`` — one derivation, no drift),
     so the dispatch key that is recorded, measured, and looked up at

@@ -77,10 +77,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax<0.6 names it TPUCompilerParams (same fields we use).
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 _LANES = 128
 
@@ -216,10 +212,6 @@ def flash_attention_spmd(q: jax.Array, k: jax.Array, v: jax.Array,
     Everywhere else this is ``flash_attention`` unchanged: with no ambient
     mesh (eager, plain-jit single device) or inside an already-manual
     region (the shard_map DP/PP/SP step bodies) there is nothing to wrap.
-    On jax<0.8 the ambient mesh comes through the ``_jaxshim``
-    ``get_abstract_mesh`` backfill (the set_mesh context), so the nested
-    manual region works on every supported jax instead of standing down
-    to gather-and-replicate.
     """
     from jax.sharding import PartitionSpec as P
 

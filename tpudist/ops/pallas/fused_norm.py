@@ -16,8 +16,11 @@ directions — into ONE streaming pass each:
 - **backward**: one pass reads x (+residual) and dy and emits dx
   (+dresidual) AND the per-channel partial sums ``Σ g·x`` / ``Σ g``
   (g = dy masked by the recomputed relu sign), blocked over rows so each
-  grid program owns a disjoint (1, C) partial row — no cross-program
-  accumulation hazard. The (grid, C) partials reduce to vectors in XLA,
+  grid program owns a disjoint (8, C) partial tile — no cross-program
+  accumulation hazard. The tile is one sublane group because Mosaic only
+  lowers blocks whose last two dims are (8, 128)-aligned or the array's
+  own; folding the row block onto 8 sublanes is also pure VPU adds, no
+  cross-sublane reduce. The (grid·8, C) partials reduce to vectors in XLA,
   and autodiff maps them back through the a/b folding to dscale/dbias/
   dmean/dvar — so the FULL BatchNorm gradient (including the paths through
   the batch statistics) is exact without the kernel knowing BN exists.
@@ -47,16 +50,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax<0.6 names it TPUCompilerParams (same fields we use).
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 # Bumped whenever kernel math/scheduling changes: norm_dispatch keys its
 # cached pallas-vs-XLA verdicts on this, so a rebuilt kernel re-measures
 # instead of inheriting the old kernel's win/loss record.
-KERNEL_REV = 1
+#   rev 2: backward partial sums are (8, bc) sublane tiles — the (1, bc)
+#          partial rows of rev 1 were refused by the TPU lowering.
+KERNEL_REV = 2
 
 _LANES = 128
+_SUBLANES = 8
 # Target block footprint: ~512 KiB of fp32 per (bm, bc) tile keeps the
 # backward's ~6 live buffers + Pallas double-buffering inside VMEM.
 _BLOCK_BYTES = 512 * 1024
@@ -97,14 +99,21 @@ def _fwd_res_kernel(x_ref, r_ref, a_ref, b_ref, o_ref):
     o_ref[...] = jnp.maximum(pre, 0.0).astype(o_ref.dtype)
 
 
+def _fold_rows(t):
+    """(bm, bc) → (_SUBLANES, bc): row r lands on sublane r % 8 (bm is a
+    multiple of 8 by ``_blocks``), summed over the bm/8 sublane groups."""
+    bm, bc = t.shape
+    return jnp.sum(t.reshape(bm // _SUBLANES, _SUBLANES, bc), axis=0)
+
+
 def _bwd_kernel(x_ref, dy_ref, a_ref, b_ref, dx_ref, da_ref, db_ref):
     xf = x_ref[...].astype(jnp.float32)
     a = a_ref[...]
     pre = xf * a + b_ref[...]
     g = jnp.where(pre > 0.0, dy_ref[...].astype(jnp.float32), 0.0)
     dx_ref[...] = (g * a).astype(dx_ref.dtype)
-    da_ref[...] = jnp.sum(g * xf, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(g, axis=0, keepdims=True)
+    da_ref[...] = _fold_rows(g * xf)
+    db_ref[...] = _fold_rows(g)
 
 
 def _bwd_res_kernel(x_ref, r_ref, dy_ref, a_ref, b_ref, dx_ref, dr_ref,
@@ -116,11 +125,14 @@ def _bwd_res_kernel(x_ref, r_ref, dy_ref, a_ref, b_ref, dx_ref, dr_ref,
     # value that rounds across zero.
     q = (xf * a + b_ref[...]).astype(dr_ref.dtype)
     pre = q + r_ref[...].astype(dr_ref.dtype)
-    g = jnp.where(pre > 0.0, dy_ref[...].astype(jnp.float32), 0.0)
+    # Compare in f32 (exact widening, same mask): v5e's VPU has no bf16
+    # compare and Mosaic refuses the narrow cmpf outright.
+    g = jnp.where(pre.astype(jnp.float32) > 0.0,
+                  dy_ref[...].astype(jnp.float32), 0.0)
     dx_ref[...] = (g * a).astype(dx_ref.dtype)
     dr_ref[...] = g.astype(dr_ref.dtype)
-    da_ref[...] = jnp.sum(g * xf, axis=0, keepdims=True)
-    db_ref[...] = jnp.sum(g, axis=0, keepdims=True)
+    da_ref[...] = _fold_rows(g * xf)
+    db_ref[...] = _fold_rows(g)
 
 
 def _pad2(x, m_pad: int, c_pad: int):
@@ -139,7 +151,7 @@ def _tile_spec(bm, bc):
 
 
 def _part_spec(bc):
-    return pl.BlockSpec((1, bc), lambda im, ic: (im, ic))
+    return pl.BlockSpec((_SUBLANES, bc), lambda im, ic: (im, ic))
 
 
 def _fwd_call(x2, r2, a2, b2, out_dtype, interpret):
@@ -188,8 +200,8 @@ def _bwd_call(x2, r2, dy2, a2, b2, interpret):
             out_specs=[tile, part, part],
             out_shape=[
                 jax.ShapeDtypeStruct((m_pad, c_pad), x2.dtype),
-                jax.ShapeDtypeStruct((nm, c_pad), jnp.float32),
-                jax.ShapeDtypeStruct((nm, c_pad), jnp.float32),
+                jax.ShapeDtypeStruct((nm * _SUBLANES, c_pad), jnp.float32),
+                jax.ShapeDtypeStruct((nm * _SUBLANES, c_pad), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
@@ -206,15 +218,15 @@ def _bwd_call(x2, r2, dy2, a2, b2, interpret):
             out_shape=[
                 jax.ShapeDtypeStruct((m_pad, c_pad), x2.dtype),
                 jax.ShapeDtypeStruct((m_pad, c_pad), r2.dtype),
-                jax.ShapeDtypeStruct((nm, c_pad), jnp.float32),
-                jax.ShapeDtypeStruct((nm, c_pad), jnp.float32),
+                jax.ShapeDtypeStruct((nm * _SUBLANES, c_pad), jnp.float32),
+                jax.ShapeDtypeStruct((nm * _SUBLANES, c_pad), jnp.float32),
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(xp, rp, dyp, ap, bp)
         dr = dr[:m, :c]
-    # (grid_rows, C) partials → per-channel vectors; an O(nm·C) XLA reduce.
+    # (grid_rows·8, C) partials → per-channel vectors; an O(nm·C) XLA reduce.
     da = jnp.sum(da_p, axis=0)[:c]
     db = jnp.sum(db_p, axis=0)[:c]
     return dx[:m, :c], dr, da, db

@@ -5,7 +5,6 @@ holds the mesh/sharding machinery that expresses it — and the extra axes
 (sequence/context via ring attention, model) the TPU design keeps open.
 """
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 from tpudist.dist import (make_mesh, batch_sharding,            # noqa: F401
                           replicated_sharding, shard_host_batch)
 from tpudist.parallel.tensor_parallel import (                  # noqa: F401

@@ -37,7 +37,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Optional, Sequence
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 import jax
 from jax.sharding import Mesh
 

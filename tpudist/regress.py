@@ -13,17 +13,14 @@ bytes GREW (a step-builder change silently re-densifying a compressed
 exchange, or a sharding change widening a gather) — more than
 ``--threshold`` (default 10%) — the
 automated tripwire the ROADMAP's "as fast as the hardware allows" needs,
-instead of a human eyeballing BENCH_r* files across rounds.
+instead of a human eyeballing bench rows across rounds.
 
 Row identity is the row's ``metric`` name — it encodes arch, image size,
 precision, remat/s2d levers, AND the platform suffix (``..._1chip`` vs
-``..._8dev_cpu_fallback``), so a CPU-fallback bench can never gate against
+``..._8dev_cpu``), so a CPU bench can never gate against
 TPU history — PLUS ``per_device_batch``, which the metric name does NOT
 encode: a batch sweep (b=16 after b=128 history) must open its own series,
-not trip a false REGRESSION against the other batch's median. Rows stamped
-``stale``/``provisional`` (bench's re-emission path) are measurement
-*echoes*, not measurements — they are never appended by bench and are
-ignored here if present.
+not trip a false REGRESSION against the other batch's median.
 
 Median (not mean) over the trailing window: one noisy historical row must
 not move the baseline; an improvement simply raises future medians.
@@ -51,7 +48,7 @@ def history_path() -> str:
 
 
 def load_history(path: str) -> list[dict]:
-    """All parseable, non-stale rows, file order (= append order)."""
+    """All parseable rows, file order (= append order)."""
     rows: list[dict] = []
     try:
         with open(path) as f:
@@ -63,11 +60,8 @@ def load_history(path: str) -> list[dict]:
                     row = json.loads(line)
                 except ValueError:
                     continue
-                if not isinstance(row, dict) or row.get("stale") \
-                        or row.get("provisional"):
-                    continue
-                if row.get("metric") and isinstance(row.get("value"),
-                                                    (int, float)):
+                if isinstance(row, dict) and row.get("metric") \
+                        and isinstance(row.get("value"), (int, float)):
                     rows.append(row)
     except OSError:
         pass

@@ -6,10 +6,11 @@ fronts it with a continuous-batching request queue whose micro-batches are
 padded to a FIXED set of bucket shapes, so steady-state traffic never
 triggers an XLA recompile:
 
-- ``serve.cache``      — persistent XLA compilation cache config
-  (``--compile-cache`` / ``TPUDIST_COMPILE_CACHE``), shared with the
-  trainer: a scaled-up replica (or an elastic reform) pays cache-hit
-  seconds instead of the 25-45 s compile every bench row shows;
+- ``serve.cache``      — the one resolver for the persistent XLA
+  compilation cache (``JAX_COMPILATION_CACHE_DIR``, else
+  ``--compile-cache`` / ``TPUDIST_COMPILE_CACHE``, else
+  ``<checkout>/.jax_cache``), shared with the trainer: a scaled-up
+  replica (or an elastic reform) pays cache-hit seconds, not a compile;
 - ``serve.export``     — checkpoint → (model, variables) in eval mode
   (bf16 compute), with ``--flash`` resolved through the SAME
   measurement-honest dispatch client the trainer uses (train=False key);

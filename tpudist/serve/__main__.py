@@ -49,8 +49,10 @@ def build_parser() -> argparse.ArgumentParser:
                         "knob)")
     p.add_argument("--compile-cache", default="", dest="compile_cache",
                    help="persistent XLA compilation cache dir (env "
-                        "TPUDIST_COMPILE_CACHE): a warm replica AOT-starts "
-                        "in seconds instead of minutes")
+                        "TPUDIST_COMPILE_CACHE; default "
+                        "<checkout>/.jax_cache; ignored when "
+                        "JAX_COMPILATION_CACHE_DIR is set): a warm replica "
+                        "AOT-starts in seconds instead of minutes")
     p.add_argument("--flash", default="auto", choices=("auto", "on", "off"),
                    help="attention backend for vit archs, resolved through "
                         "the measurement-honest dispatch layer with the "
@@ -92,14 +94,13 @@ def main(argv=None) -> int:
     buckets = parse_buckets(args.buckets)
 
     # Cache config BEFORE any jax compilation.
-    from tpudist.serve.cache import configure_compile_cache, resolve_cache_dir
-    cache_dir = resolve_cache_dir(args.compile_cache)
-    cache = configure_compile_cache(cache_dir) if cache_dir else "off"
-
-    import jax
-
     def log(msg: str) -> None:
         print(msg, flush=True)
+
+    from tpudist.serve.cache import configure_compile_cache
+    _, cache = configure_compile_cache(args.compile_cache, log=log)
+
+    import jax
 
     telemetry = None
     metrics_server = None

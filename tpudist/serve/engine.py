@@ -32,7 +32,6 @@ from __future__ import annotations
 import time
 from typing import Optional, Sequence
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 import jax
 import numpy as np
 

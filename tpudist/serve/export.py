@@ -23,7 +23,6 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 import jax
 import jax.numpy as jnp
 

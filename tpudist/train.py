@@ -31,7 +31,6 @@ from __future__ import annotations
 from functools import partial
 from typing import Any, Callable, Sequence
 
-from tpudist import _jaxshim  # noqa: F401  (jax<0.8 surface backfill)
 import jax
 import jax.numpy as jnp
 import optax
