@@ -1,0 +1,62 @@
+"""By hand, on the chip: the control's readings at a cell's own size.
+
+    python benchmarks/chip/selftest/read_limits.py <config.json> <seed> [...]
+
+For each seed: the configuration's seeded weights and the `staged` mix's first
+batches, the plain float32 reference's numbers, and the same reference computed
+with the configuration's `control_quant` operands (fp8 for a bf16
+configuration) put in the program's place. Prints the control's gap for every
+number `correct` compares. The sound program's gaps are on the `correct-check`
+lines of every benchmark run; a limit goes between the two (PERF.md section 2).
+"""
+
+import gc
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+CHIP = os.path.dirname(HERE)
+sys.path[:0] = [CHIP, os.path.dirname(os.path.dirname(CHIP))]
+
+
+def main():
+    import jax
+    import numpy as np
+    from harness import check, traffic
+    config = json.load(open(sys.argv[1]))
+    model_cfg = {k: v for k, v in config.items()
+                 if isinstance(v, (int, float, str))}
+    ref = check.load_reference(CHIP, config["reference_module"])
+    staged = json.load(open(os.path.join(CHIP, "traffic", "staged.json")))
+    dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    n = int(config["compared_steps"])
+    init = jax.jit(lambda k: ref.init(k, model_cfg))
+    for seed in (int(s) % (2 ** 31 - 1) for s in sys.argv[2:]):
+        p0, s0 = init(jax.random.PRNGKey(seed))
+        src = traffic.make_source(
+            staged, seed=seed, batch=int(config["per_chip_batch"]),
+            image_size=int(config["image_size"]),
+            num_classes=int(config["num_classes"]), sharding=dev)
+        batches = [(np.asarray(i), np.asarray(l)) for i, l in src.first(n)]
+        src.close()
+        lr = float(config["window_lr"])
+        sound = check.reference_readings(ref, model_cfg, p0, s0, batches, lr)
+        names = {"first_grad": check.leaf_names(p0),
+                 "param_change": check.leaf_names(p0),
+                 "stats_change": check.leaf_names(s0)}
+        quant = config["control_quant"]
+        got = check.reference_readings(ref, model_cfg, p0, s0, batches, lr,
+                                       quant=quant)
+        ok, rows = check.compare(got, sound, config["correct_limits"], names)
+        for name, value, limit, passed, note in rows:
+            print(f"control seed {seed} quant {quant} {name}: value "
+                  f"{value:.6g} limit {limit:.6g} "
+                  f"{'passes' if passed else 'FAILS'} ({note})", flush=True)
+        print(f"control seed {seed} quant {quant} correct={ok}", flush=True)
+        del sound, got, batches, p0, s0
+        gc.collect()
+
+
+if __name__ == "__main__":
+    main()
