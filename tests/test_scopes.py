@@ -1,0 +1,271 @@
+"""The program names what it does (tpudist/obs/scopes.py): a `tpudist_*`
+scope on every device operation of the DP step, a `tpudist.*` span on every
+part of a trainer loop turn, `init.*` phases that sum to the constructor.
+
+CPU, tiny widths. The compiled programs are built with the persistent
+compilation cache off: its key leaves HLO metadata out, so a cached
+executable carries the names of whichever tree compiled it first.
+"""
+
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpudist import telemetry
+from tpudist.config import Config
+from tpudist.obs import scopes
+from tpudist.obs.scopes import phase_of
+
+# not operations of the program's own: arguments, constants (and the
+# compiler's broadcasts of them), tuple and layout plumbing
+PLUMBING = (" parameter(", " constant(", " broadcast(%constant", " tuple(",
+            " get-tuple-element(", " bitcast(", " copy(")
+
+
+def _tiny_model(arch, cfg):
+    from tpudist.models import create_model
+    from tpudist.models.vit import VisionTransformer
+    from tpudist.train import compute_dtype
+    if arch == "vit_tiny":
+        return VisionTransformer(patch_size=8, hidden_dim=32, num_layers=2,
+                                 num_heads=2, mlp_dim=64,
+                                 num_classes=cfg.num_classes,
+                                 dtype=compute_dtype(cfg), flash=False)
+    return create_model(arch, num_classes=cfg.num_classes,
+                        dtype=compute_dtype(cfg), bn_axis_name="data")
+
+
+@pytest.fixture(scope="module")
+def compiled_steps(mesh8):
+    """{arch: [(opcode-bearing HLO line, op_name)]} of the compiled DP step,
+    for the instructions the program's own code produced: those with an
+    `op_name` under `jit(step)/` that are not parameter / constant / tuple
+    plumbing (a reducer's scalar body is named by its primitive alone)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from tpudist.train import create_train_state, make_train_step
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    out = {}
+    try:
+        for arch, optimizer in (("resnet18", "sgd"), ("vit_tiny", "adamw")):
+            cfg = Config(arch="resnet18" if arch == "resnet18" else "vit_b_16",
+                         num_classes=8, image_size=32, batch_size=16,
+                         optimizer=optimizer, use_amp=True, seed=0).finalize(8)
+            model = _tiny_model(arch, cfg)
+            state = create_train_state(jax.random.PRNGKey(0), model, cfg)
+            step = make_train_step(mesh8, model, cfg)
+            text = step.lower(
+                state, jax.ShapeDtypeStruct((16, 32, 32, 3), jnp.float32),
+                jax.ShapeDtypeStruct((16,), jnp.int32),
+                jnp.float32(0.1)).compile().as_text()
+            rows = []
+            for line in text.splitlines():
+                name = re.search(r'op_name="([^"]*)"', line)
+                if " = " not in line or name is None \
+                        or "jit(step)/" not in name.group(1) \
+                        or any(p in line for p in PLUMBING):
+                    continue
+                rows.append((line, name.group(1)))
+            out[arch] = rows
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    return out
+
+
+def _matrix_ops(rows):
+    return [n for line, n in rows
+            if " convolution(" in line or " dot(" in line]
+
+
+@pytest.mark.parametrize("arch", ["resnet18", "vit_tiny"])
+@pytest.mark.parametrize("check", ["coverage", "forward", "backward",
+                                   "collectives", "optimizer"])
+def test_every_device_op_of_the_dp_step_has_a_scope(compiled_steps, arch,
+                                                    check):
+    rows = compiled_steps[arch]
+    assert len(rows) > 200
+    phases = [phase_of(n) for _, n in rows]
+    if check == "coverage":
+        unscoped = sorted({n for (_, n), p in zip(rows, phases) if p is None})
+        assert len([p for p in phases if p is None]) <= 0.01 * len(rows), \
+            unscoped[:20]
+        assert {"fwd", "bwd", "loss", "reduce", "opt", "metrics"} \
+            <= set(phases)
+    elif check == "forward":
+        fwd = [n for n in _matrix_ops(rows) if phase_of(n) == "fwd"]
+        assert fwd and all(f"jvp({scopes.FORWARD})" in n for n in fwd)
+        assert not any("transpose(" in n for n in fwd)
+    elif check == "backward":
+        matrix = _matrix_ops(rows)
+        bwd = [n for n in matrix if phase_of(n) == "bwd"]
+        # every convolution / dot is the model's: forward or its transpose
+        assert all(phase_of(n) in ("fwd", "bwd") for n in matrix)
+        assert all(f"transpose(jvp({scopes.FORWARD}))" in n for n in bwd)
+        assert len(bwd) >= len(matrix) - len(bwd)  # each layer is transposed
+    elif check == "collectives":
+        psums = [n for line, n in rows if " all-reduce(" in line
+                 or n.endswith("/psum")]
+        assert psums
+        assert all(phase_of(n) in ("reduce", "metrics") for n in psums), psums
+        assert any(scopes.GRAD_REDUCE in n for n in psums)
+    else:
+        # the update itself: nothing of it is left outside its scope
+        assert sum(p == "opt" for p in phases) > 50
+        assert not any(scopes.OPTIMIZER in n and scopes.FORWARD in n
+                       for _, n in rows)
+
+
+def test_attention_stages_are_named(compiled_steps):
+    names = {n for _, n in compiled_steps["vit_tiny"]}
+    for stage in (scopes.ATTN_SCORES, scopes.ATTN_SOFTMAX, scopes.ATTN_VALUES):
+        assert any(f"/self_attention/{stage}/" in n and phase_of(n) == "fwd"
+                   for n in names), stage
+        assert any(f"/self_attention/{stage}/" in n and phase_of(n) == "bwd"
+                   for n in names), stage
+
+
+@pytest.mark.parametrize("op_name,phase", [
+    ("jit(step)/jvp(tpudist_forward)/ResNet/layer3_0/bn2/mul", "fwd"),
+    ("jit(step)/transpose(jvp(tpudist_forward))/ResNet/layer4_1/conv2/"
+     "conv_general_dilated", "bwd"),
+    ("jit(step)/shard_map/jvp(tpudist_forward)/VisionTransformer/"
+     "encoder_layer_3/self_attention/attn_scores/bqhd,bkhd->bhqk/dot_general",
+     "fwd"),
+    ("jit(step)/jvp(tpudist_loss)/jit(log_softmax)/reduce_max", "loss"),
+    ("jit(step)/transpose(jvp(tpudist_loss))/jit(take_along_axis)/scatter-add",
+     "bwd"),
+    ("jit(step)/shard_map/tpudist_grad_reduce/psum", "reduce"),
+    ("jit(step)/tpudist_optimizer/mul;jit(step)/shard_map", "opt"),
+    ("jit(step)/shard_map;jit(step)/tpudist_optimizer/add", "opt"),
+    ("jit(step)/tpudist_metrics/reduce_sum", "metrics"),
+    ("jit(step)/tpudist_eval_forward/ResNet/fc/dot_general", None),
+    ("jit(step)/mul", None),
+    ("state.params['fc']['kernel']", None),
+    ("", None),
+])
+def test_phase_of(op_name, phase):
+    assert phase_of(op_name) == phase
+
+
+# --- host spans of a loop turn ----------------------------------------------
+@pytest.fixture(scope="module")
+def loop_capture(tmp_path_factory):
+    """Host annotation rows [name, start_ns, end_ns] of a three-step run
+    under the profiler, and the python thread's line they were on."""
+    from tpudist.trainer import Trainer
+    tmp = tmp_path_factory.mktemp("spans")
+    cfg = Config(arch="resnet18", num_classes=8, image_size=32, batch_size=16,
+                 epochs=1, lr=0.02, workers=0, print_freq=1, synthetic=True,
+                 use_amp=False, telemetry=False, outpath=str(tmp / "out"),
+                 overwrite="delete", seed=0)
+    trainer = Trainer(cfg, writer=None)
+    rng = np.random.default_rng(0)
+
+    class Loader(list):
+        pass
+
+    batches = Loader(
+        (rng.standard_normal((16, 32, 32, 3)).astype(np.float32),
+         rng.integers(0, 8, size=(16,)).astype(np.int32)) for _ in range(3))
+    trainer.train_epoch(batches[:1], 0, 0.02)            # compile outside
+    jax.block_until_ready(trainer.state)
+    jax.profiler.start_trace(str(tmp / "trace"))
+    try:
+        trainer.train_epoch(batches, 0, 0.02)
+        jax.block_until_ready(trainer.state)
+    finally:
+        jax.profiler.stop_trace()
+    path = next((tmp / "trace").rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    lines = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            rows = [[e.name, int(e.start_ns), int(e.start_ns + e.duration_ns)]
+                    for e in line.events
+                    if e.name.startswith("tpudist.") or e.name == scopes.STEP]
+            if rows:
+                lines[line.name] = sorted(rows, key=lambda r: (r[1], -r[2]))
+    assert len(lines) == 1, list(lines)      # the loop runs on one thread
+    return next(iter(lines.values()))
+
+
+@pytest.mark.parametrize("name,at_least", [
+    (scopes.SPAN_LOADER_NEXT, 3), (scopes.SPAN_DISPATCH, 3),
+    (scopes.SPAN_DRAIN_READY, 3), (scopes.SPAN_LOOP_HOST, 7),
+    (scopes.SPAN_PREFETCH, 3), (scopes.SPAN_H2D, 3), (scopes.STEP, 3)])
+def test_loop_turn_holds_span(loop_capture, name, at_least):
+    assert sum(r[0] == name for r in loop_capture) >= at_least
+
+
+def test_loop_spans_nest_properly(loop_capture):
+    """A child lies inside its parent, siblings are disjoint: self time =
+    span minus children."""
+    stack = []
+    for name, start, end in loop_capture:
+        while stack and stack[-1][2] <= start:
+            stack.pop()
+        if stack:
+            assert end <= stack[-1][2], (name, stack[-1][0])
+        stack.append((name, start, end))
+    inside = {scopes.SPAN_DISPATCH: scopes.STEP,
+              scopes.SPAN_DRAIN_READY: scopes.SPAN_LOOP_HOST,
+              scopes.SPAN_LOADER_NEXT: scopes.SPAN_PREFETCH}
+    for child, parent in inside.items():
+        for _, start, end in (r for r in loop_capture if r[0] == child):
+            assert any(p[0] == parent and p[1] <= start and end <= p[2]
+                       for p in loop_capture), (child, parent)
+
+
+def test_loop_turn_is_covered(loop_capture):
+    """From the first loop_host span of a turn to the end of its last, at
+    least 95 % of the host's time lies inside loop_host or the step
+    annotation (the rest nest inside those two)."""
+    top = [r for r in loop_capture
+           if r[0] in (scopes.SPAN_LOOP_HOST, scopes.STEP)]
+    steps = [i for i, r in enumerate(top) if r[0] == scopes.STEP]
+    assert len(steps) == 3
+    for i in steps:
+        before, step, after = top[i - 1], top[i], top[i + 1]
+        assert before[0] == after[0] == scopes.SPAN_LOOP_HOST
+        turn = after[2] - before[1]
+        covered = sum(r[2] - r[1] for r in (before, step, after))
+        assert covered >= 0.95 * turn, (covered, turn)
+
+
+# --- set-up phases -----------------------------------------------------------
+@pytest.fixture(scope="module")
+def constructed(tmp_path_factory):
+    from tpudist.trainer import Trainer
+    tmp = tmp_path_factory.mktemp("phases")
+    cfg = Config(arch="resnet18", num_classes=8, image_size=32, batch_size=16,
+                 epochs=1, synthetic=True, use_amp=False, telemetry=False,
+                 outpath=str(tmp / "out"), overwrite="delete", seed=0)
+    t0 = time.monotonic()
+    Trainer(cfg, writer=None)
+    return time.monotonic() - t0, telemetry.phases()
+
+
+@pytest.mark.parametrize("name", scopes.INIT_PHASES)
+def test_constructor_books_phase(constructed, name):
+    _, phases = constructed
+    assert name in phases and phases[name] >= 0.0
+
+
+def test_phases_sum_to_the_constructor_and_survive_clear(constructed):
+    wall, phases = constructed
+    booked = sum(v for k, v in phases.items() if k.startswith("init."))
+    assert abs(booked - wall) <= 0.05 * wall, (booked, wall)
+    # the eager model.init + tx.init is the constructor's bulk
+    assert phases[scopes.INIT_MODEL_STATE] > 0.5 * booked
+    telemetry.clear_pending()
+    assert telemetry.phases() == phases
+    copy = telemetry.phases()
+    copy["init.mesh"] = -1.0
+    assert telemetry.phases() == phases

@@ -226,14 +226,13 @@ def test_analyze_overlap_aware_budget_no_double_count():
     assert "prefetch (ovl.)" not in format_report(a2, "plain")
 
 
-def test_device_prefetcher_order_depth_and_wait_vs_hidden_accounting():
+def test_device_prefetcher_order_depth_and_hidden_accounting():
     """The other half of the overlap contract (tpudist/dist.py
     ``DevicePrefetcher``): batches come out in order and placed exactly as
     the serial ``shard_host_batch`` path would place them, the queue never
-    exceeds ``depth``, and staging time splits into the two buckets the
-    trainer reports — exposed wait (``last_wait_s``, an empty queue) vs
-    hidden work (``last_hidden_s``, time spent inside ``poke()`` while the
-    dispatched step computes)."""
+    exceeds ``depth``, and the staging done inside ``poke()`` while the
+    dispatched step computes is what ``poke()`` returns (the step event's
+    ``prefetch_s``); only an empty queue fills inside ``__next__``."""
     import jax
     import numpy as np
 
@@ -249,13 +248,12 @@ def test_device_prefetcher_order_depth_and_wait_vs_hidden_accounting():
     seen, hidden = [], []
     for i, (imgs, labels) in enumerate(pf):
         assert pf.last_local_bs == n
-        if i == 0:
-            # nothing was prefetched yet: the first batch is an EXPOSED
-            # fill, reported as wait, with no hidden time attached
-            assert pf.last_wait_s > 0.0 and pf.last_hidden_s == 0.0
-        hidden.append(pf.last_hidden_s)
-        spent = pf.poke()          # what the trainer does mid-step
-        assert spent >= 0.0 and len(pf._q) <= pf.depth
+        # nothing was prefetched before the first batch (an EXPOSED fill
+        # inside __next__); every later one was staged by poke()
+        assert len(pf._q) == (0 if i == 0 else pf.depth - 1) \
+            or i >= len(batches) - pf.depth
+        hidden.append(pf.poke())   # what the trainer does mid-step
+        assert len(pf._q) <= pf.depth
         seen.append((np.asarray(imgs), np.asarray(labels)))
     assert len(seen) == len(batches)
     for (gi, gl), host in zip(seen, batches):
@@ -264,8 +262,9 @@ def test_device_prefetcher_order_depth_and_wait_vs_hidden_accounting():
         np.testing.assert_array_equal(gl, np.asarray(ref_l))
     # every later batch was staged by poke(): its time is reported as
     # hidden (overlapped) work, so summarize never books it as data/h2d.
-    # (The LAST batch's poke found the source exhausted — zero by design.)
-    assert all(h > 0.0 for h in hidden[1:-1]) and hidden[-1] == 0.0
+    # (The last pokes found the source exhausted — zero by design.)
+    assert all(h > 0.0 for h in hidden[:len(batches) - pf.depth])
+    assert hidden[-1] == 0.0
     # exhausted source: poke degrades to a no-op, iteration ends cleanly
     assert pf.poke() == 0.0
     with pytest.raises(StopIteration):

@@ -34,6 +34,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpudist.obs import scopes
+
 
 def initialize_runtime(coordinator_address: str | None = None,
                        num_processes: int | None = None,
@@ -308,14 +310,10 @@ class DevicePrefetcher:
         self.depth = max(1, int(depth))
         self._q: list = []
         self._exhausted = False
-        # Per-__next__ accounting. The trainer reads last_local_bs (sample
-        # cursor) and books hidden time from poke()'s return value; the
-        # wait/hidden fields are the diagnostic surface that pins the
-        # exposed-vs-overlapped split (tests/test_telemetry.py).
-        self.last_wait_s = 0.0     # exposed: blocked with an empty queue
-        self.last_hidden_s = 0.0   # overlapped: spent inside poke()
+        # The trainer reads last_local_bs (sample cursor) and books hidden
+        # time from poke()'s return value; in a trace the exposed fills are
+        # the tpudist.prefetch spans inside the loop's first loop_host span.
         self.last_local_bs = 0
-        self._pending_hidden = 0.0
 
     def _fill_one(self) -> float:
         """Pull one host batch and issue its device placement; returns the
@@ -323,42 +321,39 @@ class DevicePrefetcher:
         if self._exhausted:
             return 0.0
         t0 = time.perf_counter()
-        try:
-            batch = next(self._it)
-        except StopIteration:
-            self._exhausted = True
-            return 0.0
-        local_bs = int(batch[0].shape[0])
-        with jax.profiler.TraceAnnotation("tpudist.prefetch"):
+        # One staged batch = one tpudist.prefetch span: the program's own
+        # loader wait (tpudist.loader_next) and the placement (tpudist.h2d)
+        # nest inside it.
+        with jax.profiler.TraceAnnotation(scopes.SPAN_PREFETCH):
+            try:
+                with jax.profiler.TraceAnnotation(scopes.SPAN_LOADER_NEXT):
+                    batch = next(self._it)
+            except StopIteration:
+                self._exhausted = True
+                return 0.0
+            local_bs = int(batch[0].shape[0])
             dev = shard_host_batch(self.mesh, batch, self.data_axis)
-        self._q.append((dev, local_bs))
+            self._q.append((dev, local_bs))
         return time.perf_counter() - t0
 
     def poke(self) -> float:
         """Top the queue up to ``depth`` — the trainer calls this right
         after dispatching the step, so the loader pull + H2D issue overlap
-        the in-flight device compute. Returns the time spent (also
-        accumulated into the NEXT ``__next__``'s ``last_hidden_s``)."""
+        the in-flight device compute. Returns the time spent."""
         spent = 0.0
         while len(self._q) < self.depth and not self._exhausted:
             spent += self._fill_one()
-        self._pending_hidden += spent
         return spent
 
     def __iter__(self):
         return self
 
     def __next__(self):
-        wait = 0.0
         while not self._q and not self._exhausted:
-            wait += self._fill_one()     # exposed: the chip is waiting
+            self._fill_one()             # exposed: the chip is waiting
         if not self._q:
             raise StopIteration
-        dev, local_bs = self._q.pop(0)
-        self.last_wait_s = wait
-        self.last_hidden_s = self._pending_hidden
-        self._pending_hidden = 0.0
-        self.last_local_bs = local_bs
+        dev, self.last_local_bs = self._q.pop(0)
         return dev
 
 
@@ -372,7 +367,7 @@ def shard_host_batch(mesh: Mesh, batch, data_axis: str = "data"):
     sharding = batch_sharding(mesh, data_axis)
     # Label the copy so --profile traces attribute H2D time to this phase
     # (XProf/Perfetto show "tpudist.h2d" rows); no-op when no trace is live.
-    with jax.profiler.TraceAnnotation("tpudist.h2d"):
+    with jax.profiler.TraceAnnotation(scopes.SPAN_H2D):
         if jax.process_count() == 1:
             return jax.tree_util.tree_map(
                 lambda x: jax.device_put(x, sharding), batch)

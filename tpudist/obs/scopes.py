@@ -1,0 +1,81 @@
+"""The program's names for what it does: one place, names only.
+
+Three kinds, all read by `benchmarks/chip/` and by an operator in XProf
+(docs/OBSERVABILITY.md, "Labeled XLA traces"):
+
+- **device scopes** (`jax.named_scope`, `tpudist_*`): HLO metadata on every
+  operation of a step program. A scope changes no compiled program, FLOP or
+  byte (tests/test_compiled_cost.py). jax itself marks the backward pass:
+  an op traced under `tpudist_forward` reads `jvp(tpudist_forward)` in the
+  forward pass and `transpose(jvp(tpudist_forward))` in the backward.
+- **host spans** (`jax.profiler.TraceAnnotation`, `tpudist.*`): every host
+  microsecond of a trainer loop turn lies inside exactly one of them; a span
+  costs a flag test when no trace is live.
+- **set-up phases** (`telemetry.record_phase`, `init.*`): seconds of
+  `Trainer.__init__`, read back with `telemetry.phases()`.
+
+No jax import here: the names are plain strings.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+# -- device scopes ----------------------------------------------------------
+FORWARD = "tpudist_forward"            # model.apply in the train step
+LOSS = "tpudist_loss"                  # cross entropy, aux heads, loss scaling
+GRAD_REDUCE = "tpudist_grad_reduce"    # gradient + BN-statistics collectives
+OPTIMIZER = "tpudist_optimizer"        # tx.update, apply_updates, skips, EMA
+METRICS = "tpudist_metrics"            # accuracy, metric means, sentinels
+EVAL_FORWARD = "tpudist_eval_forward"
+SERVE_FORWARD = "tpudist_serve_forward"
+# the three stages of the XLA attention path, inside the forward scope
+ATTN_SCORES = "attn_scores"
+ATTN_SOFTMAX = "attn_softmax"
+ATTN_VALUES = "attn_values"
+
+DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
+                 EVAL_FORWARD, SERVE_FORWARD)
+
+# -- host spans of a loop turn ----------------------------------------------
+STEP = "train"                          # StepTraceAnnotation: h2d + dispatch
+SPAN_LOADER_NEXT = "tpudist.loader_next"
+SPAN_H2D = "tpudist.h2d"
+SPAN_PREFETCH = "tpudist.prefetch"      # loader_next + h2d of batch N+1
+SPAN_DISPATCH = "tpudist.dispatch"
+SPAN_DRAIN_READY = "tpudist.drain_ready"
+SPAN_METRIC_DRAIN = "tpudist.metric_drain"
+SPAN_LOOP_HOST = "tpudist.loop_host"    # hooks before the step, meters after
+
+# -- set-up phases (init.*: sum = Trainer.__init__'s wall time) -------------
+INIT_MESH = "init.mesh"
+INIT_MODEL_STATE = "init.model_state"   # create_train_state: eager init
+INIT_SHARD_STATE = "init.shard_state"
+INIT_DISPATCH = "init.dispatch"         # --flash/--fused-bn/--compress-grads
+INIT_STEP_BUILD = "init.step_build"
+INIT_RESTORE = "init.restore"           # only when resuming
+INIT_OTHER = "init.other"
+INIT_LOADERS = "init.loaders"           # fit() builds them, after __init__
+
+# always booked, in this order; INIT_RESTORE and INIT_LOADERS when they run
+INIT_PHASES = (INIT_MESH, INIT_DISPATCH, INIT_MODEL_STATE, INIT_SHARD_STATE,
+               INIT_STEP_BUILD, INIT_OTHER)
+
+
+def phase_of(op_name: str) -> Optional[str]:
+    """Which part of the train step an HLO `op_name` belongs to: "fwd",
+    "bwd", "loss", "reduce", "opt", "metrics", or None (no scope of the
+    program's). XLA joins the names of operations it merges with `;`: the
+    first part that has a phase decides."""
+    for part in op_name.split(";"):
+        if FORWARD in part or LOSS in part:
+            if "transpose(" in part:
+                return "bwd"
+            return "fwd" if FORWARD in part else "loss"
+        if GRAD_REDUCE in part:
+            return "reduce"
+        if OPTIMIZER in part:
+            return "opt"
+        if METRICS in part:
+            return "metrics"
+    return None

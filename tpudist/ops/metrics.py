@@ -11,6 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from tpudist.obs import scopes
+
 
 def accuracy(scores: jax.Array, targets: jax.Array, topk: int = 1) -> jax.Array:
     """Fraction (in %) of rows whose true label is within the top-k scores.
@@ -18,10 +20,11 @@ def accuracy(scores: jax.Array, targets: jax.Array, topk: int = 1) -> jax.Array:
     Matches reference ``accuracy`` with ``topk=(1,)`` (``utils.py:105-111``):
     returns a 0-D array scaled to percent (mul_(100.0 / batch_size)).
     """
-    if topk == 1:
-        pred = jnp.argmax(scores, axis=-1)
-        correct = (pred == targets).sum()
-    else:
-        _, pred = jax.lax.top_k(scores, topk)          # [B, k]
-        correct = (pred == targets[:, None]).any(axis=-1).sum()
-    return correct.astype(jnp.float32) * (100.0 / scores.shape[0])
+    with jax.named_scope(scopes.METRICS):     # label only; every step inherits
+        if topk == 1:
+            pred = jnp.argmax(scores, axis=-1)
+            correct = (pred == targets).sum()
+        else:
+            _, pred = jax.lax.top_k(scores, topk)          # [B, k]
+            correct = (pred == targets[:, None]).any(axis=-1).sum()
+        return correct.astype(jnp.float32) * (100.0 / scores.shape[0])

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from tpudist.config import Config
+from tpudist.obs import scopes
 
 
 def path_keys(path) -> list[str]:
@@ -199,10 +200,11 @@ def apply_optimizer_update(tx, state, grads, lr):
     (The DP step in train.py keeps its own tail — it additionally handles
     the fp16 overflow-skip path.)"""
     import optax
-    tx_state = state.opt_state
-    tx_state.hyperparams["learning_rate"] = lr
-    updates, new_opt_state = tx.update(grads, tx_state, state.params)
-    return optax.apply_updates(state.params, updates), new_opt_state
+    with jax.named_scope(scopes.OPTIMIZER):
+        tx_state = state.opt_state
+        tx_state.hyperparams["learning_rate"] = lr
+        updates, new_opt_state = tx.update(grads, tx_state, state.params)
+        return optax.apply_updates(state.params, updates), new_opt_state
 
 
 def template_state(model, cfg: Config, **twin_overrides):

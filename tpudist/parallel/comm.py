@@ -62,6 +62,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpudist.config import Config
+from tpudist.obs import scopes
 
 # Bumped whenever the wire format or reduction math changes: cached
 # compressed-vs-dense dispatch verdicts (ops/comm_dispatch) are keyed on it
@@ -332,17 +333,21 @@ def make_wus_train_step(mesh: Mesh, model, cfg: Config,
                                       images, labels)
                 acc1 = accuracy(outputs, labels, topk=1)
 
-            grads, new_comm = reduce_grads(grads, state.comm_state)
-            new_stats = jax.lax.pmean(new_stats, axis_name=data_axis)
-            tx_state = state.opt_state
-            tx_state.hyperparams["learning_rate"] = lr
-            updates, new_opt_state = tx.update(grads, tx_state, state.params)
+            with jax.named_scope(scopes.GRAD_REDUCE):
+                grads, new_comm = reduce_grads(grads, state.comm_state)
+                new_stats = jax.lax.pmean(new_stats, axis_name=data_axis)
             import optax
-            new_params = optax.apply_updates(state.params, updates)
-            metrics = {
-                "loss": jax.lax.pmean(loss, axis_name=data_axis),
-                "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
-            }
+            with jax.named_scope(scopes.OPTIMIZER):
+                tx_state = state.opt_state
+                tx_state.hyperparams["learning_rate"] = lr
+                updates, new_opt_state = tx.update(grads, tx_state,
+                                                   state.params)
+                new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope(scopes.METRICS):
+                metrics = {
+                    "loss": jax.lax.pmean(loss, axis_name=data_axis),
+                    "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
+                }
             ema = update_ema(cfg, state.ema_params, new_params, new_stats)
             new_state = state.replace(step=state.step + 1, params=new_params,
                                       batch_stats=new_stats,

@@ -39,6 +39,7 @@ from jax import shard_map
 
 from tpudist.config import Config
 from tpudist.ops import accuracy, cross_entropy_loss
+from tpudist.obs import scopes
 from tpudist.train import TrainState, make_optimizer, update_ema
 
 from tpudist.parallel._common import (accum_scan, accum_steps,
@@ -85,14 +86,16 @@ def split_grad_reduce(grads, expert_axis: str, n: int,
 def _moe_loss_fn(model: nn.Module, rng, params, batch_stats, images, labels,
                  smoothing: float = 0.0, labels2=None, lam=None):
     from tpudist.ops.mixup import mixed_ce
-    (outputs, mutated) = model.apply(
-        {"params": params, "batch_stats": batch_stats},
-        images, train=True, mutable=["batch_stats", "losses"],
-        rngs={"dropout": rng})
-    ce = mixed_ce(outputs, labels, labels2, lam, smoothing)
-    loss = ce
-    for aux in jax.tree_util.tree_leaves(mutated.get("losses", {})):
-        loss = loss + MOE_AUX_WEIGHT * aux
+    with jax.named_scope(scopes.FORWARD):
+        (outputs, mutated) = model.apply(
+            {"params": params, "batch_stats": batch_stats},
+            images, train=True, mutable=["batch_stats", "losses"],
+            rngs={"dropout": rng})
+    with jax.named_scope(scopes.LOSS):
+        ce = mixed_ce(outputs, labels, labels2, lam, smoothing)
+        loss = ce
+        for aux in jax.tree_util.tree_leaves(mutated.get("losses", {})):
+            loss = loss + MOE_AUX_WEIGHT * aux
     # ce returned separately: the Trainer logs 'Train_ce_loss', which must
     # stay pure CE (comparable with the dense-twin DP path) while the
     # optimizer trains on CE + aux.
@@ -177,18 +180,20 @@ def make_ep_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
                 lf, has_aux=True)(state.params, state.batch_stats,
                                   images, labels)
             acc1 = accuracy(outputs, labels, topk=1)
-        grads = split_grad_reduce(grads, expert_axis, n, data_axis)
-        new_stats = jax.lax.pmean(new_stats, axis_name=batch_axes)
+        with jax.named_scope(scopes.GRAD_REDUCE):
+            grads = split_grad_reduce(grads, expert_axis, n, data_axis)
+            new_stats = jax.lax.pmean(new_stats, axis_name=batch_axes)
         new_params, new_opt_state = apply_optimizer_update(tx, state, grads, lr)
         ema = update_ema(cfg, state.ema_params, new_params, new_stats)
 
         # 'loss' is pure CE (what the Trainer logs as Train_ce_loss,
         # comparable across parallelism modes); the optimizer trained on
         # CE + MOE_AUX_WEIGHT*aux above.
-        metrics = {
-            "loss": jax.lax.pmean(ce, axis_name=batch_axes),
-            "acc1": jax.lax.pmean(acc1, axis_name=batch_axes),
-        }
+        with jax.named_scope(scopes.METRICS):
+            metrics = {
+                "loss": jax.lax.pmean(ce, axis_name=batch_axes),
+                "acc1": jax.lax.pmean(acc1, axis_name=batch_axes),
+            }
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   batch_stats=new_stats, ema_params=ema,
                                   opt_state=new_opt_state)

@@ -31,6 +31,7 @@ from jax import shard_map
 
 from tpudist.config import Config
 from tpudist.ops import accuracy, cross_entropy_loss
+from tpudist.obs import scopes
 from tpudist.train import TrainState, make_optimizer, update_ema
 
 
@@ -123,9 +124,11 @@ def make_pp_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
         from tpudist.ops.mixup import mixed_ce
 
         def scaled_loss(params):
-            outputs = model.apply({"params": params}, images, train=True)
-            return mixed_ce(outputs, labels, labels2, lam,
-                            cfg.label_smoothing) / s, outputs
+            with jax.named_scope(scopes.FORWARD):
+                outputs = model.apply({"params": params}, images, train=True)
+            with jax.named_scope(scopes.LOSS):
+                return mixed_ce(outputs, labels, labels2, lam,
+                                cfg.label_smoothing) / s, outputs
 
         (loss_over_s, outputs), grads = jax.value_and_grad(
             scaled_loss, has_aux=True)(params)
@@ -160,17 +163,19 @@ def make_pp_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
             loss, outputs, grads = compute_grads(images, labels, state.params,
                                                  labels2=labels2, lam=lam)
             acc1 = accuracy(outputs, labels, topk=1)
-        grads = jax.tree_util.tree_map_with_path(
-            lambda path, g: g if _is_trunk_leaf(path)
-            else jax.lax.psum(g, axis_name=pipe_axis), grads)
-        grads = jax.lax.pmean(grads, axis_name=data_axis)
+        with jax.named_scope(scopes.GRAD_REDUCE):
+            grads = jax.tree_util.tree_map_with_path(
+                lambda path, g: g if _is_trunk_leaf(path)
+                else jax.lax.psum(g, axis_name=pipe_axis), grads)
+            grads = jax.lax.pmean(grads, axis_name=data_axis)
         new_params, new_opt_state = apply_optimizer_update(tx, state, grads, lr)
         ema = update_ema(cfg, state.ema_params, new_params, state.batch_stats)
 
-        metrics = {
-            "loss": jax.lax.pmean(loss, axis_name=data_axis),
-            "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
-        }
+        with jax.named_scope(scopes.METRICS):
+            metrics = {
+                "loss": jax.lax.pmean(loss, axis_name=data_axis),
+                "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
+            }
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   batch_stats=state.batch_stats,
                                   ema_params=ema, opt_state=new_opt_state)

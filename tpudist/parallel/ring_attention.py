@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tpudist.obs import scopes
+
 NEG_INF = -1e30
 
 
@@ -38,13 +40,18 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = False) -> jax.Array:
     """Plain softmax attention. Shapes [B, T, H, D]; fp32 softmax."""
     d = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
-    if causal:
-        tq, tk = s.shape[-2], s.shape[-1]
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    # The three stages carry scopes (labels only): a trace then says which of
+    # an encoder block's fusions is which.
+    with jax.named_scope(scopes.ATTN_SCORES):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
+        if causal:
+            tq, tk = s.shape[-2], s.shape[-1]
+            mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+            s = jnp.where(mask, s, NEG_INF)
+    with jax.named_scope(scopes.ATTN_SOFTMAX):
+        p = jax.nn.softmax(s, axis=-1)
+    with jax.named_scope(scopes.ATTN_VALUES):
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -38,6 +38,7 @@ from tpudist.ops import accuracy
 from tpudist.parallel._common import (accum_scan, accum_steps,
                                       apply_optimizer_update,
                                       check_step_supported)
+from tpudist.obs import scopes
 from tpudist.train import TrainState, _loss_fn, make_optimizer, update_ema
 
 
@@ -93,17 +94,19 @@ def make_sp_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
                 lf, has_aux=True)(state.params, state.batch_stats,
                                   images, labels)
             acc1 = accuracy(outputs, labels, topk=1)
-        grads = jax.lax.pmean(grads, axis_name=(data_axis, seq_axis))
-        # Keep replicated state consistent across data shards (no-op for the
-        # BN-free ViT family, where new_stats is {}).
-        new_stats = jax.lax.pmean(new_stats, axis_name=data_axis)
+        with jax.named_scope(scopes.GRAD_REDUCE):
+            grads = jax.lax.pmean(grads, axis_name=(data_axis, seq_axis))
+            # Keep replicated state consistent across data shards (no-op for
+            # the BN-free ViT family, where new_stats is {}).
+            new_stats = jax.lax.pmean(new_stats, axis_name=data_axis)
         new_params, new_opt_state = apply_optimizer_update(tx, state, grads, lr)
         ema = update_ema(cfg, state.ema_params, new_params, new_stats)
 
-        metrics = {
-            "loss": jax.lax.pmean(loss, axis_name=data_axis),
-            "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
-        }
+        with jax.named_scope(scopes.METRICS):
+            metrics = {
+                "loss": jax.lax.pmean(loss, axis_name=data_axis),
+                "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
+            }
         new_state = state.replace(step=state.step + 1, params=new_params,
                                   batch_stats=new_stats, ema_params=ema,
                                   opt_state=new_opt_state)

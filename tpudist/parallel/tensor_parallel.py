@@ -38,6 +38,7 @@ from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudist.config import Config
+from tpudist.obs import scopes
 from tpudist.ops import accuracy, cross_entropy_loss
 
 # (path-regex, spec) pairs, first match wins; path is '/'-joined tree keys.
@@ -397,9 +398,10 @@ def make_gspmd_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
             rngs = {"dropout": rng_i}
             if stats:
                 variables["batch_stats"] = stats
-            outputs, mutated = model.apply(
-                variables, im, train=True,
-                mutable=["batch_stats", "intermediates"], rngs=rngs)
+            with jax.named_scope(scopes.FORWARD):
+                outputs, mutated = model.apply(
+                    variables, im, train=True,
+                    mutable=["batch_stats", "intermediates"], rngs=rngs)
             new_stats = mutated.get("batch_stats", stats)
 
             from tpudist.ops.mixup import mixed_ce
@@ -407,15 +409,16 @@ def make_gspmd_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
             def ce(logits):
                 return mixed_ce(logits, lb, lb2, lam, cfg.label_smoothing)
 
-            loss = ce(outputs)                       # global-batch mean
-            # Sown aux-classifier logits (googlenet/inception) weighted into
-            # the loss, mirroring tpudist.train._loss_fn — the GSPMD path must
-            # not silently drop aux gradients.
-            aux_w = getattr(model, "aux_loss_weight", 0.0)
-            if aux_w:
-                for aux_logits in jax.tree_util.tree_leaves(
-                        mutated.get("intermediates", {})):
-                    loss = loss + aux_w * ce(aux_logits)
+            with jax.named_scope(scopes.LOSS):
+                loss = ce(outputs)                   # global-batch mean
+                # Sown aux-classifier logits (googlenet/inception) weighted
+                # into the loss, mirroring tpudist.train._loss_fn — the GSPMD
+                # path must not silently drop aux gradients.
+                aux_w = getattr(model, "aux_loss_weight", 0.0)
+                if aux_w:
+                    for aux_logits in jax.tree_util.tree_leaves(
+                            mutated.get("intermediates", {})):
+                        loss = loss + aux_w * ce(aux_logits)
             return loss, (outputs, new_stats)
 
         if accum > 1:
@@ -474,17 +477,19 @@ def make_gspmd_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
             ds, is_finite = None, None
             acc1 = accuracy(outputs, labels, topk=1)
 
-        tx_state = state.opt_state
-        tx_state.hyperparams["learning_rate"] = lr
-        updates, new_opt_state = tx.update(grads, tx_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        if ds is not None:
-            # Skip the update when grads overflowed (GradScaler.step).
-            from functools import partial
-            new_params = jax.tree_util.tree_map(
-                partial(jnp.where, is_finite), new_params, state.params)
-            new_opt_state = jax.tree_util.tree_map(
-                partial(jnp.where, is_finite), new_opt_state, state.opt_state)
+        with jax.named_scope(scopes.OPTIMIZER):
+            tx_state = state.opt_state
+            tx_state.hyperparams["learning_rate"] = lr
+            updates, new_opt_state = tx.update(grads, tx_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            if ds is not None:
+                # Skip the update when grads overflowed (GradScaler.step).
+                from functools import partial
+                new_params = jax.tree_util.tree_map(
+                    partial(jnp.where, is_finite), new_params, state.params)
+                new_opt_state = jax.tree_util.tree_map(
+                    partial(jnp.where, is_finite), new_opt_state,
+                    state.opt_state)
         metrics = {"loss": loss, "acc1": acc1}
         ema = update_ema(cfg, state.ema_params, new_params, new_stats)
         new_state = state.replace(step=state.step + 1, params=new_params,

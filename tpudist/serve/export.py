@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from tpudist.models import create_model
+from tpudist.obs import scopes
 
 
 def make_infer_step(model) -> Callable:
@@ -37,7 +38,7 @@ def make_infer_step(model) -> Callable:
     executables, which structurally cannot recompile. The images buffer is
     donated (argnum 1): a request batch is dead once the logits exist."""
     def step(variables: dict, images: jax.Array) -> jax.Array:
-        with jax.named_scope("tpudist_serve_forward"):
+        with jax.named_scope(scopes.SERVE_FORWARD):
             return model.apply(variables, images, train=False)
 
     from tpudist.parallel._common import donated_jit

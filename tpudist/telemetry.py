@@ -315,14 +315,29 @@ def env_attempt(default: int = 0) -> int:
 
 
 _pending_phases: dict[str, float] = {}
+_phases: dict[str, float] = {}
 _current: Optional["Telemetry"] = None
 
 
 def record_phase(name: str, seconds: float) -> None:
-    """Record overhead that happens BEFORE a Telemetry instance exists (e.g.
-    ``dist.initialize_runtime`` runs before the Trainer is constructed). The
-    next Telemetry() picks the stash up into its goodput accounting."""
-    _pending_phases[name] = _pending_phases.get(name, 0.0) + float(seconds)
+    """Record set-up overhead by phase name. ``init``
+    (``dist.initialize_runtime``) happens BEFORE a Telemetry instance
+    exists: it is stashed, and the next Telemetry() pops it into its goodput
+    accounting. ``Trainer.__init__`` books its own ``init.*`` phases here
+    too; nothing pops those, so they are not stashed. ``phases()`` reads the
+    newest seconds of every name."""
+    if name == "init":
+        _pending_phases[name] = _pending_phases.get(name, 0.0) + float(seconds)
+    _phases[name] = float(seconds)
+
+
+def phases() -> dict[str, float]:
+    """Set-up seconds by phase name, as last recorded in this process: the
+    runtime's ``init`` and the constructor's ``init.*`` (names in
+    ``tpudist/obs/scopes.py``, which sum to ``Trainer.__init__``'s wall
+    time). A copy, and not emptied by ``clear_pending()``: the chip
+    benchmark reads it from a run without ``--telemetry``."""
+    return dict(_phases)
 
 
 def clear_pending() -> None:

@@ -41,6 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from tpudist.config import Config
+from tpudist.obs import scopes
 from tpudist.ops import accuracy, cross_entropy_loss
 
 
@@ -185,34 +186,37 @@ def update_ema(cfg: Config, ema: Any, new_params: Any,
     if ema is None:
         return None
     d = cfg.model_ema_decay
-    return jax.tree_util.tree_map(
-        lambda e, x: d * e + (1.0 - d) * x, ema,
-        {"params": new_params, "batch_stats": new_stats})
+    with jax.named_scope(scopes.OPTIMIZER):
+        return jax.tree_util.tree_map(
+            lambda e, x: d * e + (1.0 - d) * x, ema,
+            {"params": new_params, "batch_stats": new_stats})
 
 
 def _loss_fn(model: nn.Module, rng, params, batch_stats, images, labels,
              smoothing: float = 0.0, labels2=None, lam=None):
-    # named_scope labels the HLO so --profile captures group the forward's
-    # device ops under "tpudist_forward" in XProf (metadata only: the
-    # compiled program's FLOPs/memory are unchanged — test_compiled_cost
-    # pins that).
-    with jax.named_scope("tpudist_forward"):
+    # The scopes (tpudist/obs/scopes.py) label the HLO: a trace's device ops
+    # group under them in XProf and in the chip benchmark's fwd/bwd/opt
+    # split. Metadata only: the compiled program's FLOPs/memory are
+    # unchanged — test_compiled_cost pins that.
+    with jax.named_scope(scopes.FORWARD):
         outputs, mutated = model.apply(
             {"params": params, "batch_stats": batch_stats},
             images, train=True, mutable=["batch_stats", "intermediates"],
             rngs={"dropout": rng})
     from tpudist.ops.mixup import mixed_ce
-    loss = mixed_ce(outputs, labels, labels2, lam, smoothing)
-    # Aux classifier heads (googlenet 0.3, inception_v3 0.4): their logits are
-    # sown to 'intermediates' during training; weight them into the loss so
-    # the aux params actually receive gradient (torchvision's train recipe —
-    # without this they'd only be decayed noise, ADVICE r1 #2).
-    aux_w = getattr(model, "aux_loss_weight", 0.0)
-    if aux_w:
-        for aux_logits in jax.tree_util.tree_leaves(
-                mutated.get("intermediates", {})):
-            loss = loss + aux_w * mixed_ce(aux_logits, labels, labels2,
-                                           lam, smoothing)
+    with jax.named_scope(scopes.LOSS):
+        loss = mixed_ce(outputs, labels, labels2, lam, smoothing)
+        # Aux classifier heads (googlenet 0.3, inception_v3 0.4): their
+        # logits are sown to 'intermediates' during training; weight them
+        # into the loss so the aux params actually receive gradient
+        # (torchvision's train recipe — without this they'd only be decayed
+        # noise, ADVICE r1 #2).
+        aux_w = getattr(model, "aux_loss_weight", 0.0)
+        if aux_w:
+            for aux_logits in jax.tree_util.tree_leaves(
+                    mutated.get("intermediates", {})):
+                loss = loss + aux_w * mixed_ce(aux_logits, labels, labels2,
+                                               lam, smoothing)
     return loss, (outputs, mutated.get("batch_stats", {}))
 
 
@@ -274,13 +278,14 @@ def make_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
     def reduce_grads(grads, comm_state):
         """THE gradient-reduction choke point (DDP's C++ bucketed
         allreduce): dense pmean, or the compressed twin threading the
-        error-feedback residual."""
-        if compress is None:
-            return jax.lax.pmean(grads, axis_name=data_axis), comm_state
-        from tpudist.parallel.comm import compressed_pmean
-        red, e_new = compressed_pmean(grads, comm_state["residual"][0],
-                                      data_axis)
-        return red, {"residual": e_new[None]}
+        error-feedback residual. Its scope is the collectives' own."""
+        with jax.named_scope(scopes.GRAD_REDUCE):
+            if compress is None:
+                return jax.lax.pmean(grads, axis_name=data_axis), comm_state
+            from tpudist.parallel.comm import compressed_pmean
+            red, e_new = compressed_pmean(grads, comm_state["residual"][0],
+                                          data_axis)
+            return red, {"residual": e_new[None]}
 
     def step(state: TrainState, images, labels, lr):
         # Per-step, per-shard dropout key (torch: each DDP rank has its own
@@ -331,8 +336,9 @@ def make_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
             if ds0 is not None:
                 # Post-pmean: the flag (and so the skip/scale decision) is
                 # identical on every replica by construction.
-                is_finite = ds_finite(grads)
-                ds = ds_update(ds0, is_finite)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    is_finite = ds_finite(grads)
+                    ds = ds_update(ds0, is_finite)
             else:
                 ds, is_finite = None, None
         else:
@@ -360,27 +366,31 @@ def make_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
         # Sync BN running stats across replicas so the replicated state stays
         # consistent (torch DDP keeps per-GPU stats and checkpoints rank 0's;
         # averaging is strictly more faithful to the data).
-        # (named_scope = trace label only; see _loss_fn.)
-        with jax.named_scope("tpudist_optimizer"):
+        # (Scopes = trace labels only; see _loss_fn.)
+        with jax.named_scope(scopes.GRAD_REDUCE):
             new_stats = jax.lax.pmean(new_stats, axis_name=data_axis)
 
+        with jax.named_scope(scopes.OPTIMIZER):
             tx_state = state.opt_state
             tx_state.hyperparams["learning_rate"] = lr
             updates, new_opt_state = tx.update(grads, tx_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
 
-        if ds is not None:
-            # Skip the update when grads overflowed (GradScaler.step behavior).
-            new_params = jax.tree_util.tree_map(
-                partial(jnp.where, is_finite), new_params, state.params)
-            new_opt_state = jax.tree_util.tree_map(
-                partial(jnp.where, is_finite), new_opt_state, state.opt_state)
+            if ds is not None:
+                # Skip the update when grads overflowed (GradScaler.step
+                # behavior).
+                new_params = jax.tree_util.tree_map(
+                    partial(jnp.where, is_finite), new_params, state.params)
+                new_opt_state = jax.tree_util.tree_map(
+                    partial(jnp.where, is_finite), new_opt_state,
+                    state.opt_state)
 
         # reduce_mean of loss/acc (distributed.py:78-82,254-255), fused in-program.
-        metrics = {
-            "loss": jax.lax.pmean(loss, axis_name=data_axis),
-            "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
-        }
+        with jax.named_scope(scopes.METRICS):
+            metrics = {
+                "loss": jax.lax.pmean(loss, axis_name=data_axis),
+                "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
+            }
         if guard:
             # Doctor sentinels: global grad norm + finiteness of (mean loss,
             # grad norm). ``grads`` is post-reduction, so both signals are
@@ -388,40 +398,47 @@ def make_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
             # can never diverge the gang. On a tripped flag the whole update
             # is zeroed (GradScaler-style): params, moments, BN stats, and
             # the error-feedback residual all keep their pre-step values.
-            gnorm = global_grad_norm(grads)
-            ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(gnorm)
-            if ds is not None:
-                # fp16 dynamic loss scaling: an overflow step is the
-                # scaler's jurisdiction — it already skipped params/opt
-                # and halved the scale (GradScaler semantics predate the
-                # doctor; torch's scaler doesn't flag them either).
-                # Counting scale-search overflows as doctor skips would
-                # escalate a healthy warm-up into a spurious
-                # persistent_nonfinite rollback. The sentinel only flags
-                # anomalies the scaler calls finite — but the overflow is
-                # still REPORTED (scaler_skip) so the host can tell a
-                # bounded scale search from data that is NaN at any scale
-                # (the doctor escalates those on a larger budget).
-                ok = ok | jnp.logical_not(is_finite)
-                metrics["scaler_skip"] = 1.0 - is_finite.astype(jnp.float32)
-            new_params = jax.tree_util.tree_map(
-                partial(jnp.where, ok), new_params, state.params)
-            new_opt_state = jax.tree_util.tree_map(
-                partial(jnp.where, ok), new_opt_state, state.opt_state)
-            new_stats = jax.tree_util.tree_map(
-                partial(jnp.where, ok), new_stats, state.batch_stats)
-            if new_comm is not None:
-                new_comm = jax.tree_util.tree_map(
-                    partial(jnp.where, ok), new_comm, state.comm_state)
-            metrics["notfinite"] = 1.0 - ok.astype(jnp.float32)
-            metrics["gnorm"] = gnorm
+            with jax.named_scope(scopes.METRICS):
+                gnorm = global_grad_norm(grads)
+                ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(gnorm)
+                if ds is not None:
+                    # fp16 dynamic loss scaling: an overflow step is the
+                    # scaler's jurisdiction — it already skipped params/opt
+                    # and halved the scale (GradScaler semantics predate the
+                    # doctor; torch's scaler doesn't flag them either).
+                    # Counting scale-search overflows as doctor skips would
+                    # escalate a healthy warm-up into a spurious
+                    # persistent_nonfinite rollback. The sentinel only flags
+                    # anomalies the scaler calls finite — but the overflow is
+                    # still REPORTED (scaler_skip) so the host can tell a
+                    # bounded scale search from data that is NaN at any scale
+                    # (the doctor escalates those on a larger budget).
+                    ok = ok | jnp.logical_not(is_finite)
+                    metrics["scaler_skip"] = 1.0 - is_finite.astype(
+                        jnp.float32)
+            with jax.named_scope(scopes.OPTIMIZER):
+                new_params = jax.tree_util.tree_map(
+                    partial(jnp.where, ok), new_params, state.params)
+                new_opt_state = jax.tree_util.tree_map(
+                    partial(jnp.where, ok), new_opt_state, state.opt_state)
+                new_stats = jax.tree_util.tree_map(
+                    partial(jnp.where, ok), new_stats, state.batch_stats)
+                if new_comm is not None:
+                    new_comm = jax.tree_util.tree_map(
+                        partial(jnp.where, ok), new_comm, state.comm_state)
+            with jax.named_scope(scopes.METRICS):
+                metrics["notfinite"] = 1.0 - ok.astype(jnp.float32)
+                metrics["gnorm"] = gnorm
         ema = update_ema(cfg, state.ema_params, new_params, new_stats)
         if guard and ema is not None:
             # A skipped step must not advance the EMA either (averaging the
             # unchanged params would still decay the average).
-            ema = jax.tree_util.tree_map(
-                partial(jnp.where, ok), ema, state.ema_params)
-        new_state = state.replace(step=state.step + 1, params=new_params,
+            with jax.named_scope(scopes.OPTIMIZER):
+                ema = jax.tree_util.tree_map(
+                    partial(jnp.where, ok), ema, state.ema_params)
+        with jax.named_scope(scopes.OPTIMIZER):
+            next_step = state.step + 1
+        new_state = state.replace(step=next_step, params=new_params,
                                   batch_stats=new_stats, opt_state=new_opt_state,
                                   dynamic_scale=ds, ema_params=ema,
                                   comm_state=new_comm)
@@ -472,7 +489,7 @@ def make_eval_step(mesh: Mesh, model: nn.Module, cfg: Config,
     (default: fully replicated). The expert-parallel path passes its split
     layout (expert FFN leaves sharded over the batch/expert axis)."""
     def step(state: TrainState, images, labels):
-        with jax.named_scope("tpudist_eval_forward"):
+        with jax.named_scope(scopes.EVAL_FORWARD):
             outputs = model.apply(
                 {"params": state.params, "batch_stats": state.batch_stats},
                 images, train=False)

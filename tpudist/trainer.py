@@ -38,6 +38,7 @@ from tpudist.data import build_train_val_loaders
 from tpudist.dist import (data_rank_world, replica_rank_world,
                           shard_host_batch)
 from tpudist.models import create_model
+from tpudist.obs import scopes
 from tpudist.train import (TrainState, compute_dtype, create_train_state,
                            lr_for_epoch, make_eval_step, make_train_step)
 from tpudist.utils import (AverageMeter, StepProfiler, Watchdog,
@@ -161,6 +162,17 @@ class Trainer:
 
     def __init__(self, cfg: Config, mesh=None, writer: Any = "auto"):
         self.cfg = cfg
+        # Set-up phases (scopes.INIT_*): mark(name) books the seconds since
+        # the last mark, so the phases sum to this constructor's wall time.
+        t_mark = time.monotonic()
+        spent = dict.fromkeys(scopes.INIT_PHASES, 0.0)
+
+        def mark(name: str) -> None:
+            nonlocal t_mark
+            now = time.monotonic()
+            spent[name] = spent.get(name, 0.0) + (now - t_mark)
+            t_mark = now
+
         # Arm fault injection before anything can fail: an explicit
         # cfg.inject wins, else the spec the launcher put in TPUDIST_INJECT.
         faults.configure(cfg.inject if getattr(cfg, "inject", "") else None)
@@ -171,6 +183,7 @@ class Trainer:
             raise SystemExit(
                 f"--require-platform {cfg.require_platform}: jax initialized "
                 f"on '{jax.default_backend()}' — refusing to run")
+        mark(scopes.INIT_OTHER)
         if mesh is not None:
             self.mesh = mesh
         else:
@@ -182,6 +195,7 @@ class Trainer:
             from tpudist.parallel.plane import build_mesh
             self.mesh = build_mesh(cfg)
         cfg.finalize(self.mesh.devices.size)
+        mark(scopes.INIT_MESH)
         # Data-plane identity: (process_index, process_count) under the real
         # distributed runtime; the launcher's env identity under the elastic
         # CPU gang simulation (dist.data_rank_world) — primary gating rides
@@ -306,6 +320,7 @@ class Trainer:
                          f"capture {cfg.blackbox_capture_steps} steps, "
                          f"cooldown {cfg.blackbox_cooldown_s:g}s "
                          f"(SIGUSR2 or POST /capture for manual)")
+            mark(scopes.INIT_OTHER)
             self.telemetry.emit(
                 "run_start", platform=jax.default_backend(),
                 n_devices=jax.device_count(),
@@ -313,7 +328,12 @@ class Trainer:
                 global_batch=cfg.batch_size,
                 # Surfaced here so the LIVE goodput denominator can include
                 # pre-trainer init (run_end repeats the final number).
-                init_s=round(self.telemetry.init_s, 3))
+                init_s=round(self.telemetry.init_s, 3),
+                # Set-up seconds booked so far (the runtime's `init`, this
+                # constructor's first phases); the whole set is logged at
+                # the constructor's end and read with telemetry.phases().
+                phases={k: round(v, 3) for k, v in
+                        {**telemetry_lib.phases(), **spent}.items()})
         else:
             # Nobody will pop dist.initialize_runtime's init stash: clear
             # it so a LATER in-process Telemetry can't inherit this run's
@@ -453,9 +473,11 @@ class Trainer:
         # history cover kernel choice. seq-axis runs skip it: their
         # attention goes around the ring, not through the kernel.
         self.flash_decision = None
+        mark(scopes.INIT_OTHER)
         if cfg.arch.startswith("vit") and not self.uses_seq_axis:
             self.flash_decision = self._resolve_flash_dispatch()
         seed = cfg.seed if cfg.seed is not None else 0
+        mark(scopes.INIT_DISPATCH)
         if self.uses_seq_axis or self.uses_expert_axis or self.uses_pipe_axis:
             # SPMD collectives can't be traced by model.init outside
             # shard_map: init with the unsharded twin (identical param tree —
@@ -476,6 +498,7 @@ class Trainer:
             self._init_model = self.model
             self.state = create_train_state(jax.random.PRNGKey(seed),
                                             self.model, cfg)
+        mark(scopes.INIT_MODEL_STATE)
         if cfg.pretrained:
             # Reference: torchvision pretrained=True + "=> using pre-trained
             # model" (distributed.py:134-137). Offline: local torchvision
@@ -494,6 +517,7 @@ class Trainer:
         # micro-benchmarks each on the attached chip exactly once per
         # device kind; the traced step's trace-safe lookups then hit the
         # cache. Off-TPU auto resolves to XLA without touching Pallas.
+        mark(scopes.INIT_OTHER)
         self.fused_norm_decision = self._resolve_fused_norm_dispatch()
         # Measurement-honest gradient-compression dispatch
         # (ops/comm_dispatch, the third client of the generic honesty
@@ -520,6 +544,7 @@ class Trainer:
         # on demand (the doctor's SDC probe reads it to know which leaves
         # are dp-replicated and must be bit-identical across replicas).
         self._placement = ((), None, None)
+        mark(scopes.INIT_DISPATCH)
         if self.uses_wus_path:
             from tpudist.parallel import (make_wus_eval_step,
                                           make_wus_train_step)
@@ -529,6 +554,7 @@ class Trainer:
                 self.mesh, s, (), zero_mode="full",
                 data_axis=self.data_axis)
             self.state = self._shard_state(self.state)
+            mark(scopes.INIT_SHARD_STATE)
             self.train_step = make_wus_train_step(
                 self.mesh, self.model, cfg, data_axis=self.data_axis,
                 compress=self.compress)
@@ -555,6 +581,7 @@ class Trainer:
                 zero_mode=("1" if zero_axis else None),
                 data_axis=zero_axis)
             self.state = self._shard_state(self.state)
+            mark(scopes.INIT_SHARD_STATE)
             self.train_step = make_gspmd_train_step(
                 self.mesh, self.model, cfg, self.rules,
                 data_axis=self.data_axis, opt_shard_axis=zero_axis)
@@ -624,7 +651,10 @@ class Trainer:
                     data_axis=self.data_axis)
                 self.state = self._shard_state(self.state)
             else:
+                # Nothing is placed here: the replicated state goes onto the
+                # mesh with the first step's call (init.shard_state reads 0).
                 self._shard_state = lambda s: s
+            mark(scopes.INIT_SHARD_STATE)
             self.train_step = make_train_step(self.mesh, self.model, cfg,
                                               data_axis=self.data_axis,
                                               compress=self.compress,
@@ -636,6 +666,7 @@ class Trainer:
                          f"'{self.data_axis}' "
                          f"(x{self.mesh.shape[self.data_axis]}), error "
                          f"feedback carried in state.comm_state")
+        mark(scopes.INIT_STEP_BUILD)
         # tpudist.doctor (--doctor): the guarded step's host-side policy
         # engine. The SDC probe reads the placement truth via
         # plane.state_specs so only dp-replicated leaves are compared.
@@ -693,12 +724,18 @@ class Trainer:
             if not resume_path:
                 self.log("=> --resume auto: no checkpoint in outpath, "
                          "starting fresh")
+        mark(scopes.INIT_OTHER)
         if resume_path:
             self.load(resume_path)
             # The optimizer-step counter survives checkpoints; anchor the
             # --profile window / watchdog step count to it so a resumed run
             # does not re-fire an already-captured trace window (ADVICE r1 #3).
             self.global_step = int(jax.device_get(self.state.step))
+            mark(scopes.INIT_RESTORE)
+        for name, seconds in spent.items():
+            telemetry_lib.record_phase(name, seconds)
+        self.log("=> set-up phases (s): " + ", ".join(
+            f"{name} {seconds:.2f}" for name, seconds in spent.items()))
 
     def _kick(self) -> None:
         if self.watchdog is not None:
@@ -1430,70 +1467,89 @@ class Trainer:
         # step computes — the serial data/h2d phases shrink to their
         # exposed remainder and the hidden work is reported as the step's
         # prefetch_s bucket (overlap-aware accounting; see telemetry.step).
+        # Host spans (scopes.SPAN_*): every host microsecond of a loop
+        # turn lies inside exactly one tpudist.* annotation — loop_host
+        # before and after the step annotation, the others nested inside —
+        # so a device gap a trace cannot attribute is outside this loop.
+        # An annotation costs a flag test when no trace is live.
+        span = jax.profiler.TraceAnnotation
         pf = None
-        if getattr(cfg, "device_prefetch", True):
-            from tpudist.dist import DevicePrefetcher
-            pf = DevicePrefetcher(loader, self.mesh, self.batch_axes)
+        with span(scopes.SPAN_LOOP_HOST):
+            if getattr(cfg, "device_prefetch", True):
+                from tpudist.dist import DevicePrefetcher
+                pf = DevicePrefetcher(loader, self.mesh, self.batch_axes)
+            batches = iter(pf if pf is not None else loader)
         end = time.time()
         t_prev = end                  # telemetry step boundary (own clock so
-        for i, (images, labels) in enumerate(pf if pf is not None
-                                             else loader):  # meters exact
-            local_bs = (pf.last_local_bs if pf is not None
-                        else int(images.shape[0]))
-            now = time.time()
-            data_time.update(now - end)
-            data_s = now - t_prev     # loader wait incl. prior-step residue
-            self.profiler.step(self.global_step)
-            if self.blackbox is not None:
-                # Consumes an armed deep capture / manual flag; idle cost
-                # is two attribute reads (no lock, no clock — NUM01).
-                self.blackbox.poll(self.global_step)
-            # Kick BEFORE dispatch too: the first step blocks on XLA
-            # compilation, so the full timeout budget must start here.
-            self._kick()
-            # Step boundary: the in-flight step has drained — act on a
-            # pending SIGTERM/SIGINT now (fit() writes the emergency
-            # checkpoint), and consult the hot-loop fault points.
-            if self.preemption is not None:
-                self.preemption.check()
-            if doctor is not None:
-                # Deliver a pending rollback decision (raises
-                # RollbackRequested — fit() restores last-verified-good and
-                # replays the epoch minus the poisoned window), then run
-                # the periodic SDC probe. Both happen HERE, at the step
-                # boundary where the in-flight step has drained: the probe
-                # digests a settled state, and a rollback never tears a
-                # dispatched step.
-                doctor.check_response()
-                if doctor.should_probe(self.global_step):
-                    self._kick()
-                    if doctor.probe(self.global_step, self.state) == "evict":
-                        self.log_all(
-                            f"=> doctor: this rank's replicated state is "
-                            f"minority-divergent in {doctor.sdc_windows} "
-                            f"consecutive probes — silent data corruption "
-                            f"on this host; self-quarantining (exit "
-                            f"{faults.SDC_EXIT_CODE}, no checkpoint "
-                            f"written)")
-                        raise SystemExit(faults.SDC_EXIT_CODE)
-            faults.maybe_rank_exit(self.global_step)
-            faults.maybe_slow_peer(self.global_step)
-            faults.maybe_straggle(self.global_step)
-            if faults.armed("bitflip"):
-                # SDC injection: corrupt this rank's live params in place —
-                # nothing non-finite, only the cross-replica digest probe
-                # can see it.
-                self.state = faults.maybe_bitflip(self.global_step,
-                                                  self.state)
-            if faults.armed("lossbomb"):
-                # Health injection: poison the head so the loss spikes
-                # (finite) — the EWMA detector, not the sentinel, must act.
-                self.state = faults.maybe_lossbomb(self.global_step,
-                                                   self.state)
-            step_num = self.global_step
+        i = -1                        # meters exact)
+        while True:
+            with span(scopes.SPAN_LOOP_HOST):
+                try:
+                    if pf is not None:    # its spans: dist.DevicePrefetcher
+                        images, labels = next(batches)
+                    else:
+                        with span(scopes.SPAN_LOADER_NEXT):
+                            images, labels = next(batches)
+                except StopIteration:
+                    break
+                i += 1
+                local_bs = (pf.last_local_bs if pf is not None
+                            else int(images.shape[0]))
+                now = time.time()
+                data_time.update(now - end)
+                data_s = now - t_prev     # loader wait incl. prior-step residue
+                self.profiler.step(self.global_step)
+                if self.blackbox is not None:
+                    # Consumes an armed deep capture / manual flag; idle cost
+                    # is two attribute reads (no lock, no clock — NUM01).
+                    self.blackbox.poll(self.global_step)
+                # Kick BEFORE dispatch too: the first step blocks on XLA
+                # compilation, so the full timeout budget must start here.
+                self._kick()
+                # Step boundary: the in-flight step has drained — act on a
+                # pending SIGTERM/SIGINT now (fit() writes the emergency
+                # checkpoint), and consult the hot-loop fault points.
+                if self.preemption is not None:
+                    self.preemption.check()
+                if doctor is not None:
+                    # Deliver a pending rollback decision (raises
+                    # RollbackRequested — fit() restores last-verified-good and
+                    # replays the epoch minus the poisoned window), then run
+                    # the periodic SDC probe. Both happen HERE, at the step
+                    # boundary where the in-flight step has drained: the probe
+                    # digests a settled state, and a rollback never tears a
+                    # dispatched step.
+                    doctor.check_response()
+                    if doctor.should_probe(self.global_step):
+                        self._kick()
+                        if doctor.probe(self.global_step, self.state) == "evict":
+                            self.log_all(
+                                f"=> doctor: this rank's replicated state is "
+                                f"minority-divergent in {doctor.sdc_windows} "
+                                f"consecutive probes — silent data corruption "
+                                f"on this host; self-quarantining (exit "
+                                f"{faults.SDC_EXIT_CODE}, no checkpoint "
+                                f"written)")
+                            raise SystemExit(faults.SDC_EXIT_CODE)
+                faults.maybe_rank_exit(self.global_step)
+                faults.maybe_slow_peer(self.global_step)
+                faults.maybe_straggle(self.global_step)
+                if faults.armed("bitflip"):
+                    # SDC injection: corrupt this rank's live params in place —
+                    # nothing non-finite, only the cross-replica digest probe
+                    # can see it.
+                    self.state = faults.maybe_bitflip(self.global_step,
+                                                      self.state)
+                if faults.armed("lossbomb"):
+                    # Health injection: poison the head so the loss spikes
+                    # (finite) — the EWMA detector, not the sentinel, must act.
+                    self.state = faults.maybe_lossbomb(self.global_step,
+                                                       self.state)
+                step_num = self.global_step
             # StepTraceAnnotation groups this step's device ops under one
             # labeled row in XProf/Perfetto when --profile is capturing.
-            with jax.profiler.StepTraceAnnotation("train", step_num=step_num):
+            with jax.profiler.StepTraceAnnotation(scopes.STEP,
+                                                  step_num=step_num):
                 t_h = time.time()
                 if pf is None:
                     images, labels = shard_host_batch(
@@ -1504,98 +1560,104 @@ class Trainer:
                     # sentinel, not this code, must catch the damage).
                     images = faults.maybe_nanbomb(step_num, images)
                 t_c = time.time()
-                self.state, metrics = self.train_step(self.state, images,
-                                                      labels, lr_arr)
+                with span(scopes.SPAN_DISPATCH):
+                    self.state, metrics = self.train_step(
+                        self.state, images, labels, lr_arr)
                 t_done = time.time()
-            h2d_s, compute_s = t_c - t_h, t_done - t_c
-            prefetch_s = None
-            if pf is not None:
-                # Stage batch N+1 while step N is in flight on the device:
-                # the whole point of the prefetcher. This host time is
-                # OVERLAPPED work — it rides the step event's prefetch_s
-                # field, not the serial data/h2d buckets.
-                prefetch_s = pf.poke()
-            first_dispatch = not self._train_dispatched
-            self._train_dispatched = True
-            if doctor is not None:
-                # Which global sample positions this step consumed — the
-                # mapping a rollback needs to excise the poisoned window
-                # from the replayed order. Host ints, bounded dict.
-                consumed = local_bs * self.data_world
-                doctor.note_step(step_num, epoch, self._epoch_consumed,
-                                 self._epoch_consumed + consumed)
-            drain.push(metrics, n=images.shape[0], step=step_num)
-            drain_ovl_s = None
-            if async_drain:
-                # Materialize PRIOR steps' metrics while this step's
-                # compute is in flight (their async copies landed behind
-                # the later dispatches) — overlapped work, booked in the
-                # step event's drain_ovl_s bucket like prefetch_s.
-                t_do = time.time()
-                drain.drain_ready()
-                drain_ovl_s = time.time() - t_do
-            self.global_step += 1
-            self._epoch_consumed += local_bs * self.data_world
-            self._kick()
-            batch_time.update(time.time() - end)
-            end = time.time()
-            drain_s = 0.0
-            if i % cfg.print_freq == 0:
-                with jax.profiler.TraceAnnotation("tpudist.metric_drain"):
-                    t_d = time.time()
-                    # Async mode keeps the one-step lag even at display
-                    # time — a full drain here would block on the step
-                    # just dispatched, re-exposing exactly the sync this
-                    # flag removes. The console line trails by one step.
-                    drain.drain_ready() if async_drain else drain.drain()
-                    drain_s = time.time() - t_d
-                self.log(progress.display(i))
-            if tel is not None:
-                step_s = time.time() - t_prev
-                mfu = None
-                if not first_dispatch and self._flops_per_step \
-                        and self._peak_flops:
-                    mfu = self._flops_per_step / (step_s * self._peak_flops)
-                # First dispatch blocked on trace+XLA compile: accounted as
-                # compile, not productive step time.
-                tel.step(step=step_num, epoch=epoch, data_s=data_s,
-                         h2d_s=h2d_s, compute_s=compute_s, drain_s=drain_s,
-                         step_s=step_s,
-                         compile_s=compute_s if first_dispatch else 0.0,
-                         mfu=mfu, prefetch_s=prefetch_s,
-                         drain_ovl_s=drain_ovl_s)
-                if first_dispatch:
-                    # AFTER the step event so its one-off cost lands in the
-                    # compile bucket, not in this step's step_s (the program
-                    # is already warm in the executable cache when one is
-                    # configured).
-                    self._resolve_step_flops(images, labels, lr_arr)
-                    # Reset the METER clock too: without this the next
-                    # step's data_time/batch_time console meters would
-                    # absorb the cost-analysis compile as phantom data wait.
-                    end = time.time()
-            t_prev = time.time()
-        drain.drain()
+            with span(scopes.SPAN_LOOP_HOST):
+                h2d_s, compute_s = t_c - t_h, t_done - t_c
+                prefetch_s = None
+                if pf is not None:
+                    # Stage batch N+1 while step N is in flight on the device:
+                    # the whole point of the prefetcher. This host time is
+                    # OVERLAPPED work — it rides the step event's prefetch_s
+                    # field, not the serial data/h2d buckets.
+                    prefetch_s = pf.poke()
+                first_dispatch = not self._train_dispatched
+                self._train_dispatched = True
+                if doctor is not None:
+                    # Which global sample positions this step consumed — the
+                    # mapping a rollback needs to excise the poisoned window
+                    # from the replayed order. Host ints, bounded dict.
+                    consumed = local_bs * self.data_world
+                    doctor.note_step(step_num, epoch, self._epoch_consumed,
+                                     self._epoch_consumed + consumed)
+                with span(scopes.SPAN_DRAIN_READY):
+                    drain.push(metrics, n=images.shape[0], step=step_num)
+                    drain_ovl_s = None
+                    if async_drain:
+                        # Materialize PRIOR steps' metrics while this step's
+                        # compute is in flight (their async copies landed
+                        # behind the later dispatches) — overlapped work,
+                        # booked in the step event's drain_ovl_s bucket like
+                        # prefetch_s.
+                        t_do = time.time()
+                        drain.drain_ready()
+                        drain_ovl_s = time.time() - t_do
+                self.global_step += 1
+                self._epoch_consumed += local_bs * self.data_world
+                self._kick()
+                batch_time.update(time.time() - end)
+                end = time.time()
+                drain_s = 0.0
+                if i % cfg.print_freq == 0:
+                    with span(scopes.SPAN_METRIC_DRAIN):
+                        t_d = time.time()
+                        # Async mode keeps the one-step lag even at display
+                        # time — a full drain here would block on the step
+                        # just dispatched, re-exposing exactly the sync this
+                        # flag removes. The console line trails by one step.
+                        drain.drain_ready() if async_drain else drain.drain()
+                        drain_s = time.time() - t_d
+                    self.log(progress.display(i))
+                if tel is not None:
+                    step_s = time.time() - t_prev
+                    mfu = None
+                    if not first_dispatch and self._flops_per_step \
+                            and self._peak_flops:
+                        mfu = self._flops_per_step / (step_s * self._peak_flops)
+                    # First dispatch blocked on trace+XLA compile: accounted as
+                    # compile, not productive step time.
+                    tel.step(step=step_num, epoch=epoch, data_s=data_s,
+                             h2d_s=h2d_s, compute_s=compute_s, drain_s=drain_s,
+                             step_s=step_s,
+                             compile_s=compute_s if first_dispatch else 0.0,
+                             mfu=mfu, prefetch_s=prefetch_s,
+                             drain_ovl_s=drain_ovl_s)
+                    if first_dispatch:
+                        # AFTER the step event so its one-off cost lands in the
+                        # compile bucket, not in this step's step_s (the program
+                        # is already warm in the executable cache when one is
+                        # configured).
+                        self._resolve_step_flops(images, labels, lr_arr)
+                        # Reset the METER clock too: without this the next
+                        # step's data_time/batch_time console meters would
+                        # absorb the cost-analysis compile as phantom data wait.
+                        end = time.time()
+                t_prev = time.time()
+        with span(scopes.SPAN_METRIC_DRAIN):
+            drain.drain()
         if doctor is not None:
             # A spike surfacing in the epoch-end flush must act BEFORE this
             # epoch's validate/save — otherwise the poisoned weights get
             # checkpointed first and only un-written one epoch later.
             doctor.check_response()
         self.profiler.epoch_end()
-        self.log(f"||==> Train: Epoch[{epoch}]\tLoss {losses.avg:.4e}\t"
-                 f"Acc@1 {top1.avg:6.2f}")
-        skipped = getattr(loader, "samples_skipped", 0)
-        retried = getattr(loader, "samples_retried", 0)
-        if skipped or retried:
-            # Data-path degradation meter: skips consumed corruption budget;
-            # retries healed transiently (see data/loader.py).
-            self.log(f"||==> Data: Epoch[{epoch}]\tsamples_skipped {skipped}"
-                     f"\tsamples_retried {retried}")
-            self.scalar("Data_samples_skipped", skipped, epoch)
-            self.scalar("Data_samples_retried", retried, epoch)
-        self.scalar("lr", lr, epoch)
-        self.scalar("Train_ce_loss", losses.avg, epoch)
-        self.scalar("Train_top1_accuracy", top1.avg, epoch)
+        with span(scopes.SPAN_LOOP_HOST):
+            self.log(f"||==> Train: Epoch[{epoch}]\tLoss {losses.avg:.4e}\t"
+                     f"Acc@1 {top1.avg:6.2f}")
+            skipped = getattr(loader, "samples_skipped", 0)
+            retried = getattr(loader, "samples_retried", 0)
+            if skipped or retried:
+                # Data-path degradation meter: skips consumed corruption
+                # budget; retries healed transiently (see data/loader.py).
+                self.log(f"||==> Data: Epoch[{epoch}]\tsamples_skipped "
+                         f"{skipped}\tsamples_retried {retried}")
+                self.scalar("Data_samples_skipped", skipped, epoch)
+                self.scalar("Data_samples_retried", retried, epoch)
+            self.scalar("lr", lr, epoch)
+            self.scalar("Train_ce_loss", losses.avg, epoch)
+            self.scalar("Train_top1_accuracy", top1.avg, epoch)
         return losses.avg, top1.avg
 
     def validate(self, loader, epoch: int) -> float:
@@ -1724,7 +1786,10 @@ class Trainer:
     def fit(self, train_loader=None, val_loader=None) -> float:
         cfg = self.cfg
         if train_loader is None or val_loader is None:
+            t_build = time.monotonic()
             train_loader, val_loader = build_train_val_loaders(cfg)
+            telemetry_lib.record_phase(scopes.INIT_LOADERS,
+                                       time.monotonic() - t_build)
 
         if cfg.evaluate:   # evaluate-only path (distributed.py:181-183)
             try:

@@ -8,10 +8,12 @@ TensorBoard/Perfetto/XProf).
 (``--profile start:end``): it starts the trace when the global step enters
 the window and stops it when the step leaves, writing to
 ``<outpath>/profile/attempt_<n>`` (one subdir per launcher restart attempt,
-so a relaunch cannot overwrite the pre-crash capture). The trainer labels
-the capture: ``jax.profiler.StepTraceAnnotation("train", ...)`` around each
-step and ``TraceAnnotation`` rows for the data-wait/H2D/drain phases, so
-XProf/Perfetto group device ops by step and phase out of the box.
+so a relaunch cannot overwrite the pre-crash capture). The program labels
+the capture under the names of ``tpudist/obs/scopes.py``: a
+``StepTraceAnnotation`` around each step, a ``tpudist.*`` annotation on every
+other part of a loop turn, and a ``tpudist_*`` named scope on every device
+operation, so XProf/Perfetto group host time by phase and device ops by
+forward / backward / optimizer and by block out of the box.
 Capturing a bounded window (not whole-run) is the
 standard TPU practice — traces are large and the interesting steps are the
 post-compilation steady state.
