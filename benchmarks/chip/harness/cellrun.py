@@ -19,7 +19,7 @@ import os
 import statistics
 import time
 
-from harness import check, trace_reduce, traffic
+from harness import check, scope_reduce, trace_reduce, traffic
 from harness.spans import Spans
 
 
@@ -94,6 +94,22 @@ def _flops(compiled):
     return float((cost or {}).get("flops", 0.0)) or None
 
 
+def _attention_dispatch(trainer) -> dict:
+    """Which attention kernel the program chose for this run and on what
+    grounds: the trainer's own `flash_decision` (None for a model without
+    attention) and the seconds of the constructor's `init.dispatch` phase,
+    in which a probe runs where a verdict is measured."""
+    dec = trainer.flash_decision
+    if dec is None:
+        return {"kernel": None}
+    from tpudist import telemetry
+    fields = {k: dec.get(k) for k in (
+        "kernel", "mode", "source", "flash_ms", "xla_ms", "margin",
+        "kernel_rev", "key", "cache_path")}
+    fields["init_dispatch_s"] = telemetry.phases().get("init.dispatch")
+    return fields
+
+
 def _memory_peak(devices) -> tuple[int, dict]:
     stats = [d.memory_stats() or {} for d in devices]
     return max(int(s.get("peak_bytes_in_use", 0)) for s in stats), stats[0]
@@ -135,10 +151,16 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
     seed31 = int(seed) % (2 ** 31 - 1)
     argv = [str(a).format(batch=batch, seed=seed31, outpath=outpath)
             for a in config["trainer_argv"]]
+    # a start-up probe's verdict is read only from the checkout that measured
+    # it: the program's default (~/.cache/tpudist) would hand a verdict of the
+    # parent's kernel to the change wherever both run on one machine
+    os.environ["TPUDIST_DISPATCH_CACHE"] = os.path.join(workdir, "dispatch")
     from tpudist.config import from_args
     from tpudist.trainer import Trainer
     cfg = from_args(argv)
     trainer = Trainer(cfg, writer=None)
+    attention = _attention_dispatch(trainer)
+    _say("attention_dispatch", **attention)
     phase("trainer_init_s")
 
     # --- seeded weights: the reference draws them, the trainer restores them
@@ -292,7 +314,7 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
               "memory_peak_bytes": memory_peak}
     result = {"correct": correct, "attempted": steps, "failed": 0,
               "workload": workload["name"], "seed": seed, "seconds": seconds,
-              "device": device}
+              "attention_kernel": attention["kernel"], "device": device}
     if not trace:
         values = {"train_img_per_s_chip": img_per_s_chip,
                   "hbm_step_gib": step_bytes / 2 ** 30, "setup_s": setup_s}
@@ -330,7 +352,9 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
             result["metrics"][m["name"]] = {"value": value, "unit": m["unit"]}
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
-        result["breakdown"] = {"device_ops": reduced["device_ops"],
-                               "idle_gaps": reduced["idle_gaps"]}
+        result["breakdown"] = {
+            "device_ops": scope_reduce.label_ops(
+                reduced["device_ops"], scope_reduce.step_scopes(ctx)),
+            "idle_gaps": reduced["idle_gaps"]}
     check.print_rows(rows, correct)
     return result

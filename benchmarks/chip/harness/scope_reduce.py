@@ -17,6 +17,8 @@ tensorflow or xprof import, in a process that already holds 14-19 GiB.
     by_scope(events, scopes, ..) -> device ms a step by phase and by block
     step_scopes(ctx)             -> the two above for the run's newest trace,
                                     once a process; prints `bench scope_ms`
+    label_ops(device_ops, ...)   -> the result line's `breakdown.device_ops`
+                                    with those names beside the bare ones
 
 **What the phases are.** An operation goes to the phase of its own `op_name`,
 and XLA gives a fusion its root's: `fwd` / `bwd` / `opt` are "time in fusions
@@ -216,18 +218,38 @@ def phase_of(op_name: str) -> str | None:
     return None
 
 
-def block_of(op_name: str) -> str | None:
-    """The block of a forward or backward operation: the two path elements
-    after the model's name (`layer3_0/conv1`, `encoder_layer_3/mlp_0`), the
-    trailing primitive left out; None outside the model."""
+def block_of(op_name: str, depth: int = 2) -> str | None:
+    """The block of a forward or backward operation: the `depth` path
+    elements after the model's name (`layer3_0/conv1`,
+    `encoder_layer_3/mlp_0`), the trailing primitive left out; None outside
+    the model."""
     for part in op_name.split(";"):
         path = part.split("/")
         at = next((i for i, el in enumerate(path) if "tpudist_forward" in el),
                   None)
         if at is not None:
             inner = path[at + 2:-1]       # after the model's name
-            return "/".join(inner[:2]) or "(top)"
+            return "/".join(inner[:depth]) or "(top)"
     return None
+
+
+def label_ops(device_ops: list, scopes: dict | None) -> list:
+    """`trace_reduce.reduce`'s `device_ops` ([[bare name, seconds], ...])
+    with each operation's bucket and block beside its name, as `step_scopes`
+    ranks it: `fusion.87 [bwd encoder_layer_3/self_attention/attn_scores]`.
+    An operation it does not rank keeps its bare name, and so does every
+    one where there are no scopes (`scopes` None)."""
+    ranked = {name: (bucket, op_name) for name, _, bucket, op_name
+              in (scopes or {}).get("top_ops", [])}
+    out = []
+    for name, seconds in device_ops:
+        if name in ranked:
+            bucket, op_name = ranked[name]
+            block = (block_of(op_name or "", depth=3)
+                     if bucket in ("fwd", "bwd") else None)
+            name = f"{name} [{bucket}{' ' + block if block else ''}]"
+        out.append([name, seconds])
+    return out
 
 
 # --- device time by scope ----------------------------------------------------

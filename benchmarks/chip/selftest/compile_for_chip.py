@@ -53,7 +53,9 @@ def main():
         argv = [str(a).format(batch=batch, seed=0, outpath="/tmp/x")
                 for a in config["trainer_argv"]]
         cfg = from_args(argv).finalize(chips)
-        kw = {"flash": False} if cfg.arch.startswith("vit") else {}
+        # `auto` compiles XLA's attention here: no chip, no probe, no verdict
+        kw = ({"flash": cfg.flash == "on"} if cfg.arch.startswith("vit")
+              else {})
         model = create_model(cfg.arch, num_classes=cfg.num_classes,
                              dtype=compute_dtype(cfg),
                              sync_batchnorm=cfg.sync_batchnorm,

@@ -3,7 +3,11 @@
 Run by path: `python -m pytest benchmarks/chip/selftest -q` (tier-1 does not
 collect this directory and there is no conftest here). What is checked:
 
-- a sound run of each tiny configuration comes out `correct`;
+- a sound run of each tiny configuration comes out `correct`, and says which
+  attention kernel the program chose (`bench attention_dispatch`,
+  `attention_kernel`), with the program's verdicts kept under `_work/dispatch/`;
+- every `auto|on|off` flag a configuration runs with has its reason under
+  `pinned`, in the spelling it runs with;
 - with the timed path broken underneath (a step that returns its state
   unchanged; a step that trains on half of the batch) `correct` is false;
 - the control: the reference itself, computed with fp8 operands and put in
@@ -15,6 +19,7 @@ collect this directory and there is no conftest here). What is checked:
 A CPU run reports no device time: nothing here reads a rate or a share.
 """
 
+import glob
 import json
 import os
 import subprocess
@@ -66,8 +71,12 @@ def tiny_run(config_name, *, traffic="staged", seed=11, chips=1, trace=False,
         step_hook=step_hook)
 
 
-@pytest.mark.parametrize("config_name", ["resnet18_tiny", "vit_tiny"])
-def test_sound_run_is_correct(config_name):
+@pytest.mark.parametrize("config_name,attention", [
+    ("resnet18_tiny", {"kernel": None}),
+    # 5 tokens at 32 px: the program rules the kernel out before it asks for
+    # the platform (at the cell's 197 tokens a CPU reads `source: platform`)
+    ("vit_tiny", {"kernel": "xla", "mode": "auto", "source": "ineligible"})])
+def test_sound_run_is_correct(config_name, attention, capfd):
     result = tiny_run(config_name, seed=2 ** 31 + 12345)
     assert result["correct"] is True
     assert result["attempted"] >= 1 and result["failed"] == 0
@@ -75,6 +84,38 @@ def test_sound_run_is_correct(config_name):
                                       "setup_s"}
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert result["device"]["count"] == 1
+    # which attention ran: `--flash auto` is XLA's here, without a probe
+    said = [json.loads(line.split(" ", 2)[2])
+            for line in capfd.readouterr().out.splitlines()
+            if line.startswith("bench attention_dispatch ")]
+    assert len(said) == 1
+    assert {k: said[0][k] for k in attention} == attention
+    assert result["attention_kernel"] == attention["kernel"]
+    assert os.environ["TPUDIST_DISPATCH_CACHE"] == os.path.join(
+        CHIP, "_work", "dispatch")
+
+
+DISPATCH_FLAGS = ("--flash", "--fused-bn", "--compress-grads")
+
+
+@pytest.mark.parametrize("path", sorted(glob.glob(
+    os.path.join(CHIP, "configs", "*.json"))), ids=os.path.basename)
+def test_every_dispatch_flag_is_pinned_as_it_runs(path):
+    """A configuration's text and its flags agree: each `auto|on|off` flag of
+    `trainer_argv` has its reason under `pinned`, keyed by the flag and the
+    value it runs with (`--flash auto`; keys may join several with ` / `)."""
+    config = load(path)
+    argv = config["trainer_argv"]
+    pinned = {part for key in config["pinned"] for part in key.split(" / ")}
+    runs = [f"{flag} {argv[argv.index(flag) + 1]}" for flag in DISPATCH_FLAGS
+            if flag in argv]
+    assert runs, "no dispatch flag is written out"
+    assert all(r.split()[1] in ("auto", "on", "off") for r in runs), runs
+    assert [r for r in runs if r not in pinned] == []
+    # and no reason is left behind for a value the flag no longer has
+    stale = [p for p in pinned if p.split()[0] in DISPATCH_FLAGS
+             and p not in runs]
+    assert stale == []
 
 
 def _unchanged(step, state, images, labels, lr):

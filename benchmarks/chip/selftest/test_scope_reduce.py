@@ -197,6 +197,23 @@ def test_block_of():
     assert scope_reduce.block_of(OPT) is None
 
 
+def test_label_ops_names_what_is_ranked_and_leaves_the_rest_bare():
+    attn = ("jit(step)/transpose(jvp(tpudist_forward))/VisionTransformer/"
+            "encoder_layer_3/self_attention/attn_scores/bqhd,bkhd->bhqk/"
+            "dot_general")
+    scopes = {"top_ops": [["fusion.87", 0.893, "bwd", attn],
+                          ["fusion.4", 0.5, "opt", OPT],
+                          ["copy.5", 0.2, "layout_copy", FWD],
+                          ["fusion.9", 0.1, "fwd", FWD]]}
+    ops = [["fusion.87", 0.0259], ["copy.5", 0.006], ["fusion.4", 0.005],
+           ["fusion.9", 0.004], ["fusion.1", 0.003]]
+    assert scope_reduce.label_ops(ops, scopes) == [
+        ["fusion.87 [bwd encoder_layer_3/self_attention/attn_scores]", 0.0259],
+        ["copy.5 [layout_copy]", 0.006], ["fusion.4 [opt]", 0.005],
+        ["fusion.9 [fwd layer1_0/conv1]", 0.004], ["fusion.1", 0.003]]
+    assert scope_reduce.label_ops(ops, None) == ops
+
+
 def hand_made():
     """One device, a window of [100, 1100) us. Steps (module events):
     [0, 300) starts before the window: not whole; [400, 700) and [750, 1050)
