@@ -200,40 +200,50 @@ def _bn_case(key, rows: int, channels: int, residual: bool) -> dict:
     return out
 
 
-def _flash_case(key) -> dict:
+def _flash_case(key, schedule: str) -> dict:
     """Flash attention fwd + dQ/dK/dV at ViT-B/16 widths vs XLA attention
     on the f32-widened inputs at highest matmul precision (tolerances of
     tests/test_flash_attention.py: 2e-2 forward, 1e-2 relative to max|ref|
-    on gradients)."""
+    on gradients), from one fused projection [B, T, H, 3, D]. ``whole_seq``
+    is the fused entry as ``MultiHeadAttention`` calls it (the schedule this
+    shape selects, asserted); ``streaming`` is the split entry, the
+    streaming kernels."""
     import jax
     import jax.numpy as jnp
-    from tpudist.ops.pallas.flash_attention import flash_attention
-    from tpudist.parallel.ring_attention import attention
+    from tpudist.ops.pallas.flash_attention import (
+        flash_attention, flash_attention_qkv, schedule_for)
+    from tpudist.parallel.ring_attention import attention, split_qkv
     f32 = jnp.float32
-    kq, kk, kv, kg = jax.random.split(key, 4)
-    q, k, v = (jax.random.normal(kk_, VIT_B16_ATTENTION, jnp.bfloat16)
-               for kk_ in (kq, kk, kv))
+    b, t, h, d = VIT_B16_ATTENTION
+    kq, kg = jax.random.split(key)
+    qkv = jax.random.normal(kq, (b, t, h, 3, d), jnp.bfloat16)
     g = jax.random.normal(kg, VIT_B16_ATTENTION, f32)
 
     def loss(fn):
-        def f(q, k, v):
-            o = fn(q, k, v)
+        def f(qkv):
+            o = fn(qkv)
             return (o.astype(f32) * g).sum(), o
         return f
 
-    flash = lambda q, k, v: flash_attention(  # noqa: E731
-        q, k, v, interpret=INTERPRET)
+    if schedule == "whole_seq":
+        assert schedule_for(t, h, d, qkv.dtype) == schedule
+        flash = lambda x: flash_attention_qkv(  # noqa: E731
+            x, interpret=INTERPRET)
+    else:
+        flash = lambda x: flash_attention(  # noqa: E731
+            *split_qkv(x), interpret=INTERPRET)
     (_, o1), g1 = jax.jit(jax.value_and_grad(
-        loss(flash), argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        loss(flash), has_aux=True))(qkv)
     with jax.default_matmul_precision("highest"):
         (_, o2), g2 = jax.jit(jax.value_and_grad(
-            loss(attention), argnums=(0, 1, 2), has_aux=True))(
-                q.astype(f32), k.astype(f32), v.astype(f32))
-    out = _check("flash", [("o", o1.astype(f32), o2)],
+            loss(lambda x: attention(*split_qkv(x))), has_aux=True))(
+                qkv.astype(f32))
+    out = _check(f"flash {schedule}", [("o", o1.astype(f32), o2)],
                  lambda want: (2e-2, 2e-2))
     out.update(_check(
-        "flash", [(n, a.astype(f32), b) for n, a, b in zip(
-            ("dq", "dk", "dv"), g1, g2)],
+        f"flash {schedule}",
+        [(n, a.astype(f32), b_) for n, a, b_ in zip(
+            ("dq", "dk", "dv"), split_qkv(g1), split_qkv(g2))],
         lambda want: (1e-2, 1e-2 * float(jnp.max(jnp.abs(want))))))
     return out
 
@@ -251,8 +261,12 @@ def phase_kernels(seed: int) -> None:
             name = f"bn_m{rows}_c{c}_{'res' if residual else 'plain'}"
             key, sub = jax.random.split(key)
             cases[name] = _bn_case(sub, rows, c, residual)
-    cases["flash_b{}_t{}_h{}_d{}".format(*VIT_B16_ATTENTION)] = \
-        _flash_case(key)
+    # Both schedules at ViT-B/16's shape: the one it selects (and --flash
+    # auto runs wherever the kernel wins its probe), and the streaming one.
+    for schedule in ("whole_seq", "streaming"):
+        key, sub = jax.random.split(key)
+        cases["flash_{}_b{}_t{}_h{}_d{}".format(
+            schedule, *VIT_B16_ATTENTION)] = _flash_case(sub, schedule)
     # What the default flag (--flash auto) resolves to for ViT-B/16 at the
     # per-chip batch the trainer would run: the same decide() the Trainer
     # calls, so a kernel the compiler refuses raises here too.
@@ -261,6 +275,7 @@ def phase_kernels(seed: int) -> None:
                                     mode="auto")
     say("kernels", ok=True, interpret=INTERPRET,
         fused_norm_rev=fused_norm_rev, flash_rev=flash_rev,
+        flash_schedule=attention_dispatch.schedule(t, h, d, "bfloat16"),
         cases=len(cases),
         worst_err_over_tolerance={k: max(v[n]["err_over_tol"] for n in v)
                                   for k, v in cases.items()},

@@ -505,3 +505,123 @@ def test_trainer_emits_dispatch_event_on_cpu(tmp_path):
     assert len(disp) == 1
     assert disp[0]["kernel"] == "xla" and disp[0]["mode"] == "auto" \
         and disp[0]["source"] == "ineligible"
+
+
+# -- PR 28: the probe times the model's own call; KERNEL_REV 3 ----------------
+
+def test_probe_times_the_branches_the_model_calls(monkeypatch):
+    """One helper, ``ring_attention.qkv_attention``: ``MultiHeadAttention``
+    calls it (by identity), and the probe's two timed functions are that
+    call with ``flash`` on and off, on ONE fused [B, T, H, 3, D] array,
+    differentiated with respect to it when ``train``."""
+    import jax.numpy as jnp
+    import numpy as np
+    import importlib
+    vit = importlib.import_module("tpudist.models.vit")
+    # (`tpudist.parallel` re-exports the function under the module's name)
+    ring_attention = importlib.import_module("tpudist.parallel.ring_attention")
+
+    assert vit.qkv_attention is ring_attention.qkv_attention
+    calls = []
+    real = ring_attention.qkv_attention
+
+    def spy(qkv, causal=False, flash=False):
+        calls.append((qkv.shape, causal, flash))
+        return real(qkv, causal=causal, flash=flash)
+
+    monkeypatch.setattr(ring_attention, "qkv_attention", spy)
+    timed = []
+    monkeypatch.setattr(ad, "measure_ms", lambda fn, args, steps, warmup: (
+        timed.append((fn, args)), float(len(timed)))[1])
+    assert ad.measure_attention(1, 16, 2, 64, "float32", True, True) == (
+        1.0, 2.0)
+    (flash_c, (qkv,)), (xla_c, (qkv2,)) = timed
+    assert qkv is qkv2 and qkv.shape == (1, 16, 2, 3, 64)
+    got, want = flash_c(qkv), xla_c(qkv)        # grads wrt the projection
+    assert got.shape == want.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+    assert calls == [((1, 16, 2, 3, 64), True, True),
+                     ((1, 16, 2, 3, 64), True, False)]
+    # eval: the same two calls, forward only
+    calls.clear()
+    flash_f, xla_f = ad.probe_fns(False, False)
+    assert flash_f(qkv).shape == xla_f(qkv).shape == (1, 16, 2, 64)
+    assert [c[1:] for c in calls] == [(False, True), (False, False)]
+    assert jnp.issubdtype(flash_f(qkv).dtype, jnp.floating)
+
+
+@pytest.mark.parametrize("stale_rev", [2, 1])
+def test_verdict_of_an_older_kernel_rev_is_not_used(tmp_path, stale_rev):
+    """KERNEL_REV is 3 (the whole-sequence schedule): a rev-2 verdict in
+    the cache, win or loss, is stale — ``decide`` measures again and the
+    trace-time ``lookup`` does not dispatch on it."""
+    assert ad.kernel_rev() == 3
+    cache = str(tmp_path)
+    d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
+                  measure_pair=_pair(1.0, 2.0), **TPU)
+    assert d["kernel"] == "flash" and d["kernel_rev"] == 3
+    assert ad.lookup(*SHAPE, cache_dir=cache, **TPU) is True
+    path = ad.cache_path(TPU["device_kind"], cache)
+    obj = json.load(open(path))
+    for e in obj["entries"].values():
+        e["kernel_rev"] = stale_rev
+    json.dump(obj, open(path, "w"))
+    assert ad.lookup(*SHAPE, cache_dir=cache, **TPU) is False
+    d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
+                  measure_pair=_pair(16.32, 4.55), **TPU)
+    assert d["source"] == "measured" and d["kernel"] == "xla" \
+        and d["kernel_rev"] == 3
+    d = ad.decide(*SHAPE, mode="auto", cache_dir=cache, measure_pair=_boom,
+                  **TPU)
+    assert d["source"] == "cache" and d["kernel"] == "xla"
+
+
+def test_dispatch_names_the_schedule():
+    """The kernel's own shape test, for the trainer's log line and the
+    telemetry event: ViT-B/16's 197 tokens take the whole-sequence
+    schedule, 2,048 stream; the event carries it when the decision does."""
+    from tpudist.telemetry import validate_event
+    assert ad.schedule(197, 12, 64, "bfloat16") == "whole_seq"
+    assert ad.schedule(2048, 12, 64, "bfloat16") == "streaming"
+    dec = {"kernel": "flash", "mode": "auto", "source": "measured",
+           "key": "k", "flash_ms": 2.1, "xla_ms": 5.6, "margin": 0.6,
+           "schedule": "whole_seq"}
+    fields = ad.event_fields(dec)
+    assert fields["schedule"] == "whole_seq"
+    validate_event({"type": "attention_dispatch", "t": 0.0, "rank": 0,
+                    "attempt": 0,
+                    **fields})
+    dec.pop("schedule")
+    assert "schedule" not in ad.event_fields(dec)
+
+
+def test_trainer_logs_the_schedule_when_the_kernel_runs(tmp_path):
+    """``--flash on`` at an eligible shape: the trainer's decision, its
+    ``=> attention dispatch:`` line and its event say which schedule the
+    shape selects."""
+    from tpudist.config import Config
+    from tpudist.trainer import Trainer
+
+    out = tmp_path / "run"
+    cfg = Config(arch="vit_b_32", num_classes=4, image_size=128,
+                 batch_size=8, epochs=1, workers=0, synthetic=True,
+                 synthetic_size=8, use_amp=False, outpath=str(out),
+                 overwrite="delete", seed=0, telemetry=True, flash="on")
+    t = Trainer(cfg, writer=None)
+    try:
+        dec = t.flash_decision
+        assert dec["kernel"] == "flash" and dec["source"] == "forced"
+        # (128/32)^2 + cls = 17 tokens, 12 heads x 64: one block holds it
+        assert dec["schedule"] == "whole_seq"
+        assert t.model.flash is True
+    finally:
+        from tpudist import telemetry as telemetry_lib
+        t.telemetry.close()
+        telemetry_lib.set_current(None)
+    log = open(out / "experiment.log").read()
+    assert "=> attention dispatch: flash attention (mode on, forced, " \
+        "schedule whole_seq" in log
+    disp = [json.loads(line) for line in open(out / "events.0.jsonl")
+            if '"attention_dispatch"' in line]
+    assert len(disp) == 1 and disp[0]["schedule"] == "whole_seq"

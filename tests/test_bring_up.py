@@ -161,3 +161,21 @@ def test_probe_failure_under_auto_on_tpu_propagates(family, tmp_path,
     # Nothing was cached: the next run asks the compiler again.
     assert not os.path.exists(tmp_path / "verdicts") \
         or not os.listdir(tmp_path / "verdicts")
+
+
+@pytest.mark.parametrize("schedule", ["whole_seq", "streaming"])
+def test_chip_smoke_flash_phase_checks_both_schedules(schedule, monkeypatch):
+    """The bring-up gate's flash case (PR 28): at ViT-B/16's token count and
+    head size it checks the fused entry on the whole-sequence schedule (the
+    kernel ``--flash auto`` now runs on ViT) and the streaming kernels
+    beside it. Here in interpret mode on two heads; on the chip Mosaic
+    compiles the same bodies."""
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke_for_bring_up", os.path.join(REPO, "chip_smoke.py"))
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    monkeypatch.setattr(chip_smoke, "INTERPRET", True)
+    monkeypatch.setattr(chip_smoke, "VIT_B16_ATTENTION", (1, 197, 2, 64))
+    out = chip_smoke._flash_case(jax.random.PRNGKey(0), schedule)
+    assert set(out) == {"o", "dq", "dk", "dv"}
+    assert all(v["err_over_tol"] <= 1.0 for v in out.values())

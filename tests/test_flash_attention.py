@@ -3,6 +3,8 @@ CPU — same kernel body that compiles on TPU) must match plain softmax
 attention bit-for-nearly-bit, across padded/unpadded lengths, causal masks,
 multiple block shapes, and bf16 inputs."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -197,3 +199,184 @@ def test_flash_causal_cross_attention_lengths():
     want = attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+# -- the whole-sequence schedule and the fused QKV entry (PR 28) --------------
+
+def _fused(b=2, t=197, h=2, d=64, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    return jnp.asarray(rng.standard_normal((b, t, h, 3, d)), dtype)
+
+
+@pytest.mark.parametrize("t,heads,d,want", [
+    (197, 12, 64, "whole_seq"),     # ViT-B/16
+    (256, 12, 64, "whole_seq"),
+    (50, 2, 64, "whole_seq"),
+    (197, 6, 64, "whole_seq"),      # ViT-B/16 under tp=2
+    (2048, 12, 64, "streaming"),    # scores outgrow the VMEM budget
+    (197, 3, 64, "streaming"),      # odd head count: no lane-aligned group
+    (197, 2, 32, "streaming"),      # 2 * 96 columns fill no 128-lane tile
+])
+def test_schedule_follows_the_shape(t, heads, d, want):
+    from tpudist.ops.pallas.flash_attention import schedule_for
+    assert schedule_for(t, heads, d, jnp.bfloat16) == want
+
+
+def test_head_group_is_the_largest_that_fits():
+    # (the package re-exports the function under the module's name)
+    fa = importlib.import_module("tpudist.ops.pallas.flash_attention")
+    assert fa._head_group(197, 12, 64, 2) == 12
+    assert fa._head_group(197, 6, 64, 2) == 6
+    assert fa._head_group(197, 3, 64, 2) is None
+    assert fa._head_group(197, 4, 32, 2) == 4
+    # a longer sequence leaves room for fewer heads, then for none
+    assert fa._head_group(640, 12, 64, 2) == 2
+    assert fa._head_group(2048, 12, 64, 2) is None
+    for t, h, d in ((197, 12, 64), (640, 12, 64), (512, 16, 64)):
+        g = fa._head_group(t, h, d, 2)
+        assert fa._whole_seq_vmem_bytes(t, d, g, 2) <= fa._VMEM_BUDGET
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t", [50, 197, 256])
+def test_whole_seq_matches_plain(t, causal, dtype):
+    """The whole-sequence schedule (head_dim 64, two heads: one
+    lane-aligned group) against ``attention``, forward and backward, under
+    the streaming kernel's tolerances."""
+    from tpudist.ops.pallas.flash_attention import (flash_attention_qkv,
+                                                    schedule_for)
+    from tpudist.parallel.ring_attention import split_qkv
+    dt = jnp.float32 if dtype == "float32" else jnp.bfloat16
+    ftol, gtol = (2e-5, 1e-5) if dtype == "float32" else (2e-2, 1e-2)
+    qkv = _fused(t=t, seed=t + causal, dtype=dt)
+    assert schedule_for(t, 2, 64, dt) == "whole_seq"
+    g = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (2, t, 2, 64)), jnp.float32)
+    f32 = lambda x: np.asarray(x, np.float32)  # noqa: E731
+
+    def plain(x, causal):
+        return attention(*split_qkv(x), causal=causal)
+
+    got = flash_attention_qkv(qkv, causal=causal)
+    want = plain(qkv, causal)
+    assert got.dtype == dt
+    np.testing.assert_allclose(f32(got), f32(want), rtol=ftol, atol=ftol)
+
+    def loss(fn):
+        return lambda x: (fn(x, causal=causal).astype(jnp.float32) * g).sum()
+
+    got = split_qkv(jax.grad(loss(flash_attention_qkv))(qkv))
+    want = split_qkv(jax.grad(loss(plain))(qkv))
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            f32(a), f32(b), rtol=gtol,
+            atol=gtol * max(1e-6, float(np.abs(f32(b)).max())),
+            err_msg=f"d{name} t={t} causal={causal} {dtype}")
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("heads", [2, 4])
+def test_fused_entry_matches_split_entry(heads, causal):
+    """``flash_attention_qkv`` on [B, T, H, 3, D] against the split entry
+    on its slices, to float32 rounding; its gradient arrives in the
+    projection's own layout and matches the streaming kernels' (the split
+    entry)."""
+    from tpudist.ops.pallas import flash_attention_qkv
+    from tpudist.parallel.ring_attention import split_qkv
+    qkv = _fused(t=197, h=heads, seed=heads)
+    g = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (2, 197, heads, 64)), jnp.float32)
+
+    def fused(x):
+        return (flash_attention_qkv(x, causal=causal) * g).sum()
+
+    def split(x):
+        return (flash_attention(*split_qkv(x), causal=causal) * g).sum()
+
+    np.testing.assert_allclose(
+        np.asarray(flash_attention_qkv(qkv, causal=causal)),
+        np.asarray(flash_attention(*split_qkv(qkv), causal=causal)),
+        rtol=2e-5, atol=2e-5)
+    got, want = jax.grad(fused)(qkv), jax.grad(split)(qkv)
+    assert got.shape == qkv.shape == (2, 197, heads, 3, 64)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_fused_entry_falls_back_to_split_where_no_group_fits(monkeypatch):
+    """An odd head count has no lane-aligned group: the fused entry goes
+    through slices and the split entry (the streaming kernels), same
+    numbers, gradient still in the projection's layout."""
+    from tpudist.ops.pallas import flash_attention_qkv
+    from tpudist.parallel.ring_attention import split_qkv
+    fa = importlib.import_module("tpudist.ops.pallas.flash_attention")
+    qkv = _fused(b=1, t=64, h=3, d=64, seed=11)
+    assert fa.schedule_for(64, 3, 64, qkv.dtype) == "streaming"
+    monkeypatch.setattr(fa, "_qkv_vjp", lambda *a, **k: pytest.fail(
+        "the whole-sequence kernels ran on a shape they cannot tile"))
+    got = flash_attention_qkv(qkv)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(attention(*split_qkv(qkv))),
+                               rtol=2e-5, atol=2e-5)
+    grad = jax.grad(lambda x: flash_attention_qkv(x).sum())(qkv)
+    want = jax.grad(lambda x: attention(*split_qkv(x)).sum())(qkv)
+    assert grad.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(grad), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_whole_seq_says_what_it_costs():
+    """``mfu_pct`` divides the executable's counted FLOPs by the window: a
+    Pallas call counts what its ``cost_estimate`` says. The algorithm's
+    products at the true length, forward 4 T^2 d and backward 8 T^2 d a
+    head — no padding, no recompute."""
+    from tpudist.ops.pallas import flash_attention_qkv
+    b, t, h, d = 2, 197, 2, 64
+    qkv = _fused(b=b, t=t, h=h, d=d, dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda x: flash_attention_qkv(
+        x).astype(jnp.float32).sum()))(qkv)
+    costs = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                costs.append(eqn.params["cost_estimate"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    assert sorted(c.flops for c in costs) == [
+        4 * b * h * t * t * d, 8 * b * h * t * t * d]
+    assert all(c.transcendentals == b * h * t * t for c in costs)
+    assert all(c.bytes_accessed > 0 for c in costs)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_vit_block_flash_vs_xla_outputs_and_param_grads(causal):
+    """``MultiHeadAttention`` at ViT-B/16's token count with ``flash=True``
+    (the fused entry on the whole-sequence schedule) against ``flash=False``:
+    outputs and every parameter gradient."""
+    from tpudist.models.vit import MultiHeadAttention
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((2, 197, 128)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((2, 197, 128)), jnp.float32)
+    xla = MultiHeadAttention(num_heads=2, flash=False, causal=causal)
+    variables = xla.init(jax.random.PRNGKey(0), x)
+    flash = MultiHeadAttention(num_heads=2, flash=True, causal=causal)
+
+    def loss(mod):
+        return lambda v: (mod.apply(v, x) * w).sum()
+
+    np.testing.assert_allclose(np.asarray(flash.apply(variables, x)),
+                               np.asarray(xla.apply(variables, x)),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(loss(flash))(variables)
+    want = jax.grad(loss(xla))(variables)
+    flat_g = jax.tree_util.tree_leaves_with_path(got)
+    flat_w = jax.tree_util.tree_leaves(want)
+    assert len(flat_g) == 4                    # in_proj / out_proj, w + b
+    for (path, a), b_ in zip(flat_g, flat_w):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), rtol=2e-4,
+            atol=2e-4 * float(np.abs(np.asarray(b_)).max()),
+            err_msg=jax.tree_util.keystr(path))

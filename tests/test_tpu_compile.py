@@ -43,6 +43,16 @@ BATCH = 128
 BN_WIDTHS = ((112, 64), (56, 64), (28, 128), (14, 256), (7, 512))
 FLASH_SHAPES = ((128, 197, 12, 64),      # ViT-B/16 @224
                 (8, 2048, 12, 64))       # exact-tiling long sequence
+# The fused [B, T, H, 3, D] entry (batch, tokens, heads, head_dim, causal):
+# ViT-B/16 on one chip and its tp=2 shard, then the whole-sequence
+# schedule's rule at its edge (256 tiles exactly; 577 is ViT-B/16 @384;
+# 640 is the longest that fits, at two heads a program), full and causal,
+# and the first length past it, which streams.
+FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
+                    (64, 256, 12, 64, False), (64, 256, 12, 64, True),
+                    (32, 577, 12, 64, False), (32, 577, 12, 64, True),
+                    (32, 640, 12, 64, False), (32, 640, 12, 64, True),
+                    (32, 704, 12, 64, False))
 
 
 @pytest.fixture(scope="module")
@@ -91,6 +101,16 @@ def _flash_fn(bwd: bool):
     return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
 
 
+def _flash_qkv_fn(bwd: bool, causal: bool):
+    from tpudist.ops.pallas.flash_attention import flash_attention_qkv
+
+    def f(qkv):
+        return flash_attention_qkv(
+            qkv, causal=causal, interpret=False).astype(jnp.float32).sum()
+
+    return jax.grad(f) if bwd else f
+
+
 _KERNEL_CASES = (
     [pytest.param(("bn", hw, c, residual), bwd,
                   id=f"bn_{hw}x{hw}x{c}_{'res' if residual else 'plain'}_"
@@ -100,7 +120,12 @@ _KERNEL_CASES = (
     + [pytest.param(("flash",) + shape, bwd,
                     id=f"flash_b{shape[0]}_t{shape[1]}_"
                        f"{'fwdbwd' if bwd else 'fwd'}")
-       for shape in FLASH_SHAPES for bwd in (False, True)])
+       for shape in FLASH_SHAPES for bwd in (False, True)]
+    + [pytest.param(("flash_qkv",) + shape, bwd,
+                    id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
+                       f"{'causal_' if shape[4] else ''}"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for shape in FLASH_QKV_SHAPES for bwd in (False, True)])
 
 
 @pytest.mark.parametrize("case,bwd", _KERNEL_CASES)
@@ -116,11 +141,30 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
         args = [act] + [S((c,), jnp.float32)] * 4 + ([act] if residual
                                                      else [])
         fn = _bn_fn(residual, bwd)
+    elif case[0] == "flash_qkv":
+        b, t, h, d, causal = case[1:]
+        args = [S((b, t, h, 3, d), jnp.bfloat16)]
+        fn = _flash_qkv_fn(bwd, causal)
     else:
         args = [S(case[1:], jnp.bfloat16)] * 3
         fn = _flash_fn(bwd)
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if case[0] == "flash_qkv":
+        from tpudist.ops.pallas.flash_attention import (WHOLE_SEQ,
+                                                        schedule_for)
+        b, t, h, d, _ = case[1:]
+        if schedule_for(t, h, d, jnp.bfloat16) != WHOLE_SEQ:
+            # past the rule's edge: forward, dQ and dKV streaming kernels
+            assert t == 704 and text.count("tpu_custom_call") == (
+                3 if bwd else 1)
+            return
+        # one forward and one backward kernel, and what they cost is
+        # counted (mfu_pct reads cost_analysis of the step)
+        assert text.count("tpu_custom_call") == (2 if bwd else 1)
+        flops = compiled.cost_analysis()["flops"]
+        assert flops >= (12 if bwd else 4) * b * h * t * t * d
 
 
 # -- whole train steps (slow: ~15-30 s of TPU compiler each) -----------------

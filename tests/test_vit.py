@@ -67,3 +67,33 @@ def test_vit_ring_attention_matches_local(rng, mesh8):
     got = ring_fn(variables, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("heads,entry", [(2, "whole_seq"), (3, "streaming")])
+def test_vit_block_flash_matches_xla(rng, heads, entry):
+    """An encoder block at head_dim 64 with the Pallas attention against
+    the XLA one, outputs and every parameter gradient: two heads group
+    onto lane tiles and the kernel reads the fused projection in place;
+    three do not, and the call goes through slices and the split entry.
+    Decided from the shape alone."""
+    from tpudist.models.vit import EncoderBlock
+    from tpudist.ops.pallas.flash_attention import schedule_for
+
+    dim, t = 64 * heads, 50
+    assert schedule_for(t, heads, 64, jnp.float32) == entry
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((2, t, dim)),
+                    jnp.float32)
+    xla = EncoderBlock(num_heads=heads, mlp_dim=96, flash=False)
+    flash = EncoderBlock(num_heads=heads, mlp_dim=96, flash=True)
+    variables = xla.init(rng, x)
+    np.testing.assert_allclose(np.asarray(flash.apply(variables, x)),
+                               np.asarray(xla.apply(variables, x)),
+                               rtol=2e-4, atol=2e-4)
+    got = jax.grad(lambda v: (flash.apply(v, x) ** 2).sum())(variables)
+    want = jax.grad(lambda v: (xla.apply(v, x) ** 2).sum())(variables)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-3,
+            atol=1e-3 * float(np.abs(np.asarray(b)).max()),
+            err_msg=jax.tree_util.keystr(path))

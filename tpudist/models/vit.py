@@ -20,7 +20,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
-from tpudist.parallel.ring_attention import attention, ring_attention
+from tpudist.parallel.ring_attention import (qkv_attention, ring_attention,
+                                             split_qkv)
 
 
 from functools import partial as _partial
@@ -145,10 +146,8 @@ class MultiHeadAttention(nn.Module):
         # qkv activation at the split.
         qkv = nn.Dense(3 * dim // tp, dtype=dt, name="in_proj")(x)
         qkv = qkv.reshape(b, t, local_heads, 3, head_dim)
-        q, k, v = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-
         if self.seq_axis is not None:
-            out = ring_attention(q, k, v, axis_name=self.seq_axis,
+            out = ring_attention(*split_qkv(qkv), axis_name=self.seq_axis,
                                  causal=self.causal)
         else:
             use_flash = self.flash
@@ -163,16 +162,11 @@ class MultiHeadAttention(nn.Module):
                 # see the train flag.
                 from tpudist.ops import attention_dispatch
                 use_flash = attention_dispatch.lookup(
-                    b, t, local_heads, head_dim, q.dtype,
+                    b, t, local_heads, head_dim, qkv.dtype,
                     causal=self.causal)
-            if use_flash:
-                # _spmd: under the GSPMD/TP path (ambient mesh via
-                # set_mesh) the kernel runs in a nested manual region per
-                # batch/head shard; everywhere else it is the plain kernel.
-                from tpudist.ops.pallas import flash_attention_spmd
-                out = flash_attention_spmd(q, k, v, causal=self.causal)
-            else:
-                out = attention(q, k, v, causal=self.causal)
+            # The kernel reads the projection where it lies; the XLA path
+            # slices it. The probe times this very call.
+            out = qkv_attention(qkv, causal=self.causal, flash=use_flash)
         out = out.reshape(b, t, local_heads * head_dim)
         if self.model_axis is not None:
             return _RowParallelDense(dim, self.model_axis, dtype=dt,

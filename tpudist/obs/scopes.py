@@ -33,6 +33,8 @@ SERVE_FORWARD = "tpudist_serve_forward"
 ATTN_SCORES = "attn_scores"
 ATTN_SOFTMAX = "attn_softmax"
 ATTN_VALUES = "attn_values"
+# the Pallas attention kernels, forward and backward, in their place
+ATTN_FUSED = "attn_fused"
 
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)

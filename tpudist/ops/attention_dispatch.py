@@ -16,7 +16,8 @@ What stays attention-specific here — and ONLY this:
 - static eligibility (``flash_eligible``: the windowed-attention families'
   additive bias, head_dim/seq tiling limits);
 - the on-device micro-benchmark (``measure_attention``: flash vs XLA
-  attention, fwd or fwd+bwd, at the exact shape);
+  attention, fwd or fwd+bwd, at the exact shape, through the model's own
+  call ``ring_attention.qkv_attention``);
 - the kernel revision (``flash_attention.KERNEL_REV``, imported lazily so
   the XLA-only path never drags Pallas in);
 - the telemetry-event projection (``event_fields``).
@@ -93,45 +94,55 @@ def flash_eligible(*, seq: int, head_dim: int, bias: bool = False,
     return True, "eligible"
 
 
+def probe_fns(train: bool, causal: bool):
+    """The probe's two timed functions of one fused projection
+    [B, T, H, 3, D]: ``qkv_attention`` with ``flash`` on and off — the
+    very call ``MultiHeadAttention`` makes, so the verdict is about the
+    program the model runs (the kernel's in-place read on one side, the
+    slices XLA pays on the other). ``train`` differentiates with respect to
+    the projection, as ``in_proj``'s backward does."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpudist.parallel.ring_attention import qkv_attention
+
+    def fn(flash: bool):
+        def f(qkv):
+            return qkv_attention(qkv, causal=causal, flash=flash)
+        if not train:
+            return f
+        return jax.grad(lambda qkv: f(qkv).astype(jnp.float32).sum())
+    return fn(True), fn(False)
+
+
 def measure_attention(batch: int, seq: int, heads: int, head_dim: int,
                       dtype, train: bool, causal: bool,
                       steps: int = 10, warmup: int = 2) -> tuple[float, float]:
-    """The on-device micro-benchmark: (flash_ms, xla_ms) at the exact shape.
-    ``train`` times forward+backward (grad wrt q/k/v — the configuration the
-    r3 capture showed the kernel losing); eval times forward only. Only
-    meaningful on an accelerator — callers gate on platform."""
+    """The on-device micro-benchmark: (flash_ms, xla_ms) at the exact shape,
+    both sides from one fused projection (``probe_fns``). ``train`` times
+    forward+backward (the configuration the r3 capture showed the kernel
+    losing); eval times forward only. Only meaningful on an accelerator —
+    callers gate on platform."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from tpudist.ops.pallas import flash_attention
-    from tpudist.parallel.ring_attention import attention
-
     rng = np.random.default_rng(0)
-    shape = (batch, seq, heads, head_dim)
-    q, k, v = (jnp.asarray(rng.standard_normal(shape), dtype)
-               for _ in range(3))
-
-    def flash_fn(q, k, v):
-        return flash_attention(q, k, v, causal=causal)
-
-    def xla_fn(q, k, v):
-        return attention(q, k, v, causal=causal)
-
-    if train:
-        def loss(fn):
-            def f(q, k, v):
-                return fn(q, k, v).astype(jnp.float32).sum()
-            return f
-        flash_c = jax.jit(jax.grad(loss(flash_fn), argnums=(0, 1, 2)))
-        xla_c = jax.jit(jax.grad(loss(xla_fn), argnums=(0, 1, 2)))
-    else:
-        flash_c = jax.jit(flash_fn)
-        xla_c = jax.jit(xla_fn)
-
-    flash_ms = measure_ms(flash_c, (q, k, v), steps, warmup)
-    xla_ms = measure_ms(xla_c, (q, k, v), steps, warmup)
+    qkv = jnp.asarray(
+        rng.standard_normal((batch, seq, heads, 3, head_dim)), dtype)
+    flash_fn, xla_fn = probe_fns(train, causal)
+    flash_ms = measure_ms(jax.jit(flash_fn), (qkv,), steps, warmup)
+    xla_ms = measure_ms(jax.jit(xla_fn), (qkv,), steps, warmup)
     return flash_ms, xla_ms
+
+
+def schedule(seq: int, heads: int, head_dim: int, dtype) -> str:
+    """Which of the kernel's schedules (``whole_seq`` | ``streaming``) this
+    self-attention shape takes — the kernel's own shape test, for the
+    dispatch log line and telemetry event. Imports Pallas: ask only where
+    the kernel runs."""
+    from tpudist.ops.pallas.flash_attention import schedule_for
+    return schedule_for(seq, heads, head_dim, dtype)
 
 
 def decide(batch: int, seq: int, heads: int, head_dim: int, dtype,
@@ -202,6 +213,8 @@ def event_fields(decision: dict) -> dict:
     for f in ("flash_ms", "xla_ms", "margin"):
         if isinstance(decision.get(f), (int, float)):
             out[f] = decision[f]
+    if decision.get("schedule"):
+        out["schedule"] = decision["schedule"]
     if decision.get("cache_hit"):
         out["cache_hit"] = 1
     if decision.get("reason"):

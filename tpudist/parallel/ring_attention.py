@@ -54,6 +54,30 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
+def split_qkv(qkv: jax.Array) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """q, k, v [B, T, H, D] out of a head-major fused projection
+    [B, T, H, 3, D]."""
+    return qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+
+
+def qkv_attention(qkv: jax.Array, causal: bool = False,
+                  flash: bool = False) -> jax.Array:
+    """Self-attention on a fused projection [B, T, H, 3, D] -> [B, T, H, D]:
+    the two branches ``MultiHeadAttention`` can take off the sequence-
+    parallel path, and so the two functions ``attention_dispatch``'s probe
+    times. ``flash`` runs the Pallas kernel (imported only then), which
+    reads the projection in place where its shape allows and goes through
+    ``split_qkv`` where not; otherwise slices and the XLA ``attention``."""
+    if flash:
+        # _spmd: under the GSPMD/TP path (ambient mesh via set_mesh) the
+        # kernel runs in a nested manual region per batch/head shard;
+        # everywhere else it is the plain kernel.
+        from tpudist.ops.pallas import flash_attention_spmd
+        with jax.named_scope(scopes.ATTN_FUSED):
+            return flash_attention_spmd(qkv, causal=causal)
+    return attention(*split_qkv(qkv), causal=causal)
+
+
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    axis_name: str, causal: bool = False) -> jax.Array:
     """Sequence-parallel attention over the ``axis_name`` ring.

@@ -800,8 +800,14 @@ class Trainer:
             dec = _decide()
         if cfg.flash == "auto":
             self.model = self.model.clone(flash=dec["kernel"] == "flash")
+        if dec["kernel"] == "flash":
+            # which of the kernel's schedules this shape takes
+            dec["schedule"] = attention_dispatch.schedule(
+                tokens, local_heads, hidden // heads, dt)
         msg = (f"=> attention dispatch: {dec['kernel']} attention "
                f"(mode {dec['mode']}, {dec['source']}")
+        if dec.get("schedule"):
+            msg += f", schedule {dec['schedule']}"
         if dec.get("reason"):
             msg += f": {dec['reason']}"
         if dec.get("flash_ms") is not None:
