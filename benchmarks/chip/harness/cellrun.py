@@ -5,7 +5,8 @@ through `tpudist.config.from_args` with the flags `python -m tpudist` would
 get: the trainer's own step, prefetcher and metric drain. The benchmark's part
 is the feed (`harness/traffic.py`), the seeded weights (the configuration's
 plain reference draws them; the trainer's state is set to them as a restore
-would), a handle on the step's executable (`StepHandle`: the trainer's jitted
+would, and the benchmark's own copy waits on the host until the program is
+freed), a handle on the step's executable (`StepHandle`: the trainer's jitted
 step lowered and compiled once, so the program the window runs is the one
 whose `memory_analysis()` / `cost_analysis()` are reported) and host spans.
 """
@@ -20,11 +21,8 @@ import statistics
 import time
 
 from harness import check, scope_reduce, trace_reduce, traffic
+from harness.errors import Refuse
 from harness.spans import Spans
-
-
-class Refuse(Exception):
-    """The run cannot be made here: exit non-zero, print no result."""
 
 
 class StepHandle:
@@ -62,11 +60,6 @@ def _rss_gib() -> float:
     except OSError:
         pass
     return 0.0
-
-
-def _scalars(cfg: dict) -> dict:
-    return {k: v for k, v in cfg.items() if isinstance(v, (int, float, str))
-            and not isinstance(v, bool)}
 
 
 def _load_reader(chip_dir: str, name: str):
@@ -143,7 +136,6 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
     phase("device_init_s")
 
     chips = len(devices)
-    model_cfg = _scalars(config)
     batch = int(config["per_chip_batch"]) * chips
     workdir = os.path.join(chip_dir, "_work")
     outpath = os.path.join(workdir, "out", workload["name"])
@@ -163,21 +155,25 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
     _say("attention_dispatch", **attention)
     phase("trainer_init_s")
 
-    # --- seeded weights: the reference draws them, the trainer restores them
+    # --- seeded weights: the reference draws them, the trainer restores
+    # them. The benchmark's own copy waits on the host until the program is
+    # freed: nothing of its that is the size of the parameters is on the chip
+    # while the step runs.
     from jax.sharding import NamedSharding, PartitionSpec as P
     ref = check.load_reference(chip_dir, config["reference_module"])
     red = check.Reducers()
     replicated = NamedSharding(trainer.mesh, P())
-    p0, s0 = jax.jit(lambda k: ref.init(k, model_cfg),
-                     out_shardings=replicated)(jax.random.PRNGKey(seed31))
+    p0, s0 = check.seeded_weights(ref, config, seed31, replicated)
     check.same_structure(trainer.state.params, p0, "params")
     check.same_structure(trainer.state.batch_stats, s0, "batch_stats")
-    mine_p, mine_s = red.copy((p0, s0))
-    trainer.state = trainer.state.replace(params=mine_p, batch_stats=mine_s)
-    del mine_p, mine_s
     names = {"first_grad": check.leaf_names(p0),
              "param_change": check.leaf_names(p0),
              "stats_change": check.leaf_names(s0)}
+    host0 = jax.device_get((p0, s0))
+    trainer.state = trainer.state.replace(params=p0, batch_stats=s0)
+    del p0, s0
+    leaves = jax.tree_util.tree_leaves
+    p0_leaves, s0_leaves = map(leaves, host0)
     phase("weights_s")
 
     spans = Spans()
@@ -185,9 +181,7 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
     trainer.train_step = handle
     from tpudist.dist import batch_sharding
     source = traffic.make_source(
-        traffic_spec, seed=seed31, batch=batch,
-        image_size=int(config["image_size"]),
-        num_classes=int(config["num_classes"]),
+        traffic_spec, seed=seed31, batch=batch, config=config,
         sharding=batch_sharding(trainer.mesh, trainer.batch_axes),
         cfg=cfg, workdir=workdir)
     feed = traffic.Feed(source, spans)
@@ -205,14 +199,13 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
         if i == 0:
             phases["compile_s"] = spans.total("bench.compile")
             prog["first_grad_leaves"] = check.first_grad(
-                red, trainer.state.opt_state, p0, model_cfg)
+                red, trainer.state.opt_state, p0_leaves, config)
             prog["first_grad"] = check._norms(prog["first_grad_leaves"])
-    leaves = jax.tree_util.tree_leaves
-    prog["param_change"] = np.asarray(
-        red.diff_norms(leaves(trainer.state.params), leaves(p0)))
-    if leaves(s0):
-        prog["stats_change"] = np.asarray(
-            red.diff_norms(leaves(trainer.state.batch_stats), leaves(s0)))
+    prog["param_change"] = red.diff_norms(
+        leaves(trainer.state.params), p0_leaves)
+    if s0_leaves:
+        prog["stats_change"] = red.diff_norms(
+            leaves(trainer.state.batch_stats), s0_leaves)
     phase("warmup_s")
     phases["warmup_s"] -= phases["compile_s"]
 
@@ -221,8 +214,11 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
     step_bytes = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     flops = _flops(compiled)
-    step_module = compiled.as_text().split("\n", 1)[0].split(",")[0] \
+    step_hlo = compiled.as_text()
+    step_module = step_hlo.split("\n", 1)[0].split(",")[0] \
         .replace("HloModule", "").strip()
+    if not trace:              # only a traced run's readers look inside it
+        step_hlo = None
     phase("analysis_s")
 
     loader_rate = None
@@ -250,6 +246,9 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
 
     # --- the window ---------------------------------------------------------
     jax.block_until_ready(trainer.state)
+    _say("resident_at_window",
+         parameter_sized_extras=check.parameter_sized_extras(trainer.state))
+    phase("census_s")
     rss = {"before_window": _rss_gib()}
     steps0 = trainer.global_step
     setup_s = time.time() - t_start
@@ -277,9 +276,12 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
         q = statistics.quantiles(gaps_ms, n=20)
         _say("step_times_ms", n=len(gaps_ms), p50=q[9], p95=q[18],
              max=max(gaps_ms), min=min(gaps_ms))
+    # a row is an image or a sequence, as the mix draws it
     img_per_s_chip = steps * batch / window_s / chips
+    tokens = ({"tokens_per_s_chip": img_per_s_chip * source.info["seq_len"]}
+              if "seq_len" in source.info else {})
     _say("window", steps=steps, window_s=window_s, batch=batch,
-         img_per_s_chip=img_per_s_chip, step_module=step_module,
+         img_per_s_chip=img_per_s_chip, **tokens, step_module=step_module,
          allocator_peak_bytes=alloc_peak, step_temp_bytes=mem.temp_size_in_bytes,
          flops_per_step=flops)
     _say("memory_stats", **{k: v for k, v in mem_stats.items()
@@ -297,7 +299,9 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
     del trainer, handle, compiled, feed
     gc.collect()
     t_ref = time.time()
-    refd = check.reference_readings(ref, model_cfg, p0, s0, first, lr)
+    p0, s0 = jax.device_put(host0, replicated)
+    del host0, p0_leaves, s0_leaves
+    refd = check.reference_readings(ref, config, p0, s0, first, lr)
     if input_rows is not None:
         from harness import input_check
         prog["rows"] = input_check.compare(
@@ -337,7 +341,8 @@ def run_cell(*, bench: dict, workload: dict, config: dict, traffic_spec: dict,
                "window_s": window_s, "steps": steps, "batch": batch,
                "chips": chips, "trace": reduced, "flops_per_step": flops,
                "peak": peak, "loader_img_per_s": loader_rate,
-               "compile_s": phases["compile_s"]}
+               "compile_s": phases["compile_s"], "config": config,
+               "attention_kernel": attention["kernel"], "step_hlo": step_hlo}
         result["metrics"] = {}
         for m in _metrics_for(bench, "per_layer", workload["name"]):
             value = _load_reader(chip_dir, m["name"])(ctx)

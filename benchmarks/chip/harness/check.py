@@ -31,6 +31,17 @@ def load_reference(chip_dir: str, name: str):
     return mod
 
 
+def seeded_weights(ref, config: dict, seed: int, sharding):
+    """(params, batch statistics) as the configuration's reference draws
+    them from the seed, in one jitted call on the device. The reference is
+    handed the configuration's file as parsed, lists and nested objects
+    included: it imports nothing of the program and has no other way to
+    learn a per-layer pattern."""
+    import jax
+    return jax.jit(lambda k: ref.init(k, config),
+                   out_shardings=sharding)(jax.random.PRNGKey(seed))
+
+
 def _leaves(tree):
     import jax
     return jax.tree_util.tree_leaves(tree)
@@ -57,6 +68,26 @@ def same_structure(ours, theirs, what: str) -> None:
                          f"{diff}")
 
 
+def parameter_sized_extras(state) -> list:
+    """Live device arrays with the shape and type of one of the trainer's
+    parameter leaves (of two dimensions or more: a vector's shape is anyone's)
+    beyond those the trainer's own state holds: [[shape, dtype, count, bytes],
+    ...], empty where the benchmark keeps nothing of that size on the chip."""
+    import collections
+    import jax
+
+    def key(x):
+        return tuple(x.shape), str(x.dtype)
+
+    extra = (collections.Counter(map(key, jax.live_arrays()))
+             - collections.Counter(map(key, _leaves(state))))
+    sized = {key(x): x.nbytes for x in _leaves(state.params) if x.ndim >= 2}
+    return [[list(shape), dtype, extra[shape, dtype],
+             extra[shape, dtype] * nbytes]
+            for (shape, dtype), nbytes in sorted(sized.items())
+            if extra[shape, dtype]]
+
+
 def optimizer_leaves(opt_state, field: str):
     """Leaves of the optimizer-state member called `field` (optax's
     `TraceState.trace`, `ScaleByAdamState.mu`), in parameter order."""
@@ -70,8 +101,27 @@ def optimizer_leaves(opt_state, field: str):
     return out
 
 
+GROUP_BYTES = 512 * 2 ** 20
+
+
+def _groups(leaves: list) -> list[slice]:
+    """Runs of consecutive leaves of at most `GROUP_BYTES` together (a larger
+    leaf is a run alone): what one reduction program is handed, so that what
+    it makes on the device beside the program's own state stays 3 % of a
+    16 GB chip whatever the model's size. A model under the bound is one run,
+    one program."""
+    runs, start, size = [], 0, 0
+    for i, leaf in enumerate(leaves):
+        if i > start and size + leaf.nbytes > GROUP_BYTES:
+            runs.append(slice(start, i))
+            start, size = i, 0
+        size += leaf.nbytes
+    return runs + [slice(start, len(leaves))] if leaves else []
+
+
 class Reducers:
-    """Small jitted reductions to per-leaf norms (one host copy each)."""
+    """Small jitted reductions over runs of leaves (`_groups`), one host copy
+    each. A leaf that waits on the host is placed for its run's call."""
 
     def __init__(self):
         import jax
@@ -81,32 +131,41 @@ class Reducers:
             return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(
                 x.astype(jnp.float32)))) for x in xs])
 
-        self.norms = jax.jit(norms)
-        self.diff_norms = jax.jit(
+        self._diff_norms = jax.jit(
             lambda a, b: norms([x.astype(jnp.float32) - y.astype(jnp.float32)
                                 for x, y in zip(a, b)]))
         # torch SGD keeps v1 = g + wd * p0; AdamW keeps mu1 = (1 - b1) * g
-        self.sgd_grad = jax.jit(
+        self._sgd_grad = jax.jit(
             lambda trace, p0, wd: [t - wd * p for t, p in zip(trace, p0)])
-        self.adam_grad = jax.jit(
+        self._adam_grad = jax.jit(
             lambda mu, b1: [m / (1.0 - b1) for m in mu])
-        self.copy = jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t))
+
+    def diff_norms(self, a: list, b: list) -> np.ndarray:
+        """||a_i - b_i|| of every leaf (device or host leaves), float32."""
+        return np.concatenate([np.asarray(self._diff_norms(a[run], b[run]))
+                               for run in _groups(a)])
+
+    def sgd_grad(self, trace: list, p0: list, wd: float) -> list:
+        return [np.asarray(g) for run in _groups(trace)
+                for g in self._sgd_grad(trace[run], p0[run], wd)]
+
+    def adam_grad(self, mu: list, b1: float) -> list:
+        return [np.asarray(g) for run in _groups(mu)
+                for g in self._adam_grad(mu[run], b1)]
 
 
-def first_grad(red: Reducers, opt_state, p0, model_cfg) -> list:
+def first_grad(red: Reducers, opt_state, p0_leaves: list, model_cfg) -> list:
     """The first gradient as the optimizer got it, leaf by leaf, worked out
     from the optimizer's own state after one step; copied to the host, where
-    it waits for the reference's."""
+    it waits for the reference's. `p0_leaves` may wait on the host."""
     kind = model_cfg["optimizer"]
     if kind == "sgd":
-        g = red.sgd_grad(optimizer_leaves(opt_state, "trace"), _leaves(p0),
-                         float(model_cfg["weight_decay"]))
-    elif kind == "adamw":
-        g = red.adam_grad(optimizer_leaves(opt_state, "mu"),
-                          float(model_cfg["adam_b1"]))
-    else:
-        raise SystemExit(f"no first-gradient recovery for optimizer {kind!r}")
-    return [np.asarray(x) for x in g]
+        return red.sgd_grad(optimizer_leaves(opt_state, "trace"), p0_leaves,
+                            float(model_cfg["weight_decay"]))
+    if kind == "adamw":
+        return red.adam_grad(optimizer_leaves(opt_state, "mu"),
+                             float(model_cfg["adam_b1"]))
+    raise SystemExit(f"no first-gradient recovery for optimizer {kind!r}")
 
 
 def _norms(leaves) -> np.ndarray:
@@ -174,11 +233,9 @@ def reference_readings(ref, model_cfg, p0, s0, batches, lr, quant=None):
             out["first_grad_leaves"] = [np.asarray(g) for g in _leaves(grads)]
             out["first_grad"] = _norms(out["first_grad_leaves"])
         del grads, images, labels
-    out["param_change"] = np.asarray(
-        red.diff_norms(_leaves(params), _leaves(p0)))
+    out["param_change"] = red.diff_norms(_leaves(params), _leaves(p0))
     if _leaves(s0):
-        out["stats_change"] = np.asarray(
-            red.diff_norms(_leaves(stats), _leaves(s0)))
+        out["stats_change"] = red.diff_norms(_leaves(stats), _leaves(s0))
     return out
 
 

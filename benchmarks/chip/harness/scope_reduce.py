@@ -17,6 +17,8 @@ tensorflow or xprof import, in a process that already holds 14-19 GiB.
     by_scope(events, scopes, ..) -> device ms a step by phase and by block
     step_scopes(ctx)             -> the two above for the run's newest trace,
                                     once a process; prints `bench scope_ms`
+                                    (`ops`, every operation's row, is kept
+                                    for `harness/roofline.py`, not printed)
     label_ops(device_ops, ...)   -> the result line's `breakdown.device_ops`
                                     with those names beside the bare ones
 
@@ -359,6 +361,7 @@ def by_scope(events: dict, scopes: dict, step_module: str = "",
                 name, (ns, bucket, op_name) in
                 sorted(ops.items(), key=lambda kv: -kv[1][0]) if keep(bucket)]
 
+    all_ops = ranked(lambda b: True)
     return {
         "steps": steps,
         "modules": sorted(modules),
@@ -379,7 +382,10 @@ def by_scope(events: dict, scopes: dict, step_module: str = "",
         "blocks": sorted(([k, per_step(v["fwd"]), per_step(v["bwd"])]
                           for k, v in blocks.items()),
                          key=lambda b: -(b[1] + b[2])),
-        "top_ops": ranked(lambda b: True)[:24],
+        "top_ops": all_ops[:24],
+        # every operation, for a reader that sums one scope of its own
+        # (`harness/roofline.py`); not printed
+        "ops": all_ops,
     }
 
 
@@ -471,6 +477,7 @@ def step_scopes(ctx: dict) -> dict | None:
         json.dump(result, f)
     shown = dict(result, blocks=result["blocks"][:20],
                  top_ops=result["top_ops"][:12])
+    del shown["ops"]
     print("bench scope_ms " + json.dumps(shown, default=float), flush=True)
     _CACHE[path] = result
     return result

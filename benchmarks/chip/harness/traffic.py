@@ -4,11 +4,19 @@ A mix's file (`traffic/<name>.json`) has a `kind` and that kind's
 parameters; a new mix is a new file. Two kinds:
 
 - `resident`: `distinct_batches` seeded batches made on the device in one
-  jitted function and cycled, so the step program does all the work;
+  jitted function and cycled, so the step program does all the work. What a
+  row is the mix's `rows` says: `images` (the default: float32 NHWC pixels
+  and a class label, sized by the configuration's `image_size` and
+  `num_classes`) or `tokens` (int32 ids of `seq_len` positions, the mix's
+  own, drawn from the configuration's `vocab_size`, with the next id as each
+  position's target);
 - `imagefolder`: the program's own ImageFolder loader
   (`tpudist.data.pipeline.build_train_val_loaders`, `native/` built on this
   machine) over a generated JPEG corpus, epochs chained; order and
   augmentation follow `--seed`.
+
+What a mix needs of the configuration it is paired with it asks for by name:
+a configuration that lacks it is refused (`Refuse`), never a `KeyError`.
 
 `Feed` is what the trainer iterates (it wraps it in its own
 `DevicePrefetcher`): it hands out a set number of batches, or batches until a
@@ -19,6 +27,8 @@ from __future__ import annotations
 
 import os
 import time
+
+from harness.errors import Refuse
 
 
 class Feed:
@@ -57,30 +67,78 @@ class Feed:
         return batch
 
 
-class ResidentSource:
-    """`distinct_batches` batches of seeded N(0,1) images and uniform labels,
-    made on the device already laid out as the trainer shards a batch."""
+def _need(holder: dict, key: str, mix: dict) -> int:
+    """The whole number `key` that `mix` needs of `holder`: the configuration
+    it is paired with, or its own file."""
+    value = holder.get(key)
+    if isinstance(value, bool) or not isinstance(value, int) or value < 1:
+        whose = ("its own file" if holder is mix
+                 else f"the configuration {holder.get('name')!r}")
+        raise Refuse(f"traffic mix {mix.get('name')!r} draws "
+                     f"{mix.get('rows', 'images')} rows and needs a whole "
+                     f"number {key!r} of {whose}, which has {value!r}")
+    return value
 
-    def __init__(self, spec, *, seed, batch, image_size, num_classes,
-                 sharding, **_):
+
+def _image_rows(spec: dict, config: dict, batch: int):
+    """Seeded N(0,1) pixels, float32 NHWC, and uniform class labels."""
+    import jax
+    import jax.numpy as jnp
+    image_size = _need(config, "image_size", spec)
+    num_classes = _need(config, "num_classes", spec)
+
+    def make(key):
+        k1, k2 = jax.random.split(key)
+        return (jax.random.normal(
+            k1, (batch, image_size, image_size, 3), jnp.float32),
+            jax.random.randint(k2, (batch,), 0, num_classes, jnp.int32))
+
+    return make, {}
+
+
+def _token_rows(spec: dict, config: dict, batch: int):
+    """One seeded draw of `seq_len + 1` int32 ids a row, uniform over the
+    ids the configuration holds (a sliced vocabulary draws from the slice);
+    inputs are all but the last, targets all but the first."""
+    import jax
+    import jax.numpy as jnp
+    seq_len = _need(spec, "seq_len", spec)
+    vocab_size = _need(config, "vocab_size", spec)
+
+    def make(key):
+        ids = jax.random.randint(key, (batch, seq_len + 1), 0, vocab_size,
+                                 jnp.int32)
+        return ids[:, :-1], ids[:, 1:]
+
+    return make, {"seq_len": seq_len, "vocab_size": vocab_size,
+                  "tokens_per_batch": batch * seq_len}
+
+
+ROWS = {"images": _image_rows, "tokens": _token_rows}
+
+
+class ResidentSource:
+    """`distinct_batches` seeded batches of the mix's `rows`, made on the
+    device already laid out as the trainer shards a batch (the leading axis
+    of inputs and targets alike)."""
+
+    def __init__(self, spec, *, seed, batch, config, sharding, **_):
         import jax
-        import jax.numpy as jnp
         k = int(spec["distinct_batches"])
         if k < 3:
-            raise ValueError("the comparison follows three steps on rows "
-                             "that all differ: distinct_batches >= 3")
-
-        def make(key):
-            k1, k2 = jax.random.split(key)
-            return (jax.random.normal(
-                k1, (batch, image_size, image_size, 3), jnp.float32),
-                jax.random.randint(k2, (batch,), 0, num_classes, jnp.int32))
-
+            raise Refuse("the comparison follows three steps on rows that "
+                         "all differ: distinct_batches >= 3")
+        rows = spec.get("rows", "images")
+        if rows not in ROWS:
+            raise Refuse(f"traffic mix {spec.get('name')!r}: rows {rows!r} "
+                         f"is not one of {sorted(ROWS)}")
+        make, said = ROWS[rows](spec, config, batch)
         make = jax.jit(make, out_shardings=(sharding, sharding))
         root = jax.random.fold_in(jax.random.PRNGKey(seed), 0x7AFF1C)
         self._batches = [make(jax.random.fold_in(root, i)) for i in range(k)]
         self._i = 0
-        self.info = {"kind": "resident", "distinct_batches": k}
+        self.info = {"kind": "resident", "rows": rows,
+                     "distinct_batches": k, **said}
 
     def next(self):
         b = self._batches[self._i % len(self._batches)]
@@ -179,9 +237,8 @@ KINDS = {"resident": ResidentSource, "imagefolder": FolderSource}
 
 
 def make_source(spec, **kw):
-    try:
-        kind = KINDS[spec["kind"]]
-    except KeyError:
-        raise ValueError(f"traffic kind {spec.get('kind')!r} is not one of "
-                         f"{sorted(KINDS)}") from None
+    kind = KINDS.get(spec.get("kind"))
+    if kind is None:
+        raise Refuse(f"traffic mix {spec.get('name')!r}: kind "
+                     f"{spec.get('kind')!r} is not one of {sorted(KINDS)}")
     return kind(spec, **kw)

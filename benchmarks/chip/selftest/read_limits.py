@@ -25,28 +25,24 @@ def main():
     import numpy as np
     from harness import check, traffic
     config = json.load(open(sys.argv[1]))
-    model_cfg = {k: v for k, v in config.items()
-                 if isinstance(v, (int, float, str))}
     ref = check.load_reference(CHIP, config["reference_module"])
     staged = json.load(open(os.path.join(CHIP, "traffic", "staged.json")))
     dev = jax.sharding.SingleDeviceSharding(jax.devices()[0])
     n = int(config["compared_steps"])
-    init = jax.jit(lambda k: ref.init(k, model_cfg))
     for seed in (int(s) % (2 ** 31 - 1) for s in sys.argv[2:]):
-        p0, s0 = init(jax.random.PRNGKey(seed))
+        p0, s0 = check.seeded_weights(ref, config, seed, dev)
         src = traffic.make_source(
             staged, seed=seed, batch=int(config["per_chip_batch"]),
-            image_size=int(config["image_size"]),
-            num_classes=int(config["num_classes"]), sharding=dev)
+            config=config, sharding=dev)
         batches = [(np.asarray(i), np.asarray(l)) for i, l in src.first(n)]
         src.close()
         lr = float(config["window_lr"])
-        sound = check.reference_readings(ref, model_cfg, p0, s0, batches, lr)
+        sound = check.reference_readings(ref, config, p0, s0, batches, lr)
         names = {"first_grad": check.leaf_names(p0),
                  "param_change": check.leaf_names(p0),
                  "stats_change": check.leaf_names(s0)}
         quant = config["control_quant"]
-        got = check.reference_readings(ref, model_cfg, p0, s0, batches, lr,
+        got = check.reference_readings(ref, config, p0, s0, batches, lr,
                                        quant=quant)
         ok, rows = check.compare(got, sound, config["correct_limits"], names)
         for name, value, limit, passed, note in rows:

@@ -14,11 +14,16 @@ collect this directory and there is no conftest here). What is checked:
   the program's place, is not correct under the limits a sound bf16 run
   passes (the chip-size readings of both are in PERF.md);
 - a cell on four (virtual) devices, DP + SyncBN, arrives as data files only
-  (`selftest/tiny/*.json`) and runs through the same `run_cell`.
+  (`selftest/tiny/*.json`) and runs through the same `run_cell`;
+- when the window opens nothing of the benchmark's that has a parameter's
+  shape is live on the device: its copy of the seeded weights waits on the
+  host (`bench resident_at_window`), and the census that says so does find a
+  copy that is held.
 
 A CPU run reports no device time: nothing here reads a rate or a share.
 """
 
+import gc
 import glob
 import json
 import os
@@ -77,6 +82,7 @@ def tiny_run(config_name, *, traffic="staged", seed=11, chips=1, trace=False,
     # the platform (at the cell's 197 tokens a CPU reads `source: platform`)
     ("vit_tiny", {"kernel": "xla", "mode": "auto", "source": "ineligible"})])
 def test_sound_run_is_correct(config_name, attention, capfd):
+    gc.collect()       # arrays of earlier tests' runs are not this run's
     result = tiny_run(config_name, seed=2 ** 31 + 12345)
     assert result["correct"] is True
     assert result["attempted"] >= 1 and result["failed"] == 0
@@ -85,14 +91,36 @@ def test_sound_run_is_correct(config_name, attention, capfd):
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert result["device"]["count"] == 1
     # which attention ran: `--flash auto` is XLA's here, without a probe
-    said = [json.loads(line.split(" ", 2)[2])
-            for line in capfd.readouterr().out.splitlines()
+    lines = capfd.readouterr().out.splitlines()
+    said = [json.loads(line.split(" ", 2)[2]) for line in lines
             if line.startswith("bench attention_dispatch ")]
     assert len(said) == 1
     assert {k: said[0][k] for k in attention} == attention
+    # the benchmark's copy of the seeded weights is off the device
+    census = [json.loads(line.split(" ", 2)[2]) for line in lines
+              if line.startswith("bench resident_at_window ")]
+    assert census == [{"parameter_sized_extras": []}]
     assert result["attention_kernel"] == attention["kernel"]
     assert os.environ["TPUDIST_DISPATCH_CACHE"] == os.path.join(
         CHIP, "_work", "dispatch")
+
+
+def test_census_finds_a_parameter_sized_array_that_is_held():
+    """`check.parameter_sized_extras` against a stand-in state: clean while
+    only the state's own leaves are live, and not once a copy of the
+    parameters is held beside them (what the harness did before PR 29)."""
+    import collections
+    import jax.numpy as jnp
+    from harness import check
+    params = {"w": jnp.ones((7, 33, 5)), "b": jnp.ones((5,))}
+    state = collections.namedtuple("State", "params mu")(
+        params, {"w": jnp.zeros((7, 33, 5))})
+    assert check.parameter_sized_extras(state) == []
+    held = {k: jnp.array(v) for k, v in params.items()}
+    assert check.parameter_sized_extras(state) == [
+        [[7, 33, 5], "float32", 1, 7 * 33 * 5 * 4]]
+    del held
+    assert check.parameter_sized_extras(state) == []
 
 
 DISPATCH_FLAGS = ("--flash", "--fused-bn", "--compress-grads")
@@ -160,9 +188,7 @@ def test_fp8_control_is_not_correct(config_name):
     with bf16 operands (the configuration's own precision) it passes."""
     import jax
     from harness import check
-    config = load(HERE, "tiny", config_name + ".json")
-    model_cfg = {k: v for k, v in config.items()
-                 if isinstance(v, (int, float, str))}
+    model_cfg = config = load(HERE, "tiny", config_name + ".json")
     ref = check.load_reference(CHIP, config["reference_module"])
     b, s = config["per_chip_batch"], config["image_size"]
     verdicts = {"bf16": [], "fp8": []}
