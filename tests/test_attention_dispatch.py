@@ -551,16 +551,17 @@ def test_probe_times_the_branches_the_model_calls(monkeypatch):
     assert jnp.issubdtype(flash_f(qkv).dtype, jnp.floating)
 
 
-@pytest.mark.parametrize("stale_rev", [2, 1])
+@pytest.mark.parametrize("stale_rev", [3, 2, 1])
 def test_verdict_of_an_older_kernel_rev_is_not_used(tmp_path, stale_rev):
-    """KERNEL_REV is 3 (the whole-sequence schedule): a rev-2 verdict in
-    the cache, win or loss, is stale — ``decide`` measures again and the
-    trace-time ``lookup`` does not dispatch on it."""
-    assert ad.kernel_rev() == 3
+    """KERNEL_REV is 4 (a window, grouped heads and banded grids on the
+    streaming schedule): a rev-3 verdict in the cache, win or loss, is
+    stale — ``decide`` measures again and the trace-time ``lookup`` does
+    not dispatch on it."""
+    assert ad.kernel_rev() == 4
     cache = str(tmp_path)
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
                   measure_pair=_pair(1.0, 2.0), **TPU)
-    assert d["kernel"] == "flash" and d["kernel_rev"] == 3
+    assert d["kernel"] == "flash" and d["kernel_rev"] == 4
     assert ad.lookup(*SHAPE, cache_dir=cache, **TPU) is True
     path = ad.cache_path(TPU["device_kind"], cache)
     obj = json.load(open(path))
@@ -571,7 +572,7 @@ def test_verdict_of_an_older_kernel_rev_is_not_used(tmp_path, stale_rev):
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
                   measure_pair=_pair(16.32, 4.55), **TPU)
     assert d["source"] == "measured" and d["kernel"] == "xla" \
-        and d["kernel_rev"] == 3
+        and d["kernel_rev"] == 4
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache, measure_pair=_boom,
                   **TPU)
     assert d["source"] == "cache" and d["kernel"] == "xla"

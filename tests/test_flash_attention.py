@@ -380,3 +380,33 @@ def test_vit_block_flash_vs_xla_outputs_and_param_grads(causal):
             np.asarray(a), np.asarray(b_), rtol=2e-4,
             atol=2e-4 * float(np.abs(np.asarray(b_)).max()),
             err_msg=jax.tree_util.keystr(path))
+
+
+# -- a window, and fewer key-value heads than query heads --------------------
+
+@pytest.mark.parametrize("t", [32, 37, 100])
+@pytest.mark.parametrize("window,kv_heads", [(8, 2), (40, 1), (None, 2)])
+def test_streaming_kernel_matches_windowed_grouped_attention(t, window,
+                                                             kv_heads):
+    """Interpret mode, forward and gradients, blocks of 16 x 32: lengths
+    that are and are not block multiples, a window smaller and larger than
+    a block, 4 query heads over 1 and 2 key-value heads."""
+    ks = jax.random.split(jax.random.PRNGKey(t), 4)
+    q = jax.random.normal(ks[0], (2, t, 4, 16))
+    k = jax.random.normal(ks[1], (2, t, kv_heads, 16))
+    v = jax.random.normal(ks[2], (2, t, kv_heads, 16))
+    g = jax.random.normal(ks[3], (2, t, 4, 16))
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * g), argnums=(0, 1, 2))(
+                q, k, v)
+
+    got, got_grads = both(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=16, block_k=32))
+    want, want_grads = both(lambda q, k, v: attention(
+        q, k, v, causal=True, window=window))
+    assert abs(float(got) - float(want)) < 1e-3
+    for a, b in zip(got_grads, want_grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=2e-5)

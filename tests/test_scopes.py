@@ -129,6 +129,65 @@ def test_attention_stages_are_named(compiled_steps):
                    for n in names), stage
 
 
+@pytest.fixture(scope="module")
+def decoder_step(mesh8):
+    """[(HLO line, op_name)] of the DP step of the tiny decoder of tokens
+    (models/decoder.py), every layer rematerialised and its attention on
+    the streaming kernel, as the chip benchmark's cell runs the large one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from tpudist.models import create_model
+    from tpudist.train import (compute_dtype, create_train_state,
+                               make_train_step)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = Config(arch="mellum2_tiny", batch_size=16, seq_len=32,
+                     optimizer="adamw", use_amp=True, seed=0).finalize(8)
+        model = create_model(cfg.arch, dtype=compute_dtype(cfg), layers=2,
+                             expert_share=(0, 4), flash=True, remat=True,
+                             loss_chunk=16)   # four turns of the head's loop
+        state = create_train_state(jax.random.PRNGKey(0), model, cfg)
+        rows = jax.ShapeDtypeStruct((16, 32), jnp.int32)
+        text = make_train_step(mesh8, model, cfg).lower(
+            state, rows, rows, jnp.float32(0.1)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    out = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if " = " in line and name is not None \
+                and "jit(step)/" in name.group(1) \
+                and not any(p in line for p in PLUMBING):
+            out.append((line, name.group(1)))
+    return out
+
+
+def test_every_device_op_of_the_decoder_step_has_a_scope(decoder_step):
+    assert len(decoder_step) > 1000
+    unscoped = [n for _, n in decoder_step if phase_of(n) is None]
+    assert len(unscoped) <= 0.01 * len(decoder_step), sorted(set(unscoped))[:20]
+
+
+@pytest.mark.parametrize("scope,where", [
+    (scopes.MOE_ROUTER, "/moe/"), (scopes.MOE_DISPATCH, "/moe/"),
+    (scopes.MOE_EXPERTS, "/moe/"), (scopes.MOE_COMBINE, "/moe/"),
+    (scopes.ATTN_FUSED, "/self_attention/"), (scopes.LM_HEAD, "MoEDecoder/"),
+    (scopes.LM_EMBED, "MoEDecoder/"), (scopes.LOSS, "MoEDecoder/")])
+def test_decoder_scopes_are_named_forward_and_backward(decoder_step, scope,
+                                                       where):
+    """A layer's parts lie under the layer's name inside the forward scope,
+    plain and transposed: what `moe_ms`, `lm_head_ms` and
+    `attn_stream_roofline` of the chip benchmark sum."""
+    named = [n for _, n in decoder_step
+             if f"/{scope}/" in n and where in n and scopes.FORWARD in n]
+    assert any(phase_of(n) == "fwd" for n in named), scope
+    assert any(phase_of(n) == "bwd" for n in named), scope
+    if where == "/moe/":
+        assert any("/layer_0/" in n for n in named)
+        assert any("/layer_1/" in n for n in named)
+
+
 @pytest.mark.parametrize("op_name,phase", [
     ("jit(step)/jvp(tpudist_forward)/ResNet/layer3_0/bn2/mul", "fwd"),
     ("jit(step)/transpose(jvp(tpudist_forward))/ResNet/layer4_1/conv2/"

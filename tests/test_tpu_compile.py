@@ -48,6 +48,10 @@ FLASH_SHAPES = ((128, 197, 12, 64),      # ViT-B/16 @224
 # schedule's rule at its edge (256 tiles exactly; 577 is ViT-B/16 @384;
 # 640 is the longest that fits, at two heads a program), full and causal,
 # and the first length past it, which streams.
+# The streaming schedule at a decoder's shape (batch, tokens, query heads,
+# key-value heads, head_dim, window): two sequences of 8,192 with 32 heads
+# over 4 of 128, a sliding-window layer and a full one.
+FLASH_GQA_SHAPES = ((2, 8192, 32, 4, 128, 1024), (2, 8192, 32, 4, 128, None))
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -101,6 +105,26 @@ def _flash_fn(bwd: bool):
     return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
 
 
+def _flash_gqa_fn(bwd: bool, window):
+    from tpudist.ops.pallas.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               interpret=False).astype(jnp.float32).sum()
+
+    return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
+
+
+def _grouped_fn(bwd: bool):
+    from tpudist.ops.pallas.grouped_matmul import grouped_matmul
+
+    def f(x, w, sizes):
+        return grouped_matmul(x, w, sizes,
+                              interpret=False).astype(jnp.float32).sum()
+
+    return jax.grad(f, argnums=(0, 1)) if bwd else f
+
+
 def _flash_qkv_fn(bwd: bool, causal: bool):
     from tpudist.ops.pallas.flash_attention import flash_attention_qkv
 
@@ -121,6 +145,17 @@ _KERNEL_CASES = (
                     id=f"flash_b{shape[0]}_t{shape[1]}_"
                        f"{'fwdbwd' if bwd else 'fwd'}")
        for shape in FLASH_SHAPES for bwd in (False, True)]
+    + [pytest.param(("flash_gqa",) + shape, bwd,
+                    id=f"flash_gqa_t{shape[1]}_h{shape[2]}_kv{shape[3]}_"
+                       f"{'w%d_' % shape[5] if shape[5] else ''}"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for shape in FLASH_GQA_SHAPES for bwd in (False, True)]
+    # the grouped products of an expert layer's pair buffer: 131,072 rows
+    # (the worst case of two sequences of 8,192 with 8 experts a token),
+    # 16 experts held, hidden 2,304 <-> expert width 896, both directions
+    + [pytest.param(("grouped", 131072, 16, k, n), bwd,
+                    id=f"grouped_{k}x{n}_{'fwdbwd' if bwd else 'fwd'}")
+       for k, n in ((2304, 896), (896, 2304)) for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -145,12 +180,38 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
         b, t, h, d, causal = case[1:]
         args = [S((b, t, h, 3, d), jnp.bfloat16)]
         fn = _flash_qkv_fn(bwd, causal)
+    elif case[0] == "grouped":
+        _, rows, groups, k, n = case
+        args = [S((rows, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
+                S((groups,), jnp.int32)]
+        fn = _grouped_fn(bwd)
+    elif case[0] == "flash_gqa":
+        b, t, h, hkv, d, window = case[1:]
+        args = [S((b, t, h, d), jnp.bfloat16)] + [
+            S((b, t, hkv, d), jnp.bfloat16)] * 2
+        fn = _flash_gqa_fn(bwd, window)
     else:
         args = [S(case[1:], jnp.bfloat16)] * 3
         fn = _flash_fn(bwd)
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    if case[0] == "grouped":
+        # the product; or its two transposes (dx, dw: a sum's gradient
+        # needs no forward)
+        assert text.count("tpu_custom_call") == (2 if bwd else 1)
+        return
+    if case[0] == "flash_gqa":
+        # forward, dQ and dKV kernels; what they claim is the blocks inside
+        # the band (two products forward, four more backward), not the
+        # square's: between the pairs the mask allows and little over
+        # twice that (blocks of 1,024 under a window of 1,024: two a q block)
+        assert text.count("tpu_custom_call") == (3 if bwd else 1)
+        allowed = (t * (t + 1) // 2 if window is None
+                   else window * t - window * (window - 1) // 2)
+        least = (6 if bwd else 2) * 2 * b * h * allowed * d
+        assert least <= compiled.cost_analysis()["flops"] <= 2.1 * least
+        return
     if case[0] == "flash_qkv":
         from tpudist.ops.pallas.flash_attention import (WHOLE_SEQ,
                                                         schedule_for)

@@ -52,6 +52,13 @@ class Config:
     pretrained: bool = False
     pretrained_path: str = ""           # torchvision .pth file/dir ('' = torch-hub cache)
     num_classes: int = 1000
+    # a model of tokens: the rows' length, and what
+    # this holder keeps of a deployment's model (models/decoder.py)
+    seq_len: int = 0                    # ids a row (required for such a model)
+    layers: int = 0                     # leading layers kept (0 = all)
+    expert_share: str = "0/1"           # "i/n": the i-th of n holders of
+                                        # each layer's experts
+    vocab_share: str = "0/1"            # "i/n" of the vocabulary's rows
 
     # schedule (reference --epochs, --step, --start-epoch, --lr, --momentum,
     # --wd, --gamma, --lr-scheduler)
@@ -65,6 +72,7 @@ class Config:
     lr_scheduler: str = "steplr"
     optimizer: str = "sgd"              # sgd (reference) | adamw (for the
                                         # transformer-era zoo: vit/swin/convnext)
+    adam_b2: float = 0.999              # AdamW's second-moment decay
     warmup_epochs: int = 0              # linear lr warmup epochs (0 = off)
     label_smoothing: float = 0.0        # CE label smoothing (train loss only)
     model_ema_decay: float = 0.0        # EMA of params for eval (0 = off)
@@ -86,7 +94,7 @@ class Config:
                                         # activations in backward, trading
                                         # ~33% step FLOPs for O(depth) less
                                         # HBM (resnet/vit families)
-    flash: str = "auto"                 # Pallas flash attention (vit archs):
+    flash: str = "auto"                 # Pallas flash attention (attention archs):
                                         # auto = measurement-honest dispatch
                                         # (ops/attention_dispatch: kernel only
                                         # where a cached on-chip measurement
@@ -609,6 +617,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "complete on the CPU)")
     p.add_argument("--overwrite", default=d.overwrite, choices=["prompt", "delete", "quit", "keep"], help="what to do if outpath exists (keep = reuse untouched, for elastic restarts)")
     p.add_argument("--num-classes", default=d.num_classes, type=int, dest="num_classes")
+    p.add_argument("--seq-len", default=d.seq_len, type=int, dest="seq_len", help="ids a row, for a model of tokens (-b counts rows)")
+    p.add_argument("--layers", default=d.layers, type=int, help="leading layers of the model kept here (0 = all); the rest are further pipeline stages")
+    p.add_argument("--expert-share", default=d.expert_share, dest="expert_share", help="'i/n': this holder is the i-th of n that divide each layer's experts")
+    p.add_argument("--vocab-share", default=d.vocab_share, dest="vocab_share", help="'i/n': this holder's slice of the vocabulary's rows; ids, logits and loss are over the slice")
+    p.add_argument("--adam-b2", default=d.adam_b2, type=float, dest="adam_b2", help="AdamW second-moment decay")
     p.add_argument("--image-size", default=d.image_size, type=int, dest="image_size")
     p.add_argument("--mesh-shape", default=None, dest="mesh_shape", help="comma-separated mesh shape, e.g. '8' or '4,2'")
     p.add_argument("--mesh-axes", default=",".join(d.mesh_axes), dest="mesh_axes", help="comma-separated mesh axis names; 'data' = DP, plus ONE of 'model' (tensor parallel), 'seq' (ring-attention sequence parallel, vit_*), 'pipe' (GPipe pipeline parallel, vit_pipe_*), or 'expert' (MoE expert parallel, vit_moe_*; pure 'expert' or composed 'data,expert')")

@@ -135,7 +135,9 @@ class DataLoader:
                     rng = np.random.default_rng(
                         (self.seed, self.epoch, ds_index))
                     sample = self.transform(sample, rng)
-                sample = np.asarray(sample, dtype=np.float32)
+                sample = np.asarray(sample)
+                if sample.dtype != np.int32:      # token ids stay ids
+                    sample = sample.astype(np.float32)
                 if attempt:
                     with self._stats_lock:
                         self.samples_retried += 1
@@ -147,15 +149,16 @@ class DataLoader:
         raise last_err
 
     def _assemble(self, batch_idx: np.ndarray, batch_no: int):
-        images = None
-        labels = np.empty((len(batch_idx),), dtype=np.int32)
+        # allocated on the first sample: float32 images [n, H, W, C] with
+        # a class each, or int32 token rows [n, T] with a target a position
+        images = labels = None
         lock = threading.Lock()
         positions = list(enumerate(batch_idx))
         cursor = [0]
         errors: list[BaseException] = []
 
         def worker():
-            nonlocal images
+            nonlocal images, labels
             while True:
                 with lock:
                     if errors or cursor[0] >= len(positions):
@@ -201,7 +204,9 @@ class DataLoader:
                 with lock:
                     if images is None:
                         images = np.empty((len(batch_idx),) + sample.shape,
-                                          dtype=np.float32)
+                                          dtype=sample.dtype)
+                        labels = np.empty(
+                            (len(batch_idx),) + np.shape(label), np.int32)
                 images[pos] = sample
                 labels[pos] = label
 

@@ -21,7 +21,10 @@ from tpudist.data.synthetic import SyntheticDataset
 from tpudist.data import transforms
 
 
-def build_train_val_loaders(cfg: Config):
+def build_train_val_loaders(cfg: Config, vocab_size: int | None = None):
+    """``vocab_size`` (the ids a model of tokens holds) selects the token
+    source: rows of ``cfg.seq_len`` ids behind the same ``--synthetic`` /
+    ``--synthetic-size`` flags as the image source (no corpus reader yet)."""
     import os
 
     # Data rank/world from the distributed runtime, or — in the launcher's
@@ -33,7 +36,17 @@ def build_train_val_loaders(cfg: Config):
     host_batch = cfg.batch_size // nproc
     seed = cfg.seed if cfg.seed is not None else 0
 
-    if cfg.synthetic or not cfg.data:
+    if vocab_size and not (cfg.synthetic or not cfg.data):
+        raise ValueError("a model of tokens trains on --synthetic rows only: "
+                         "there is no corpus reader yet")
+    if vocab_size:
+        from tpudist.data.synthetic import SyntheticTokens
+        n_train = cfg.synthetic_size or max(host_batch * nproc * 4, 64)
+        train_ds = SyntheticTokens(n_train, cfg.seq_len, vocab_size, seed)
+        val_ds = SyntheticTokens(max(n_train // 2, host_batch), cfg.seq_len,
+                                 vocab_size, seed + 1)
+        train_tf = val_tf = None
+    elif cfg.synthetic or not cfg.data:
         n_train = getattr(cfg, "synthetic_size", 0) \
             or max(host_batch * nproc * 4, 256)
         train_ds = SyntheticDataset(n_train, cfg.image_size,

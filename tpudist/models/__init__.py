@@ -110,6 +110,11 @@ from tpudist.models import maxvit as _maxvit_mod                    # noqa: E402
 
 register_model("maxvit_t", _maxvit_mod.maxvit_t)
 
+from tpudist.models import decoder as _decoder_mod                  # noqa: E402
+
+register_model("mellum2_12b_a2_5b", _decoder_mod.mellum2_12b_a2_5b)
+register_model("mellum2_tiny", _decoder_mod.mellum2_tiny)
+
 
 def model_names() -> list[str]:
     return sorted(_REGISTRY)
@@ -119,11 +124,25 @@ def model_names() -> list[str]:
 # (models/resnet.py, models/vit.py). The single source of truth for every
 # entry point (trainer, bench.py, direct create_model callers).
 REMAT_FAMILIES = ("resnet", "resnext", "wide_resnet", "vit_b", "vit_l",
-                  "vit_h")
+                  "vit_h", "mellum2")
+# Families whose attention can run the Pallas kernel (``--flash``), and of
+# them the ones whose ``--flash auto`` has a start-up probe (a fused
+# projection of equal head counts; a decoder's grouped, windowed attention
+# has none yet: ``auto`` there is the XLA path).
+FLASH_FAMILIES = ("vit", "mellum2")
+FLASH_PROBE_FAMILIES = ("vit",)
 
 
 def supports_remat(arch: str) -> bool:
     return arch.startswith(REMAT_FAMILIES)
+
+
+def takes_flash(arch: str) -> bool:
+    return arch.startswith(FLASH_FAMILIES)
+
+
+def probes_flash(arch: str) -> bool:
+    return arch.startswith(FLASH_PROBE_FAMILIES)
 
 
 def create_model(arch: str, **kwargs: Any) -> nn.Module:

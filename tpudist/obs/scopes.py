@@ -1,7 +1,8 @@
 """The program's names for what it does: one place, names only.
 
-Three kinds, all read by `benchmarks/chip/` and by an operator in XProf
-(docs/OBSERVABILITY.md, "Labeled XLA traces"):
+Three kinds of span, all read by `benchmarks/chip/` and by an operator in
+XProf (docs/OBSERVABILITY.md, "Labeled XLA traces"), and the counters a
+model hands the step:
 
 - **device scopes** (`jax.named_scope`, `tpudist_*`): HLO metadata on every
   operation of a step program. A scope changes no compiled program, FLOP or
@@ -35,6 +36,22 @@ ATTN_SOFTMAX = "attn_softmax"
 ATTN_VALUES = "attn_values"
 # the Pallas attention kernels, forward and backward, in their place
 ATTN_FUSED = "attn_fused"
+# a top-k expert layer (parallel/moe.py::moe_topk_held), inside the forward
+# scope under the layer's name
+MOE_ROUTER = "moe_router"              # softmax over all experts, top k
+MOE_DISPATCH = "moe_dispatch"          # sort the held pairs, gather rows
+MOE_EXPERTS = "moe_experts"            # grouped products over the held
+MOE_COMBINE = "moe_combine"            # weighted sum back to tokens
+# a language model's ends
+LM_EMBED = "lm_embed"                  # the embedding's rows
+LM_HEAD = "lm_head"                    # the output head's product, a chunk
+
+# -- counters a model hands the step ----------------------------------------
+# One scalar a layer and a step (`<name>.layer_<l>`), through the step's
+# metrics and the trainer's drain to `telemetry.counters()`.
+MOE_PAIRS = "moe_pairs"                # pairs the held experts computed
+MOE_LOAD = "moe_load_max_over_mean"    # the fullest held expert over the mean
+MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD)
 
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)

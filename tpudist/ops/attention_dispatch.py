@@ -54,18 +54,24 @@ clear_cache = partial(dispatch.clear_cache, CLIENT)
 
 
 def shape_key(batch: int, seq: int, heads: int, head_dim: int, dtype,
-              train: bool, causal: bool) -> str:
+              train: bool, causal: bool, kv_heads: Optional[int] = None,
+              window: Optional[int] = None) -> str:
     """The dispatch identity: the exact attention workload. ``dtype`` may be
     a jnp/numpy dtype, scalar type, or string — normalized to the canonical
-    dtype name so every spelling of bfloat16 keys the same cache entry."""
+    dtype name so every spelling of bfloat16 keys the same cache entry.
+    Fewer key-value heads than query heads and a window are part of the
+    identity (``_kv4``, ``_w1024`` after the head count and the mask); a
+    workload with neither keeps the key it always had."""
     try:
         import numpy as np
         name = np.dtype(dtype).name
     except TypeError:
         name = getattr(dtype, "name", None) or str(dtype)
-    return (f"b{batch}_t{seq}_h{heads}_d{head_dim}_{name}_"
+    grouped = f"_kv{kv_heads}" if kv_heads not in (None, heads) else ""
+    return (f"b{batch}_t{seq}_h{heads}{grouped}_d{head_dim}_{name}_"
             f"{'train' if train else 'eval'}_"
-            f"{'causal' if causal else 'full'}")
+            f"{'causal' if causal else 'full'}"
+            + (f"_w{window}" if window is not None else ""))
 
 
 def kernel_rev() -> int:

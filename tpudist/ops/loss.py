@@ -3,19 +3,41 @@
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
+from tpudist.obs import scopes
 
-def cross_entropy_loss(logits: jax.Array, targets: jax.Array,
+
+class Scored(NamedTuple):
+    """What a model that takes its loss itself hands the step in place of
+    logits (a language model given ``targets``: its logits are never
+    whole). ``cross_entropy_loss`` and ``ops.accuracy`` read it as they
+    read logits; ``counters`` ride the step's metrics to the drain."""
+    loss: jax.Array            # mean over every position, float32
+    acc1: jax.Array            # top-1 of the target, percent
+    counters: dict             # name -> scalar, a step
+
+
+def cross_entropy_loss(logits: jax.Array | Scored, targets: jax.Array,
                        label_smoothing: float = 0.0) -> jax.Array:
-    """Mean softmax cross-entropy over integer labels.
+    """Mean softmax cross-entropy over integer labels: ``logits``
+    [..., classes] against ``targets`` [...] (a batch of labels, or
+    [rows, T] next ids under [rows, T, vocabulary] logits), the mean over
+    every leading position. A ``Scored`` is its own loss.
 
     Matches ``nn.CrossEntropyLoss`` (log-softmax + NLL, mean reduction,
     ``distributed.py:147,247``). Computed in float32 regardless of the compute
     dtype so the loss/grad scale is stable under the bf16 policy (the
     GradScaler-free TPU answer to ``distributed_syncBN_amp.py:275-278``).
     """
+    if isinstance(logits, Scored):
+        if label_smoothing:
+            raise ValueError("a model that takes its own loss does not "
+                             "smooth its labels")
+        return logits.loss
     logits = logits.astype(jnp.float32)
     log_probs = jax.nn.log_softmax(logits, axis=-1)
     n_classes = logits.shape[-1]
@@ -24,5 +46,41 @@ def cross_entropy_loss(logits: jax.Array, targets: jax.Array,
         onehot = onehot * (1.0 - label_smoothing) + label_smoothing / n_classes
         nll = -(onehot * log_probs).sum(axis=-1)
     else:
-        nll = -jnp.take_along_axis(log_probs, targets[:, None], axis=-1)[:, 0]
+        nll = -jnp.take_along_axis(log_probs, targets[..., None],
+                                   axis=-1)[..., 0]
     return nll.mean()
+
+
+def lm_head_loss(hidden: jax.Array, kernel: jax.Array, targets: jax.Array,
+                 chunk: int = 2048) -> tuple[jax.Array, jax.Array]:
+    """Output head and next-id cross entropy of a language model, taken
+    ``chunk`` positions at a time so that the logits are never whole
+    (``[16384, 24576]`` float32 would be 1.6 GB, twice with the cotangent):
+    ``hidden`` [rows, T, d] x ``kernel`` [d, V] against ``targets``
+    [rows, T]. Each chunk's logits are made again in the backward pass
+    (``jax.checkpoint``), products in ``hidden``'s dtype accumulated in
+    float32, the loss in float32. Returns (mean loss over all rows x T
+    positions, top-1 accuracy in percent)."""
+    rows, t, d = hidden.shape
+    n = rows * t
+    # the largest chunk up to the asked size that divides the positions
+    chunk = next(c for c in range(min(chunk, n), 0, -1) if n % c == 0)
+    w = kernel.astype(hidden.dtype)
+
+    @jax.checkpoint
+    def one(carry, xs):
+        h, y = xs
+        with jax.named_scope(scopes.LM_HEAD):
+            logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
+        with jax.named_scope(scopes.LOSS):
+            nll = (jax.nn.logsumexp(logits, axis=-1)
+                   - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0])
+        with jax.named_scope(scopes.METRICS):
+            hits = jnp.sum(jnp.argmax(logits, axis=-1) == y)
+        return (carry[0] + jnp.sum(nll), carry[1] + hits), None
+
+    (total, hits), _ = jax.lax.scan(
+        one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
+        (hidden.reshape(n // chunk, chunk, d),
+         targets.reshape(n // chunk, chunk)))
+    return total / n, hits.astype(jnp.float32) * (100.0 / n)

@@ -21,6 +21,14 @@ top-1 routing, cf. Fedus et al., and the Mesh-TF capacity formulation):
 ``moe_spmd`` is the inside-shard_map form; ``moe_dense`` is the
 single-device reference (same routing math, no capacity drop when C covers
 all tokens) used by tests and small-scale runs.
+
+``moe_topk_held`` (last section) is the other layer: softmax over all
+experts, the k largest renormalised, no capacity and no dropped pair, for a
+holder of ``n`` consecutive experts of ``E`` that computes its own experts'
+part of the result (grouped products over the experts held:
+``ops/pallas/grouped_matmul.py``). On one chip it
+runs without an exchange; the exchange across the chips that share a layer
+is not written yet.
 """
 
 from __future__ import annotations
@@ -32,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
+
+from tpudist.obs import scopes
 
 
 def init_moe_params(rng: jax.Array, d_model: int, d_hidden: int,
@@ -147,3 +157,147 @@ def make_moe(mesh: Mesh, expert_axis: str = "expert",
         in_specs=(param_specs, P(expert_axis)),
         out_specs=(P(expert_axis), P()),
         check_vma=False))
+
+
+# -- top-k routing over all experts, a share of them held ---------------------
+
+def route_topk(u: jax.Array, router: jax.Array, top_k: int):
+    """``p = softmax(u router)`` over all experts in float32 (the product at
+    full precision: a near-tie decides which expert runs), the ``top_k``
+    largest and their weights ``p_e / sum of the top_k``. u [T, d] ->
+    (experts [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    _, experts = lax.top_k(probs, top_k)
+    # the chosen probabilities, read through a one-hot: the transpose is a
+    # sum where top_k's own would scatter T x k scalars
+    chosen = jax.nn.one_hot(experts, probs.shape[-1], dtype=probs.dtype)
+    top = jnp.sum(probs[:, None, :] * chosen, axis=-1)
+    return experts, top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+@jax.custom_vjp
+def _take_pairs(x, tok, pos):
+    """The pair buffer's rows: ``x[tok]`` [C, d]. Its transpose is a gather
+    too (every token sums the rows of its own pairs, found at ``pos``
+    [T, k]; C marks a pair with no row), where XLA's would scatter-add C
+    rows."""
+    return x[tok]
+
+
+def _take_pairs_fwd(x, tok, pos):
+    return x[tok], pos
+
+
+def _take_pairs_bwd(pos, g):
+    g = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)])
+    return g[pos].astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+
+
+_take_pairs.defvjp(_take_pairs_fwd, _take_pairs_bwd)
+
+
+def _rows_of_pairs(out, pos):
+    """[T, k, d]: the row of each of a token's pairs, zeros for a pair with
+    no row (``pos == C``)."""
+    return jnp.concatenate(
+        [out, jnp.zeros((1, out.shape[1]), out.dtype)])[pos]
+
+
+@jax.custom_vjp
+def _combine_pairs(out, w, tok, pair, pos):
+    """``y[t] = sum_k w[t, k] out[pos[t, k]]`` in float32 (a pair with no
+    row adds nothing). ``tok`` / ``pair`` [C] name the token and the pair
+    (``t * k + slot``) of each row: the transpose with respect to ``out``
+    gathers with them."""
+    return _combine_pairs_fwd(out, w, tok, pair, pos)[0]
+
+
+def _combine_pairs_fwd(out, w, tok, pair, pos):
+    rows = _rows_of_pairs(out, pos)
+    y = jnp.einsum("tk,tkd->td", w, rows.astype(jnp.float32))
+    return y.astype(out.dtype), (rows, w, tok, pair)
+
+
+def _combine_pairs_bwd(res, dy):
+    rows, w, tok, pair = res
+    d_out = (w.reshape(-1)[pair][:, None] * dy[tok].astype(jnp.float32)
+             ).astype(rows.dtype)
+    d_w = jnp.einsum("td,tkd->tk", dy.astype(jnp.float32),
+                     rows.astype(jnp.float32))
+    return d_out, d_w, None, None, None
+
+
+_combine_pairs.defvjp(_combine_pairs_fwd, _combine_pairs_bwd)
+
+
+def moe_topk_held(params: dict, u: jax.Array, *, top_k: int,
+                  first_expert: int = 0,
+                  router_input: jax.Array | None = None):
+    """The part of a top-k expert layer that the holder of experts
+    ``first_expert .. first_expert + n - 1`` computes.
+
+    ``params``: ``router`` [d, E] over ALL experts, ``gate`` / ``up``
+    [n, d, f] and ``down`` [n, f, d] of the n held. ``u`` [T, d] (the
+    products run in its dtype, accumulated in float32); ``router_input``,
+    where given, is what the router reads instead (the same values before
+    they were rounded to ``u``'s dtype). Every token routes over all E
+    experts (``route_topk``); a (token, expert) pair whose expert is held
+    here gets a row in the pair buffer, sorted by expert, and the result is
+    ``sum over a token's held pairs of w * (silu(x gate_e) * (x up_e))
+    down_e``. The weights stay normalised over all k chosen, held or not;
+    what the absent experts would add is left out; a token none of whose k
+    is held gets zero.
+
+    Nothing is dropped and nothing can be: the pair buffer has the worst
+    case's ``k T`` rows (every pair of every token held here). The grouped
+    products walk only the tiles that hold pairs; the gathers move every
+    row of the buffer, filled or not.
+
+    Returns (y [T, d], counters): ``moe_pairs`` (pairs the held experts
+    computed), ``moe_load_max_over_mean`` (the fullest held expert's pairs
+    over the mean)."""
+    t, _ = u.shape
+    n = params["gate"].shape[0]
+    n_pairs = t * top_k
+    with jax.named_scope(scopes.MOE_ROUTER):
+        experts, weights = route_topk(
+            u if router_input is None else router_input, params["router"],
+            top_k)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        local = experts - first_expert
+        held = (local >= 0) & (local < n)
+        key = jnp.where(held, local, n).reshape(-1).astype(jnp.int32)
+        iota = jnp.arange(n_pairs, dtype=jnp.int32)
+        # held pairs first, by expert, in token order; then the rest
+        _, order = lax.sort((key, iota), num_keys=1)
+        _, rank = lax.sort((order, iota), num_keys=1)
+        load = jnp.sum(jax.nn.one_hot(key, n, dtype=jnp.int32), axis=0)
+        rows = jnp.sum(load)
+        tok = order // top_k
+        # the row of each of a token's pairs; ``n_pairs`` for one not held
+        pos = jnp.where(held.reshape(-1), rank, n_pairs).reshape(t, top_k)
+        x = _take_pairs(u, tok, pos)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        from tpudist.ops.pallas.grouped_matmul import grouped_matmul
+        dt = u.dtype
+
+        def grouped(a, w):
+            return grouped_matmul(a, w.astype(dt), load)
+        gate = grouped(x, params["gate"]).astype(jnp.float32)
+        h = (jax.nn.silu(gate) * grouped(x, params["up"])).astype(dt)
+        out = grouped(h, params["down"])
+    with jax.named_scope(scopes.MOE_COMBINE):
+        # a row past the last pair holds whatever the product left there
+        # (its input was some token's row, its group nobody's)
+        out = jnp.where((iota < rows)[:, None], out, 0)
+        y = _combine_pairs(out, jnp.where(held, weights, 0.0), tok, order,
+                           pos)
+    total = rows.astype(jnp.float32)
+    counters = {
+        scopes.MOE_PAIRS: total,
+        scopes.MOE_LOAD: jnp.max(load).astype(jnp.float32)
+        * n / jnp.maximum(total, 1.0),
+    }
+    return y, counters

@@ -37,9 +37,21 @@ NEG_INF = -1e30
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal: bool = False) -> jax.Array:
-    """Plain softmax attention. Shapes [B, T, H, D]; fp32 softmax."""
+              causal: bool = False, window: Optional[int] = None) -> jax.Array:
+    """Plain softmax attention; fp32 softmax. ``q`` [B, T, H, D]; ``k`` and
+    ``v`` [B, Tk, Hkv, D], where ``Hkv`` divides ``H``: query head ``j``
+    reads key-value head ``j // (H // Hkv)`` (grouped-query attention).
+    ``window`` (with ``causal``) keeps, of the keys a query may see, the
+    nearest ``window``. The scores are whole, [B, H, T, Tk] float32: the
+    Pallas kernel (``ops/pallas/flash_attention.py``) takes the same
+    arguments where they do not fit."""
+    if window is not None and not causal:
+        raise ValueError("a window is the nearest keys of a causal mask: "
+                         "pass causal=True with window")
     d = q.shape[-1]
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     # The three stages carry scopes (labels only): a trace then says which of
     # an encoder block's fusions is which.
     with jax.named_scope(scopes.ATTN_SCORES):
@@ -47,6 +59,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if causal:
             tq, tk = s.shape[-2], s.shape[-1]
             mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+            if window is not None:
+                mask &= ~jnp.tril(jnp.ones((tq, tk), bool),
+                                  k=tk - tq - window)
             s = jnp.where(mask, s, NEG_INF)
     with jax.named_scope(scopes.ATTN_SOFTMAX):
         p = jax.nn.softmax(s, axis=-1)

@@ -159,6 +159,9 @@ NO_TP_FAMILIES = (
     "resnext", "wide_resnet", "alexnet", "squeezenet",
     "mobilenet", "shufflenet", "mnasnet", "googlenet",
     "inception", "efficientnet", "regnet", "maxvit",
+    # the decoder of tokens divides a layer by expert and vocabulary share
+    # (models/decoder.py), not by a 'model' axis: no rule table yet
+    "mellum2",
 )
 
 

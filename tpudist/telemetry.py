@@ -331,6 +331,26 @@ def record_phase(name: str, seconds: float) -> None:
     _phases[name] = float(seconds)
 
 
+_counters: dict[str, list] = {}
+_COUNTER_KEEP = 4096
+
+
+def record_counter(name: str, value: float) -> None:
+    """Keep one step's reading of a model's own counter (the trainer's
+    metric drain hands them over as host floats, a step late like every
+    metric): the newest ``_COUNTER_KEEP`` of each name."""
+    kept = _counters.setdefault(name, [])
+    kept.append(float(value))
+    if len(kept) > _COUNTER_KEEP:
+        del kept[:len(kept) - _COUNTER_KEEP]
+
+
+def counters() -> dict[str, list]:
+    """Every counter's readings, oldest first, a step each (a copy). Like
+    ``phases()`` it needs no ``--telemetry``: the chip benchmark reads it."""
+    return {k: list(v) for k, v in _counters.items()}
+
+
 def phases() -> dict[str, float]:
     """Set-up seconds by phase name, as last recorded in this process: the
     runtime's ``init`` and the constructor's ``init.*`` (names in

@@ -126,7 +126,9 @@ def make_optimizer(cfg: Config) -> optax.GradientTransformation:
     if cfg.optimizer == "sgd":
         return sgd_torch(cfg.lr, cfg.momentum, cfg.weight_decay)
     if cfg.optimizer == "adamw":
-        return adamw_torch(cfg.lr, cfg.weight_decay, mask=no_decay_mask)
+        return adamw_torch(cfg.lr, cfg.weight_decay,
+                           b2=getattr(cfg, "adam_b2", 0.999),
+                           mask=no_decay_mask)
     raise ValueError(f"unsupported optimizer '{cfg.optimizer}' (sgd|adamw)")
 
 
@@ -160,9 +162,19 @@ def create_train_state(rng: jax.Array, model: nn.Module, cfg: Config,
                        input_shape: Sequence[int] | None = None) -> TrainState:
     """Init params/BN stats (DDP's rank0-broadcast init is implicit: the same
     seed produces identical params everywhere; under pjit they are one
-    replicated global array)."""
-    shape = tuple(input_shape or (1, cfg.image_size, cfg.image_size, 3))
-    variables = model.init(rng, jnp.ones(shape, jnp.float32), train=False)
+    replicated global array). The model is initialised on its own
+    ``example_input()`` where it has one (a model of tokens: a short int32
+    row), else on a float image ``[1, image_size, image_size, 3]`` or
+    ``input_shape``. The initialisation is one compiled program: the
+    forward pass that shapes the parameters is traced and never run (eagerly
+    it was most of a decoder's set-up, a small compile an operation)."""
+    example = getattr(model, "example_input", None)
+    if example is not None and input_shape is None:
+        inputs = example()
+    else:
+        shape = tuple(input_shape or (1, cfg.image_size, cfg.image_size, 3))
+        inputs = jnp.ones(shape, jnp.float32)
+    variables = jax.jit(partial(model.init, train=False))(rng, inputs)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     tx = make_optimizer(cfg)
@@ -198,11 +210,16 @@ def _loss_fn(model: nn.Module, rng, params, batch_stats, images, labels,
     # group under them in XProf and in the chip benchmark's fwd/bwd/opt
     # split. Metadata only: the compiled program's FLOPs/memory are
     # unchanged — test_compiled_cost pins that.
+    # A model that takes its loss itself (a language model: its logits are
+    # never whole) is handed the targets and returns an ``ops.Scored``, which
+    # the loss and the accuracy below read as they read logits.
+    targets = {"targets": labels} if getattr(model, "takes_targets",
+                                             False) else {}
     with jax.named_scope(scopes.FORWARD):
         outputs, mutated = model.apply(
             {"params": params, "batch_stats": batch_stats},
             images, train=True, mutable=["batch_stats", "intermediates"],
-            rngs={"dropout": rng})
+            rngs={"dropout": rng}, **targets)
     from tpudist.ops.mixup import mixed_ce
     with jax.named_scope(scopes.LOSS):
         loss = mixed_ce(outputs, labels, labels2, lam, smoothing)
@@ -210,7 +227,7 @@ def _loss_fn(model: nn.Module, rng, params, batch_stats, images, labels,
         # logits are sown to 'intermediates' during training; weight them
         # into the loss so the aux params actually receive gradient
         # (torchvision's train recipe — without this they'd only be decayed
-        # noise, ADVICE r1 #2).
+        # noise).
         aux_w = getattr(model, "aux_loss_weight", 0.0)
         if aux_w:
             for aux_logits in jax.tree_util.tree_leaves(
@@ -218,6 +235,13 @@ def _loss_fn(model: nn.Module, rng, params, batch_stats, images, labels,
                 loss = loss + aux_w * mixed_ce(aux_logits, labels, labels2,
                                                lam, smoothing)
     return loss, (outputs, mutated.get("batch_stats", {}))
+
+
+def model_counters(outputs) -> dict:
+    """The counters a model's ``Scored`` carries (an expert layer's pairs,
+    its fullest expert over the mean), for the step's metrics; nothing for
+    logits."""
+    return dict(getattr(outputs, "counters", None) or {})
 
 
 def global_grad_norm(grads) -> jax.Array:
@@ -391,6 +415,9 @@ def make_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
                 "loss": jax.lax.pmean(loss, axis_name=data_axis),
                 "acc1": jax.lax.pmean(acc1, axis_name=data_axis),
             }
+            counters = model_counters(outputs) if accum == 1 else {}
+            if counters:
+                metrics.update(jax.lax.pmean(counters, axis_name=data_axis))
         if guard:
             # Doctor sentinels: global grad norm + finiteness of (mean loss,
             # grad norm). ``grads`` is post-reduction, so both signals are
@@ -489,10 +516,12 @@ def make_eval_step(mesh: Mesh, model: nn.Module, cfg: Config,
     (default: fully replicated). The expert-parallel path passes its split
     layout (expert FFN leaves sharded over the batch/expert axis)."""
     def step(state: TrainState, images, labels):
+        targets = {"targets": labels} if getattr(model, "takes_targets",
+                                                 False) else {}
         with jax.named_scope(scopes.EVAL_FORWARD):
             outputs = model.apply(
                 {"params": state.params, "batch_stats": state.batch_stats},
-                images, train=False)
+                images, train=False, **targets)
         loss = cross_entropy_loss(outputs, labels)
         acc1 = accuracy(outputs, labels, topk=1)
         return {
