@@ -242,6 +242,118 @@ def test_the_expert_layers_gradients_match_a_dense_loop():
             jax.tree_util.keystr(path)
 
 
+# tokens of 48 whose two chosen experts are named, so that the pairs held by
+# experts 2..5 (a buffer of 96 rows, walked in blocks of 32) are counted by
+# hand: (held experts of a token's two, how many such tokens)
+FILLS = {
+    "none_held": [((), 48)],
+    "a_whole_block": [((2,), 10), ((5,), 10), ((3, 4), 6), ((), 22)],
+    "one_past_a_block": [((2,), 10), ((5,), 11), ((3, 4), 6), ((), 21)],
+    "a_quarter": [((4,), 12), ((2, 3), 6), ((), 30)],
+    "all_held": [((2, 3), 16), ((4, 5), 16), ((3, 5), 16)],
+    "one_held_expert": [((3,), 48)],
+}
+FILL_PAIRS = {fill: sum(len(held) * count for held, count in spec)
+              for fill, spec in FILLS.items()}      # 0, 32, 33, 24, 96, 48
+BLOCK = 32
+
+
+def _filled(fill, seed=8):
+    """(params, u): a router that reads a token's first eight features as
+    its logits, and tokens whose two largest are the experts the fill
+    names (held ones first, the rest from the experts not held)."""
+    params = _moe_params(jax.random.PRNGKey(seed))
+    params["router"] = jnp.zeros((32, 8)).at[:8].set(jnp.eye(8))
+    u = jax.random.normal(jax.random.PRNGKey(seed + 1), (48, 32))
+    chosen = [tuple(held) + (0, 7, 1, 6)[:2 - len(held)]
+              for held, count in FILLS[fill] for _ in range(count)]
+    order = np.random.RandomState(seed).permutation(48)
+    logits = np.asarray(u[:, :8]) * 0.3
+    for t, (a, b) in zip(order, chosen):
+        logits[t, a], logits[t, b] = 4.0, 3.0
+    return params, u.at[:, :8].set(logits)
+
+
+@pytest.mark.parametrize("fill", list(FILLS))
+def test_the_block_loops_match_a_dense_loop_at_every_fill(fill, monkeypatch):
+    """The result, every gradient and the counters where the loops over the
+    pair buffer take no turn, whole turns, one row into the next, a quarter
+    of the buffer, all of it, and one expert's rows only. The buffers start
+    as NaN here (on the chip: whatever the memory held), so nothing may
+    read a row that no pair wrote."""
+    monkeypatch.setattr(
+        jax.lax, "empty", lambda shape, dtype: jnp.full(shape, jnp.nan, dtype))
+    params, u = _filled(fill)
+
+    def ours(p, x):
+        y, counters = moe_topk_held(_share(p, 2, 4), x, top_k=2,
+                                    first_expert=2)
+        return jnp.sum(jnp.square(y)) + jnp.sum(y[:, 0]), (y, counters)
+
+    def dense(p, x):
+        y, _ = REF._moe(x, _share(p, 2, 4), dict(k=2, first=2, held=4),
+                        None)
+        return jnp.sum(jnp.square(y)) + jnp.sum(y[:, 0]), y
+
+    with jax.default_matmul_precision("highest"):
+        got, (y, counters) = jax.grad(ours, argnums=(0, 1), has_aux=True)(
+            params, u)
+        want, y_want = jax.grad(dense, argnums=(0, 1), has_aux=True)(
+            params, u)
+    np.testing.assert_allclose(y, y_want, atol=2e-5)
+    for (path, g), (_, w) in zip(leaves(got), leaves(want)):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-6
+        assert float(jnp.max(jnp.abs(g - w))) < 1e-5 * scale, \
+            jax.tree_util.keystr(path)
+    pairs = FILL_PAIRS[fill]
+    assert float(counters["moe_pairs"]) == pairs
+    assert float(counters["moe_rows_walked"]) == -(-pairs // BLOCK) * BLOCK
+
+
+def _shapes_in(jaxpr):
+    """The shape of every value a jaxpr computes, its inner jaxprs' too."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _shapes_in(sub)
+
+
+def test_no_tensor_of_tokens_by_slots_by_width_is_built():
+    """Forward and backward hold no [T, k, d] intermediate (a token's rows
+    are summed where they lie), and the walked rows reach
+    ``telemetry.counters()`` through the drain as the pairs do."""
+    from tpudist import telemetry
+    from tpudist.trainer import _MetricDrain
+    from tpudist.utils import AverageMeter
+    params, u = _filled("a_quarter")
+
+    def loss(p, x):
+        return jnp.sum(jnp.square(moe_topk_held(
+            _share(p, 2, 4), x, top_k=2, first_expert=2)[0]))
+
+    shapes = set(_shapes_in(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, u).jaxpr))
+    assert (96, 32) in shapes                  # the pair buffer is there
+    assert (48, 2, 32) not in shapes and (96, 2, 32) not in shapes
+
+    drain = _MetricDrain({"loss": AverageMeter("Loss")})
+    seen = {k: len(telemetry.counters().get(f"{k}.layer_1", []))
+            for k in ("moe_pairs", "moe_rows_walked")}
+    for step, fill in enumerate(FILLS):
+        p, x = _filled(fill)
+        _, counters = moe_topk_held(_share(p, 2, 4), x, top_k=2,
+                                    first_expert=2)
+        drain.push({"loss": 1.0, **{f"{k}.layer_1": v
+                                    for k, v in counters.items()}},
+                   n=2, step=step)
+    drain.drain()
+    pairs, walked = (telemetry.counters()[f"{k}.layer_1"][seen[k]:]
+                     for k in ("moe_pairs", "moe_rows_walked"))
+    assert pairs == [float(FILL_PAIRS[f]) for f in FILLS]
+    assert walked == [float(-(-int(n) // BLOCK) * BLOCK) for n in pairs]
+
+
 # --- attention --------------------------------------------------------------
 
 def test_windowed_attention_sees_the_nearest_keys_only():

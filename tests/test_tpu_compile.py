@@ -228,6 +228,51 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
         assert flops >= (12 if bwd else 4) * b * h * t * t * d
 
 
+# -- a whole expert layer (slow: ~25 s of TPU compiler) ----------------------
+
+@pytest.mark.slow
+@pytest.mark.parametrize("held", [16, 64], ids=["quarter_held", "all_held"])
+def test_expert_layer_compiles_for_v5e(topo, monkeypatch, held):
+    """``moe_topk_held`` forward + backward at the decoder cell's shape (two
+    sequences of 8,192, hidden 2,304, 64 experts of width 896, 8 a token;
+    16 held as in the cell, and all 64: the share at which every block of
+    the pair buffer is walked). The loops with a trip count on the device
+    lower, nothing of [T, k, d] is built, and outside the grouped products
+    no operation of the program's body runs over the buffer's 131,072 rows:
+    they are allocated, and written by loops."""
+    import re
+
+    from tpudist.parallel.moe import moe_topk_held
+    one = SingleDeviceSharding(topo.devices[0])
+    t, d, f, experts, k = 16384, 2304, 896, 64, 8
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = {"router": S((d, experts)), "gate": S((held, d, f)),
+              "up": S((held, d, f)), "down": S((held, f, d))}
+
+    def loss(p, u):
+        y, counters = moe_topk_held(p, u.astype(jnp.bfloat16), top_k=k,
+                                    router_input=u)
+        return jnp.sum(jnp.square(y.astype(jnp.float32))), counters
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        params, S((t, d))).compile()
+    text = compiled.as_text()
+    # two products forward; their four transposes
+    assert text.count("tpu_custom_call") == 6
+    assert f"[{t},{k},{d}]" not in text
+    entry = text[text.index("\nENTRY "):]
+    over_the_buffer = set(re.findall(
+        rf"= (?:bf16|f32)\[{t * k},\d+\]\S* ([\w\-]+)\(", entry))
+    assert over_the_buffer <= {"custom-call", "get-tuple-element", "bitcast"}
+    # one layer's temporaries: 2.29 GB with a quarter held (2.66 before the
+    # loops), and no more of the buffer's size with every expert held
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 # -- whole train steps (slow: ~15-30 s of TPU compiler each) -----------------
 
 def _abstract_state(model, cfg, mesh, specs_fn=None):
@@ -323,3 +368,46 @@ def test_vit_b16_flash_step_compiles_for_v5e(topo, monkeypatch, tp):
     text = _compile_step(monkeypatch, step, state, cfg, mesh).as_text()
     assert "tpu_custom_call" in text           # flash really is in the step
     assert ("all-reduce" in text) == tp
+
+
+@pytest.mark.slow
+def test_decoder_step_compiles_for_v5e(topo, monkeypatch):
+    """The whole step of the benchmark's decoder cell (one chip's share of
+    Mellum2-12B-A2.5B: 4 layers, 16 of 64 experts, a quarter of the
+    vocabulary, two rows of 8,192 ids, ``--remat``, the streaming attention
+    kernel): it fits, 11.80 GiB of the chip's 15.75, and inside each
+    rematerialised layer no loop copies a pair buffer (a carry that XLA
+    could not update in place cost 29 ms a step a loop, on the chip)."""
+    import json
+    import re
+
+    from tpudist.config import from_args
+    from tpudist.models import create_model
+    from tpudist.train import compute_dtype, make_train_step
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "chip", "configs",
+                           "mellum2_12b_ep4.json")) as f:
+        argv = [str(a).format(batch=2, seed=0, outpath="unused")
+                for a in json.load(f)["trainer_argv"]]
+    cfg = from_args(argv)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    model = create_model(cfg.arch, num_classes=cfg.num_classes,
+                         dtype=compute_dtype(cfg), remat=True,
+                         flash=True).clone(
+        layers=4, expert_share=(0, 4), vocab_share=(0, 4))
+    ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    lr = jax.ShapeDtypeStruct((), jnp.float32,
+                              sharding=NamedSharding(mesh, P()))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the state is donated on the chip (conftest.py turns that off for the
+    # CPU runtime): the outputs then lie where the arguments lay
+    monkeypatch.delenv("TPUDIST_NO_DONATE", raising=False)
+    compiled = make_train_step(mesh, model, cfg).lower(
+        _abstract_state(model, cfg, mesh), ids, ids, lr).compile()
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 12.0 * 2**30
+    pairs = 2 * cfg.seq_len * 8
+    assert not re.search(rf"= (?:bf16|f32)\[{pairs},\d+\]\S* copy\(",
+                         compiled.as_text())

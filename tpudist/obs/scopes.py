@@ -39,7 +39,7 @@ ATTN_FUSED = "attn_fused"
 # a top-k expert layer (parallel/moe.py::moe_topk_held), inside the forward
 # scope under the layer's name
 MOE_ROUTER = "moe_router"              # softmax over all experts, top k
-MOE_DISPATCH = "moe_dispatch"          # sort the held pairs, gather rows
+MOE_DISPATCH = "moe_dispatch"          # sort the held pairs, take their rows
 MOE_EXPERTS = "moe_experts"            # grouped products over the held
 MOE_COMBINE = "moe_combine"            # weighted sum back to tokens
 # a language model's ends
@@ -51,7 +51,8 @@ LM_HEAD = "lm_head"                    # the output head's product, a chunk
 # metrics and the trainer's drain to `telemetry.counters()`.
 MOE_PAIRS = "moe_pairs"                # pairs the held experts computed
 MOE_LOAD = "moe_load_max_over_mean"    # the fullest held expert over the mean
-MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD)
+MOE_WALKED = "moe_rows_walked"         # buffer rows the block loops touched
+MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED)
 
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)

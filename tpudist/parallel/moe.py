@@ -34,7 +34,7 @@ is not written yet.
 from __future__ import annotations
 
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -177,56 +177,221 @@ def route_topk(u: jax.Array, router: jax.Array, top_k: int):
     return experts, top / jnp.sum(top, axis=-1, keepdims=True)
 
 
+class _Pairs(NamedTuple):
+    """Where the held (token, expert) pairs lie in a layer's pair buffer of
+    ``C = k T`` rows, sorted by expert, the first ``rows`` of them filled.
+    A pair is ``t * k + slot``."""
+    rows: jax.Array      # [] int32: pairs held
+    tok: jax.Array       # [C] the token of each row
+    pair: jax.Array      # [C] the pair of each row
+    by_pair: jax.Array   # [C] the held pairs ascending (a token's lie
+    src: jax.Array       # [C] together), C past the last; the row of each
+    pos: jax.Array       # [T, k] the row of each pair; C for one not held
+
+
+def _block(c: int) -> int:
+    """Rows a turn of the loops over a pair buffer of ``c`` rows: up to four
+    of the grouped product's row tiles (2,048 rows of 131,072), whole turns
+    of the buffer."""
+    from tpudist.ops.pallas.grouped_matmul import _row_tile
+    tile = _row_tile(c)
+    return next(m * tile for m in (4, 2, 1) if c % (m * tile) == 0)
+
+
+def _walk(rows, like, body):
+    """Buffers like ``like`` (a tree of [C, ...] shapes and dtypes) written
+    a block at a time: ``body(at, filled) -> blocks`` for each block of
+    ``_block(C)`` rows that holds one of the ``rows`` pairs (on the device),
+    ``ceil(rows / block)`` turns, none for an empty buffer. ``at(a, more=0)``
+    is the block's rows of a [C, ...] array (and ``more`` after them);
+    ``filled`` [block, 1] says which of them hold a pair: ``body`` writes
+    zeros in the others. A block no pair reaches is never written and holds
+    whatever the memory held (``lax.empty``: zeros off the TPU), as the
+    grouped products leave theirs: nothing reads it. A loop writes fresh
+    buffers only (one whose carry it also read copied the whole buffer
+    every turn, on the chip). Only the hand-written halves of the custom
+    VJPs below call this: a loop whose trip count is on the device has no
+    transpose."""
+    block = _block(jax.tree.leaves(like)[0].shape[0])
+    iota = jnp.arange(block, dtype=jnp.int32)[:, None]
+
+    def turn(i, bufs):
+        start = i * block
+
+        def at(a, more=0):
+            return lax.dynamic_slice_in_dim(a, start, block + more)
+        return jax.tree.map(
+            lambda buf, blk: lax.dynamic_update_slice_in_dim(
+                buf, blk.astype(buf.dtype), start, 0),
+            bufs, body(at, iota < rows - start))
+    return lax.fori_loop(
+        0, (rows + block - 1) // block, turn,
+        jax.tree.map(lambda a: lax.empty(a.shape, a.dtype), like))
+
+
+def _sum_by_token(buf, pairs: _Pairs, weights=None):
+    """[T, d] in ``buf``'s dtype: the float32 sum, a token, of its pairs'
+    rows of ``buf`` [C, d], each times its weight (``weights`` [T k]) where
+    given; zeros for a token that holds none.
+
+    The rows are taken in (token, slot) order, a block at a time, so a
+    token's rows lie together, at most ``k`` of them: log2(k) shifted adds
+    leave each token's sum on its first row, which a gather of T rows
+    reads. No [T, k, d] tensor, and the work follows the pairs held."""
+    c = buf.shape[0]
+    k = pairs.pos.shape[1]
+    shifts = [1 << i for i in range((k - 1).bit_length())]
+    halo = sum(shifts) + 1                     # k, rounded up to a power of 2
+    by_pair = jnp.concatenate([pairs.by_pair, jnp.full((halo,), c, jnp.int32)])
+    src = jnp.concatenate([pairs.src, jnp.zeros((halo,), jnp.int32)])
+
+    def sums(at, _):
+        pair = at(by_pair, halo)
+        z = buf[at(src, halo)].astype(jnp.float32)
+        if weights is not None:
+            z = z * weights[jnp.minimum(pair, c - 1)][:, None]
+        z = jnp.where((pair < c)[:, None], z, 0.0)
+        tok = pair // k
+        for s in shifts:
+            same = jnp.concatenate([tok[s:] == tok[:-s],
+                                    jnp.zeros((s,), bool)])
+            z = z + jnp.where(same[:, None], jnp.roll(z, -s, axis=0), 0.0)
+        return z[:-halo]
+    by_first = _walk(pairs.rows, buf, sums)
+    count = jnp.sum(pairs.pos < c, axis=1)
+    # a token that holds a pair has its first among the filled rows
+    return jnp.where((count > 0)[:, None],
+                     by_first[jnp.cumsum(count) - count], 0)
+
+
+def _take_pairs(u, pairs: _Pairs):
+    """The pair buffer's rows: ``u[tok]`` [C, d] for the rows that hold a
+    pair."""
+    like = jax.ShapeDtypeStruct((pairs.tok.shape[0], u.shape[1]), u.dtype)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        return _walk(pairs.rows, like, lambda at, _: u[at(pairs.tok)])
+
+
+def _product(a, w, load):
+    # Pallas is imported where a layer is traced, not with the package
+    from tpudist.ops.pallas.grouped_matmul import grouped_matmul
+    return grouped_matmul(a, w, load)
+
+
+def _transposes(a, w, load, g):
+    """The grouped product's two cotangents, handed on together: the
+    weights' is then computed where the rows' is, and not at the
+    scheduler's leisure with both of its [C, .] operands kept until then (a
+    whole step's peak read 14.0 GiB for 11.97)."""
+    # the product's own transposes (its forward, unused here, is dropped)
+    _, transposed = jax.vjp(lambda a, w: _product(a, w, load), a, w)
+    return lax.optimization_barrier(transposed(g))
+
+
 @jax.custom_vjp
-def _take_pairs(x, tok, pos):
-    """The pair buffer's rows: ``x[tok]`` [C, d]. Its transpose is a gather
-    too (every token sums the rows of its own pairs, found at ``pos``
-    [T, k]; C marks a pair with no row), where XLA's would scatter-add C
-    rows."""
-    return x[tok]
+def _project_pairs(u, w, pairs: _Pairs, load):
+    """The grouped product of the pairs' rows ``u[tok]`` with their
+    experts' ``w``. The rows are not kept for the transposes but taken
+    again (one more gather of the filled blocks): kept, they would hold
+    [C, d] through the whole of the layer's backward. The cotangent of
+    ``u`` sums a token's rows in float32."""
+    return _project_pairs_fwd(u, w, pairs, load)[0]
 
 
-def _take_pairs_fwd(x, tok, pos):
-    return x[tok], pos
+def _project_pairs_fwd(u, w, pairs, load):
+    return _product(_take_pairs(u, pairs), w, load), (u, w, pairs, load)
 
 
-def _take_pairs_bwd(pos, g):
-    g = jnp.concatenate([g, jnp.zeros((1, g.shape[1]), g.dtype)])
-    return g[pos].astype(jnp.float32).sum(axis=1).astype(g.dtype), None, None
+def _project_pairs_bwd(res, g):
+    u, w, pairs, load = res
+    dx, dw = _transposes(_take_pairs(u, pairs), w, load, g)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        du = _sum_by_token(dx, pairs)
+    return du, dw, None, None
 
 
-_take_pairs.defvjp(_take_pairs_fwd, _take_pairs_bwd)
-
-
-def _rows_of_pairs(out, pos):
-    """[T, k, d]: the row of each of a token's pairs, zeros for a pair with
-    no row (``pos == C``)."""
-    return jnp.concatenate(
-        [out, jnp.zeros((1, out.shape[1]), out.dtype)])[pos]
+_project_pairs.defvjp(_project_pairs_fwd, _project_pairs_bwd)
 
 
 @jax.custom_vjp
-def _combine_pairs(out, w, tok, pair, pos):
-    """``y[t] = sum_k w[t, k] out[pos[t, k]]`` in float32 (a pair with no
-    row adds nothing). ``tok`` / ``pair`` [C] name the token and the pair
-    (``t * k + slot``) of each row: the transpose with respect to ``out``
-    gathers with them."""
-    return _combine_pairs_fwd(out, w, tok, pair, pos)[0]
+def _swiglu(gu, rows):
+    """``silu(gate) * up`` in float32 of ``gu`` = [gate | up] [C, 2f], over
+    the ``rows`` rows that hold a pair; zeros in the rest of their last
+    block, in the result and in the cotangent."""
+    return _swiglu_fwd(gu, rows)[0]
 
 
-def _combine_pairs_fwd(out, w, tok, pair, pos):
-    rows = _rows_of_pairs(out, pos)
-    y = jnp.einsum("tk,tkd->td", w, rows.astype(jnp.float32))
-    return y.astype(out.dtype), (rows, w, tok, pair)
+def _swiglu_fwd(gu, rows):
+    c, f = gu.shape[0], gu.shape[1] // 2
+
+    def act(at, filled):
+        blk = at(gu).astype(jnp.float32)
+        return jnp.where(filled, jax.nn.silu(blk[:, :f]) * blk[:, f:], 0.0)
+    h = _walk(rows, jax.ShapeDtypeStruct((c, f), gu.dtype), act)
+    return h, (gu, rows)
+
+
+def _swiglu_bwd(res, dh):
+    gu, rows = res
+    f = gu.shape[1] // 2
+
+    def act_t(at, filled):
+        blk = at(gu).astype(jnp.float32)
+        gate, up = blk[:, :f], blk[:, f:]
+        d = at(dh).astype(jnp.float32)
+        s = jax.nn.sigmoid(gate)
+        return jnp.where(filled, jnp.concatenate(
+            [d * up * s * (1.0 + gate * (1.0 - s)), d * gate * s], axis=1),
+            0.0)
+    return _walk(rows, gu, act_t), None
+
+
+_swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+@jax.custom_vjp
+def _grouped(a, w, load):
+    """``ops/pallas/grouped_matmul.py``'s product, with ``_transposes``."""
+    return _product(a, w, load)
+
+
+def _grouped_fwd(a, w, load):
+    return _product(a, w, load), (a, w, load)
+
+
+def _grouped_bwd(res, g):
+    return (*_transposes(*res, g), None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@jax.custom_vjp
+def _combine_pairs(out, w, pairs: _Pairs):
+    """``y[t] = sum over t's held pairs of w[t, k] out[row of (t, k)]`` in
+    float32: no row past the last pair is read, and their cotangent in the
+    last block is zero."""
+    return _combine_pairs_fwd(out, w, pairs)[0]
+
+
+def _combine_pairs_fwd(out, w, pairs):
+    return _sum_by_token(out, pairs, w.reshape(-1)), (out, w, pairs)
 
 
 def _combine_pairs_bwd(res, dy):
-    rows, w, tok, pair = res
-    d_out = (w.reshape(-1)[pair][:, None] * dy[tok].astype(jnp.float32)
-             ).astype(rows.dtype)
-    d_w = jnp.einsum("td,tkd->tk", dy.astype(jnp.float32),
-                     rows.astype(jnp.float32))
-    return d_out, d_w, None, None, None
+    out, w, pairs = res
+    c = out.shape[0]
+    flat = w.reshape(-1)
+
+    def spread(at, filled):
+        g = dy[at(pairs.tok)].astype(jnp.float32)
+        dot = jnp.sum(g * at(out).astype(jnp.float32), axis=1, keepdims=True)
+        return (jnp.where(filled, flat[at(pairs.pair)][:, None] * g, 0.0),
+                jnp.where(filled, dot, 0.0)[:, 0])
+    d_out, dot = _walk(pairs.rows, (
+        out, jax.ShapeDtypeStruct((c,), jnp.float32)), spread)
+    d_w = jnp.where(pairs.pos < c, dot[jnp.minimum(pairs.pos, c - 1)], 0.0)
+    return d_out, d_w, None
 
 
 _combine_pairs.defvjp(_combine_pairs_fwd, _combine_pairs_bwd)
@@ -251,13 +416,16 @@ def moe_topk_held(params: dict, u: jax.Array, *, top_k: int,
     is held gets zero.
 
     Nothing is dropped and nothing can be: the pair buffer has the worst
-    case's ``k T`` rows (every pair of every token held here). The grouped
-    products walk only the tiles that hold pairs; the gathers move every
-    row of the buffer, filled or not.
+    case's ``k T`` rows (every pair of every token held here). What is
+    touched of it follows the pairs held, counted on the device: the grouped
+    products walk only the tiles that hold pairs, and everything around
+    them (the rows' gather, SwiGLU, the weighted sum back to tokens and
+    their transposes) only the blocks that do (``_walk``).
 
     Returns (y [T, d], counters): ``moe_pairs`` (pairs the held experts
     computed), ``moe_load_max_over_mean`` (the fullest held expert's pairs
-    over the mean)."""
+    over the mean), ``moe_rows_walked`` (rows of the buffer the block loops
+    touched: the pairs, rounded up to a block)."""
     t, _ = u.shape
     n = params["gate"].shape[0]
     n_pairs = t * top_k
@@ -272,32 +440,31 @@ def moe_topk_held(params: dict, u: jax.Array, *, top_k: int,
         iota = jnp.arange(n_pairs, dtype=jnp.int32)
         # held pairs first, by expert, in token order; then the rest
         _, order = lax.sort((key, iota), num_keys=1)
-        _, rank = lax.sort((order, iota), num_keys=1)
         load = jnp.sum(jax.nn.one_hot(key, n, dtype=jnp.int32), axis=0)
         rows = jnp.sum(load)
-        tok = order // top_k
-        # the row of each of a token's pairs; ``n_pairs`` for one not held
-        pos = jnp.where(held.reshape(-1), rank, n_pairs).reshape(t, top_k)
-        x = _take_pairs(u, tok, pos)
+        # the filled rows again, by pair: a token's rows lie together
+        by_pair, src = lax.sort(
+            (jnp.where(iota < rows, order, n_pairs), iota), num_keys=1)
+        # a held pair's row: as many rows before it as held pairs
+        flat = held.reshape(-1)
+        before = jnp.cumsum(flat, dtype=jnp.int32) - flat
+        pairs = _Pairs(
+            rows=rows, tok=order // top_k, pair=order, by_pair=by_pair,
+            src=src, pos=jnp.where(flat, src[before], n_pairs).reshape(
+                t, top_k))
     with jax.named_scope(scopes.MOE_EXPERTS):
-        from tpudist.ops.pallas.grouped_matmul import grouped_matmul
         dt = u.dtype
-
-        def grouped(a, w):
-            return grouped_matmul(a, w.astype(dt), load)
-        gate = grouped(x, params["gate"]).astype(jnp.float32)
-        h = (jax.nn.silu(gate) * grouped(x, params["up"])).astype(dt)
-        out = grouped(h, params["down"])
+        # gate and up in one product: one cotangent for the pairs' rows
+        gate_up = jnp.concatenate([params["gate"], params["up"]], axis=2)
+        h = _swiglu(_project_pairs(u, gate_up.astype(dt), pairs, load), rows)
+        out = _grouped(h, params["down"].astype(dt), load)
     with jax.named_scope(scopes.MOE_COMBINE):
-        # a row past the last pair holds whatever the product left there
-        # (its input was some token's row, its group nobody's)
-        out = jnp.where((iota < rows)[:, None], out, 0)
-        y = _combine_pairs(out, jnp.where(held, weights, 0.0), tok, order,
-                           pos)
-    total = rows.astype(jnp.float32)
+        y = _combine_pairs(out, jnp.where(held, weights, 0.0), pairs)
+    total, block = rows.astype(jnp.float32), _block(n_pairs)
     counters = {
         scopes.MOE_PAIRS: total,
         scopes.MOE_LOAD: jnp.max(load).astype(jnp.float32)
         * n / jnp.maximum(total, 1.0),
+        scopes.MOE_WALKED: (-(-rows // block) * block).astype(jnp.float32),
     }
     return y, counters
