@@ -123,7 +123,7 @@ def _stub_trainer(cfg, arch, **model_kw):
     return t
 
 
-@pytest.mark.parametrize("family", ["fused_norm", "attention", "comm"])
+@pytest.mark.parametrize("family", ["attention", "comm"])
 def test_probe_failure_under_auto_on_tpu_propagates(family, tmp_path,
                                                     monkeypatch):
     """Under ``auto`` on a TPU only a measured loss or a static
@@ -131,36 +131,57 @@ def test_probe_failure_under_auto_on_tpu_propagates(family, tmp_path,
     the compiler refuses) must end the run — before this PR the trainer
     caught it, logged 'probe failed' and trained on XLA with exit 0."""
     from tpudist.config import Config
-    from tpudist.ops import attention_dispatch, comm_dispatch, norm_dispatch
+    from tpudist.ops import attention_dispatch, comm_dispatch
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setenv("TPUDIST_DISPATCH_CACHE", str(tmp_path / "verdicts"))
     base = dict(num_classes=4, batch_size=64, synthetic=True, use_amp=True,
                 outpath=str(tmp_path / "run"), seed=0)
-    try:
-        if family == "fused_norm":
-            monkeypatch.setattr(norm_dispatch, "measure_fused_norm", _boom)
-            t = _stub_trainer(Config(arch="resnet18", image_size=32, **base),
-                              "resnet18")
-            resolve = t._resolve_fused_norm_dispatch
-        elif family == "attention":
-            monkeypatch.setattr(attention_dispatch, "measure_attention",
-                                _boom)
-            t = _stub_trainer(Config(arch="vit_b_16", image_size=224, **base),
-                              "vit_b_16")
-            resolve = t._resolve_flash_dispatch
-        else:
-            monkeypatch.setattr(comm_dispatch, "measure_comm", _boom)
-            t = _stub_trainer(Config(arch="resnet18", image_size=32,
-                                     compress_grads="auto", **base),
-                              "resnet18")
-            resolve = t._resolve_comm_dispatch
-        with pytest.raises(_MosaicRefused):
-            resolve()
-    finally:
-        norm_dispatch.set_mode(None)
+    if family == "attention":
+        monkeypatch.setattr(attention_dispatch, "measure_attention", _boom)
+        t = _stub_trainer(Config(arch="vit_b_16", image_size=224, **base),
+                          "vit_b_16")
+        resolve = t._resolve_flash_dispatch
+    else:
+        monkeypatch.setattr(comm_dispatch, "measure_comm", _boom)
+        t = _stub_trainer(Config(arch="resnet18", image_size=32,
+                                 compress_grads="auto", **base),
+                          "resnet18")
+        resolve = t._resolve_comm_dispatch
+    with pytest.raises(_MosaicRefused):
+        resolve()
     # Nothing was cached: the next run asks the compiler again.
     assert not os.path.exists(tmp_path / "verdicts") \
         or not os.listdir(tmp_path / "verdicts")
+
+
+def test_an_unwritable_verdict_store_still_builds_the_measured_kernel(
+        tmp_path, monkeypatch):
+    """A verdict that cannot be written down (a read-only cache directory)
+    still stands for the run that measured it: the trainer builds its
+    model from the decision it logs, not from a look-up in the store, and
+    the decision says that nothing was kept (``cache_path`` None)."""
+    from tpudist.config import Config
+    from tpudist.ops import attention_dispatch, dispatch
+
+    def read_only(path, cache):
+        raise OSError("read-only file system")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("TPUDIST_DISPATCH_CACHE", str(tmp_path / "verdicts"))
+    monkeypatch.setattr(dispatch, "save_cache", read_only)
+    monkeypatch.setattr(attention_dispatch, "measure_attention",
+                        lambda *a, **k: (1.0, 2.0))
+    t = _stub_trainer(Config(arch="vit_b_16", image_size=224, num_classes=4,
+                             batch_size=64, synthetic=True, use_amp=True,
+                             outpath=str(tmp_path / "run"), seed=0),
+                      "vit_b_16")
+    t.log = lambda line: None
+    assert t.model.flash is None
+    dec = t._resolve_flash_dispatch()
+    assert dec["kernel"] == "flash" and dec["source"] == "measured"
+    assert dec["cache_path"] is None and t.model.flash is True
+    assert not os.path.exists(tmp_path / "verdicts")
+    # the next run measures again: nothing answers a look-up
+    assert attention_dispatch.lookup(8, 197, 12, 64, "bfloat16") is False
 
 
 @pytest.mark.parametrize("schedule", ["whole_seq", "streaming"])

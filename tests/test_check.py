@@ -402,7 +402,7 @@ def test_module_level_pallas_import(tmp_path):
     findings = run_on(tmp_path, """
         from jax.experimental import pallas as pl
         from tpudist.ops.pallas import flash_attention
-        import tpudist.ops.pallas.fused_norm
+        import tpudist.ops.pallas.grouped_matmul
         """)
     assert rule_ids(findings) == ["PALLAS01"] * 3
 
@@ -2074,8 +2074,6 @@ def test_shard05_active_on_real_tree_and_clean():
     paths = [os.path.join(REPO, "tpudist", "parallel", "plane.py"),
              os.path.join(REPO, "tpudist", "parallel",
                           "tensor_parallel.py"),
-             os.path.join(REPO, "tpudist", "ops", "pallas",
-                          "fused_norm.py"),
              os.path.join(REPO, "tpudist", "ops", "pallas",
                           "flash_attention.py")]
     findings, _ = core.run_check(REPO, paths=paths)

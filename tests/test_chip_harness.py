@@ -3,10 +3,16 @@ arithmetic, collected in tier-1: the cases of
 `benchmarks/chip/selftest/test_feed_cpu.py` (run by path there, in seconds,
 with no `Trainer`), loaded from that file so that there is one copy of them.
 The rest of `benchmarks/chip/selftest/` builds trainers for minutes and
-stays run by path."""
+stays run by path. Below them: what the configurations' `trainer_argv` pins
+against the program's defaults."""
 
+import dataclasses
+import glob
 import importlib.util
+import json
 import os
+
+import pytest
 
 _PATH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "benchmarks", "chip", "selftest", "test_feed_cpu.py")
@@ -18,3 +24,81 @@ _spec.loader.exec_module(_feed)
 # every test of the file, under its own name (parametrised cases and all)
 globals().update({name: value for name, value in vars(_feed).items()
                   if name.startswith("test_")})
+
+
+# -- the cells measure the default program -----------------------------------
+# Every selector a configuration's `trainer_argv` writes out, read from the
+# files under `benchmarks/chip/configs/` (none is edited here).
+
+_CONFIGS = sorted(glob.glob(os.path.join(os.path.dirname(_PATH), os.pardir,
+                                         "configs", "*.json")))
+_TAKES_VALUE = ("--fused-bn", "--flash", "--compress-grads", "--zero",
+                "--accum-steps")
+_SELECTORS = _TAKES_VALUE + (
+    "--remat", "--no-remat", "--no-telemetry", "--no-doctor", "--no-blackbox",
+    "--device_prefetch", "--async_drain")
+# The pins that are NOT a default written out: (configuration, selector) ->
+# the one field that differs, and why the cell needs it.
+_NOT_DEFAULTS = {
+    ("mellum2_12b_ep4", "--flash"):
+        ("flash", "a decoder has no start-up probe, `auto` is XLA's path, and "
+                  "XLA's scores at 8,192 tokens would be 17 GB"),
+    ("mellum2_12b_ep4", "--remat"):
+        ("remat", "15.8 GiB a step without it, 11.8 with: only so it fits"),
+    ("resnet18_ref", "--flash"):
+        ("flash", "the field differs and the program does not: resnet18 has "
+                  "no attention (models.takes_flash)"),
+}
+
+
+def _pins():
+    for path in _CONFIGS:
+        name = os.path.splitext(os.path.basename(path))[0]
+        with open(path) as f:
+            argv = [str(a).format(batch=8, seed=0, outpath="unused")
+                    for a in json.load(f)["trainer_argv"]]
+        for flag in _SELECTORS:
+            if flag in argv:
+                yield pytest.param(name, argv, flag, id=f"{name}{flag}")
+
+
+@pytest.mark.parametrize("name,argv,flag", _pins())
+def test_a_pin_writes_a_default_out_and_no_more(name, argv, flag):
+    """`python -m tpudist` with no selector builds the program the cell
+    measures: `from_args` of a configuration's `trainer_argv` equals, field
+    for field, `from_args` of the same argv with one selector taken out.
+    The exceptions are listed above with their reasons, and for them the
+    two DO differ, in exactly the field named, so the list cannot go
+    stale."""
+    from tpudist.config import from_args
+    i = argv.index(flag)
+    without = argv[:i] + argv[i + (2 if flag in _TAKES_VALUE else 1):]
+    pinned, default = (dataclasses.asdict(from_args(a))
+                       for a in (argv, without))
+    differs = [k for k in pinned if pinned[k] != default[k]]
+    field, _why = _NOT_DEFAULTS.get((name, flag), (None, None))
+    assert differs == ([field] if field else [])
+    if (name, flag) == ("resnet18_ref", "--flash"):
+        from tpudist.models import takes_flash
+        assert not takes_flash(pinned["arch"])
+
+
+def test_every_exception_names_a_pin_that_is_written_out():
+    written = {(p.values[0], p.values[2]) for p in _pins()}
+    assert set(_NOT_DEFAULTS) <= written
+    assert len(written) == 33
+
+
+def test_fused_bn_takes_off_and_nothing_else(capsys):
+    """The configurations still write `--fused-bn off`, so it parses (and
+    sets no field); `on` and `auto` are refused with the line that says
+    where the kernel went."""
+    from tpudist.config import Config, from_args
+    assert not hasattr(from_args(["--fused-bn", "off"]), "fused_bn")
+    assert "fused_bn" not in {f.name for f in dataclasses.fields(Config)}
+    for value in ("on", "auto"):
+        with pytest.raises(SystemExit) as refused:
+            from_args(["--fused-bn", value])
+        assert refused.value.code == 2
+        assert f"'{value}': the fused BN kernel left in PR 32" \
+            in capsys.readouterr().err

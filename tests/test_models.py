@@ -146,6 +146,107 @@ def test_sync_batchnorm_pmean_stats(mesh8):
                                rtol=1e-5, atol=1e-6)
 
 
+def _epilogue_pair(bn, variables, x, res):
+    """((value, (y, batch_stats)), grads) twice: of BatchNorm's own epilogue,
+    and of the chain the call sites wrote out before ``act=`` /
+    ``residual=``: relu(bn(x) + res)."""
+    def chain(handed_over):
+        def f(params, x, res):
+            kw = dict(act="relu", residual=res) if handed_over else {}
+            y, mut = bn.apply({"params": params,
+                               "batch_stats": variables["batch_stats"]}, x,
+                              mutable=["batch_stats"], **kw)
+            if not handed_over:
+                y = jax.nn.relu(y if res is None else y + res)
+            return y.astype(jnp.float32).sum(), (y, mut.get("batch_stats"))
+        return f
+
+    argnums = (0, 1) if res is None else (0, 1, 2)
+    return [jax.value_and_grad(chain(handed_over), argnums=argnums,
+                               has_aux=True)(variables["params"], x, res)
+            for handed_over in (True, False)]
+
+
+def _assert_bit_equal(got, want):
+    got, want = jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want) and got
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
+                                      np.asarray(w.astype(jnp.float32)))
+
+
+# resnet18's five BN widths (spatial edge at 224 px, channels), the edges cut
+# to CPU size: the channel counts are the model's
+RESNET18_BN = ((8, 64), (6, 64), (4, 128), (3, 256), (2, 512))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("residual", [False, True], ids=["plain", "residual"])
+@pytest.mark.parametrize("edge,channels", RESNET18_BN,
+                         ids=[f"{e}x{e}x{c}" for e, c in RESNET18_BN])
+def test_batchnorm_epilogue_is_the_written_out_chain(edge, channels, residual,
+                                                     dtype):
+    """``BatchNorm(act="relu", residual=...)`` IS relu(bn(x) + residual), to
+    the bit, in output, running statistics and every gradient (scale, bias,
+    x, residual): the hot path of every conv net's blocks, and the order
+    (float32 normalise -> cast -> add -> relu) that the compiled-cost
+    goldens were taken with."""
+    rng = np.random.default_rng(channels + edge)
+    shape = (4, edge, edge, channels)
+    x = jnp.asarray(rng.standard_normal(shape), dtype)
+    res = jnp.asarray(rng.standard_normal(shape), dtype) if residual else None
+    bn = BatchNorm(use_running_average=False, dtype=dtype)
+    variables = bn.init(jax.random.PRNGKey(0), x)
+    variables = {**variables, "params": {
+        "scale": jnp.asarray(rng.standard_normal(channels), jnp.float32),
+        "bias": jnp.asarray(rng.standard_normal(channels), jnp.float32)}}
+    got, want = _epilogue_pair(bn, variables, x, res)
+    assert got[0][1][0].dtype == dtype
+    _assert_bit_equal(got, want)
+
+
+def test_batchnorm_epilogue_is_the_written_out_chain_in_eval_mode():
+    """The same identity on running statistics (what validation runs)."""
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((4, 5, 5, 24)), jnp.bfloat16)
+    res = jnp.asarray(rng.standard_normal((4, 5, 5, 24)), jnp.bfloat16)
+    bn = BatchNorm(use_running_average=True, dtype=jnp.bfloat16)
+    variables = bn.init(jax.random.PRNGKey(0), x)
+    variables = {**variables, "batch_stats": {
+        "mean": jnp.asarray(rng.standard_normal(24), jnp.float32),
+        "var": jnp.asarray(rng.random(24) + 0.5, jnp.float32)}}
+    got, want = _epilogue_pair(bn, variables, x, res)
+    _assert_bit_equal(got, want)
+    # running statistics are read, not written
+    _assert_bit_equal(got[0][1][1], variables["batch_stats"])
+
+
+def test_batchnorm_epilogue_is_the_written_out_chain_under_syncbn(mesh8):
+    """And with ``axis_name`` set: statistics pmean-ed over the data axis,
+    the epilogue on each shard's rows."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.standard_normal((16, 4, 4, 24)), jnp.float32)
+    res = jnp.asarray(rng.standard_normal((16, 4, 4, 24)), jnp.float32)
+    bn = BatchNorm(use_running_average=False, axis_name="data")
+    variables = BatchNorm(use_running_average=False).init(
+        jax.random.PRNGKey(0), x[:2])
+
+    def both(x, res):
+        # a leading axis on every leaf, so that each shard's own values
+        # (its loss, its gradients) come back side by side
+        return jax.tree_util.tree_map(
+            lambda a: a[None], _epilogue_pair(bn, variables, x, res))
+
+    got, want = jax.jit(shard_map(
+        both, mesh=mesh8, in_specs=(P("data"), P("data")),
+        out_specs=P("data"), check_vma=False))(x, res)
+    _assert_bit_equal(got, want)
+
+
 @pytest.mark.parametrize("arch,layers,std,uniform", [
     # torchvision: normal(0, 0.01) for mobilenet v2/v3 Linears
     pytest.param("mobilenet_v2", ["classifier_1"], 0.01, False,

@@ -556,24 +556,30 @@ def test_two_replica_scale_up_e2e(tmp_path, mp_timeout):
                        text=True, timeout=mp_timeout(1, compile_cost=2.0))
     assert r.returncode == 0 and "SERVE_SUMMARY" in r.stdout, \
         (r.stdout[-2000:], r.stderr[-2000:])
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "tpudist.launch", "--nprocs", "1",
-         "--scale-up", "2@3", "--metrics-port", "0",
-         "--telemetry-dir", str(out), "--",
-         *serve_cmd, "--telemetry", "--metrics-port", "0",
-         "--outpath", str(out), "--load-rate", "25",
-         "--load-duration", "12"],
-        cwd=REPO, env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-        text=True)
+    # Into files, not pipes: nothing reads a pipe while this test polls the
+    # endpoint, and the replicas write hundreds of KB to stderr (XLA's note
+    # on every program loaded from the cache), so a pipe fills, the replica
+    # blocks in write() and never serves (how this test failed in every
+    # tier-1 run from PR 21 to PR 31).
+    launch_out, launch_err = tmp_path / "launch.out", tmp_path / "launch.err"
+    with open(launch_out, "w") as so, open(launch_err, "w") as se:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "tpudist.launch", "--nprocs", "1",
+             "--scale-up", "2@3", "--metrics-port", "0",
+             "--telemetry-dir", str(out), "--",
+             *serve_cmd, "--telemetry", "--metrics-port", "0",
+             "--outpath", str(out), "--load-rate", "25",
+             "--load-duration", "12"],
+            cwd=REPO, env=env, stdout=so, stderr=se)
     try:
         port = None
         deadline = time.time() + mp_timeout(2, compile_cost=2.0)
-        while time.time() < deadline:
-            line = proc.stderr.readline()
-            m = re.search(r"fleet metrics on :(\d+)", line or "")
+        while time.time() < deadline and proc.poll() is None:
+            m = re.search(r"fleet metrics on :(\d+)", launch_err.read_text())
             if m:
                 port = int(m.group(1))
                 break
+            time.sleep(0.2)
         assert port, "launcher never announced the fleet endpoint"
         both = ""
         while time.time() < deadline and proc.poll() is None:
@@ -595,8 +601,8 @@ def test_two_replica_scale_up_e2e(tmp_path, mp_timeout):
         assert 'tpudist_rank_serve_requests_total{rank="0"}' in both
         assert 'tpudist_rank_serve_requests_total{rank="1"}' in both
         rc = proc.wait(timeout=mp_timeout(2, compile_cost=2.0))
-        assert rc == 0, (proc.stdout.read()[-2000:],
-                         proc.stderr.read()[-2000:])
+        assert rc == 0, (launch_out.read_text()[-2000:],
+                         launch_err.read_text()[-2000:])
     finally:
         if proc.poll() is None:
             proc.terminate()

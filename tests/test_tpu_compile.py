@@ -4,11 +4,11 @@ The TPU compiler installed beside jax compiles for a *described* v5e
 topology — no device attached, nothing runs — and raises exactly what the
 chip's compiler would raise. Interpret mode checks none of this: it never
 looks at Mosaic's tiling rules, its supported ops or VMEM limits, which is
-how a fused-BN backward that 21 interpret-mode tests passed was refused at
-every resnet18 width on the real target (fused_norm KERNEL_REV 2).
+how a kernel backward that 21 interpret-mode tests passed was refused at
+every width on the real target (PR 21).
 
-- the two main-path Pallas kernels at real widths, forward and
-  forward+backward (tier-1, well under a second each);
+- the Pallas kernels at real widths, forward and forward+backward (tier-1,
+  well under a second each);
 - the whole train-step programs the chip smoke runs (``slow``): resnet18
   on one and on four described chips, ViT-B/16 with flash on one chip and
   dp x tp over the 2x2.
@@ -37,10 +37,7 @@ import pytest  # noqa: E402
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
                           SingleDeviceSharding)
 
-# resnet18 @224, per-chip batch 128: (spatial edge, channels) of every BN
-# epilogue in the model — chip_smoke.py runs the same widths on the chip.
-BATCH = 128
-BN_WIDTHS = ((112, 64), (56, 64), (28, 128), (14, 256), (7, 512))
+BATCH = 128                              # resnet18 @224, per chip
 FLASH_SHAPES = ((128, 197, 12, 64),      # ViT-B/16 @224
                 (8, 2048, 12, 64))       # exact-tiling long sequence
 # The fused [B, T, H, 3, D] entry (batch, tokens, heads, head_dim, causal):
@@ -81,18 +78,6 @@ def _no_persistent_cache():
     yield
     jax.config.update("jax_enable_compilation_cache", old)
     compilation_cache.reset_cache()
-
-
-def _bn_fn(residual: bool, bwd: bool):
-    from tpudist.ops.pallas.fused_norm import fused_bn_act
-
-    def f(x, scale, bias, mean, var, res=None):
-        return fused_bn_act(x, scale, bias, mean, var, residual=res,
-                            interpret=False).astype(jnp.float32).sum()
-
-    if not bwd:
-        return f
-    return jax.grad(f, argnums=(0, 1, 2, 3, 4) + ((5,) if residual else ()))
 
 
 def _flash_fn(bwd: bool):
@@ -136,12 +121,7 @@ def _flash_qkv_fn(bwd: bool, causal: bool):
 
 
 _KERNEL_CASES = (
-    [pytest.param(("bn", hw, c, residual), bwd,
-                  id=f"bn_{hw}x{hw}x{c}_{'res' if residual else 'plain'}_"
-                     f"{'fwdbwd' if bwd else 'fwd'}")
-     for hw, c in BN_WIDTHS for residual in (False, True)
-     for bwd in (False, True)]
-    + [pytest.param(("flash",) + shape, bwd,
+    [pytest.param(("flash",) + shape, bwd,
                     id=f"flash_b{shape[0]}_t{shape[1]}_"
                        f"{'fwdbwd' if bwd else 'fwd'}")
        for shape in FLASH_SHAPES for bwd in (False, True)]
@@ -170,13 +150,7 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    if case[0] == "bn":
-        _, hw, c, residual = case
-        act = S((BATCH * hw * hw, c), jnp.bfloat16)
-        args = [act] + [S((c,), jnp.float32)] * 4 + ([act] if residual
-                                                     else [])
-        fn = _bn_fn(residual, bwd)
-    elif case[0] == "flash_qkv":
+    if case[0] == "flash_qkv":
         b, t, h, d, causal = case[1:]
         args = [S((b, t, h, 3, d), jnp.bfloat16)]
         fn = _flash_qkv_fn(bwd, causal)
@@ -301,16 +275,13 @@ def _compile_step(monkeypatch, step, state, cfg, mesh):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("n_dev,fused,sync_bn", [
-    (1, "off", False),      # the program chip_smoke's train phase may run
-    (1, "on", False),       # ... with every BN epilogue on the Pallas kernel
-    (4, "off", True),       # chip_smoke --multichip: DP + SyncBN
-], ids=["1chip_xla", "1chip_fused", "4chip_syncbn"])
-def test_resnet18_step_compiles_for_v5e(topo, monkeypatch, n_dev, fused,
-                                        sync_bn):
+@pytest.mark.parametrize("n_dev,sync_bn", [
+    (1, False),             # the program chip_smoke's train phase runs
+    (4, True),              # chip_smoke --multichip: DP + SyncBN
+], ids=["1chip_xla", "4chip_syncbn"])
+def test_resnet18_step_compiles_for_v5e(topo, monkeypatch, n_dev, sync_bn):
     from tpudist.config import Config
     from tpudist.models import create_model
-    from tpudist.ops import norm_dispatch
     from tpudist.train import compute_dtype, make_train_step
     mesh = Mesh(np.asarray(topo.devices[:n_dev]), ("data",))
     cfg = Config(arch="resnet18", num_classes=1000, image_size=224,
@@ -319,15 +290,11 @@ def test_resnet18_step_compiles_for_v5e(topo, monkeypatch, n_dev, fused,
     model = create_model("resnet18", num_classes=1000,
                          dtype=compute_dtype(cfg), sync_batchnorm=sync_bn,
                          bn_axis_name="data")
-    norm_dispatch.set_mode(fused)
-    try:
-        compiled = _compile_step(
-            monkeypatch, make_train_step(mesh, model, cfg),
-            _abstract_state(model, cfg, mesh), cfg, mesh)
-    finally:
-        norm_dispatch.set_mode(None)
+    compiled = _compile_step(
+        monkeypatch, make_train_step(mesh, model, cfg),
+        _abstract_state(model, cfg, mesh), cfg, mesh)
     text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == (fused == "on")
+    assert "tpu_custom_call" not in text
     assert ("all-reduce" in text) == (n_dev > 1)
     # Fits one v5e chip (16 GB) with room for the prefetch double buffer.
     ma = compiled.memory_analysis()

@@ -1,6 +1,5 @@
-"""Ambient-mesh helper for the trace-time kernel wrappers
-(``flash_attention_spmd``, ``fused_bn_act_spmd``,
-``norm_dispatch.epilogue_shard_axes``)."""
+"""Ambient-mesh helper for the trace-time kernel wrapper
+(``flash_attention_spmd``)."""
 
 from __future__ import annotations
 

@@ -1,9 +1,9 @@
 """PALLAS01 — lazy-Pallas discipline.
 
-PR 5/6's measurement-honesty invariant, made structural: on a CPU host,
-``--flash auto`` / ``--fused-bn auto`` must resolve to XLA *without Pallas
-ever entering ``sys.modules``* (``__graft_entry__`` dryrun modes 10/11
-prove it at runtime by inspecting ``sys.modules``). That only holds if no
+PR 5's measurement-honesty invariant, made structural: on a CPU host,
+``--flash auto`` must resolve to XLA *without Pallas ever entering
+``sys.modules``* (``__graft_entry__`` dryrun mode 10 proves it at runtime
+by inspecting ``sys.modules``). That only holds if no
 module outside ``tpudist/ops/pallas/`` imports Pallas — or anything from
 the ``tpudist.ops.pallas`` package — at module level. Kernel access from
 dispatch clients, models, and benches is function-local by convention
@@ -74,6 +74,6 @@ def check(ctx: dict, mod: Module) -> list:
             mod, "PALLAS01", node.lineno, node.col_offset,
             f"module-level import of '{target}' outside tpudist/ops/pallas/ "
             f"— breaks the 'CPU auto never imports Pallas' honesty "
-            f"invariant (dryrun modes 10/11); move the import inside the "
+            f"invariant (dryrun mode 10); move the import inside the "
             f"function that already decided to use the kernel"))
     return out

@@ -100,13 +100,6 @@ class Config:
                                         # where a cached on-chip measurement
                                         # says it wins); on/off force it
                                         # (off = pure-XLA attention)
-    fused_bn: str = "auto"              # Pallas fused BN+ReLU / BN+add+ReLU
-                                        # epilogues (conv families): auto =
-                                        # measurement-honest dispatch
-                                        # (ops/norm_dispatch, same honesty
-                                        # layer as --flash); on/off force.
-                                        # SyncBN and eval mode always take
-                                        # the XLA path (docs/KERNELS.md)
     device_prefetch: bool = True        # double-buffered device prefetch:
                                         # issue batch N+1's host→device copy
                                         # while step N computes, so the
@@ -272,10 +265,6 @@ class Config:
             # Config directly, where a typo must not silently coerce to off.
             raise ValueError(
                 f"--flash must be one of auto|on|off, got '{self.flash}'")
-        if self.fused_bn not in ("auto", "on", "off"):
-            raise ValueError(
-                f"--fused-bn must be one of auto|on|off, got "
-                f"'{self.fused_bn}'")
         # -- mesh/axis-composition validation (ISSUE 12: loud errors, not
         # silent pure-DP no-ops). The parallelism plane owns the axis
         # vocabulary and the rule tables; lazily imported (jax-facing) and
@@ -463,6 +452,14 @@ def _bool_flag(parser: argparse.ArgumentParser, name: str, default: bool, help: 
                         help=help)
 
 
+def _fused_bn_off(value: str) -> str:
+    if value != "off":
+        raise argparse.ArgumentTypeError(
+            f"'{value}': the fused BN kernel left in PR 32 and `off` is the "
+            f"flag's one value (PERF.md, section 7)")
+    return value
+
+
 def build_parser() -> argparse.ArgumentParser:
     """The reference CLI surface (distributed_syncBN_amp.py:42-75), cleaned up."""
     d = Config()
@@ -502,16 +499,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "never selected where it loses; off-TPU auto = XLA "
                         "attention); on forces the kernel (A/B work), off "
                         "forces XLA attention. See docs/ATTENTION.md")
-    p.add_argument("--fused-bn", default=d.fused_bn, dest="fused_bn",
-                   choices=("auto", "on", "off"),
-                   help="Pallas fused BN+ReLU / BN+add+ReLU epilogue kernels "
-                        "for the conv families: auto = measurement-honest "
-                        "dispatch (on-device pallas-vs-XLA micro-benchmark "
-                        "per epilogue workload, verdict cached per device "
-                        "kind — the kernel is never selected where it loses; "
-                        "off-TPU auto = XLA); on forces the kernels (A/B "
-                        "work), off forces the XLA epilogue. SyncBN and "
-                        "eval mode always run XLA. See docs/KERNELS.md")
+    # the chip benchmark's configurations still write `--fused-bn off`
+    # (benchmarks/chip/configs/*.json, which only a `benchmark` PR edits):
+    # parsed and dropped until they stop (ROADMAP.md, "pins that are defaults")
+    p.add_argument("--fused-bn", type=_fused_bn_off, default=argparse.SUPPRESS,
+                   help=argparse.SUPPRESS)
     _bool_flag(p, "device_prefetch", d.device_prefetch,
                "double-buffered device prefetch: issue the next batch's "
                "host-to-device copy while the current step computes "

@@ -28,8 +28,6 @@ class DenseLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool) -> jax.Array:
-        # BN+ReLU epilogues ride the fused-dispatch path (layers.BatchNorm
-        # act kwarg; the XLA fallback is bit-identical to bn → relu).
         y = self.norm(use_running_average=not train, dtype=self.dtype,
                       name="norm1")(x, act="relu")
         y = conv_kaiming(self.bn_size * self.growth_rate, 1, 1, self.dtype,

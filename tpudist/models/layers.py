@@ -33,20 +33,10 @@ class BatchNorm(nn.Module):
     """torch.nn.BatchNorm2d-semantics batch normalization over NHWC inputs,
     with optional cross-replica statistics (SyncBN) via ``axis_name``.
 
-    The call sites may ask for a FUSED epilogue: ``act="relu"`` (and
-    optionally ``residual=...`` for the pre-activation add of residual
-    blocks) folds the normalize → affine → (add) → relu chain into a single
-    Pallas pass (``tpudist/ops/pallas/fused_norm``), gated by the
-    measurement-honest dispatch layer (``tpudist/ops/norm_dispatch``): the
-    kernel runs only where a cached on-device measurement says it wins.
-    Structural fallbacks take the XLA path explicitly, regardless of mode:
-
-    - **SyncBN** (``axis_name`` set): the stat pmean has no fused kernel;
-    - **eval mode** (running stats): inference epilogues are XLA's.
-
-    With ``act``/``residual`` unset this module is byte-identical to its
-    pre-fusion self, and the XLA fallback reproduces the historical call
-    sites' op order exactly (f32 normalize → cast → add → relu)."""
+    The call sites may hand over their epilogue: ``act="relu"`` (and
+    ``residual=...`` for the add that closes a residual block) runs
+    normalise -> affine -> cast -> (add) -> relu here, in the operation
+    order the call sites always had (float32 normalise, then the cast)."""
 
     momentum: float = 0.1            # torch convention: weight of the NEW stat
     epsilon: float = 1e-5
@@ -60,11 +50,11 @@ class BatchNorm(nn.Module):
                  act: Optional[str] = None,
                  residual: Optional[jax.Array] = None) -> jax.Array:
         if act not in (None, "relu"):
-            raise ValueError(f"BatchNorm fused act must be None or 'relu', "
+            raise ValueError(f"BatchNorm act must be None or 'relu', "
                              f"got {act!r}")
         if residual is not None and act is None:
-            raise ValueError("BatchNorm residual fusion requires act='relu' "
-                             "(the kernels implement BN+add+ReLU)")
+            raise ValueError("BatchNorm residual= requires act='relu' "
+                             "(the epilogue is BN + add + ReLU)")
         if use_running_average is None:
             use_running_average = self.use_running_average
         use_ra = bool(use_running_average) if use_running_average is not None else False
@@ -102,39 +92,9 @@ class BatchNorm(nn.Module):
                 ra_var.value = (1 - m) * ra_var.value + m * unbiased
 
         out_dt = self.dtype or x.dtype
-        if act == "relu" and self.axis_name is None and not use_ra:
-            # The fused-epilogue question — asked only where the statistics
-            # path has no structural objection (plain BN, train mode). The
-            # stats above are computed OUTSIDE the kernel either way, so the
-            # running-average update (and its gradient paths) are identical
-            # on both branches. The workload is the SHARD-LOCAL one: under
-            # a GSPMD (global-shape) trace, shard_local_workload divides by
-            # the ambient mesh's data/model axes — the same cut the
-            # shard_map wrapper below applies — so the honesty layer keys,
-            # measures, and dispatches the block a device actually runs.
-            from tpudist.ops import norm_dispatch
-            rows, local_feats, sharded = \
-                norm_dispatch.shard_local_workload(x.shape)
-            if norm_dispatch.use_fused(rows, local_feats, out_dt,
-                                       residual=residual is not None):
-                if sharded:
-                    from tpudist.ops.pallas.fused_norm import \
-                        fused_bn_act_spmd
-                    return fused_bn_act_spmd(x, scale, bias, mean, var,
-                                             eps=self.epsilon,
-                                             residual=residual,
-                                             out_dtype=out_dt)
-                from tpudist.ops.pallas.fused_norm import fused_bn_act
-                return fused_bn_act(x, scale, bias, mean, var,
-                                    eps=self.epsilon, residual=residual,
-                                    out_dtype=out_dt)
-
         y = (x.astype(jnp.float32) - mean) * jax.lax.rsqrt(var + self.epsilon)
         y = y * scale + bias
         y = y.astype(out_dt)
-        # XLA epilogue: the EXACT op order the unfused call sites ran
-        # (cast → add → relu), so passing act/residual is a pure refactor
-        # on this branch — bit-identical programs, goldens untouched.
         if residual is not None:
             y = y + residual
         if act == "relu":
@@ -188,8 +148,6 @@ class BasicConv2d(nn.Module):
                     padding=[(p[0],) * 2, (p[1],) * 2], use_bias=False,
                     kernel_init=nn.initializers.truncated_normal(self.stddev),
                     dtype=self.dtype, name="conv")(x)
-        # Fused BN+ReLU epilogue where the dispatch layer says it wins
-        # (norm_dispatch; XLA path is bit-identical to the old bn → relu).
         return norm(use_running_average=not train, epsilon=1e-3,
                     dtype=self.dtype, name="bn")(x, act="relu")
 

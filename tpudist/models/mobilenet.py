@@ -57,10 +57,8 @@ class ConvBNAct(nn.Module):
         x = conv_kaiming(self.features, self.kernel, self.strides, self.dtype,
                          "conv", groups=self.groups)(x)
         if self.act is nn.relu:
-            # The one activation the fused BN epilogue implements: BN+ReLU
-            # in a single Pallas pass where the dispatch layer says it wins
-            # (regnet and the V3 relu blocks; relu6/hardswish stay on the
-            # XLA path — the kernel doesn't implement them).
+            # the one activation BatchNorm's epilogue takes (regnet and the
+            # V3 relu blocks)
             return self.norm(use_running_average=not train, dtype=self.dtype,
                              name="bn")(x, act="relu")
         x = self.norm(use_running_average=not train, dtype=self.dtype,

@@ -83,10 +83,6 @@ class BasicBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool):
-        # BN epilogues ride the fused-dispatch path (layers.BatchNorm
-        # act/residual kwargs): BN+ReLU after conv1, BN+add+ReLU closing the
-        # block. The XLA fallback is bit-identical to the historical
-        # bn → (add) → relu chain.
         residual = x
         y = conv_kaiming(self.features, 3, self.strides, self.dtype, "conv1")(x)
         y = self.norm(use_running_average=not train, dtype=self.dtype,

@@ -57,8 +57,6 @@ class VGG(nn.Module):
                              use_bias=True)(x)
             idx += 1
             if self.batch_norm:
-                # BN+ReLU fused where the dispatch layer says it wins
-                # (layers.BatchNorm act kwarg; XLA fallback bit-identical).
                 x = norm(use_running_average=not train, dtype=self.dtype,
                          name=f"features_{idx}")(x, act="relu")
                 idx += 1

@@ -71,7 +71,7 @@ SPAN_LOOP_HOST = "tpudist.loop_host"    # hooks before the step, meters after
 INIT_MESH = "init.mesh"
 INIT_MODEL_STATE = "init.model_state"   # create_train_state: eager init
 INIT_SHARD_STATE = "init.shard_state"
-INIT_DISPATCH = "init.dispatch"         # --flash/--fused-bn/--compress-grads
+INIT_DISPATCH = "init.dispatch"         # --flash/--compress-grads
 INIT_STEP_BUILD = "init.step_build"
 INIT_RESTORE = "init.restore"           # only when resuming
 INIT_OTHER = "init.other"

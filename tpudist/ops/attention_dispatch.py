@@ -6,8 +6,8 @@ XLA attention in training while ``--flash auto`` still selected it on TPU —
 default ViT training was slower than if the kernel didn't exist. The root failure wasn't the kernel; it was *auto deciding
 without a measurement*. PR 5 made the decision empirical; PR 6 hoisted the
 machinery (cache, timing harness, never-pick-a-loser invariant, multi-host
-shared verdict) into ``ops/dispatch`` so the fused-norm kernels
-(``ops/norm_dispatch``) ride the SAME policy instead of a drifting copy.
+shared verdict) into ``ops/dispatch`` so a second client
+(``ops/comm_dispatch``) rides the SAME policy instead of a drifting copy.
 
 What stays attention-specific here — and ONLY this:
 

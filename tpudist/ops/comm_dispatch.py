@@ -1,6 +1,6 @@
 """Measurement-honest gradient-compression dispatch (``--compress-grads``)
-— the third client of the generic dispatch layer (``tpudist/ops/dispatch``),
-beside attention and fused-norm.
+— the second client of the generic dispatch layer (``tpudist/ops/dispatch``),
+beside attention.
 
 The candidate here is not a Pallas kernel but a COLLECTIVE ALGORITHM
 (``parallel/comm.py``: int8 two-phase all-reduce with error feedback), so

@@ -1,16 +1,15 @@
 """Measurement-honest kernel dispatch — the GENERIC layer.
 
 PR 5 built this machinery for one client (``--flash auto``,
-``ops/attention_dispatch``); PR 6 needed the identical policy for the fused
-BN-epilogue kernels, and duplicating the cache/timing/shared-verdict logic
-would have let the two honesty policies drift. So the policy lives HERE,
-once, and each kernel family registers as a *client*:
+``ops/attention_dispatch``); a second needs the identical policy, and
+duplicating the cache/timing/shared-verdict logic would let the two honesty
+policies drift. So the policy lives HERE, once, and each family registers
+as a *client*:
 
 - **attention** (``ops/attention_dispatch``): Pallas flash attention vs XLA
   attention, keyed by the exact attention workload;
-- **fused_norm** (``ops/norm_dispatch``): Pallas fused BN+ReLU /
-  BN+add+ReLU epilogue vs the XLA epilogue, keyed by (rows, channels,
-  dtype, variant).
+- **comm** (``ops/comm_dispatch``): the int8 gradient exchange vs the dense
+  pmean, keyed by the gradient's element count and the data axis's size.
 
 One timing harness, one cache format, one honesty policy:
 
@@ -30,7 +29,7 @@ One timing harness, one cache format, one honesty policy:
   measures): no cache entry on TPU → baseline — an unmeasured custom
   kernel is never the default.
 - ``shared_decision()`` gives a multi-host gang ONE verdict (the primary
-  publishes into the shared run dir; peers adopt a fresh, matching file or
+  publishes into the shared run dir; peers read a fresh, matching file or
   fail over identically).
 
 The micro-benchmark is injectable (``measure_pair``) so every honesty
@@ -87,41 +86,6 @@ def load_cache(path: str) -> dict:
     return {"version": CACHE_VERSION, "entries": {}}
 
 
-_read_memo: dict = {}
-
-# (path, key) -> entry, populated ONLY when a measured verdict could not be
-# persisted (read-only cache dir): the decision a run just reported must
-# still bind its own trace-time lookup()s, or the dispatch line would name
-# a kernel that never compiled. In-process only — the next run re-measures.
-_local_entries: dict = {}
-
-
-def seed_local(path: str, key: str, entry: dict) -> None:
-    """Fallback persistence for one verdict when the cache file cannot be
-    written — consulted by ``lookup()`` after the file."""
-    _local_entries[(path, key)] = entry
-
-
-def _load_cache_cached(path: str) -> dict:
-    """Read-only ``load_cache`` memoized on (mtime_ns, size): ``lookup()``
-    runs once per kernel call site per trace — ~50+ BN epilogues for a deep
-    convnet — and must not re-open and re-parse the same JSON each time. A
-    ``save_cache`` (os.replace) or ``clear_cache`` changes the stat key, so
-    writers invalidate readers for free. Callers must not mutate the
-    returned dict."""
-    try:
-        st = os.stat(path)
-        key = (st.st_mtime_ns, st.st_size)
-    except OSError:
-        return {"version": CACHE_VERSION, "entries": {}}
-    hit = _read_memo.get(path)
-    if hit is not None and hit[0] == key:
-        return hit[1]
-    obj = load_cache(path)
-    _read_memo[path] = (key, obj)
-    return obj
-
-
 def save_cache(path: str, cache: dict) -> None:
     """Atomic write (tmp + rename): a preempted rank mid-save must not leave
     a torn JSON that poisons every later run's load."""
@@ -148,8 +112,6 @@ def clear_cache(client: str, device_kind: Optional[str] = None,
         except OSError:
             paths = []
     for p in paths:
-        for k in [k for k in _local_entries if k[0] == p]:
-            del _local_entries[k]
         try:
             os.remove(p)
             removed += 1
@@ -161,8 +123,8 @@ def clear_cache(client: str, device_kind: Optional[str] = None,
 def measure_ms(fn, args, steps: int = 10, warmup: int = 2) -> float:
     """THE on-device timing harness (mean ms/call over ``steps`` after
     ``warmup``), shared by every dispatch client AND the kernel benchmarks
-    (``benchmarks/bench_flash.py``/``bench_fused_norm.py``) so verdicts and
-    bench rows cannot drift in methodology. Completion is forced via
+    (``benchmarks/bench_flash.py``, ``bench_comm.py``) so verdicts and bench
+    rows cannot drift in methodology. Completion is forced via
     ``device_get`` of a value depending on the full computation."""
     import jax
     out = None
@@ -273,10 +235,8 @@ def decide(client: str, key: str, *, mode: str,
         save_cache(path, cache)
     except OSError:
         # A read-only cache dir degrades to re-measuring next run, but the
-        # decision itself stands — seed the in-process overlay so this
-        # run's trace-time lookup()s agree with the verdict just reported.
+        # decision itself stands: the caller builds its program from it.
         out["cache_path"] = None
-        seed_local(path, key, cache["entries"][key])
     return out
 
 
@@ -299,15 +259,14 @@ def lookup(client: str, key: str, *, candidate: str,
         import jax
         device_kind = jax.devices()[0].device_kind
     path = cache_path(client, device_kind, cache_dir)
-    entry = (_load_cache_cached(path)["entries"].get(key)
-             or _local_entries.get((path, key)))
+    entry = load_cache(path)["entries"].get(key)
     return bool(entry and entry.get("kernel_rev") == kernel_rev()
                 and entry.get("kernel") == candidate)
 
 
 def shared_decision(outpath: str, primary: bool, decide_fn,
                     *, filename: str,
-                    kernel_rev: Optional[Callable[[], int]] = None,
+                    kernel_rev: Callable[[], int],
                     expect_key: Optional[str] = None,
                     timeout_s: float = 300.0, poll_s: float = 0.25,
                     log=None, what: str = "dispatch") -> dict:
@@ -369,7 +328,7 @@ def shared_decision(outpath: str, primary: bool, decide_fn,
         fresh = (isinstance(dec, dict)
                  and dec.get("attempt") == attempt
                  and (expect_key is None or dec.get("key") == expect_key)
-                 and ("kernel_rev" not in dec or kernel_rev is None
+                 and ("kernel_rev" not in dec
                       or dec["kernel_rev"] == kernel_rev()))
         if fresh:
             if dec.get("failed"):

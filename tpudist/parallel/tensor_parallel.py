@@ -94,11 +94,9 @@ SWIN_RULES: Rules = (
 # cuts its OUTPUT-channel dim over ``model``, so each shard computes 1/tp of
 # the output channels (conv FLOPs and params shard; the partitioner inserts
 # the channel all-gather where a consumer needs full input channels), and
-# every BN/bias per-channel vector cuts on the same channel dim — which is
-# exactly the layout the shard_map-wrapped fused-BN epilogue
-# (``ops/pallas/fused_norm.fused_bn_act_spmd``) binds, so the Pallas kernel
-# meets no reshard on either side. BN *statistics* are computed in the
-# global trace (models/layers.py): the batch mean over a data-sharded
+# every BN/bias per-channel vector cuts on the same channel dim. BN
+# *statistics* are computed in the global trace (models/layers.py): the
+# batch mean over a data-sharded
 # activation IS SyncBN (partitioner-reduced over ``data``), and the
 # per-channel stat vectors shard over ``model`` with their params. Heads
 # (fc/classifier) that contract into a small class dim stay replicated,

@@ -352,14 +352,11 @@ def analyze(events: list[dict],
             break
     out["xla"] = xla
 
-    # -- kernel dispatch (the two ops/dispatch clients): which kernels
-    # --flash and --fused-bn resolved to, on what evidence — the newest
-    # decision of each wins ------------------------------------------------
+    # -- kernel dispatch (the two ops/dispatch clients): what --flash and
+    # --compress-grads resolved to, on what evidence — the newest decision
+    # of each wins ---------------------------------------------------------
     out["attention_dispatch"] = next(
         (e for e in reversed(events) if e["type"] == "attention_dispatch"),
-        None)
-    out["fused_norm_dispatch"] = next(
-        (e for e in reversed(events) if e["type"] == "fused_norm_dispatch"),
         None)
     out["comm_dispatch"] = next(
         (e for e in reversed(events) if e["type"] == "comm_dispatch"), None)
@@ -522,22 +519,6 @@ def format_report(a: dict, rundir: str = "") -> str:
                 line += f", margin {ad['margin']:.1%}"
         if ad.get("shape_key"):
             line += f"; shape {ad['shape_key']}"
-        L.append(line + ")")
-    # fused-norm dispatch (which epilogue --fused-bn resolved to)
-    fn = a.get("fused_norm_dispatch")
-    if fn:
-        prov = fn["source"]
-        if prov == "cache":
-            prov = "cache hit"
-        elif prov == "measured":
-            prov = "measured now, cached"
-        line = (f"  fused-norm dispatch: {fn['kernel']} epilogue "
-                f"(mode {fn['mode']}, {prov}")
-        if isinstance(fn.get("n_sites"), (int, float)) and fn["n_sites"]:
-            line += (f"; {int(fn.get('n_fused', 0))}/{int(fn['n_sites'])} "
-                     f"BN workloads fused")
-        if fn.get("reason"):
-            line += f"; {fn['reason']}"
         L.append(line + ")")
     # comm dispatch (which gradient wire format --compress-grads resolved to)
     cd = a.get("comm_dispatch")

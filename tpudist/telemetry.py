@@ -138,11 +138,6 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     # cache / measured). Emitted once per Trainer construction for vit*
     # archs so summarize and the regression gate cover kernel choice.
     "attention_dispatch": ("kernel", "mode", "source"),
-    # Fused BN-epilogue resolution (tpudist/ops/norm_dispatch): which
-    # epilogue --fused-bn resolved to across the model's BN sites
-    # ("pallas" | "xla" | "mixed"), on what evidence, with n_sites/n_fused
-    # counts. Emitted once per Trainer construction.
-    "fused_norm_dispatch": ("kernel", "mode", "source"),
     # Gradient-compression resolution (tpudist/ops/comm_dispatch): which
     # wire format --compress-grads resolved to ("int8" | "dense"), on what
     # evidence, with the dense-equivalent gradient payload bytes summarize
@@ -217,8 +212,8 @@ _NUMERIC = {"t", "rank", "attempt", "step", "epoch", "seconds", "code",
             "straggler_rank", "factor", "wall_s", "productive_s", "goodput",
             "from_world", "to_world", "zero1_recut", "zero1_fallback",
             "consumed", "flash_ms", "xla_ms", "margin", "cache_hit",
-            "pallas_ms", "n_sites", "n_fused", "int8_ms", "dense_ms",
-            "dense_bytes", "world", "n_grads", "windows", "suspect_rank",
+            "int8_ms", "dense_ms", "dense_bytes", "world", "n_grads",
+            "windows", "suspect_rank",
             "deadline_s", "n_buckets", "bucket", "n_valid", "queue_depth",
             "n_requests", "n_images", "image_size", "gnorm", "loss", "mean",
             "std", "sigmas", "divergent", "tie", "divergent_rank",

@@ -547,18 +547,9 @@ class Trainer:
             self.log(f"=> using pre-trained model '{cfg.arch}' (from {p})")
         else:
             self.log(f"=> creating model '{cfg.arch}'")
-        # Measurement-honest fused BN-epilogue dispatch (ops/norm_dispatch,
-        # the second client of the generic ops/dispatch honesty layer):
-        # resolve --fused-bn OUTSIDE any trace, BEFORE the step builders
-        # trace the model — `auto` records every BN epilogue workload the
-        # model will run (an abstract eval_shape, no compute) and
-        # micro-benchmarks each on the attached chip exactly once per
-        # device kind; the traced step's trace-safe lookups then hit the
-        # cache. Off-TPU auto resolves to XLA without touching Pallas.
         mark(scopes.INIT_OTHER)
-        self.fused_norm_decision = self._resolve_fused_norm_dispatch()
         # Measurement-honest gradient-compression dispatch
-        # (ops/comm_dispatch, the third client of the generic honesty
+        # (ops/comm_dispatch, the second client of the generic honesty
         # layer): resolve --compress-grads OUTSIDE any trace, BEFORE the
         # step builders — `auto` A/Bs the quantized exchange against the
         # dense pmean at the exact gradient size over the real mesh
@@ -893,159 +884,6 @@ class Trainer:
         dec["reason"] = "; ".join(filter(None, [dec.get("reason"),
                                                 dec["key"]]))
         return self._announce_flash_decision(dec)
-
-    def _resolve_fused_norm_dispatch(self) -> dict:
-        """Resolve ``--fused-bn`` for every BN epilogue workload this model
-        will trace (host-side, before any step is built). Under `auto` on
-        TPU the model's requested (rows, channels, dtype, variant) set is
-        recorded via an abstract ``eval_shape`` and each workload is
-        decided through the shared honesty layer (never-pick-a-loser,
-        cached per device_kind, multi-host single-verdict with peers
-        adopting the primary's set into their local cache). The aggregate
-        decision is logged and emitted as a ``fused_norm_dispatch``
-        telemetry event. A probe that raises (kernel refused by the
-        compiler, runtime fault) propagates: only a measured loss or a
-        static ineligibility selects the XLA epilogue."""
-        from tpudist.ops import norm_dispatch
-        cfg = self.cfg
-        norm_dispatch.set_mode(cfg.fused_bn)
-        agg = {"kernel": "xla", "mode": cfg.fused_bn, "source": "platform",
-               "n_sites": 0, "n_fused": 0}
-        if cfg.fused_bn == "off":
-            agg.update(source="forced")
-        elif (self.uses_seq_axis or self.uses_pipe_axis
-              or self.uses_expert_axis):
-            # Structural, and it outranks even a forced `on`: the seq/pipe/
-            # expert specialty paths are ViT-family (LayerNorm) models with
-            # no fused-eligible BN site, and the wrapped epilogue is not
-            # plumbed through their manual regions. (The GSPMD stand-down
-            # is GONE — ISSUE 12: the shard_map-wrapped kernel
-            # fused_bn_act_spmd composes with the partitioned trace, and
-            # the dispatch key is the shard-local workload, so `auto`
-            # keeps its never-pick-a-loser guarantee under sharding.)
-            norm_dispatch.set_mode("off")
-            if cfg.fused_bn == "on":
-                self.log("=> --fused-bn on overridden on the seq/pipe/"
-                         "expert paths — XLA epilogue")
-            agg.update(source="ineligible",
-                       reason="fused-norm covers the DP/GSPMD paths; the "
-                              "seq/pipe/expert specialty paths run the "
-                              "XLA epilogue")
-        elif cfg.evaluate:
-            # Eval-only runs normalize with running stats — the structural
-            # XLA fallback every call site enforces, so even a forced `on`
-            # must REPORT xla here: the dispatch line is this PR's honesty
-            # surface and it must name the kernel that actually executed.
-            agg.update(source="ineligible",
-                       reason="eval mode runs the XLA epilogue")
-        elif cfg.sync_batchnorm and not self.uses_gspmd_path:
-            # Every BN site is SyncBN — the structural fallback the call
-            # site enforces (even under forced `on`); probing would just
-            # trace unbound pmeans. Under GSPMD the flag is structurally
-            # satisfied instead (global-batch statistics ARE SyncBN, the
-            # BN call sites are plain), so the fused question proceeds.
-            agg.update(source="ineligible",
-                       reason="SyncBN's statistics pmean has no fused "
-                              "kernel; XLA epilogue")
-        elif cfg.fused_bn == "on":
-            # Forced `on` must still report what the trace RUNS: a model
-            # with no fused-eligible BN epilogue (vit*, layernorm families)
-            # executes pure XLA no matter the flag, and the dispatch line
-            # is this PR's honesty surface.
-            reqs = self._record_fused_norm_requests(norm_dispatch)
-            if not reqs:
-                agg.update(source="no_sites",
-                           reason="no fused-eligible BN epilogue in this "
-                                  "model")
-            else:
-                agg.update(kernel="pallas", source="forced",
-                           n_sites=len(reqs), n_fused=len(reqs))
-        elif jax.default_backend() != "tpu":
-            pass  # platform: auto off-TPU IS the XLA path, no Pallas import
-        else:
-            agg = self._probe_fused_norm(norm_dispatch, agg)
-        msg = (f"=> fused-norm dispatch: {agg['kernel']} epilogue "
-               f"(mode {agg['mode']}, {agg['source']}")
-        if agg.get("n_sites"):
-            msg += f"; {agg['n_fused']}/{agg['n_sites']} BN workloads fused"
-        if agg.get("reason"):
-            msg += f": {agg['reason']}"
-        self.log(msg + ")")
-        if self.telemetry is not None:
-            self.telemetry.emit("fused_norm_dispatch",
-                                **norm_dispatch.event_fields(agg))
-        return agg
-
-    def _record_fused_norm_requests(self, norm_dispatch):
-        """Record the (rows, channels, dtype, variant) set the model's BN
-        epilogues will ask for, via an abstract ``eval_shape`` — no device
-        work."""
-        cfg = self.cfg
-        variables = {"params": self.state.params,
-                     "batch_stats": self.state.batch_stats}
-        # The workload key must be the shape the traced step ACTUALLY
-        # applies the model at: under gradient accumulation the scan
-        # slices the per-device batch into accum microbatches
-        # (parallel/_common.py::accum_scan), so probing the full batch
-        # would measure (and cache) rows no trace-time lookup ever asks
-        # for — every site would silently run XLA while the dispatch
-        # event claimed fused. Under GSPMD the trace applies the model
-        # at the GLOBAL microbatch, and the recording runs under the
-        # step builders' ambient mesh (set_mesh) so BatchNorm's
-        # shard_local_workload divides exactly as the traced step will
-        # — the recorded keys ARE the per-shard workloads.
-        accum = max(1, int(getattr(cfg, "accum_steps", 1) or 1))
-        batch = (cfg.batch_size if self.uses_gspmd_path
-                 else cfg.per_device_batch_size)
-        mb = max(1, batch // accum)
-        dummy = jax.ShapeDtypeStruct(
-            (mb, cfg.image_size, cfg.image_size, 3), jax.numpy.float32)
-
-        def _fwd(v, im):
-            return self.model.apply(
-                v, im, train=True,
-                mutable=["batch_stats", "intermediates"],
-                rngs={"dropout": jax.random.PRNGKey(0)})
-
-        import contextlib
-        ctx = (jax.sharding.set_mesh(self.mesh)
-               if self.uses_gspmd_path else contextlib.nullcontext())
-        with ctx:
-            with norm_dispatch.record_requests() as reqs:
-                jax.eval_shape(_fwd, variables, dummy)
-        return reqs
-
-    def _probe_fused_norm(self, norm_dispatch, agg: dict) -> dict:
-        """The on-TPU `auto` probe: record the model's BN epilogue
-        workloads abstractly, then decide each through the honesty layer
-        (one gang-wide verdict set on multi-host runs)."""
-        cfg = self.cfg
-        reqs = self._record_fused_norm_requests(norm_dispatch)
-        if not reqs:
-            return dict(agg, source="no_sites",
-                        reason="no fused-eligible BN epilogue in this model")
-
-        def _decide_all():
-            decisions = {}
-            for rows, channels, key, residual, dt in sorted(
-                    reqs, key=lambda r: r[2]):
-                decisions[key] = norm_dispatch.decide(
-                    rows, channels, dt, residual=residual, mode="auto")
-            out = norm_dispatch.aggregate(decisions, "auto")
-            out["key"] = norm_dispatch.combined_key(reqs)
-            return out
-
-        if jax.process_count() > 1:
-            # One verdict set for the gang: a near-tie workload must not
-            # compile different epilogue backends into one SPMD program.
-            # The primary decides and publishes; peers adopt the set into
-            # their local cache so their trace-time lookups agree.
-            return norm_dispatch.shared_decide_all(
-                cfg.outpath, self.primary, _decide_all,
-                expect_key=norm_dispatch.combined_key(reqs),
-                log=self.log,
-                device_kind=jax.devices()[0].device_kind)
-        return _decide_all()
 
     def _resolve_comm_dispatch(self) -> dict:
         """Resolve ``--compress-grads`` through ``ops/comm_dispatch``
