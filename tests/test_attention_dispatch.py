@@ -584,17 +584,17 @@ def test_probe_times_the_branches_the_model_calls(monkeypatch):
     assert jnp.issubdtype(flash_f(qkv).dtype, jnp.floating)
 
 
-@pytest.mark.parametrize("stale_rev", [3, 2, 1])
+@pytest.mark.parametrize("stale_rev", [4, 3, 2, 1])
 def test_verdict_of_an_older_kernel_rev_is_not_used(tmp_path, stale_rev):
-    """KERNEL_REV is 4 (a window, grouped heads and banded grids on the
-    streaming schedule): a rev-3 verdict in the cache, win or loss, is
+    """KERNEL_REV is 5 (a streaming program holds a key-value head's whole
+    group of query heads): a rev-4 verdict in the cache, win or loss, is
     stale — ``decide`` measures again and the trace-time ``lookup`` does
     not dispatch on it."""
-    assert ad.kernel_rev() == 4
+    assert ad.kernel_rev() == 5
     cache = str(tmp_path)
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
                   measure_pair=_pair(1.0, 2.0), **TPU)
-    assert d["kernel"] == "flash" and d["kernel_rev"] == 4
+    assert d["kernel"] == "flash" and d["kernel_rev"] == 5
     assert ad.lookup(*SHAPE, cache_dir=cache, **TPU) is True
     path = ad.cache_path(TPU["device_kind"], cache)
     obj = json.load(open(path))
@@ -605,7 +605,7 @@ def test_verdict_of_an_older_kernel_rev_is_not_used(tmp_path, stale_rev):
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
                   measure_pair=_pair(16.32, 4.55), **TPU)
     assert d["source"] == "measured" and d["kernel"] == "xla" \
-        and d["kernel_rev"] == 4
+        and d["kernel_rev"] == 5
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache, measure_pair=_boom,
                   **TPU)
     assert d["source"] == "cache" and d["kernel"] == "xla"
@@ -628,6 +628,33 @@ def test_dispatch_names_the_schedule():
                     **fields})
     dec.pop("schedule")
     assert "schedule" not in ad.event_fields(dec)
+
+
+def test_dispatch_says_how_far_the_kernel_engages():
+    """What the decision says of each attention workload's programs
+    (``ad.program``) rides the event as one list a field, in the shape
+    keys' order: ViT-B/16's one whole-sequence program of twelve heads, and
+    a decoder's windowed and full layers on the streaming schedule, a
+    key-value head's eight query heads a program."""
+    from tpudist.telemetry import validate_event
+    vit = ad.program(197, 12, 64, "bfloat16", fused=True)
+    assert vit == {"schedule": "whole_seq", "heads_per_program": 12,
+                   "block_q": 197, "block_k": 197, "band_fill": 1.0}
+    layers = [ad.program(8192, 32, 128, "bfloat16", kv_heads=4, causal=True,
+                         window=w) for w in (1024, None)]
+    dec = {"kernel": "flash", "mode": "on", "source": "forced", "key": "k",
+           "schedule": "streaming", "programs": layers}
+    fields = ad.event_fields(dec)
+    validate_event({"type": "attention_dispatch", "t": 0.0, "rank": 0,
+                    "attempt": 0, **fields})
+    assert set(ad.PROGRAM_FIELDS) <= set(fields)
+    assert fields["heads_per_program"] == [8, 8]
+    assert fields["block_q"] == [p["block_q"] for p in layers]
+    windowed, full = fields["band_fill"]
+    assert 0.75 <= windowed <= 1.0 and 0.88 <= full <= 1.0
+    json.dumps(fields)
+    dec.pop("programs")
+    assert not set(ad.PROGRAM_FIELDS) & set(ad.event_fields(dec))
 
 
 def test_trainer_logs_the_schedule_when_the_kernel_runs(tmp_path):
@@ -655,7 +682,9 @@ def test_trainer_logs_the_schedule_when_the_kernel_runs(tmp_path):
         telemetry_lib.set_current(None)
     log = open(out / "experiment.log").read()
     assert "=> attention dispatch: flash attention (mode on, forced, " \
-        "schedule whole_seq" in log
+        "schedule whole_seq, heads_per_program 12 block_q 17 block_k 17 " \
+        "band_fill 1.0" in log
     disp = [json.loads(line) for line in open(out / "events.0.jsonl")
             if '"attention_dispatch"' in line]
     assert len(disp) == 1 and disp[0]["schedule"] == "whole_seq"
+    assert disp[0]["heads_per_program"] == [12] and disp[0]["block_q"] == [17]

@@ -369,20 +369,31 @@ def test_windowed_attention_sees_the_nearest_keys_only():
 
 def test_a_streaming_call_claims_the_blocks_it_runs():
     from tpudist.ops.pallas.flash_attention import _Band
-    full = _Band(causal=True, window=None, block_q=512, block_k=512,
-                 q_len=8192, k_len=8192, nq=16, nk=16)
-    banded = _Band(causal=True, window=1024, block_q=512, block_k=512,
-                   q_len=8192, k_len=8192, nq=16, nk=16)
-    assert full.pairs() == 16 * 17 // 2 and full.steps_k == 16
+    long = dict(causal=True, q_len=8192, k_len=8192)
+    full = _Band(window=None, block_q=512, block_k=512, **long)
+    banded = _Band(window=1024, block_q=512, block_k=512, **long)
+    assert full.pairs() == 16 * 17 // 2 and full.steps == 16
     # a q block of 512 rows under a window of 1,024 touches three k blocks
-    assert banded.steps_k == 4 and banded.steps_q == 4
-    assert banded.pairs() == 1 + 2 + 14 * 3
+    # (the grid counts the most any block touches), and a k block three q
+    # blocks in the dKV pass, where the queries stream
+    assert banded.steps == 3 and banded.pairs() == 1 + 2 + 14 * 3
+    streamed_q = _Band(window=1024, block_q=512, block_k=512, stream="q",
+                       **long)
+    assert streamed_q.steps == 3 and streamed_q.pairs() == 14 * 3 + 2 + 1
     # and one of 1,024 rows two of 1,024
-    wide = _Band(causal=True, window=1024, block_q=1024, block_k=1024,
-                 q_len=8192, k_len=8192, nq=8, nk=8)
-    assert wide.steps_k == 3 and wide.pairs() == 1 + 7 * 2
+    wide = _Band(window=1024, block_q=1024, block_k=1024, **long)
+    assert wide.steps == 2 and wide.pairs() == 1 + 7 * 2
+    # the steps slide with the band (PR 33): a q block of 256 rows sees
+    # 1,279 keys, one step of 1,280 and not the two aligned blocks of 1,024
+    # its band straddles
+    slid = _Band(window=1024, block_q=256, block_k=1280, **long)
+    assert slid.steps == 1 and slid.pairs() == 32
+    assert [slid.span(i)[0] for i in (0, 4, 5, 31)] == [0, 0, 256, 6912]
+    # a window that shares no edge with the granule needs no more steps
+    odd = _Band(window=1000, block_q=512, block_k=512, **long)
+    assert odd.steps == 3
     square = _Band(causal=False, window=None, block_q=128, block_k=128,
-                   q_len=200, k_len=200, nq=2, nk=2)
+                   q_len=200, k_len=200)
     assert square.pairs() == 4
 
 

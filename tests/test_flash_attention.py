@@ -410,3 +410,215 @@ def test_streaming_kernel_matches_windowed_grouped_attention(t, window,
     for a, b in zip(got_grads, want_grads):
         assert a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+# -- a program holds a key-value head's whole group of query heads (PR 33) ---
+
+@pytest.mark.parametrize("d", [128, 16], ids=["d128", "d16"])
+@pytest.mark.parametrize("t", [48, 37], ids=["tiles", "ragged"])
+@pytest.mark.parametrize("window", [8, 16, 24, None])
+@pytest.mark.parametrize("group", [1, 2, 8])
+def test_grouped_program_matches_attention(group, window, t, d):
+    """Interpret mode, forward and dQ / dK / dV against ``attention``, blocks
+    of 16 x 16: one, two and eight query heads a key-value head, a window
+    shorter than, equal to and longer than a block and none, a length that
+    is and is not a block multiple, a head size that is whole lane tiles
+    and one that is not."""
+    kv_heads = 2 if group < 8 else 1
+    ks = jax.random.split(jax.random.PRNGKey(group * t + d), 4)
+    q = jax.random.normal(ks[0], (1, t, group * kv_heads, d))
+    k = jax.random.normal(ks[1], (1, t, kv_heads, d))
+    v = jax.random.normal(ks[2], (1, t, kv_heads, d))
+    g = jax.random.normal(ks[3], q.shape)
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * g), argnums=(0, 1, 2))(
+                q, k, v)
+
+    got, got_grads = both(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=16, block_k=16))
+    want, want_grads = both(lambda q, k, v: attention(
+        q, k, v, causal=True, window=window))
+    assert abs(float(got) - float(want)) < 2e-3
+    for a, b in zip(got_grads, want_grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=3e-5)
+
+
+@pytest.mark.parametrize("kv_heads", [1, 3])
+def test_grouped_program_full_and_cross_lengths(kv_heads):
+    """No mask at all (non-causal, exact tiling), a ragged key length
+    (the last k block alone builds a mask) and more keys than queries under
+    a causal mask, six query heads over one and three key-value heads."""
+    ks = jax.random.split(jax.random.PRNGKey(kv_heads), 3)
+    for t, tk, causal in ((16, 32, False), (16, 25, False), (16, 40, True)):
+        q = jax.random.normal(ks[0], (1, t, 6, 128))
+        k = jax.random.normal(ks[1], (1, tk, kv_heads, 128))
+        v = jax.random.normal(ks[2], (1, tk, kv_heads, 128))
+
+        def loss(fn):
+            return jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v) ** 2), argnums=(0, 1, 2))(q, k, v)
+
+        got = loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=8, block_k=16))
+        want = loss(lambda q, k, v: attention(q, k, v, causal=causal))
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+@pytest.mark.parametrize("d", [128, 16], ids=["d128", "d16"])
+@pytest.mark.parametrize("t", [64, 61], ids=["tiles", "ragged"])
+@pytest.mark.parametrize("window", [8, 20, 33])
+@pytest.mark.parametrize("blocks", [(8, 24), (16, 40), (24, 8)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+def test_steps_slide_with_the_window(blocks, window, t, d):
+    """Blocks that are no multiple of one another, so that the streamed
+    side's steps start where the band does (rounded down to a granule of 8)
+    and not at a block multiple: forward, dQ (keys stream) and dK / dV
+    (queries stream) against ``attention``, two query heads a key-value
+    head."""
+    from tpudist.ops.pallas.flash_attention import _Band
+    bands = [_Band(causal=True, window=window, block_q=blocks[0],
+                   block_k=blocks[1], q_len=t, k_len=t, stream=side)
+             for side in "kq"]
+    assert min(band.granule for band in bands) == 8
+    ks = jax.random.split(jax.random.PRNGKey(window + t + d), 4)
+    q = jax.random.normal(ks[0], (1, t, 4, d))
+    k = jax.random.normal(ks[1], (1, t, 2, d))
+    v = jax.random.normal(ks[2], (1, t, 2, d))
+    g = jax.random.normal(ks[3], q.shape)
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * g), argnums=(0, 1, 2))(
+                q, k, v)
+
+    got, got_grads = both(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=blocks[0],
+        block_k=blocks[1]))
+    want, want_grads = both(lambda q, k, v: attention(
+        q, k, v, causal=True, window=window))
+    assert abs(float(got) - float(want)) < 2e-3
+    for a, b in zip(got_grads, want_grads):
+        np.testing.assert_allclose(a, b, atol=3e-5)
+
+
+_DECODER = dict(seq=8192, heads=32, head_dim=128, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("window,least", [(1024, 0.75), (None, 0.88)],
+                         ids=["windowed", "full"])
+def test_blocks_follow_the_band_at_the_decoder_shape(window, least):
+    """The block rule and ``band_fill`` as static numbers: eight query heads
+    a program, and the scores the forward programs run are at most 1.34 x
+    (windowed; 2.0 x with one head a program at blocks of 1,024) and 1.14 x
+    (full) the scores the mask allows."""
+    from tpudist.ops.pallas.flash_attention import (_Band, _default_blocks,
+                                                    program_plan)
+    plan = program_plan(**_DECODER, kv_heads=4, causal=True, window=window)
+    assert plan["schedule"] == "streaming"
+    assert plan["heads_per_program"] == 8
+    rule = _default_blocks(8192, 8192, window, 8)
+    assert (plan["block_q"], plan["block_k"]) == rule.fwd
+    assert least <= plan["band_fill"] <= 1.0
+    # the same count by hand: rows x the keys each may see
+    allowed = (8192 * 8193 // 2 if window is None
+               else window * 8192 - window * (window - 1) // 2)
+    for blocks, stream in zip(rule, "kkq"):
+        band = _Band(causal=True, window=window, block_q=blocks[0],
+                     block_k=blocks[1], q_len=8192, k_len=8192,
+                     stream=stream)
+        assert abs(band.fill() - allowed / (
+            band.pairs() * band.bq * band.bk)) < 1e-12
+        # every pass follows the band more closely than the parent's
+        # blocks of 1,024 did (0.5 windowed, 0.889 full)
+        assert band.fill() > (0.5 if window else 0.88)
+        # what the chip's memory tiling asks of an element offset
+        assert band.granule == 128
+        assert (band.tq_pad, band.tk_pad) == (8192, 8192)
+    # one head a program at blocks of 1,024: what the parent ran
+    one = program_plan(**_DECODER, causal=True, window=window)
+    assert (one["block_q"], one["block_k"]) == (1024, 1024)
+    assert one["band_fill"] == (0.5 if window else 0.889)
+
+
+@pytest.mark.parametrize("t,tk,window", [
+    (197, 197, None), (1024, 1024, None), (1025, 640, None),
+    (2048, 2048, None), (8192, 8192, 1024), (8192, 8192, None),
+    (300, 8192, 64)])
+def test_one_head_a_program_keeps_the_parents_blocks(t, tk, window):
+    """``G = 1``: 128 up to 1,024 positions and 1,024 beyond, each side by
+    its own length, whatever the window, in all three kernels: what was
+    measured."""
+    from tpudist.ops.pallas.flash_attention import _default_blocks
+    want = (128 if t <= 1024 else 1024, 128 if tk <= 1024 else 1024)
+    assert set(_default_blocks(t, tk, window, 1)) == {want}
+
+
+def _band(t, tk, causal, window, block_q, block_k, stream="k"):
+    from tpudist.ops.pallas.flash_attention import _Band
+    return _Band(causal=causal, window=window, block_q=block_q,
+                 block_k=block_k, q_len=t, k_len=tk, stream=stream)
+
+
+def test_a_pair_inside_the_band_builds_no_mask():
+    """``_Band.interior``: at blocks of 1,024 a full layer of 8,192 runs 36
+    tiles and 28 of them lie wholly under the diagonal; under a window
+    the far edge is an edge too; a ragged key length makes one of the last k
+    block; a call that needs no mask says so statically."""
+    def count(band):
+        tiles = band.tiles()
+        assert len(tiles) == band.pairs()
+        return len(tiles), sum(bool(band.interior(*at)) for at in tiles)
+
+    full = _band(8192, 8192, True, None, 1024, 1024)
+    assert count(full) == (36, 28) and full.masks
+    assert count(_band(8192, 8192, True, None, 1024, 1024, "q")) == (36, 28)
+    banded = _band(8192, 8192, True, 1024, 256, 256)
+    # five k blocks a q block: the diagonal's and the window's far one are
+    # edges, three between them are not
+    assert banded.steps == 5
+    assert count(banded) == (banded.pairs(), 3 * (32 - 4) + 3 + 2 + 1)
+    ragged = _band(64, 41, False, None, 16, 16)
+    assert ragged.masks and count(ragged) == (12, 8)
+    square = _band(64, 48, False, None, 16, 16)
+    assert not square.masks and count(square) == (12, 12)
+
+
+@pytest.mark.parametrize("stream", ["k", "q"])
+@pytest.mark.parametrize("t,tk,window,block_q,block_k", [
+    (64, 64, 24, 16, 8), (64, 64, 24, 8, 24), (61, 61, 20, 24, 8),
+    (64, 64, None, 16, 8), (40, 64, 24, 16, 16), (64, 40, 10, 8, 24),
+    (64, 64, 7, 16, 40)])
+def test_the_tiles_that_run_cover_the_band(t, tk, window, block_q, block_k,
+                                           stream):
+    """By brute force over positions, whichever side streams and wherever
+    its steps start: every allowed score lies in exactly one tile that
+    runs, every tile that runs lies inside the padded operands at a
+    multiple of the granule, and a tile is interior exactly where the mask
+    allows every score of it."""
+    band = _band(t, tk, True, window, block_q, block_k, stream)
+    rows, cols = np.arange(t)[:, None], np.arange(tk)[None, :]
+    ok = cols <= rows + (tk - t)
+    if window is not None:
+        ok &= rows + (tk - t) - cols < window
+    seen = np.zeros((band.tq_pad, band.tk_pad), int)
+    padded = np.zeros((band.tq_pad, band.tk_pad), bool)
+    padded[:t, :tk] = ok
+    assert band.steps >= 1
+    for i in range(band.n):
+        start, lo, hi = band.span(i)
+        assert hi - lo + 1 <= band.steps
+    for row0, col0 in band.tiles():
+        assert 0 <= row0 <= band.tq_pad - band.bq
+        assert 0 <= col0 <= band.tk_pad - band.bk
+        assert (col0 if stream == "k" else row0) % band.granule == 0
+        tile = (slice(row0, row0 + band.bq), slice(col0, col0 + band.bk))
+        seen[tile] += 1
+        rows_in = padded[tile][:max(0, min(band.bq, t - row0))]
+        assert bool(band.interior(row0, col0)) == bool(
+            rows_in.size and rows_in.all()
+            and col0 + band.bk <= tk), (row0, col0)
+    assert (seen[padded] == 1).all() and seen.max() <= 1

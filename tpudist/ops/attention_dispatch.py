@@ -38,6 +38,8 @@ from tpudist.ops import dispatch
 
 CLIENT = "attention_dispatch"
 NAMES = ("flash", "xla")
+# what the event says of each attention workload's programs (``program``)
+PROGRAM_FIELDS = ("heads_per_program", "block_q", "block_k", "band_fill")
 
 # Re-exported so existing callers (bench_flash's timing rows, tests, tools)
 # keep ONE surface; these ARE the generic layer's objects — no copies.
@@ -151,6 +153,16 @@ def schedule(seq: int, heads: int, head_dim: int, dtype) -> str:
     return schedule_for(seq, heads, head_dim, dtype)
 
 
+def program(seq: int, heads: int, head_dim: int, dtype, **shape) -> dict:
+    """How far the kernel engages at a static self-attention shape
+    (``flash_attention.program_plan``: its ``schedule``, the query heads a
+    program holds, its blocks, ``band_fill`` = scores the mask allows over
+    scores the programs run) — for the dispatch log line and telemetry
+    event. Imports Pallas: ask only where the kernel runs."""
+    from tpudist.ops.pallas.flash_attention import program_plan
+    return program_plan(seq, heads, head_dim, dtype, **shape)
+
+
 def decide(batch: int, seq: int, heads: int, head_dim: int, dtype,
            *, train: bool = True, causal: bool = False, mode: str = "auto",
            cache_dir: Optional[str] = None,
@@ -221,6 +233,10 @@ def event_fields(decision: dict) -> dict:
             out[f] = decision[f]
     if decision.get("schedule"):
         out["schedule"] = decision["schedule"]
+    if decision.get("programs"):
+        # one entry an attention workload, in the shape keys' order
+        for f in PROGRAM_FIELDS:
+            out[f] = [p[f] for p in decision["programs"]]
     if decision.get("cache_hit"):
         out["cache_hit"] = 1
     if decision.get("reason"):

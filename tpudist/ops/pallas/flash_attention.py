@@ -15,35 +15,49 @@ sets them side by side.
 Streaming forward (flash-attention-2 schedule mapped onto the TPU memory
 hierarchy):
 
-- grid = (batch, heads, q_blocks, k_blocks), k innermost and marked
+- grid = (batch, kv_heads, q_blocks, k_steps), k innermost and marked
   "arbitrary" (sequential) so the running-softmax state carried in VMEM
   scratch is valid across k steps; batch/head/q are "parallel".
-- Q stays resident in VMEM for all k steps of a q block; K/V blocks stream
-  HBM→VMEM via the BlockSpec pipeline (Pallas double-buffers automatically).
-- online softmax in fp32: running max ``m`` and normalizer ``l`` live in
-  (block_q, 128) VMEM scratch (lane-broadcast — TPU vregs are 8×128, a
-  (bq, 1) column would occupy a full vreg anyway), the unnormalized
-  accumulator ``acc`` in (block_q, head_dim) fp32 scratch.
+- a program belongs to one KEY-VALUE head and holds all ``G = H / Hkv``
+  query heads that read it (grouped-query attention; ``G = 1`` where every
+  query head has its own): the members are a static loop over one resident
+  K / V block, so K and V are fetched once a group, a layer makes ``G``
+  times fewer grid steps, and the rows that fill the MXU come from heads,
+  not from positions. A q block can then be short along the sequence and
+  a step wide in keys, and under a window the steps slide with the band
+  (``_Band``: block offsets are elements, ``pl.Element``, not block
+  multiples), so a q block walks the keys its rows may see and little
+  more (``_default_blocks`` chooses the blocks from the static shape).
+- operands lie as [B, Hkv, G, T, D] (``_operand``): a block is G tiles of
+  (rows, D), each whole rows of one head. (Read as [B, T, H*D], where the
+  projections wrote them, the kernels ran as fast alone, but the decoder's
+  step lost 44 ms to the layouts XLA then gave the norm and RoPE around
+  the calls: measured in PR 33 and taken out.)
+- Q stays resident in VMEM for all k steps of a q block (scaled by the
+  softmax temperature once, into scratch, at the block's first step: S =
+  (scale·Q)Kᵀ needs no per-tile VPU rescale); K/V blocks stream HBM→VMEM via
+  the BlockSpec pipeline (Pallas double-buffers automatically).
+- online softmax in fp32, a member each: the running max ``m`` and
+  normalizer ``l`` live in (block_q, 128) VMEM scratch, member ``j`` in lane
+  ``j``; the unnormalized accumulators in (G, block_q, head_dim) fp32.
 - the two matmuls (S = QKᵀ, O += P·V) hit the MXU in the input dtype
   (bf16 under the AMP policy) with fp32 accumulation; masking/exp/rescale
   fuse into the VPU between them.
-- the softmax temperature is folded into Q once on the way in (one XLA
-  elementwise pass) instead of rescaling every (bq, bk) score tile on the
-  VPU — S = (scale·Q)Kᵀ is already scaled.
 - masking is by GLOBAL position: causal (rows ≥ cols), a window (of those,
   the nearest ``window`` keys) and key-validity
   (cols < true key length, so sequence lengths that aren't block multiples —
   ViT's 197 tokens — are padded then exactly masked). The mask is built
-  ONLY under configurations that statically need one (causal, or a key
-  length that isn't a block multiple) — an exact-tiling non-causal call
-  (the 2k-token bench shape) runs a mask-free VPU path. The k dimension of
+  ONLY for block pairs that the band's edges cross (``_Band.interior``: the
+  diagonal, the window's far edge, the last block of a ragged key length),
+  once a step for all the members; a pair inside the band runs a mask-free
+  VPU path, and a call that statically needs no mask (exact tiling,
+  non-causal) holds no masked path at all. The k dimension of
   the grid counts steps inside a q block's band (``_Band``): with a window
   it is as long as the band is wide, not as the sequence; a step past the
   band's end (above the diagonal) is skipped with ``pl.when`` and fetches
-  nothing, its block index staying where it was.
-- grouped-query attention: with fewer key-value heads than query heads, a
-  query head's index map reads head ``h // group`` of K and V; nothing is
-  repeated in HBM.
+  nothing, its offset staying where it was.
+- the per-row logsumexp leaves as [B, Hkv, T, G] float32 (the members on the
+  minor dimension: the block's full dimension).
 
 Streaming backward (the two-pass schedule):
 
@@ -52,27 +66,30 @@ drowns: it must be two dedicated passes with the right grid parallelism,
 each recomputing probabilities from the forward's saved per-row logsumexp —
 never one recompute-everything loop and never an O(T²) tensor.
 
-- **dKV pass**: grid (batch, kv_heads, k_blocks, group, q_blocks), the
-  last two sequential — each program owns one (block_k, d) dK/dV tile in
-  fp32 VMEM scratch and streams past it the Q/dO blocks of every query head
-  that reads this key-value head (the group's sum is taken in VMEM). dK needs no epilogue scale:
-  contracting dS (unscaled) against the pre-scaled Q IS the scaled dK.
-- **dQ pass**: grid (batch, heads, q_blocks, k_blocks), k innermost
-  sequential — each program owns one (block_q, d) dQ tile and streams K/V
-  blocks; the temperature is applied once per tile in the epilogue.
-- both reuse the forward's saved logsumexp and the precomputed
-  ``delta = rowsum(dO ∘ O)`` (an XLA-fused elementwise+reduce outside the
-  kernels) instead of rematerializing the softmax normalization per tile,
-  so each pass is exactly two MXU matmuls of recompute (S and dP) plus its
-  two gradient matmuls.
-- accumulators are fp32 over bf16 MXU operands; block sizes default to
-  128×128 (a whole MXU tile per matmul, (8, 128)-aligned) and the backward
-  blocks are independently tunable (``block_q_bwd``/``block_k_bwd``) from
-  the forward's, since the dKV pass wants its resident tile on the KV dim
-  while the forward wants it on Q.
+- **dQ pass**: grid (batch, kv_heads, q_blocks, k_steps), k innermost
+  sequential — each program owns the group's (G, block_q, d) dQ tiles and
+  streams K/V blocks; the temperature is applied once per tile in the
+  epilogue. It also takes ``delta = rowsum(dO ∘ O)`` of its rows, once a q
+  block, from the dO and O blocks it holds, and writes it out beside dQ:
+  XLA makes no float32 copy of dO or O for it.
+- **dKV pass**: grid (batch, kv_heads, k_blocks, q_steps), the last
+  sequential — each program owns one (block_k, d) dK/dV tile in fp32 VMEM
+  scratch and streams past it the Q/dO rows of the whole group (the
+  group's sum is taken in VMEM; under a window the q steps slide with the
+  band as the forward's k steps do). The temperature rides on the resident
+  K (scaled once a program into scratch) and on dK's epilogue, so the q
+  blocks that stream past are used as they arrive.
+- both reuse the forward's saved logsumexp and the dQ pass's delta instead
+  of rematerializing the softmax normalization per tile, so each pass is
+  exactly two MXU matmuls of recompute (S and dP) plus its two gradient
+  matmuls. q, o, dO, dQ, dK, dV lie as the forward's operands do.
+- accumulators are fp32 over bf16 MXU operands; each of the three kernels
+  has blocks of its own (``_Blocks``; ``block_q_bwd``/``block_k_bwd`` set
+  both backward passes' for tests), since the dKV pass wants its resident
+  tile on the KV dim while the forward and dQ want it on Q.
 - zero-padded Q rows cancel exactly (their dO and delta rows are zero), so
-  only key-padding and causality ever generate a mask — the same static
-  specialization as the forward.
+  only key-padding, causality and a window ever generate a mask — the same
+  static specialization as the forward.
 
 Whether this kernel actually beats XLA attention *in training* on a real
 chip is decided by measurement, not by this docstring: the dispatch layer
@@ -86,9 +103,12 @@ Falls back to interpreter mode off-TPU so CPU tests exercise the same kernel.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -106,7 +126,11 @@ _LANES = 128
 #   rev 4: the streaming schedule takes a window and fewer key-value heads
 #          than query heads, walks only the block pairs inside the band, and
 #          states its cost; blocks of 1,024 past 1,024 positions.
-KERNEL_REV = 4
+#   rev 5: a streaming program holds a key-value head's whole group of
+#          query heads; blocks from (T, window, group); no mask inside the
+#          band; steps that slide with a window's band (element offsets);
+#          delta taken in the dQ pass; K scaled in the dKV pass.
+KERNEL_REV = 5
 
 # the streaming forward's results, as ``jax.ad_checkpoint`` names them
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
@@ -136,147 +160,321 @@ def _max(a, b):
 
 
 class _Band:
-    """Which (q block, k block) pairs of a streaming call hold a score the
-    mask allows, as static numbers and as functions of a block index.
+    """Which score tiles of a streaming kernel hold a score the mask allows,
+    as static numbers and as functions of a block index.
 
     Query row ``i`` sees key ``j`` where ``j <= i + offset`` (causal;
     ``offset = k_len - q_len``, the XLA ``attention``'s convention) and, with
-    a window, ``i + offset - j < window``. A q block then needs the k blocks
-    ``k_lo(iq) .. k_hi(iq)`` and a k block the q blocks ``q_lo(ik) ..
-    q_hi(ik)``. The kernels' innermost grid dimension counts steps from the
-    low end (``steps_k`` / ``steps_q`` of them, the most any block needs: with
-    a window far fewer than there are blocks); a step past the high end runs
-    nothing, and its block index stays at the high end, so the pipeline
-    fetches nothing for it either."""
+    a window, ``i + offset - j < window``. A kernel keeps one side RESIDENT,
+    in aligned blocks (the forward and dQ passes a q block of ``bq`` rows,
+    the dKV pass a k block of ``bk`` keys), and STREAMS the other past it
+    (``stream``: "k" or "q") in steps of that side's block. The innermost
+    grid dimension counts those steps, ``steps`` of them: the most any
+    resident block needs.
+
+    Where the steps start (``span``): without a window at position 0, every
+    step an aligned block, counted from the first one the resident block
+    needs. With a window the steps SLIDE with the band: they start at the
+    first position the resident block needs, rounded down to the
+    ``granule`` (block offsets are elements, ``pl.Element``), so a q block
+    of 256 rows under a window of 1,024 walks 1,280 keys, not the 2,048 of
+    the two aligned blocks of 1,024 its band straddles. A step past the
+    high end runs nothing, and its offset stays at the last step's, so the
+    pipeline fetches nothing for it either."""
 
     def __init__(self, *, causal, window, block_q, block_k, q_len, k_len,
-                 nq, nk):
-        self.causal, self.window = causal, window
-        self.bq, self.bk, self.nq, self.nk = block_q, block_k, nq, nk
+                 stream="k"):
+        self.causal, self.window, self.stream = causal, window, stream
+        self.q_len, self.k_len = q_len, k_len
         self.offset = k_len - q_len
-        self.steps_k, self.steps_q = nk, nq
+        self.bq = min(block_q, _ceil_to(q_len, 8))
+        self.bk = min(block_k, _ceil_to(k_len, 8))
+        lens, blocks = (q_len, k_len), (self.bq, self.bk)
+        side = 0 if stream == "k" else 1           # the resident side
+        self.n = -(-lens[side] // blocks[side])    # resident blocks
+        self._res, self.b = blocks[side], blocks[1 - side]
+        self.granule = math.gcd(self.b, _LANES)
+        self._last = None                          # a sliding start's limit
+        self.steps = max(1, max(hi - lo + 1 for _, lo, hi in
+                                map(self.span, range(self.n))))
+        pads = [self.n * self._res, _ceil_to(lens[1 - side], self.b)]
         if window is not None:
-            self.steps_k = min(nk, (block_q + window - 2) // block_k + 2)
-            self.steps_q = min(nq, (block_k + window - 2) // block_q + 2)
+            pads[1] = max(_ceil_to(lens[1 - side], self.granule),
+                          self.steps * self.b)
+            self._last = pads[1] - self.steps * self.b
+        self.tq_pad, self.tk_pad = pads if stream == "k" else pads[::-1]
+
+    def _needs(self, i):
+        """(first, last) position of the streamed side that the resident
+        block ``i`` holds an allowed score with; last < first where none."""
+        first = i * self._res
+        if self.stream == "k":
+            lo = (0 if self.window is None
+                  else _max(first + self.offset - self.window + 1, 0))
+            hi = (self.k_len - 1 if not self.causal
+                  else _min(first + self.bq - 1 + self.offset,
+                            self.k_len - 1))
+        else:
+            lo = _max(first - self.offset, 0) if self.causal else 0
+            hi = (self.q_len - 1 if self.window is None
+                  else _min(first + self.bk + self.window - 2 - self.offset,
+                            self.q_len - 1))
+        return lo, hi
 
     # floor division rounds down for negative numbers too (Python and jnp
-    # alike), so a block with nothing to see gets a high end below its low
+    # alike), so a block with nothing to see gets a last step below its first
 
-    def k_lo(self, iq):
-        if self.window is None:
-            return 0
-        return _max(iq * self.bq + self.offset - self.window + 1,
-                    0) // self.bk
+    def span(self, i):
+        """(position of step 0, first step that runs, last step that runs)
+        of resident block ``i``, steps counted in blocks from the start."""
+        lo, hi = self._needs(i)
+        start = 0
+        if self.window is not None:
+            start = lo // self.granule * self.granule
+            if self._last is not None:
+                start = _min(start, self._last)
+        return start, (lo - start) // self.b, (hi - start) // self.b
 
-    def k_hi(self, iq):
-        if not self.causal:
-            return self.nk - 1
-        return _min((iq * self.bq + self.bq - 1 + self.offset) // self.bk,
-                    self.nk - 1)
+    def step(self, i, s):
+        """(position of grid step ``s`` of resident block ``i``, whether it
+        runs, the position to fetch)."""
+        start, lo, hi = self.span(i)
+        pad = self.tk_pad if self.stream == "k" else self.tq_pad
+        # in granules, multiplied out last: the chip's compiler has to see
+        # that the offset is a multiple of the memory tiling
+        g = self.granule
+        fetch = jnp.clip(start // g + jnp.minimum(lo + s, hi) * (self.b // g),
+                         0, (pad - self.b) // g) * g
+        return start + (lo + s) * self.b, lo + s <= hi, fetch
 
-    def q_lo(self, ik):
-        if not self.causal:
-            return 0
-        return _max(ik * self.bk - self.offset, 0) // self.bq
+    @property
+    def masks(self) -> bool:
+        """Whether any tile of the call needs a mask (static): a non-causal
+        call whose key length tiles exactly holds none."""
+        return self.causal or self.tk_pad != self.k_len
 
-    def q_hi(self, ik):
-        if self.window is None:
-            return self.nq - 1
-        return _min((ik * self.bk + self.bk + self.window - 2
-                     - self.offset) // self.bq, self.nq - 1)
+    def interior(self, row0, col0):
+        """Whether every score of the tile at (row0, col0) is one the mask
+        allows, so that the tile builds no mask: no padded key, its last key
+        at or below the diagonal for its first row, its first key inside the
+        window of its last row."""
+        inside = col0 + self.bk <= self.k_len
+        if self.causal:
+            first = row0 + self.offset
+            inside &= first >= col0 + self.bk - 1
+            if self.window is not None:
+                inside &= first + self.bq - 1 - col0 < self.window
+        return inside
 
-    def k_block(self, iq, step):
-        """(k block of this step, whether it runs, the block to fetch)."""
-        kb, hi = self.k_lo(iq) + step, self.k_hi(iq)
-        return kb, kb <= hi, jnp.clip(jnp.minimum(kb, hi), 0, self.nk - 1)
+    def valid(self, row0, col0):
+        """The mask of the tile at (row0, col0): built once a step, shared
+        by the group's members. Zero-padded q rows need NO mask anywhere:
+        the forward drops them on the way out (its l==0 guard), and in the
+        backward their dO and delta rows are zero, so every contribution
+        they could make (dV += Pᵀ·dO, dS = P·(dP − δ)) cancels exactly; the
+        only hazard — exp(s − (−inf)) from their forward lse — is removed
+        by the backward's lse clamp."""
+        return _valid((self.bq, self.bk), row0, col0, causal=self.causal,
+                      q_len=self.q_len, k_len=self.k_len,
+                      mask_k=self.tk_pad != self.k_len, window=self.window)
 
-    def q_block(self, ik, step):
-        qb, hi = self.q_lo(ik) + step, self.q_hi(ik)
-        return qb, qb <= hi, jnp.clip(jnp.minimum(qb, hi), 0, self.nq - 1)
+    def tiles(self) -> list[tuple[int, int]]:
+        """(first row, first key) of every tile that runs (static)."""
+        out = []
+        for i in range(self.n):
+            start, lo, hi = self.span(i)
+            for s in range(lo, hi + 1):
+                at = (i * self._res, start + s * self.b)
+                out.append(at if self.stream == "k" else at[::-1])
+        return out
 
     def pairs(self) -> int:
-        """Block pairs that run (static): what a call's cost is counted
-        from, so that a banded call does not claim the square's work."""
-        return sum(max(0, self.k_hi(iq) - self.k_lo(iq) + 1)
-                   for iq in range(self.nq))
+        """Tiles that run (static): what a call's cost is counted from, so
+        that a banded call does not claim the square's work."""
+        return len(self.tiles())
 
-
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                  *, band: _Band, q_len: int, k_len: int, mask_k: bool):
-    iq = pl.program_id(2)
-    step = pl.program_id(3)
-    # the k block this step holds; blocks with no unmasked column (above the
-    # diagonal, left of the window) are never reached or not run
-    ik, run, _ = band.k_block(iq, step)
-
-    @pl.when(step == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]                                     # (bq, d), scaled
-        k = k_ref[0, 0]                                     # (bk, d)
-        v = v_ref[0, 0]                                     # (bk, d)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk) f32
-
-        # Mask only under configs that statically need one (mask_k: the key
-        # length isn't a block multiple). Padded q ROWS need none: they are
-        # dropped on the way out, and their lse guard below keeps them 0.
-        s, valid = _masked_scores(s, iq, ik, causal=band.causal,
-                                  block_q=band.bq, block_k=band.bk,
-                                  q_len=q_len, k_len=k_len, mask_k=mask_k,
-                                  window=band.window)
-
-        m_prev = m_scr[:, :1]                               # (bq, 1)
-        l_prev = l_scr[:, :1]
-        m_curr = jnp.max(s, axis=1, keepdims=True)
-        m_next = jnp.maximum(m_prev, m_curr)
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)                             # (bq, bk)
-        if valid is not None:
-            p = jnp.where(valid, p, 0.0)    # exp(-1e30-m)≈0 anyway
-        l_next = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-
-        m_scr[...] = jnp.broadcast_to(m_next, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_next, l_scr.shape)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, d) f32
-        acc_scr[...] = acc_scr[...] * alpha + pv
-
-    @pl.when(step == band.steps_k - 1)
-    def _finish():
-        l = l_scr[:, :1]
-        m = m_scr[:, :1]
-        # Fully-masked rows (padded q rows, dropped on the way out): emit 0,
-        # not NaN.
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-        # Per-row logsumexp, saved for the backward recompute. Stored with a
-        # trailing singleton dim, (B, H, Tq, 1): Mosaic requires the last two
-        # block dims be (multiple-of-8, multiple-of-128-or-full-dim) — a
-        # rank-3 (1, 1, block_q) block puts the size-1 head slice in the
-        # sublane position and fails to lower on real TPU hardware.
-        lse_ref[0, 0] = m + jnp.log(l)
+    def fill(self) -> float:
+        """Scores the mask allows over scores the programs run (static):
+        how closely the tiles follow the band."""
+        if not self.causal:
+            allowed = self.q_len * self.k_len
+        else:
+            last = np.arange(self.q_len) + self.offset
+            first = (0 if self.window is None
+                     else np.maximum(last - self.window + 1, 0))
+            allowed = int(np.maximum(
+                np.minimum(last, self.k_len - 1) - first + 1, 0).sum())
+        return allowed / (self.pairs() * self.bq * self.bk)
 
 
 def _ceil_to(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def _default_block(t: int) -> int:
-    """128 (one MXU tile) up to 1,024 positions, as every shape before the
-    long ones ran; 1,024 beyond, where a step's fixed cost (about a third of
-    a microsecond) would otherwise rival a small tile's products. Measured
-    on a v5e at two sequences of 8,192, 32 heads over 4 of 128, forward +
-    backward, ms (docs/ATTENTION.md): blocks of 256 / 512 / 1,024 take 116.1
-    / 55.3 / 39.2 full and 36.2 / 22.5 / 20.7 under a window of 1,024."""
-    return 128 if t <= 1024 else 1024
+class _Blocks(NamedTuple):
+    """(block_q, block_k) of the three streaming kernels."""
+    fwd: tuple[int, int]
+    dq: tuple[int, int]
+    dkv: tuple[int, int]
+
+
+def _default_blocks(t: int, tk: int, window: int | None,
+                    group: int) -> _Blocks:
+    """The blocks of a streaming call, from its static shape.
+
+    One query head a program (``group == 1``): 128 (one MXU tile) up to
+    1,024 positions, as every shape before the long ones ran; 1,024 beyond,
+    where a step's fixed cost (about a third of a microsecond) would
+    otherwise rival a small tile's products (measured with one head a
+    program on a v5e at two sequences of 8,192, 32 heads over 4 of 128,
+    forward + backward, ms: blocks of 256 / 512 / 1,024 took 116.1 / 55.3 /
+    39.2 full and 36.2 / 22.5 / 20.7 under a window of 1,024).
+
+    A group a program (measured at the same shape, eight heads a program,
+    each kernel alone; docs/ATTENTION.md has the table): a tile has to be
+    wide in keys, because the (bq, 1) column statistics and the
+    accumulator's rescale cost a 128-key slice of the scores each whatever
+    the tile's width, so blocks of 256 x 256 lose to 256 x 1,024 with a
+    group too; what follows a window's band is a step that slides with it.
+    Windowed: forward 256 x 1,280 (one step a q block) 4.02 ms against 5.59
+    at 256 x 1,024 (two), dQ 128 x 1,152 4.70, dKV 1,280 x 256 (one step a
+    k block) 4.09 against 4.67 at 512 x 512. Full: forward 512 x 1,024 9.75
+    against 11.19 at 256 x 1,024, dQ 512 x 512 12.43, dKV 512 x 1,024
+    13.43. Another window takes the same resident blocks and steps of at
+    most 1,280 positions that span its band (``_band_steps``); nothing else
+    has been measured."""
+    if group == 1:
+        one = (128 if t <= 1024 else 1024), (128 if tk <= 1024 else 1024)
+        return _Blocks(one, one, one)
+    if window is not None:
+        return _Blocks((256, _band_steps(window, 256)),
+                       (128, _band_steps(window, 128)),
+                       (_band_steps(window, 256), 256))
+    return _Blocks((512, 1024), (512, 512), (512, 1024))
+
+
+def _band_steps(window: int, resident: int, most: int = 1280) -> int:
+    """The streamed side's block under a window: what a resident block's
+    band spans (``window + resident - 1`` positions), in as few equal steps
+    as stay within ``most`` positions each, whole 128s (one step of 1,280
+    keys for 256 rows under a window of 1,024)."""
+    span = _ceil_to(window + resident - 1, _LANES)
+    steps = -(-span // most)
+    return _ceil_to(-(-span // steps), _LANES)
+
+
+def _member(ref, j: int):
+    """Member ``j``'s (rows, d) tile of a group's (G, rows, d) block; the
+    block itself where one head is a program's whole group."""
+    return ref if len(ref.shape) == 2 else ref.at[j]
+
+
+def _scaled(q, scale: float):
+    """Q at the softmax temperature (fp32 multiply, cast back to the MXU
+    input dtype): S = (scale·Q)Kᵀ needs no per-tile VPU rescale, and dK =
+    dSᵀ·(scale·Q) comes out scaled for free in the backward."""
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
+def _valid(shape, row0, col0, *, causal, q_len, k_len, mask_k,
+           keys_axis: int = 1, window: int | None = None):
+    """The validity mask of one score tile whose first row and first key are
+    global positions ``row0`` and ``col0``, or None under a configuration
+    that statically needs none: key padding (``mask_k``), causality (tril
+    with the k_len−q_len offset, matching the XLA ``attention``) or a window
+    (of the keys causality allows, the nearest ``window``). ``keys_axis``
+    says which axis of the tile the keys lie on: 1 for the streaming
+    kernels' (bq, bk) tiles, 0 for the whole-sequence kernels' transposed
+    (T_k, T_q) scores."""
+    offset = k_len - q_len
+    valid = None
+    if mask_k or causal:
+        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, keys_axis)
+    if mask_k:
+        valid = cols < k_len
+    if causal:
+        rows = row0 + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1 - keys_axis)
+        c = rows + offset >= cols
+        if window is not None:
+            c = jnp.logical_and(c, rows + offset - cols < window)
+        valid = c if valid is None else jnp.logical_and(valid, c)
+    return valid
+
+
+def _on_band(band: _Band, row0, col0, run, step_fn):
+    """Run ``step_fn(valid)`` for the tile at (row0, col0) where the step
+    runs: with the tile's mask where an edge of the band crosses it, with
+    None (no mask is built) inside the band or where the call needs none."""
+    if not band.masks:
+        pl.when(run)(lambda: step_fn(None))
+        return
+    inside = band.interior(row0, col0)
+    pl.when(jnp.logical_and(run, inside))(lambda: step_fn(None))
+    pl.when(jnp.logical_and(run, jnp.logical_not(inside)))(
+        lambda: step_fn(band.valid(row0, col0)))
+
+
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
+                  acc_scr, *, band: _Band, group: int, scale: float):
+    row0 = pl.program_id(2) * band.bq
+    step = pl.program_id(3)
+    # the keys this step holds; tiles with no unmasked column (above the
+    # diagonal, left of the window) are never reached or not run
+    col0, run, _ = band.step(pl.program_id(2), step)
+
+    @pl.when(step == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        qs_scr[...] = _scaled(q_ref[...], scale)
+
+    def _step(valid):
+        k = k_ref[...]                                      # (bk, d)
+        v = v_ref[...]                                      # (bk, d)
+        for j in range(group):
+            q = _member(qs_scr, j)[...]                  # (bq, d), scaled
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bq, bk) f32
+            if valid is not None:
+                s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_scr[:, j:j + 1]                      # (bq, 1)
+            l_prev = l_scr[:, j:j + 1]
+            m_next = jnp.maximum(m_prev,
+                                 jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - m_next)                         # (bq, bk)
+            if valid is not None:
+                # a row with no key in this block yet: exp(-1e30 + 1e30)
+                p = jnp.where(valid, p, 0.0)
+            m_scr[:, j:j + 1] = m_next
+            l_scr[:, j:j + 1] = l_prev * alpha + jnp.sum(p, axis=1,
+                                                         keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bq, d) f32
+            acc_scr[j] = acc_scr[j] * alpha + pv
+
+    _on_band(band, row0, col0, run, _step)
+
+    @pl.when(step == band.steps - 1)
+    def _finish():
+        m = m_scr[:, :group]                                # (bq, G)
+        l = l_scr[:, :group]
+        # Fully-masked rows (padded q rows, dropped on the way out): emit 0,
+        # not NaN.
+        l = jnp.where(l == 0.0, 1.0, l)
+        for j in range(group):
+            _member(o_ref, j)[...] = (
+                acc_scr[j] / l[:, j:j + 1]).astype(o_ref.dtype)
+        # Per-row logsumexp, saved for the backward recompute, the members
+        # on the minor dimension: (B, Hkv, Tq, G). Mosaic requires the last
+        # two block dims be (multiple-of-8, multiple-of-128-or-full-dim) — a
+        # (1, block_q) row per member would put a size-1 slice in the
+        # sublane position and fails to lower on real TPU hardware.
+        lse_ref[...] = m + jnp.log(l)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -293,8 +491,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``tpudist.parallel.ring_attention.attention``); returns [B, T, H, D].
 
     ``Hkv`` divides ``H``: query head ``j`` reads key-value head ``j // (H //
-    Hkv)`` (grouped-query attention; the kernels index the shared head, and
-    the backward sums dK / dV over the group in VMEM). ``window`` (static,
+    Hkv)`` (grouped-query attention; one program holds a key-value head's
+    whole group of query heads, and the backward sums dK / dV over it in
+    VMEM). ``window`` (static,
     with ``causal``) keeps, of the keys a query may see, the nearest
     ``window``; blocks wholly outside the band are neither run nor fetched
     (``_Band``).
@@ -308,7 +507,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     and the precomputed ``delta = rowsum(dO ∘ O)``; no O(T²) tensor is ever
     materialized. ``block_q_bwd``/``block_k_bwd`` tune the backward blocks
     independently of the forward's (None = same as forward; the forward's
-    default is ``_default_block`` of the length).
+    default is ``_default_blocks`` of the lengths, the window and the
+    group). The block arguments are for tests and measurements: they select
+    no path.
 
     A caller that holds the fused projection calls ``flash_attention_qkv``:
     that entry picks the schedule from the shape and comes here only where
@@ -323,11 +524,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          f"v {v.shape})")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    block_q = block_q or _default_block(q.shape[1])
-    block_k = block_k or _default_block(k.shape[1])
-    return _flash_vjp(q, k, v, causal, window, block_q, block_k,
-                      block_q_bwd or block_q, block_k_bwd or block_k,
-                      interpret)
+    rule = _default_blocks(q.shape[1], k.shape[1], window,
+                           q.shape[2] // k.shape[2])
+    bwd_q, bwd_k = block_q_bwd or block_q, block_k_bwd or block_k
+    blocks = _Blocks(
+        (block_q or rule.fwd[0], block_k or rule.fwd[1]),
+        (bwd_q or rule.dq[0], bwd_k or rule.dq[1]),
+        (bwd_q or rule.dkv[0], bwd_k or rule.dkv[1]))
+    return _flash_vjp(q, k, v, causal, window, blocks, interpret)
 
 
 def flash_attention_spmd(qkv: jax.Array, causal: bool = False, **kw):
@@ -375,18 +579,13 @@ def flash_attention_spmd(qkv: jax.Array, causal: bool = False, **kw):
                          check_vma=False)(qkv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_vjp(q, k, v, causal, window, block_q, block_k, block_q_bwd,
-               block_k_bwd, interpret):
-    o, _ = _flash_forward(q, k, v, causal, window, block_q, block_k,
-                          interpret)
-    return o
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_vjp(q, k, v, causal, window, blocks, interpret):
+    return _flash_forward(q, k, v, causal, window, blocks.fwd, interpret)[0]
 
 
-def _flash_vjp_fwd(q, k, v, causal, window, block_q, block_k, block_q_bwd,
-                   block_k_bwd, interpret):
-    o, lse = _flash_forward(q, k, v, causal, window, block_q, block_k,
-                            interpret)
+def _flash_vjp_fwd(q, k, v, causal, window, blocks, interpret):
+    o, lse = _flash_forward(q, k, v, causal, window, blocks.fwd, interpret)
     # named, so that a caller that rematerialises its layer can keep the
     # kernel's two results (``jax.checkpoint_policies.save_only_these_names(
     # *SAVED_BY_NAME)``) and not run the forward kernel a second time
@@ -395,33 +594,13 @@ def _flash_vjp_fwd(q, k, v, causal, window, block_q, block_k, block_q_bwd,
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, window, block_q, block_k, block_q_bwd,
-                   block_k_bwd, interpret, res, g):
+def _flash_vjp_bwd(causal, window, blocks, interpret, res, g):
     q, k, v, o, lse = res
-    return _flash_backward(q, k, v, o, lse, g, causal, window, block_q_bwd,
-                           block_k_bwd, interpret)
+    return _flash_backward(q, k, v, o, lse, g, causal, window, blocks.dq,
+                           blocks.dkv, interpret)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
-
-
-def _scaled_q(q, d: int):
-    """Softmax temperature folded into Q once (fp32 multiply, cast back to
-    the MXU input dtype) — S = (scale·Q)Kᵀ needs no per-tile VPU rescale,
-    and dK = dSᵀ·(scale·Q) comes out scaled for free in the backward."""
-    scale = 1.0 / (d ** 0.5)
-    return (q.astype(jnp.float32) * scale).astype(q.dtype)
-
-
-def _stream_geometry(t, tk, causal, window, block_q, block_k):
-    block_q = min(block_q, _ceil_to(t, 8))
-    block_k = min(block_k, _ceil_to(tk, 8))
-    tq_pad = _ceil_to(t, block_q)
-    tk_pad = _ceil_to(tk, block_k)
-    band = _Band(causal=causal, window=window, block_q=block_q,
-                 block_k=block_k, q_len=t, k_len=tk, nq=tq_pad // block_q,
-                 nk=tk_pad // block_k)
-    return band, tq_pad, tk_pad
 
 
 def _stream_cost(band: _Band, products: int, b, h, d, isz, arrays: int,
@@ -433,161 +612,178 @@ def _stream_cost(band: _Band, products: int, b, h, d, isz, arrays: int,
     ``arrays`` [B, T, H, D] operands and ``rows`` float32 row statistics
     moved once."""
     scores = b * h * band.pairs() * band.bq * band.bk
-    t = band.nq * band.bq
+    t = band.tq_pad
     return pl.CostEstimate(
         flops=2 * products * scores * d, transcendentals=scores,
         bytes_accessed=arrays * b * t * h * d * isz + 4 * rows * b * h * t)
 
 
-def _flash_forward(q, k, v, causal, window, block_q, block_k, interpret):
+# A [B, T, heads, D] operand as the streaming kernels read it, a key-value
+# head's ``members`` heads (the group of query heads; 1 for k and v
+# themselves) a block: moved to [B, Hkv, members, T, D], so that a block is
+# whole (rows, D) tiles of one head each (XLA writes q and k there straight
+# out of the norm and RoPE that make them, and those run with positions on
+# the sublanes, where their tables lie; read as [B, T, H*D] where the
+# projection wrote them, the decoder's step lost 44 ms to the layouts XLA
+# then chose around the calls: PERF.md section 6, PR 33); rows padded to the
+# block multiple (padded keys are masked inside the kernel, padded q rows
+# drop on exit).
+
+def _operand(x, hkv: int, t_pad: int):
+    b, t, h, d = x.shape
+    x = jnp.moveaxis(x, 1, 2).reshape(b, hkv, h // hkv, t, d)
+    return x if t_pad == t else jnp.pad(
+        x, ((0, 0),) * 3 + ((0, t_pad - t), (0, 0)))
+
+
+def _result(x, t: int):
+    """A kernel's result, laid as ``_operand`` lays it, as [B, T, h, D]."""
+    b, hkv, members, _, d = x.shape
+    return jnp.moveaxis(x[:, :, :, :t].reshape(b, hkv * members, t, d), 1, 2)
+
+
+def _block_view(rows: int, members: int, d: int):
+    """A block's shape inside a kernel: what ``_member`` indexes."""
+    return (rows, d) if members == 1 else (members, rows, d)
+
+
+def _operand_spec(rows: int, members: int, d: int, index):
+    """The BlockSpec of ``rows`` positions of one key-value head's
+    ``members``; ``index(*grid)`` gives (batch, key-value head, first
+    position): an element offset, so that a window's steps can start where
+    its band does."""
+    return pl.BlockSpec(
+        (None, None, None if members == 1 else pl.Element(members),
+         pl.Element(rows), pl.Element(d)),
+        lambda *g: (lambda b_, hk, r: (b_, hk, 0, r, 0))(*index(*g)))
+
+
+def _row_spec(rows: int, group: int, index):
+    """The BlockSpec of a [B, Hkv, T, G] float32 row statistic."""
+    return pl.BlockSpec(
+        (None, None, pl.Element(rows), pl.Element(group)),
+        lambda *g: (lambda b_, hk, r: (b_, hk, r, 0))(*index(*g)))
+
+
+# A group's tiles are wide (eight members' q, o, dO blocks and one (bq, bk)
+# float32 tile each for S, P, dP, dS): a program may hold 32 MiB of the
+# chip's 128 MiB of VMEM, twice what a kernel is given by default.
+_GRID_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=32 * 2**20)
+
+
+def _flash_forward(q, k, v, causal, window, blocks, interpret):
     b, t, h, d = q.shape
-    tk, group = k.shape[1], h // k.shape[2]
-    band, tq_pad, tk_pad = _stream_geometry(t, tk, causal, window, block_q,
-                                            block_k)
-    block_q, block_k = band.bq, band.bk
+    tk, hkv = k.shape[1], k.shape[2]
+    group = h // hkv
+    band = _Band(causal=causal, window=window, block_q=blocks[0],
+                 block_k=blocks[1], q_len=t, k_len=tk)
 
-    # (B, T, H, D) → (B, H, T, D); pad T so the grid tiles exactly. Padded
-    # keys are masked inside the kernel (k_len); padded q rows drop on exit.
-    qt = jnp.moveaxis(_scaled_q(q, d), 1, 2)
-    kt = jnp.moveaxis(k, 1, 2)
-    vt = jnp.moveaxis(v, 1, 2)
-    if tq_pad != t:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, tq_pad - t), (0, 0)))
-    if tk_pad != tk:
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, tk_pad - tk), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, tk_pad - tk), (0, 0)))
+    def at_q(b_, hk, iq, s):
+        return b_, hk, iq * band.bq
 
-    kernel = functools.partial(_flash_kernel, band=band, q_len=t, k_len=tk,
-                               mask_k=tk_pad != tk)
-    # a query head reads the key-value head of its group
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d),
-        lambda b_, h_, iq, s: (b_, h_ // group, band.k_block(iq, s)[2], 0))
+    def at_k(b_, hk, iq, s):
+        return b_, hk, band.step(iq, s)[2]
 
+    q_spec = _operand_spec(band.bq, group, d, at_q)
+    kv_spec = _operand_spec(band.bk, 1, d, at_k)
     out, lse = pl.pallas_call(
-        kernel,
-        grid=(b, h, band.nq, band.steps_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, iq, s: (b_, h_, iq, 0)),
-            kv_spec, kv_spec,
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, iq, s: (b_, h_, iq, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b_, h_, iq, s: (b_, h_, iq, 0)),
-        ],
+        functools.partial(_flash_kernel, band=band, group=group,
+                          scale=1.0 / (d ** 0.5)),
+        grid=(b, hkv, band.n, band.steps),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, _row_spec(band.bq, group, at_q)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, tq_pad, 1), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (b, hkv, group, band.tq_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, band.tq_pad, group), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM(_block_view(band.bq, group, d), q.dtype),
+            pltpu.VMEM((band.bq, _LANES), jnp.float32),
+            pltpu.VMEM((band.bq, _LANES), jnp.float32),
+            pltpu.VMEM((group, band.bq, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=_GRID_SEMANTICS,
         # two products (S, P V); q, k, v in (k and v at their own head
         # count: less than the two arrays claimed), o and the logsumexp out
         cost_estimate=_stream_cost(band, 2, b, h, d, q.dtype.itemsize,
                                    arrays=4, rows=1),
         interpret=interpret,
-    )(qt, kt, vt)
-
-    out = out[:, :, :t, :]
-    return jnp.moveaxis(out, 1, 2), lse
-
-
-def _masked_scores(s, iq, ik, *, causal, block_q, block_k, q_len, k_len,
-                   mask_k, keys_axis: int = 1, window: int | None = None):
-    """Static mask specialization shared by the forward and both backward
-    passes: build the (bq, bk) validity mask only under configs that need
-    one — key padding (``mask_k``), causality (global-position tril with
-    the k_len−q_len offset, matching the XLA ``attention``) or a window
-    (of the keys causality allows, the nearest ``window``). Zero-padded q
-    rows need NO mask anywhere: the forward drops them on the way out (its
-    l==0 guard), and in the backward their dO and delta rows are zero, so
-    every contribution they could make (dV += Pᵀ·dO, dS = P·(dP − δ))
-    cancels exactly; the only hazard — exp(s − (−inf)) from their forward
-    lse — is removed by the backward's lse clamp. Returns (masked scores,
-    valid-or-None): the forward also zeroes its probabilities by
-    ``valid``. ``keys_axis`` says which axis of ``s`` the keys lie on: 1
-    for the streaming kernels' (bq, bk) tiles, 0 for the whole-sequence
-    kernels' transposed (T_k, T_q) scores."""
-    offset = k_len - q_len
-    valid = None
-    if mask_k:
-        cols = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, keys_axis)
-        valid = cols < k_len
-    if causal:
-        cols = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, keys_axis)
-        rows = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1 - keys_axis)
-        c = rows + offset >= cols
-        if window is not None:
-            c = jnp.logical_and(c, rows + offset - cols < window)
-        valid = c if valid is None else jnp.logical_and(valid, c)
-    if valid is not None:
-        s = jnp.where(valid, s, NEG_INF)
-    return s, valid
+    )(_operand(q, hkv, band.tq_pad), _operand(k, hkv, band.tk_pad),
+      _operand(v, hkv, band.tk_pad))
+    return _result(out, t), lse
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, scale: float, band: _Band, q_len: int,
-                   k_len: int, mask_k: bool):
-    """dQ pass: parallel over q blocks, k blocks stream sequentially.
+def _masked_scores(s, row0, col0, *, keys_axis: int = 1, **mask):
+    """Scores with what ``_valid`` refuses at -1e30 (the whole-sequence
+    kernels' one tile)."""
+    valid = _valid(s.shape, row0, col0, keys_axis=keys_axis, **mask)
+    return s if valid is None else jnp.where(valid, s, NEG_INF)
 
-    The (block_q, d) dQ tile accumulates in fp32 scratch across the k
-    stream; the temperature (folded out of dS) is applied once per tile in
-    the epilogue instead of once per (bq, bk) score tile."""
-    iq = pl.program_id(2)
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
+                   delta_ref, qs_scr, dq_scr, dl_scr, *, band: _Band,
+                   group: int, scale: float):
+    """dQ pass: parallel over q blocks, the keys stream sequentially.
+
+    The group's (G, block_q, d) dQ tiles accumulate in fp32 scratch across
+    the k stream; the temperature (folded out of dS) is applied once per
+    tile in the epilogue instead of once per (bq, bk) score tile. The pass
+    also takes ``delta = rowsum(dO ∘ O)`` of its rows, once a q block, from
+    the blocks it holds (no float32 copy of dO or O is made in HBM for it),
+    and hands it to the dKV pass."""
+    row0 = pl.program_id(2) * band.bq
     step = pl.program_id(3)
-    ik, run, _ = band.k_block(iq, step)
+    col0, run, _ = band.step(pl.program_id(2), step)
 
     @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        qs_scr[...] = _scaled(q_ref[...], scale)
+        for j in range(group):
+            dl_scr[:, j:j + 1] = jnp.sum(
+                _member(do_ref, j)[...].astype(jnp.float32)
+                * _member(o_ref, j)[...].astype(jnp.float32),
+                axis=1, keepdims=True)                      # (bq, 1)
+        delta_ref[...] = dl_scr[:, :group]
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]                                     # (bq, d), scaled
-        k = k_ref[0, 0]                                     # (bk, d)
-        v = v_ref[0, 0]                                     # (bk, d)
-        do = do_ref[0, 0]                                   # (bq, d)
-        lse = lse_ref[0, 0]                                 # (bq, 1)
-        delta = delta_ref[0, 0]                             # (bq, 1)
+    def _step(valid):
+        k = k_ref[...]                                      # (bk, d)
+        v = v_ref[...]                                      # (bk, d)
+        for j in range(group):
+            q = _member(qs_scr, j)[...]                  # (bq, d), scaled
+            do = _member(do_ref, j)[...]                 # (bq, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bq, bk)
+            if valid is not None:
+                s = jnp.where(valid, s, NEG_INF)
+            # p from the saved statistics — no second softmax pass.
+            p = jnp.exp(s - lse_ref[:, j:j + 1])            # (bq, bk)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bq, bk)
+            ds = p * (dp - dl_scr[:, j:j + 1])              # (bq, bk)
+            dq_scr[j] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bq, d)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        s, _ = _masked_scores(s, iq, ik, causal=band.causal,
-                              block_q=band.bq, block_k=band.bk, q_len=q_len,
-                              k_len=k_len, mask_k=mask_k, window=band.window)
-        # p from the saved statistics — no second softmax pass.
-        p = jnp.exp(s - lse)                                 # (bq, bk)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        ds = p * (dp - delta)                                # (bq, bk)
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, d)
+    _on_band(band, row0, col0, run, _step)
 
-    @pl.when(step == band.steps_k - 1)
+    @pl.when(step == band.steps - 1)
     def _finish():
-        dq_ref[0, 0, :, :] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        for j in range(group):
+            _member(dq_ref, j)[...] = (dq_scr[j] * scale).astype(
+                dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, band: _Band,
-                    group: int, q_len: int, k_len: int, mask_k: bool):
-    """dKV pass: parallel over KV blocks; the query heads of the group, and
-    under each the q blocks, stream sequentially.
+                    dk_ref, dv_ref, ks_scr, dk_scr, dv_scr, *, band: _Band,
+                    group: int, scale: float):
+    """dKV pass: parallel over KV blocks; the q rows of the group stream
+    sequentially.
 
     Each program owns one (block_k, d) dK tile and one dV tile in fp32
     scratch and streams Q/dO past them, of every query head that reads this
@@ -596,155 +792,151 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     probabilities are transposed only implicitly, by contracting over the q
     dim in the two gradient matmuls. (A materialized (1, bq) lse/delta row
     would need a sublane→lane relayout that Mosaic can't lower; a (bq, 1)
-    column is native.) dK needs no epilogue scale: Q arrives pre-scaled, and
-    dK = dSᵀ·(scale·Q) IS the scaled gradient."""
-    ik = pl.program_id(2)
-    member = pl.program_id(3)
-    step = pl.program_id(4)
-    iq, run, _ = band.q_block(ik, step)
+    column is native.) The temperature rides on the side that stays: K is
+    scaled once a program into scratch (S = Q·(scale·K)ᵀ, no rescale of
+    every q block that streams past), and dK = scale·dSᵀ·Q takes it once
+    more in the epilogue."""
+    col0 = pl.program_id(2) * band.bk
+    step = pl.program_id(3)
+    row0, run, _ = band.step(pl.program_id(2), step)
 
-    @pl.when(jnp.logical_and(member == 0, step == 0))
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        ks_scr[...] = _scaled(k_ref[...], scale)
 
-    @pl.when(run)
-    def _step():
-        k = k_ref[0, 0]                                     # (bk, d)
-        v = v_ref[0, 0]                                     # (bk, d)
-        q = q_ref[0, 0]                                     # (bq, d), scaled
-        do = do_ref[0, 0]                                   # (bq, d)
-        lse = lse_ref[0, 0]                                 # (bq, 1)
-        delta = delta_ref[0, 0]                             # (bq, 1)
+    def _step(valid):
+        k = ks_scr[...]                                     # (bk, d), scaled
+        v = v_ref[...]                                      # (bk, d)
+        dk = dv = 0.0
+        for j in range(group):
+            q = _member(q_ref, j)[...]                   # (bq, d)
+            do = _member(do_ref, j)[...]                 # (bq, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bq, bk)
+            if valid is not None:
+                s = jnp.where(valid, s, NEG_INF)
+            p = jnp.exp(s - lse_ref[:, j:j + 1])            # (bq, bk)
+            dv += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bk, d)
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bq, bk)
+            ds = p * (dp - delta_ref[:, j:j + 1])           # (bq, bk)
+            dk += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (bk, d)
+        dk_scr[...] += dk
+        dv_scr[...] += dv
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        s, _ = _masked_scores(s, iq, ik, causal=band.causal,
-                              block_q=band.bq, block_k=band.bk, q_len=q_len,
-                              k_len=k_len, mask_k=mask_k, window=band.window)
-        p = jnp.exp(s - lse)                                 # (bq, bk)
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, d)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bq, bk)
-        ds = p * (dp - delta)                                # (bq, bk)
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, d)
+    _on_band(band, row0, col0, run, _step)
 
-    @pl.when(jnp.logical_and(member == group - 1,
-                             step == band.steps_q - 1))
+    @pl.when(step == band.steps - 1)
     def _finish():
-        dk_ref[0, 0, :, :] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_scr[...].astype(dv_ref.dtype)
+        dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, o, lse, g, causal, window, block_q, block_k,
-                    interpret):
+def _rows(x, t: int, t_pad: int):
+    """A [B, Hkv, T', G] row statistic at another pass's padded length."""
+    x = x[:, :, :t]
+    return x if t_pad == t else jnp.pad(
+        x, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
+
+
+def _flash_backward(q, k, v, o, lse, g, causal, window, blocks_dq,
+                    blocks_dkv, interpret):
     """Two-pass flash backward (see module docstring): a dQ pass parallel
-    over q blocks and a dKV pass parallel over KV blocks, sharing the saved
-    ``lse`` and the XLA-precomputed ``delta = rowsum(dO ∘ O)``."""
+    over q blocks, which also takes ``delta = rowsum(dO ∘ O)``, and a dKV
+    pass parallel over KV blocks, sharing the saved ``lse`` and delta."""
     b, t, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
     group = h // hkv
     scale = 1.0 / (d ** 0.5)
-    band, tq_pad, tk_pad = _stream_geometry(t, tk, causal, window, block_q,
-                                            block_k)
-    block_q, block_k = band.bq, band.bk
-    mask_k = tk_pad != tk
     isz = q.dtype.itemsize
+    # Both per-row statistics ride as (B, Hkv, Tq, G), as the forward wrote
+    # the logsumexp, padded to the FORWARD's q-block multiple: each pass
+    # re-pads from the true length. Fully-masked (padded) q rows carry lse =
+    # NEG_INF; exp(s - NEG_INF) would overflow to inf → NaN via inf·0 in the
+    # matmuls, so clamp those rows to 0 — with the clamp their contributions
+    # cancel exactly (zero dO/delta rows), which is why the backward kernels
+    # need no q-row mask.
+    lse = jnp.where(lse[:, :, :t] <= NEG_INF / 2, 0.0, lse[:, :, :t])
 
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)                                 # (b, t, h)
-    delta = jnp.moveaxis(delta, -1, 1)                       # (b, h, t)
+    band = _Band(causal=causal, window=window, block_q=blocks_dq[0],
+                 block_k=blocks_dq[1], q_len=t, k_len=tk)
 
-    qt = jnp.moveaxis(_scaled_q(q, d), 1, 2)
-    kt = jnp.moveaxis(k, 1, 2)
-    vt = jnp.moveaxis(v, 1, 2)
-    dot = jnp.moveaxis(g, 1, 2)
-    # The forward's lse is padded to the FORWARD q-block multiple, which may
-    # differ from this pass's (block_q_bwd): re-pad from the true length.
-    # Fully-masked (padded) q rows carry lse = NEG_INF; exp(s - NEG_INF)
-    # would overflow to inf → NaN via inf·0 in the matmuls, so clamp those
-    # rows to 0 — with the clamp their contributions cancel exactly (zero
-    # dO/delta rows), which is why the backward kernels need no q-row mask.
-    # Both per-row stats ride in the (B, H, Tq, 1) layout (see _flash_kernel's
-    # _finish for why rank-3 blocks don't lower on TPU).
-    lse_safe = jnp.where(lse[:, :, :t] <= NEG_INF / 2, 0.0, lse[:, :, :t])
-    if tq_pad != t:
-        pad_q = ((0, 0), (0, 0), (0, tq_pad - t), (0, 0))
-        qt = jnp.pad(qt, pad_q)
-        dot = jnp.pad(dot, pad_q)
-        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, tq_pad - t)))
-        lse_safe = jnp.pad(lse_safe, ((0, 0), (0, 0), (0, tq_pad - t),
-                                      (0, 0)))
-    if tk_pad != tk:
-        pad_k = ((0, 0), (0, 0), (0, tk_pad - tk), (0, 0))
-        kt = jnp.pad(kt, pad_k)
-        vt = jnp.pad(vt, pad_k)
-    delta = delta[..., None]
+    def at_q(b_, hk, iq, s):
+        return b_, hk, iq * band.bq
 
-    q_spec = pl.BlockSpec((1, 1, block_q, d),
-                          lambda b_, h_, iq, s: (b_, h_, iq, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, 1),
-                            lambda b_, h_, iq, s: (b_, h_, iq, 0))
-    kv_spec = pl.BlockSpec(
-        (1, 1, block_k, d),
-        lambda b_, h_, iq, s: (b_, h_ // group, band.k_block(iq, s)[2], 0))
+    def at_k(b_, hk, iq, s):
+        return b_, hk, band.step(iq, s)[2]
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, band=band, q_len=t,
-                          k_len=tk, mask_k=mask_k),
-        grid=(b, h, band.nq, band.steps_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, tq_pad, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+    q_spec = _operand_spec(band.bq, group, d, at_q)
+    kv_spec = _operand_spec(band.bk, 1, d, at_k)
+    row_spec = _row_spec(band.bq, group, at_q)
+    qt, ot, dot = (_operand(x, hkv, band.tq_pad) for x in (q, o, g))
+    dq, delta = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, band=band, group=group,
+                          scale=scale),
+        grid=(b, hkv, band.n, band.steps),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(
+                (b, hkv, group, band.tq_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hkv, band.tq_pad, group), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM(_block_view(band.bq, group, d), q.dtype),
+                        pltpu.VMEM((group, band.bq, d), jnp.float32),
+                        pltpu.VMEM((band.bq, _LANES), jnp.float32)],
+        compiler_params=_GRID_SEMANTICS,
         # the model's work: dP and dQ (the recomputed scores are left out,
-        # as the whole-sequence backward leaves them); q, k, v, dO in, dQ
-        # out, the logsumexp and delta
-        cost_estimate=_stream_cost(band, 2, b, h, d, isz, arrays=5, rows=2),
+        # as the whole-sequence backward leaves them); q, k, v, o, dO in,
+        # dQ out, the logsumexp in and delta out
+        cost_estimate=_stream_cost(band, 2, b, h, d, isz, arrays=6, rows=2),
         interpret=interpret,
-    )(qt, kt, vt, dot, lse_safe, delta)
+    )(qt, _operand(k, hkv, band.tk_pad), _operand(v, hkv, band.tk_pad), ot,
+      dot, _rows(lse, t, band.tq_pad))
+    dq = _result(dq, t)
 
-    # dKV: one program a key-value head and k block; the group's members
-    # and the q blocks in its band stream past it
-    def q_index(b_, hk, ik, m, s):
-        return b_, hk * group + m, band.q_block(ik, s)[2], 0
+    # dKV: one program a key-value head and k block; the q rows in its band
+    # stream past it, the whole group in each step
+    band = _Band(causal=causal, window=window, block_q=blocks_dkv[0],
+                 block_k=blocks_dkv[1], q_len=t, k_len=tk, stream="q")
 
-    k_spec = pl.BlockSpec((1, 1, block_k, d),
-                          lambda b_, hk, ik, m, s: (b_, hk, ik, 0))
-    q_spec_b = pl.BlockSpec((1, 1, block_q, d), q_index)
-    row_spec_b = pl.BlockSpec((1, 1, block_q, 1), q_index)
+    def own_k(b_, hk, ik, s):
+        return b_, hk, ik * band.bk
 
+    def band_q(b_, hk, ik, s):
+        return b_, hk, band.step(ik, s)[2]
+
+    k_spec = _operand_spec(band.bk, 1, d, own_k)
+    q_spec = _operand_spec(band.bq, group, d, band_q)
+    row_spec = _row_spec(band.bq, group, band_q)
+    kv_shape = jax.ShapeDtypeStruct(
+        (b, hkv, 1, band.tk_pad, d), k.dtype)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, band=band, group=group, q_len=t,
-                          k_len=tk, mask_k=mask_k),
-        grid=(b, hkv, band.nk, group, band.steps_q),
-        in_specs=[k_spec, k_spec, q_spec_b, q_spec_b, row_spec_b, row_spec_b],
+        functools.partial(_bwd_dkv_kernel, band=band, group=group,
+                          scale=scale),
+        grid=(b, hkv, band.n, band.steps),
+        in_specs=[k_spec, k_spec, q_spec, q_spec, row_spec, row_spec],
         out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct((b, hkv, tk_pad, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, hkv, tk_pad, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary")),
+        out_shape=[kv_shape, kv_shape],
+        scratch_shapes=[pltpu.VMEM((band.bk, d), k.dtype),
+                        pltpu.VMEM((band.bk, d), jnp.float32),
+                        pltpu.VMEM((band.bk, d), jnp.float32)],
+        compiler_params=_GRID_SEMANTICS,
         # the model's work: dV and dK
         cost_estimate=_stream_cost(band, 2, b, h, d, isz, arrays=6, rows=2),
         interpret=interpret,
-    )(kt, vt, qt, dot, lse_safe, delta)
-
-    dq = jnp.moveaxis(dq[:, :, :t, :], 1, 2)
-    dk = jnp.moveaxis(dk[:, :, :tk, :], 1, 2)
-    dv = jnp.moveaxis(dv[:, :, :tk, :], 1, 2)
-    return dq, dk, dv
+    )(_operand(k, hkv, band.tk_pad), _operand(v, hkv, band.tk_pad),
+      _operand(q, hkv, band.tq_pad), _operand(g, hkv, band.tq_pad),
+      _rows(lse, t, band.tq_pad), _rows(delta, t, band.tq_pad))
+    return dq, _result(dk, tk), _result(dv, tk)
 
 
 # -- whole-sequence schedule --------------------------------------------------
@@ -814,6 +1006,30 @@ def schedule_for(seq: int, heads: int, head_dim: int, dtype) -> str:
     return STREAMING
 
 
+def program_plan(seq: int, heads: int, head_dim: int, dtype, *,
+                 kv_heads: int | None = None, causal: bool = False,
+                 window: int | None = None, fused: bool = False) -> dict:
+    """How far the kernels engage at a static self-attention shape, for the
+    dispatch line and its telemetry event: the ``schedule`` (the fused entry
+    chooses; split operands stream), the query heads a program holds, its
+    blocks (the forward's), and ``band_fill`` = scores the mask allows over
+    scores the forward's programs run."""
+    kv_heads = kv_heads or heads
+    if (fused and kv_heads == heads and window is None
+            and schedule_for(seq, heads, head_dim, dtype) == WHOLE_SEQ):
+        fill = (seq + 1) / (2 * seq) if causal else 1.0
+        return {"schedule": WHOLE_SEQ, "heads_per_program": _head_group(
+                    seq, heads, head_dim, jnp.dtype(dtype).itemsize),
+                "block_q": seq, "block_k": seq, "band_fill": round(fill, 4)}
+    group = heads // kv_heads
+    block_q, block_k = _default_blocks(seq, seq, window, group).fwd
+    band = _Band(causal=causal, window=window, block_q=block_q,
+                 block_k=block_k, q_len=seq, k_len=seq)
+    return {"schedule": STREAMING, "heads_per_program": group,
+            "block_q": band.bq, "block_k": band.bk,
+            "band_fill": round(band.fill(), 4)}
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
 def flash_attention_qkv(qkv: jax.Array, causal: bool = False,
                         interpret: bool | None = None):
@@ -864,9 +1080,8 @@ def _scores_t(q, k, *, scale: float, causal: bool):
         k, q, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
     t = q.shape[0]
-    st, _ = _masked_scores(st, 0, 0, causal=causal, block_q=t, block_k=t,
-                           q_len=t, k_len=t, mask_k=False, keys_axis=0)
-    return st
+    return _masked_scores(st, 0, 0, causal=causal, q_len=t, k_len=t,
+                          mask_k=False, keys_axis=0)
 
 
 def _whole_seq_fwd_kernel(qkv_ref, o_ref, lse_ref, *, group: int, d: int,

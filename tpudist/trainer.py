@@ -833,9 +833,11 @@ class Trainer:
         if cfg.flash == "auto":
             self.model = self.model.clone(flash=dec["kernel"] == "flash")
         if dec["kernel"] == "flash":
-            # which of the kernel's schedules this shape takes
-            dec["schedule"] = attention_dispatch.schedule(
-                tokens, local_heads, hidden // heads, dt)
+            # which of the kernel's schedules this shape takes, and how far
+            # it engages there
+            dec["programs"] = [attention_dispatch.program(
+                tokens, local_heads, hidden // heads, dt, fused=True)]
+            dec["schedule"] = dec["programs"][0]["schedule"]
         return self._announce_flash_decision(dec)
 
     def _announce_flash_decision(self, dec: dict) -> dict:
@@ -845,6 +847,10 @@ class Trainer:
                f"(mode {dec['mode']}, {dec['source']}")
         if dec.get("schedule"):
             msg += f", schedule {dec['schedule']}"
+        for p in dec.get("programs", ()):
+            msg += (f", heads_per_program {p['heads_per_program']} "
+                    f"block_q {p['block_q']} block_k {p['block_k']} "
+                    f"band_fill {p['band_fill']}")
         if dec.get("reason"):
             msg += f": {dec['reason']}"
         if dec.get("flash_ms") is not None:
@@ -866,11 +872,12 @@ class Trainer:
         from tpudist.ops import attention_dispatch
         cfg = self.cfg
         kernel = "flash" if cfg.flash == "on" else "xla"
+        workloads = self.model.attention_workloads(cfg.seq_len)
         keys = [attention_dispatch.shape_key(
                     cfg.per_device_batch_size, w["seq"], w["heads"],
                     w["head_dim"], compute_dtype(cfg), not cfg.evaluate,
                     w["causal"], kv_heads=w["kv_heads"], window=w["window"])
-                for w in self.model.attention_workloads(cfg.seq_len)]
+                for w in workloads]
         dec = {"kernel": kernel, "mode": cfg.flash, "source": "forced",
                "key": ",".join(keys),
                "kernel_rev": attention_dispatch.kernel_rev()
@@ -879,8 +886,12 @@ class Trainer:
             dec["reason"] = ("no start-up probe for grouped-query or "
                              "windowed attention: auto is the XLA path")
         if kernel == "flash":
-            from tpudist.ops.pallas.flash_attention import STREAMING
-            dec["schedule"] = STREAMING
+            # split q / k / v stream; one entry a workload, in the keys' order
+            dec["programs"] = [attention_dispatch.program(
+                w["seq"], w["heads"], w["head_dim"], compute_dtype(cfg),
+                kv_heads=w["kv_heads"], causal=w["causal"],
+                window=w["window"]) for w in workloads]
+            dec["schedule"] = dec["programs"][0]["schedule"]
         dec["reason"] = "; ".join(filter(None, [dec.get("reason"),
                                                 dec["key"]]))
         return self._announce_flash_decision(dec)
