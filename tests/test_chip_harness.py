@@ -1,7 +1,10 @@
 """The chip benchmark's feed, refusals, bounded readings and roofline
 arithmetic, collected in tier-1: the cases of
 `benchmarks/chip/selftest/test_feed_cpu.py` (run by path there, in seconds,
-with no `Trainer`), loaded from that file so that there is one copy of them.
+with no `Trainer`), loaded from that file so that there is one copy of them,
+and the readers' cases of `selftest/test_sdar_cpu.py` (its `TIER1`: the
+count of the block-diffusion mask's pairs, a share above 100, the plan's
+fill, the noise's scope).
 The rest of `benchmarks/chip/selftest/` builds trainers for minutes and
 stays run by path. Below them: what the configurations' `trainer_argv` pins
 against the program's defaults."""
@@ -24,6 +27,13 @@ _spec.loader.exec_module(_feed)
 # every test of the file, under its own name (parametrised cases and all)
 globals().update({name: value for name, value in vars(_feed).items()
                   if name.startswith("test_")})
+
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_test_sdar_cpu", os.path.join(os.path.dirname(_PATH),
+                                            "test_sdar_cpu.py"))
+_sdar = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_sdar)
+globals().update({test.__name__: test for test in _sdar.TIER1})
 
 
 # -- the cells measure the default program -----------------------------------
@@ -48,6 +58,13 @@ _NOT_DEFAULTS = {
     ("resnet18_ref", "--flash"):
         ("flash", "the field differs and the program does not: resnet18 has "
                   "no attention (models.takes_flash)"),
+    ("sdar_30b_ep8", "--flash"):
+        ("flash", "a decoder has no start-up probe, `auto` is XLA's path, and "
+                  "XLA's scores over the doubled row of 16,384 positions "
+                  "would be 34 GB a row"),
+    ("sdar_30b_ep8", "--remat"):
+        ("remat", "32,768 positions a step through every layer: only "
+                  "rematerialised do the temporaries fit beside the state"),
 }
 
 
@@ -86,7 +103,7 @@ def test_a_pin_writes_a_default_out_and_no_more(name, argv, flag):
 def test_every_exception_names_a_pin_that_is_written_out():
     written = {(p.values[0], p.values[2]) for p in _pins()}
     assert set(_NOT_DEFAULTS) <= written
-    assert len(written) == 33
+    assert len(written) == 44
 
 
 def test_fused_bn_takes_off_and_nothing_else(capsys):

@@ -19,24 +19,27 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpudist.models.decoder import mellum2_12b_a2_5b, mellum2_tiny
+from tpudist.models.decoder import (block_noise, mellum2_12b_a2_5b,
+                                    mellum2_tiny, sdar_30b_a3b, sdar_tiny)
 from tpudist.ops import rope
 from tpudist.parallel.moe import moe_topk_held, route_topk
-from tpudist.parallel.ring_attention import attention
+from tpudist.parallel.ring_attention import attention, block_diffusion_mask
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CHIP = os.path.join(ROOT, "benchmarks", "chip")
 
 
-def _reference():
+def _reference(name):
     spec = importlib.util.spec_from_file_location(
-        "chipbench_ref_mellum2_for_tests", os.path.join(
-            ROOT, "benchmarks", "chip", "refs", "mellum2_12b_ep4.py"))
+        f"chipbench_ref_{name}_for_tests", os.path.join(
+            CHIP, "refs", name + ".py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
-REF = _reference()
+REF = _reference("mellum2_12b_ep4")
+REF_SDAR = _reference("sdar_30b_ep8")
 ROPE = {kind: dict(p) for kind, p in
         mellum2_12b_a2_5b().rope_parameters.items()}
 
@@ -144,25 +147,30 @@ def _share(params, lo, n):
             **{m: params[m][lo:lo + n] for m in ("gate", "up", "down")}}
 
 
-@pytest.mark.parametrize("held", [2, 4])
-def test_the_shares_add_up_to_the_uncut_layer(held):
+@pytest.mark.parametrize("ref,experts,held", [
+    (REF, 8, 2), (REF, 8, 4), (REF_SDAR, 16, 4), (REF_SDAR, 16, 2)],
+    ids=["mellum2_2of8", "mellum2_4of8", "sdar_4of16", "sdar_2of16"])
+def test_the_shares_add_up_to_the_uncut_layer(ref, experts, held):
     """The add-up test: every holder's part of the result, summed over the
     holders, is what the reference gives for the whole layer with all the
-    experts held in one place."""
-    params = _moe_params(jax.random.PRNGKey(0))
+    experts held in one place (the tiny twins' 8 and 16 experts, a quarter,
+    a half and an eighth of them held: the cells hold 16 of 64 and 16 of
+    128)."""
+    params = _moe_params(jax.random.PRNGKey(0), experts=experts)
     u = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
-    z = dict(k=2, first=0, held=8)
     with jax.default_matmul_precision("highest"):
-        whole, (pairs, _) = REF._moe(u, params, z, None)
+        whole, routed = ref._moe(u, params, dict(k=2, first=0, held=experts),
+                                 None)
         total, computed = 0.0, 0.0
-        for lo in range(0, 8, held):
+        for lo in range(0, experts, held):
             y, counters = moe_topk_held(_share(params, lo, held), u, top_k=2,
                                         first_expert=lo)
-            part, _ = REF._moe(u, _share(params, lo, held),
+            part, _ = ref._moe(u, _share(params, lo, held),
                                dict(k=2, first=lo, held=held), None)
             np.testing.assert_allclose(y, part, atol=2e-5)
             total, computed = total + y, computed + counters["moe_pairs"]
     np.testing.assert_allclose(total, whole, atol=5e-5)
+    pairs = routed[0] if isinstance(routed, tuple) else routed
     assert float(computed) == 64 * 2 == float(jnp.sum(pairs))
 
 
@@ -471,6 +479,71 @@ def test_head_loss_in_chunks_is_the_whole_cross_entropy():
         np.testing.assert_allclose(a, b, atol=1e-6)
 
 
+def test_head_loss_with_weights_is_the_whole_weighted_cross_entropy():
+    """With weights: sum(w * nll) over the normaliser, gradients and all;
+    the accuracy over the weighted positions. Without: to the bit what the
+    function computed before it took them (the same operations)."""
+    from tpudist.ops import lm_head_loss
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    hidden = jax.random.normal(ks[0], (2, 37, 16))
+    kernel = jax.random.normal(ks[1], (16, 50))
+    targets = jax.random.randint(ks[2], (2, 37), 0, 50)
+    t = jax.random.uniform(ks[3], (2, 37), minval=0.1)
+    weights = jnp.where(t < 0.6, 1.0 / t, 0.0)
+
+    def whole(h, w):
+        logp = jax.nn.log_softmax(h @ w, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return jnp.sum(weights * nll) / 74
+
+    with jax.default_matmul_precision("highest"):
+        (loss, acc), grads = jax.value_and_grad(
+            lambda h, w: lm_head_loss(h, w, targets, chunk=16,
+                                      weights=weights),
+            argnums=(0, 1), has_aux=True)(hidden, kernel)
+        want, want_grads = jax.value_and_grad(whole, argnums=(0, 1))(
+            hidden, kernel)
+        hit = (jnp.argmax(hidden @ kernel, axis=-1) == targets) & (weights > 0)
+        halved, _ = lm_head_loss(hidden, kernel, targets, chunk=16,
+                                 weights=weights, normaliser=148)
+        plain = lm_head_loss(hidden, kernel, targets, chunk=16)
+        ones = lm_head_loss(hidden, kernel, targets, chunk=16,
+                            weights=jnp.ones((2, 37)))
+    assert abs(float(loss) - float(want)) < 1e-5
+    assert abs(float(halved) - float(want) / 2) < 1e-5
+    assert abs(float(acc) - 100.0 * float(hit.sum())
+               / float((weights > 0).sum())) < 1e-4
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(a, b, atol=1e-6)
+
+    # no weights: the program before this PR, operation for operation
+    def before(hidden, kernel, targets, chunk):
+        rows, t_, d = hidden.shape
+        n = rows * t_
+        chunk = next(c for c in range(min(chunk, n), 0, -1) if n % c == 0)
+
+        @jax.checkpoint
+        def one(carry, xs):
+            h, y = xs
+            logits = jnp.dot(h, kernel, preferred_element_type=jnp.float32)
+            nll = (jax.nn.logsumexp(logits, axis=-1)
+                   - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0])
+            hits = jnp.sum(jnp.argmax(logits, axis=-1) == y)
+            return (carry[0] + jnp.sum(nll), carry[1] + hits), None
+        (total, hits), _ = jax.lax.scan(
+            one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
+            (hidden.reshape(n // chunk, chunk, d),
+             targets.reshape(n // chunk, chunk)))
+        return total / n, hits.astype(jnp.float32) * (100.0 / n)
+    with jax.default_matmul_precision("highest"):
+        was = before(hidden, kernel, targets, 16)
+    assert float(plain[0]) == float(was[0]) and float(plain[1]) == float(
+        was[1])
+    # and weights of one are the plain mean
+    assert abs(float(ones[0]) - float(plain[0])) < 1e-6
+    assert abs(float(ones[1]) - float(plain[1])) < 1e-4
+
+
 def test_cross_entropy_takes_any_leading_dimensions():
     from tpudist.ops import cross_entropy_loss
     logits = jax.random.normal(jax.random.PRNGKey(0), (3, 5, 7))
@@ -491,31 +564,55 @@ def test_synthetic_token_rows():
     assert np.array_equal(x, again) and not np.array_equal(x, ds[6][0])
 
 
-def test_python_m_tpudist_trains_on_synthetic_tokens(tmp_path):
+@pytest.mark.parametrize("arch,held,key", [
+    ("mellum2_tiny", 2, "_t32_h8_kv2_d16_bfloat16_train_causal_w8"),
+    ("sdar_tiny", 4, "_t64_h8_kv2_d16_bfloat16_train_bd4")])
+def test_python_m_tpudist_trains_on_synthetic_tokens(tmp_path, arch, held,
+                                                     key):
     """The normal entry point's path (`config.from_args` -> `Trainer.fit`)
-    on the tiny twin: the loss falls, tokens/s is in the log line, the
-    share arrives as statements."""
+    on a tiny twin: the loss falls, tokens/s is in the log line, the share
+    arrives as statements, and the objective comes with the registered
+    model: `sdar_tiny` trains by diffusion over blocks through the same
+    trainer, step, prefetcher and drain, its noise key in the state and its
+    counters in the drain."""
+    from tpudist import telemetry
     from tpudist.config import from_args
     from tpudist.trainer import Trainer
     cfg = from_args([
-        "--synthetic", "-a", "mellum2_tiny", "--seq-len", "32", "-b", "16",
+        "--synthetic", "-a", arch, "--seq-len", "32", "-b", "16",
         "--layers", "2", "--epochs", "2", "--step", "5", "--optimizer", "adamw", "--lr",
         "0.01", "--wd", "0.1", "--adam-b2", "0.95", "--expert-share", "1/4",
         "--vocab-share", "0/2", "--flash", "off", "-j", "2", "-p", "2",
         "--no-telemetry", "--outpath", str(tmp_path / "out"), "--overwrite",
         "delete", "--seed", "0"])
+    seen = len(telemetry.counters().get("bd_masked_share", []))
     trainer = Trainer(cfg, writer=None)
     assert trainer.model.vocab_held == 128
     assert trainer.flash_decision["kernel"] == "xla"
-    assert "_kv2_" in trainer.flash_decision["key"]
-    assert trainer.state.params["layer_0"]["moe"]["gate"].shape == (2, 64, 32)
+    assert key in trainer.flash_decision["key"]
+    assert trainer.state.params["layer_0"]["moe"]["gate"].shape == (
+        held, 64, 32)
+    diffusion = arch == "sdar_tiny"
+    assert ("noise_key" in trainer.state.batch_stats) == diffusion
+    first = jax.device_get(trainer.state.batch_stats)
     trainer.fit()
     log = open(os.path.join(cfg.outpath, "experiment.log")).read()
     import re
     losses = [float(x) for x in re.findall(
         r"\|\|==> Train: Epoch\[\d+\]\s+Loss ([0-9.e+-]+)", log)]
-    assert len(losses) == 2 and losses[1] < losses[0] < math.log(128) + 0.5
+    # a masked position's loss is weighted 1 / t (up to 1 / eps): its mean is
+    # the plain cross entropy's, but a step's reading, and AdamW's first
+    # steps on such gradients, swing far more
+    assert len(losses) == 2 and losses[1] < losses[0] < math.log(128) * (
+        2.0 if diffusion else 1.0) + 0.5
     assert re.search(r"Acc@1\s+[0-9.]+\t[0-9.]+ tokens/s", log)
+    shares = telemetry.counters().get("bd_masked_share", [])[seen:]
+    if diffusion:
+        last = jax.device_get(trainer.state.batch_stats)
+        assert not np.array_equal(first["noise_key"], last["noise_key"])
+        assert len(shares) >= 4 and 0.3 < sum(shares) / len(shares) < 0.7
+    else:
+        assert shares == []
 
 
 def test_the_compiled_initialisation_draws_what_the_eager_one_drew():
@@ -550,12 +647,16 @@ def test_a_share_is_refused_by_a_model_that_is_not_of_tokens(tmp_path):
         Trainer(cfg, writer=None)
 
 
-def test_the_configurations_file_keeps_every_published_number():
-    """`configs/mellum2_12b_ep4.json` against the registered model: the
-    widths are the published ones, the cut is the three keys in `reduced`."""
-    cfg = json.load(open(os.path.join(
-        ROOT, "benchmarks", "chip", "configs", "mellum2_12b_ep4.json")))
-    model = mellum2_12b_a2_5b()
+@pytest.mark.parametrize("name,build,cut", [
+    ("mellum2_12b_ep4", mellum2_12b_a2_5b, (4, 16, 24576)),
+    ("sdar_30b_ep8", sdar_30b_a3b, (4, 16, 18992))])
+def test_the_configurations_file_keeps_every_published_number(name, build,
+                                                              cut):
+    """`configs/<name>.json` against the registered model: the widths are
+    the published ones, the cut is the three keys in `reduced`; for a model
+    of the guide's catalog, every number of its entry but those three."""
+    cfg = json.load(open(os.path.join(CHIP, "configs", name + ".json")))
+    model = build()
     assert cfg["reduced"] == ["num_hidden_layers", "num_experts_held",
                               "vocab_size"]
     for key, value in dict(
@@ -565,11 +666,370 @@ def test_the_configurations_file_keeps_every_published_number():
             num_experts=model.num_experts,
             num_experts_per_tok=model.experts_per_token,
             moe_intermediate_size=model.expert_width,
-            sliding_window=model.sliding_window,
             vocab_size_published=model.vocab_size,
             num_hidden_layers_published=model.num_layers).items():
         assert cfg[key] == value, key
-    assert cfg["layer_types"] == list(model.layer_types[:4])
-    assert cfg["rope_parameters"] == ROPE
     assert (cfg["num_hidden_layers"], cfg["num_experts_held"],
-            cfg["vocab_size"]) == (4, 16, 24576)
+            cfg["vocab_size"]) == cut
+    assert cfg["arch"] in cfg["trainer_argv"]
+    if name == "mellum2_12b_ep4":
+        assert cfg["sliding_window"] == model.sliding_window
+        assert cfg["layer_types"] == list(model.layer_types[:4])
+        assert cfg["rope_parameters"] == ROPE
+        return
+    # the published config, key for key (a copy of the catalog's entry: the
+    # guide is not in the repo)
+    published = {
+        "attention_bias": False, "decoder_sparse_step": 1, "head_dim": 128,
+        "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 6144,
+        "max_position_embeddings": 32768, "max_window_layers": 48,
+        "mlp_only_layers": [], "model_type": "sdar_moe",
+        "moe_intermediate_size": 768, "norm_topk_prob": True,
+        "num_attention_heads": 32, "num_experts": 128,
+        "num_experts_per_tok": 8, "num_hidden_layers": 48,
+        "num_key_value_heads": 4, "rms_norm_eps": 1e-06,
+        "rope_scaling": None, "rope_theta": 1000000, "sliding_window": None,
+        "tie_word_embeddings": False, "use_sliding_window": False,
+        "vocab_size": 151936}
+    for key, value in published.items():
+        if key not in ("num_hidden_layers", "vocab_size"):
+            assert cfg[key] == value, key
+    rope_p = model.rope_parameters["full_attention"]
+    assert (rope_p["rope_type"], rope_p["rope_theta"]) == (
+        "default", cfg["rope_theta"])
+    assert set(model.layer_types) == {"full_attention"}
+    # the objective comes with the registered model, and the file says so
+    assert (model.objective, model.block_length, model.noise_eps) == (
+        cfg["objective"], cfg["block_length"], cfg["noise_eps"]) == (
+        "block_diffusion", 4, 1e-3)
+    held = model.clone(vocab_share=(0, 8), expert_share=(0, 8), layers=4)
+    assert held.vocab_held == cfg["vocab_size"]
+    assert held.mask_id == cfg["mask_token_id"] == 18991
+    assert cfg["expert_share"] == cfg["vocab_share"] == "0 of 8"
+    assert "8 chips a layer" in cfg["deployment"]
+    for said in ("block_length", "noise schedule", "noise derivation",
+                 "logit shift", "mask_token_id", "q and k norm"):
+        assert len(cfg["assumed"][said]) > 40, said
+
+
+# --- training by diffusion over blocks (sdar_tiny) ---------------------------
+
+def sdar_cfg(held=4, share=1, layers=2, vocab=128):
+    """The tiny twin's sizes as the reference reads a configuration."""
+    return dict(
+        hidden_size=64, num_attention_heads=8, num_key_value_heads=2,
+        head_dim=16, num_hidden_layers=layers, vocab_size=vocab,
+        num_experts=16, num_experts_per_tok=2, num_experts_held=held,
+        expert_share=f"{share} of {16 // held}", moe_intermediate_size=32,
+        rms_norm_eps=1e-6, rope_theta=1000000, reference_block_rows=16,
+        block_length=4, noise_eps=1e-3, mask_token_id=vocab - 1,
+        adam_b1=0.9, adam_b2=0.95, adam_eps=1e-8, weight_decay=0.1,
+        decay_min_ndim=2)
+
+
+def sdar_model(held=4, share=1, **kw):
+    return sdar_tiny(dtype=jnp.float32, expert_share=(share, 16 // held),
+                     vocab_share=(0, 2), layers=2, **kw)
+
+
+def sdar_loss(model, params, stats, x):
+    out, mutated = model.apply({"params": params, "batch_stats": stats}, x,
+                               train=True, targets=x,
+                               mutable=["batch_stats"])
+    return out.loss, (out, mutated["batch_stats"])
+
+
+@pytest.mark.parametrize("t,held,share,flash", [
+    (32, 4, 1, False), (32, 4, 3, True), (38, 2, 5, True), (38, 8, 0, False)])
+def test_diffusion_loss_and_every_gradient_leaf_match_the_reference(
+        t, held, share, flash):
+    """`sdar_tiny` against `refs/sdar_30b_ep8.py`: the tree, the loss, every
+    gradient leaf, the key the state holds next and the counters, through
+    the XLA path and through the kernel (interpreted, rematerialised), a
+    share of the experts held, at a length the blocks tile and one they do
+    not (38 = 9 blocks of 4 and one of 2)."""
+    cfg = sdar_cfg(held, share)
+    params, stats = REF_SDAR.init(jax.random.PRNGKey(0), cfg)
+    model = sdar_model(held, share, flash=flash, remat=flash)
+    x, _ = tokens(t, vocab=127)
+    ours = model.init(jax.random.PRNGKey(0), x)
+    for mine, theirs in ((ours["params"], params),
+                         (ours["batch_stats"], stats)):
+        assert [(jax.tree_util.keystr(k), v.shape, v.dtype)
+                for k, v in leaves(mine)] == [
+            (jax.tree_util.keystr(k), v.shape, v.dtype)
+            for k, v in leaves(theirs)]
+    with jax.default_matmul_precision("highest"):
+        (loss, (out, after)), grads = jax.value_and_grad(
+            lambda p: sdar_loss(model, p, stats, x), has_aux=True)(params)
+    (want, (carry, routed, masked, weight)), want_grads = jax.value_and_grad(
+        REF_SDAR.loss_fn, has_aux=True)(params, stats["noise_key"], x, cfg)
+    assert abs(float(loss) - float(want)) < 1e-5 * float(want)
+    for (path, g), (_, w) in zip(leaves(grads), leaves(want_grads)):
+        gap = float(jnp.linalg.norm(g - w) / (jnp.linalg.norm(w) + 1e-12))
+        assert gap < 2e-4, (jax.tree_util.keystr(path), gap)
+    assert np.array_equal(after["noise_key"], carry)
+    assert float(out.counters["bd_masked_share"]) == float(masked)
+    assert abs(float(out.counters["bd_weight_sum"]) - float(weight)) < 1e-6
+    for layer in range(2):
+        assert float(out.counters[f"moe_pairs.layer_{layer}"]) == float(
+            jnp.sum(routed[layer]))
+
+
+@pytest.mark.parametrize("length,block", [(8, 4), (10, 4), (12, 3), (6, 8)])
+def test_the_block_diffusion_mask_is_its_four_rules(length, block):
+    """`block_diffusion_mask` (what `attention` applies, and the kernel's
+    oracle) and the reference's `seen`, against the rules written out pair
+    by pair; the pairs allowed count `L (L + block)` where the blocks tile
+    the row."""
+    got = block_diffusion_mask(2 * length, length, block)
+    theirs = np.asarray(REF_SDAR.seen(jnp.arange(2 * length), length, block))
+    want = np.zeros((2 * length, 2 * length), bool)
+    for i in range(2 * length):
+        for j in range(2 * length):
+            noisy_i, noisy_j = i < length, j < length
+            n_i, n_j = (i % length) // block, (j % length) // block
+            if noisy_i and noisy_j:
+                want[i, j] = n_j == n_i
+            elif noisy_i:
+                want[i, j] = n_j < n_i
+            elif not noisy_j:
+                want[i, j] = n_j <= n_i
+    assert np.array_equal(got, want) and np.array_equal(theirs, want)
+    if length % block == 0:
+        assert want.sum() == length * (length + block)
+    # the clean row alone (generation's view) is causal by blocks
+    alone = block_diffusion_mask(length, 0, block)
+    assert np.array_equal(alone, want[length:, length:])
+    q = jnp.ones((1, 2 * length, 1, 4))
+    with pytest.raises(ValueError, match="whole mask"):
+        attention(q, q, q, causal=True, block_diffusion=(length, block))
+
+
+def test_the_doubled_row_is_the_models_definition():
+    """What one pass over `[x_t ; x_0]` computes is, block by block, what
+    the model is defined to compute: the noised half's logits of block n
+    equal a plain forward over `[x_0 blocks < n ; x_t block n]` under the
+    clean copy's rule alone (causal by blocks), for every n."""
+    model = sdar_model()
+    rows, length, block = 2, 24, 4
+    x0, _ = tokens(length, rows=rows, vocab=127)
+    state = model.init(jax.random.PRNGKey(2), x0)
+    _, xt, _, masked = block_noise(state["batch_stats"]["noise_key"], x0,
+                                   block, 1e-3, model.mask_id)
+    assert bool(masked.any()) and not bool(masked.all())
+    with jax.default_matmul_precision("highest"):
+        doubled = model.apply(state, x0, noised=xt)
+        assert doubled.shape == (rows, length, 128)
+        for n in range(length // block):
+            lo, hi = n * block, (n + 1) * block
+            plain = model.apply(
+                state, jnp.concatenate([x0[:, :lo], xt[:, lo:hi]], axis=1))
+            np.testing.assert_allclose(doubled[:, lo:hi], plain[:, lo:hi],
+                                       atol=2e-5, err_msg=f"block {n}")
+    # a next-id model has no noised copy to run
+    plain_model = mellum2_tiny(dtype=jnp.float32, layers=2)
+    with pytest.raises(ValueError, match="objective"):
+        plain_model.apply(plain_model.init(jax.random.PRNGKey(0), x0), x0,
+                          noised=xt)
+
+
+def test_program_and_reference_draw_the_same_noise(tmp_path, mesh8):
+    """The draws of t and of the masked positions: bit for bit the
+    reference's from the same leaf, different by seed and by step, and the
+    leaf survives a save and a restore, so a resumed run draws what the
+    uninterrupted one would have."""
+    from tpudist import checkpoint
+    from tpudist.config import Config
+    from tpudist.train import create_train_state, make_train_step
+    cfg = sdar_cfg()
+    z = REF_SDAR._sizes(cfg)
+    x0, _ = tokens(30, rows=3, vocab=127)
+    keys = [REF_SDAR.init(jax.random.PRNGKey(seed), cfg)[1]["noise_key"]
+            for seed in (0, 1)]
+    assert keys[0].dtype == jnp.uint32 and keys[0].shape == (2,)
+    drawn = []
+    for key in keys:
+        ours = jax.jit(lambda k: block_noise(k, x0, 4, 1e-3, 127))(key)
+        theirs = jax.jit(lambda k: REF_SDAR.noise(k, x0, z))(key)
+        for a, b in zip(ours, theirs):
+            assert a.dtype == b.dtype and np.array_equal(a, b)
+        drawn.append(ours)
+    (carry, xt, weights, masked), other = drawn
+    assert not np.array_equal(masked, other[3])             # by seed
+    again = block_noise(carry, x0, 4, 1e-3, 127)
+    assert not np.array_equal(masked, again[3])             # by step
+    assert not np.array_equal(carry, again[0])
+    # one t a block: a block's weights are 0 or one value, at most 1 / eps
+    w = np.asarray(weights).reshape(3, -1)[:, :28].reshape(3, 7, 4)
+    assert all(len(set(blk[blk > 0])) <= 1 for row in w for blk in row)
+    assert np.array_equal(np.asarray(xt) == 127,
+                          np.asarray(masked) | (np.asarray(x0) == 127))
+    assert 1.0 <= float(weights[weights > 0].min()) and float(
+        weights.max()) <= 1e3
+
+    # through the trainer's step: the leaf advances as the reference's does,
+    # is replicated (never averaged) over eight shards, and comes back from
+    # a checkpoint as it was saved
+    tcfg = Config(arch="sdar_tiny", batch_size=8, seq_len=32,
+                  optimizer="adamw", lr=1e-3, weight_decay=0.1, adam_b2=0.95,
+                  use_amp=False, seed=0).finalize(8)
+    model = sdar_model()
+    state = create_train_state(jax.random.PRNGKey(0), model, tcfg)
+    assert state.batch_stats["noise_key"].dtype == jnp.uint32
+    x, _ = tokens(32, rows=8, vocab=127)
+    step = make_train_step(mesh8, model, tcfg)
+    first = np.asarray(state.batch_stats["noise_key"])
+    state, metrics = step(state, x, x, jnp.float32(1e-3))
+    second = np.asarray(state.batch_stats["noise_key"])
+    assert np.array_equal(second, np.asarray(jax.random.split(first)[0]))
+    assert 0.0 < float(metrics["bd_masked_share"]) < 1.0
+    assert 0.0 < float(metrics["bd_weight_sum"]) < 4.0
+    checkpoint.save_checkpoint(
+        checkpoint.state_to_dict(state, "sdar_tiny", 0, 0.0), False,
+        str(tmp_path))
+    fresh = create_train_state(jax.random.PRNGKey(9), model, tcfg)
+    assert not np.array_equal(fresh.batch_stats["noise_key"], second)
+    restored = checkpoint.restore_train_state(
+        fresh, checkpoint.load_checkpoint(str(tmp_path)))
+    key = restored.batch_stats["noise_key"]
+    assert key.dtype == jnp.uint32 and np.array_equal(key, second)
+    resumed, _ = step(restored, x, x, jnp.float32(1e-3))
+    onward, _ = step(state, x, x, jnp.float32(1e-3))
+    assert np.array_equal(resumed.batch_stats["noise_key"],
+                          onward.batch_stats["noise_key"])
+
+
+def test_diffusion_step_takes_the_references_first_step(mesh8):
+    """Through `create_train_state` and `make_train_step`, the path the cell
+    runs: with one row a shard the step's loss is the mean over the shards
+    of the reference's loss of each row under the same key."""
+    from tpudist.config import Config
+    from tpudist.train import create_train_state, make_train_step
+    tcfg = Config(arch="sdar_tiny", batch_size=8, seq_len=32,
+                  optimizer="adamw", lr=1e-3, weight_decay=0.1, adam_b2=0.95,
+                  use_amp=False, seed=0).finalize(8)
+    cfg = sdar_cfg()
+    model = sdar_model()
+    state = create_train_state(jax.random.PRNGKey(0), model, tcfg)
+    params, stats = REF_SDAR.init(jax.random.PRNGKey(3), cfg)
+    state = state.replace(params=params, batch_stats=stats)
+    x, _ = tokens(32, rows=8, vocab=127)
+    with jax.default_matmul_precision("highest"):
+        state, metrics = make_train_step(mesh8, model, tcfg)(
+            state, x, x, jnp.float32(1e-3))
+    want = np.mean([float(REF_SDAR.loss_fn(
+        params, stats["noise_key"], x[i:i + 1], cfg)[0]) for i in range(8)])
+    assert abs(float(metrics["loss"]) - want) < 1e-5 * want
+
+
+def _readings(loss, grads):
+    """A loss and a gradient as `harness/check.py::compare` reads a side."""
+    flat = [np.asarray(g) for g in jax.tree_util.tree_leaves(grads)]
+    norms = np.array([np.linalg.norm(g) for g in flat])
+    return {"loss": [float(loss)], "first_grad_leaves": flat,
+            "first_grad": norms, "param_change": norms}
+
+
+@pytest.mark.parametrize("fault", [None, "causal_over_2L", "clean_half_scored",
+                                   "no_1_over_t", "shifted_targets"])
+def test_a_wrong_objective_fails_the_comparison(fault, monkeypatch):
+    """The comparison that decides `correct` (`harness/check.py::compare`
+    under the tiny twin's limits) passes the program against the reference,
+    and fails it against a reference that walks a causal mask over the
+    doubled row, scores the clean half, drops the 1 / t, or shifts the
+    targets by one: each is a different training run, and at least one
+    limit says so."""
+    import sys
+    sys.path.insert(0, CHIP)
+    try:
+        from harness import check
+    finally:
+        sys.path.remove(CHIP)
+    tiny = json.load(open(os.path.join(CHIP, "selftest", "tiny",
+                                       "sdar_tiny.json")))
+    cfg = sdar_cfg()
+    params, stats = REF_SDAR.init(jax.random.PRNGKey(0), cfg)
+    model = sdar_model()
+    x, _ = tokens(32, vocab=127)
+    with jax.default_matmul_precision("highest"):
+        (loss, _), grads = jax.value_and_grad(
+            lambda p: sdar_loss(model, p, stats, x), has_aux=True)(params)
+
+    if fault == "causal_over_2L":
+        monkeypatch.setattr(
+            REF_SDAR, "seen", lambda at, length, bl: (
+                jnp.arange(2 * length)[None, :] <= at[:, None]))
+
+    def theirs(p):
+        length = x.shape[1]
+        _, xt, weights, m = REF_SDAR.noise(stats["noise_key"], x,
+                                           REF_SDAR._sizes(cfg))
+        hidden, _ = REF_SDAR.hidden_states(p, xt, x, cfg)
+        half, y = hidden[:, :length], x
+        if fault == "clean_half_scored":
+            half = hidden[:, length:]
+        if fault == "no_1_over_t":
+            weights = m.astype(jnp.float32)
+        if fault == "shifted_targets":
+            y = jnp.roll(x, -1, axis=1)
+        return REF_SDAR.head_loss(p, half, y, weights, cfg)
+
+    want, want_grads = jax.value_and_grad(theirs)(params)
+    names = {k: check.leaf_names(params)
+             for k in ("first_grad", "param_change")}
+    correct, rows = check.compare(_readings(loss, grads),
+                                  _readings(want, want_grads),
+                                  tiny["correct_limits"], names)
+    assert correct is (fault is None), [r for r in rows if not r[3]]
+
+
+def test_no_square_of_the_doubled_row_is_in_the_step():
+    """With the kernel on, forward and backward of the whole model hold no
+    [.., 2L, 2L] and no [.., 2L, L] tensor: attention is handed the mask as
+    a statement, never as an array (the XLA path, for contrast, builds the
+    scores whole)."""
+    length = 40                 # 2L = 80 is no width of the tiny twin
+    x, _ = tokens(length, vocab=127)
+
+    def shapes(flash):
+        model = sdar_model(flash=flash, remat=flash)
+        state = model.init(jax.random.PRNGKey(0), x)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: sdar_loss(
+            model, p, state["batch_stats"], x)[0]))(state["params"])
+        return set(_shapes_in(jaxpr.jaxpr))
+
+    def squares(found):
+        return {s for s in found if len(s) >= 2 and s[-2] == 2 * length
+                and s[-1] in (length, 2 * length)}
+    assert not squares(shapes(True))
+    assert squares(shapes(False))
+
+
+@pytest.mark.parametrize("held,share", [(4, 1), (2, 5), (8, 0)])
+def test_the_reference_seats_the_mask_tokens_experts(held, share):
+    """The seeded weights of a configuration trained by diffusion over
+    blocks: each router's columns are relabelled so that exactly one of the
+    experts the mask token routes to is held here, whatever the seed and
+    the share (left to the seed it is 0 to 3 of them and a quarter of all
+    positions follows: `configs/sdar_30b_ep8.json`, "mask token's
+    experts"). A relabelling: the columns are the drawn ones, each once."""
+    cfg = sdar_cfg(held, share)
+    z = REF_SDAR._sizes(cfg)
+    mine = range(z["first"], z["first"] + held)
+    for seed in range(5):
+        params, _ = REF_SDAR.init(jax.random.PRNGKey(seed), cfg)
+        row = params["embed"]["embedding"][z["mask"]]
+        u = row * jax.lax.rsqrt(jnp.mean(row * row) + 1e-6)
+        keys = iter(jax.random.split(jax.random.PRNGKey(seed), 2 + 8 * 2))
+        drawn = [jax.random.normal(k, (64, 16)) * 0.02
+                 for i, k in enumerate(keys) if i >= 2 and (i - 2) % 8 == 4]
+        for layer, was in zip(range(2), drawn):
+            router = params[f"layer_{layer}"]["moe"]["router"]
+            order = np.argsort(-np.asarray(u @ router))
+            assert order[0] == z["first"]        # its favourite: first held
+            assert sum(e in mine for e in order[:z["k"]]) == 1
+            # the rest of the held seats: the experts it favours least
+            assert set(order[-(held - 1):]) == set(mine) - {z["first"]}
+            assert sorted(map(tuple, np.asarray(router.T))) == sorted(
+                map(tuple, np.asarray(was.T)))

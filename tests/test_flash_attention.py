@@ -622,3 +622,4 @@ def test_the_tiles_that_run_cover_the_band(t, tk, window, block_q, block_k,
             rows_in.size and rows_in.all()
             and col0 + band.bk <= tk), (row0, col0)
     assert (seen[padded] == 1).all() and seen.max() <= 1
+

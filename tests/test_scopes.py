@@ -7,6 +7,7 @@ compilation cache off: its key leaves HLO metadata out, so a cached
 executable carries the names of whichever tree compiled it first.
 """
 
+import os
 import re
 import time
 
@@ -129,11 +130,12 @@ def test_attention_stages_are_named(compiled_steps):
                    for n in names), stage
 
 
-@pytest.fixture(scope="module")
-def decoder_step(mesh8):
-    """[(HLO line, op_name)] of the DP step of the tiny decoder of tokens
-    (models/decoder.py), every layer rematerialised and its attention on
-    the streaming kernel, as the chip benchmark's cell runs the large one."""
+@pytest.fixture(scope="module", params=["mellum2_tiny", "sdar_tiny"])
+def decoder_step(mesh8, request):
+    """[(HLO line, op_name)] of the DP step of a tiny decoder of tokens
+    (models/decoder.py: trained to predict the next id, and by diffusion
+    over blocks), every layer rematerialised and its attention on the
+    streaming kernel, as the chip benchmark's cells run the large ones."""
     from jax.experimental.compilation_cache import compilation_cache
     from tpudist.models import create_model
     from tpudist.train import (compute_dtype, create_train_state,
@@ -141,7 +143,7 @@ def decoder_step(mesh8):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        cfg = Config(arch="mellum2_tiny", batch_size=16, seq_len=32,
+        cfg = Config(arch=request.param, batch_size=16, seq_len=32,
                      optimizer="adamw", use_amp=True, seed=0).finalize(8)
         model = create_model(cfg.arch, dtype=compute_dtype(cfg), layers=2,
                              expert_share=(0, 4), flash=True, remat=True,
@@ -186,6 +188,74 @@ def test_decoder_scopes_are_named_forward_and_backward(decoder_step, scope,
     if where == "/moe/":
         assert any("/layer_0/" in n for n in named)
         assert any("/layer_1/" in n for n in named)
+
+
+def test_the_noise_of_block_diffusion_has_its_scope(decoder_step):
+    """`bd_noise` (what `bd_noise_ms` of the chip benchmark sums) lies
+    inside the forward scope of a step trained by diffusion over blocks,
+    holds the draws, and is no part of a next-id step; attention's calls
+    stay under `attn_fused` and the weighted loss under `tpudist_loss`."""
+    named = [(line, n) for line, n in decoder_step
+             if f"/{scopes.BD_NOISE}/" in n]
+    diffusion = any("noise_key" in line or "/bd_noise/" in n
+                    for line, n in decoder_step)
+    if not diffusion:
+        assert not named
+        return
+    assert named and all(scopes.FORWARD in n for _, n in named)
+    assert all(phase_of(n) == "fwd" for _, n in named)
+    # the draws: threefry's bits, or the rng's own instruction
+    assert any("threefry" in n or "random_bits" in n or "rng" in line
+               for line, n in named)
+    assert not any(f"/{scopes.ATTN_FUSED}/" in n for _, n in named)
+    # (the kernels are interpreted here: their operations carry the scope)
+    fused = [n for _, n in decoder_step if f"/{scopes.ATTN_FUSED}/" in n]
+    assert fused and all("/self_attention/" in n for n in fused)
+
+
+def test_the_noise_counters_are_a_models_counters():
+    """`bd_masked_share` and `bd_weight_sum` ride the step's metrics through
+    the drain to `telemetry.counters()`, as an expert layer's do."""
+    from tpudist import telemetry
+    from tpudist.trainer import _MetricDrain
+    from tpudist.utils import AverageMeter
+    assert {scopes.BD_MASKED, scopes.BD_WEIGHT} <= set(scopes.MODEL_COUNTERS)
+    assert (scopes.BD_NOISE, scopes.BD_MASKED, scopes.BD_WEIGHT) == (
+        "bd_noise", "bd_masked_share", "bd_weight_sum")
+    drain = _MetricDrain({"loss": AverageMeter("Loss")})
+    before = {k: len(telemetry.counters().get(k, []))
+              for k in (scopes.BD_MASKED, scopes.BD_WEIGHT)}
+    drain.push({"loss": 1.0, scopes.BD_MASKED: 0.5, scopes.BD_WEIGHT: 1.25},
+               n=2, step=3)
+    drain.drain()
+    assert telemetry.counters()[scopes.BD_MASKED][
+        before[scopes.BD_MASKED]:] == [0.5]
+    assert telemetry.counters()[scopes.BD_WEIGHT][
+        before[scopes.BD_WEIGHT]:] == [1.25]
+
+
+def test_the_dispatch_line_names_the_mask(tmp_path, capsys):
+    """The trainer's `attention dispatch` line and decision of a model
+    trained by diffusion over blocks: the mask kind, the block length, and
+    the plan's heads a program, blocks and fill."""
+    from tpudist.config import from_args
+    from tpudist.trainer import Trainer
+    cfg = from_args([
+        "--synthetic", "-a", "sdar_tiny", "--seq-len", "32", "-b", "16",
+        "--layers", "2", "--optimizer", "adamw", "--flash", "on", "--remat",
+        "-j", "2", "--no-telemetry", "--outpath", str(tmp_path / "out"),
+        "--overwrite", "delete", "--seed", "0"])
+    trainer = Trainer(cfg, writer=None)
+    dec = trainer.flash_decision
+    assert dec["kernel"] == "flash" and dec["key"].endswith("_train_bd4")
+    assert "_t64_" in dec["key"]                # the doubled row's positions
+    plan, = dec["programs"]
+    assert (plan["mask"], plan["block_length"]) == ("block_diffusion", 4)
+    assert plan["heads_per_program"] == 4 and 0.0 < plan["band_fill"] <= 1.0
+    log = open(os.path.join(cfg.outpath, "experiment.log")).read()
+    assert (f"heads_per_program 4 block_q {plan['block_q']} block_k "
+            f"{plan['block_k']} band_fill {plan['band_fill']} mask "
+            f"block_diffusion block_length 4") in log
 
 
 @pytest.mark.parametrize("op_name,phase", [

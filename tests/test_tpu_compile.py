@@ -49,6 +49,11 @@ FLASH_SHAPES = ((128, 197, 12, 64),      # ViT-B/16 @224
 # key-value heads, head_dim, window): two sequences of 8,192 with 32 heads
 # over 4 of 128, a sliding-window layer and a full one.
 FLASH_GQA_SHAPES = ((2, 8192, 32, 4, 128, 1024), (2, 8192, 32, 4, 128, None))
+# The same schedule under the mask of training by diffusion over blocks
+# (batch, positions of the doubled row, query heads, key-value heads,
+# head_dim, block length): two rows of 8,192 ids, each a noised and a
+# clean copy.
+FLASH_BD_SHAPES = ((2, 16384, 32, 4, 128, 4),)
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -100,6 +105,16 @@ def _flash_gqa_fn(bwd: bool, window):
     return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
 
 
+def _flash_bd_fn(bwd: bool, length: int, block: int):
+    from tpudist.ops.pallas.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return flash_attention(q, k, v, block_diffusion=(length, block),
+                               interpret=False).astype(jnp.float32).sum()
+
+    return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
+
+
 def _grouped_fn(bwd: bool):
     from tpudist.ops.pallas.grouped_matmul import grouped_matmul
 
@@ -130,6 +145,10 @@ _KERNEL_CASES = (
                        f"{'w%d_' % shape[5] if shape[5] else ''}"
                        f"{'fwdbwd' if bwd else 'fwd'}")
        for shape in FLASH_GQA_SHAPES for bwd in (False, True)]
+    + [pytest.param(("flash_bd",) + shape, bwd,
+                    id=f"flash_bd_t{shape[1]}_h{shape[2]}_kv{shape[3]}_"
+                       f"bl{shape[5]}_{'fwdbwd' if bwd else 'fwd'}")
+       for shape in FLASH_BD_SHAPES for bwd in (False, True)]
     # the grouped products of an expert layer's pair buffer: 131,072 rows
     # (the worst case of two sequences of 8,192 with 8 experts a token),
     # 16 experts held, hidden 2,304 <-> expert width 896, both directions
@@ -159,11 +178,12 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
         args = [S((rows, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
                 S((groups,), jnp.int32)]
         fn = _grouped_fn(bwd)
-    elif case[0] == "flash_gqa":
+    elif case[0] in ("flash_gqa", "flash_bd"):
         b, t, h, hkv, d, window = case[1:]
         args = [S((b, t, h, d), jnp.bfloat16)] + [
             S((b, t, hkv, d), jnp.bfloat16)] * 2
-        fn = _flash_gqa_fn(bwd, window)
+        fn = (_flash_gqa_fn(bwd, window) if case[0] == "flash_gqa"
+              else _flash_bd_fn(bwd, t // 2, window))
     else:
         args = [S(case[1:], jnp.bfloat16)] * 3
         fn = _flash_fn(bwd)
@@ -174,6 +194,15 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
         # the product; or its two transposes (dx, dw: a sum's gradient
         # needs no forward)
         assert text.count("tpu_custom_call") == (2 if bwd else 1)
+        return
+    if case[0] == "flash_bd":
+        # the same three kernels; what they claim lies between the pairs the
+        # mask allows, L (L + block) a head and a row, and a quarter more
+        # (blocks of 512 x 1,024: a fill of 0.80)
+        assert text.count("tpu_custom_call") == (3 if bwd else 1)
+        length, block = t // 2, window
+        least = (6 if bwd else 2) * 2 * b * h * length * (length + block) * d
+        assert least <= compiled.cost_analysis()["flops"] <= 1.3 * least
         return
     if case[0] == "flash_gqa":
         # forward, dQ and dKV kernels; what they claim is the blocks inside
@@ -338,23 +367,35 @@ def test_vit_b16_flash_step_compiles_for_v5e(topo, monkeypatch, tp):
 
 
 @pytest.mark.slow
-def test_decoder_step_compiles_for_v5e(topo, monkeypatch):
-    """The whole step of the benchmark's decoder cell (one chip's share of
-    Mellum2-12B-A2.5B: 4 layers, 16 of 64 experts, a quarter of the
-    vocabulary, two rows of 8,192 ids, ``--remat``, the streaming attention
-    kernel): it fits, 11.04 GiB of the chip's 15.75 (11.80 before PR 33:
-    four layers' logsumexp lay lane-padded, 268 MB each), and inside each
-    rematerialised layer no loop copies a pair buffer (a carry that XLA
-    could not update in place cost 29 ms a step a loop, on the chip)."""
+@pytest.mark.parametrize("name,most_gib", [
+    ("mellum2_12b_ep4", 11.2), ("sdar_30b_ep8", 14.5)])
+def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
+    """The whole step of a benchmark's decoder cell, as its configuration's
+    ``trainer_argv`` builds it (two rows of 8,192 ids, ``--remat``, the
+    streaming attention kernel); its bytes are printed (``-s``).
+
+    ``mellum2_12b_ep4`` (one chip's share of Mellum2-12B-A2.5B: 4 layers, 16
+    of 64 experts, a quarter of the vocabulary): it fits, 11.04 GiB of the
+    chip's 15.75 (11.80 before PR 33: four layers' logsumexp lay
+    lane-padded, 268 MB each). ``sdar_30b_ep8`` (one chip's share of
+    SDAR-30B-A3B-Chat, trained by diffusion over blocks: 4 layers, 16 of
+    128 experts, an eighth of the vocabulary, each row a noised and a clean
+    copy, 16,384 positions): it fits too, and no score tensor of the doubled
+    row by itself or by a row is in the step.
+
+    Inside each rematerialised layer no loop copies a pair buffer (a carry
+    that XLA could not update in place cost 29 ms a step a loop, on the
+    chip)."""
     import json
     import re
 
     from tpudist.config import from_args
     from tpudist.models import create_model
     from tpudist.train import compute_dtype, make_train_step
+    from tpudist.trainer import _parse_share
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "chip", "configs",
-                           "mellum2_12b_ep4.json")) as f:
+                           name + ".json")) as f:
         argv = [str(a).format(batch=2, seed=0, outpath="unused")
                 for a in json.load(f)["trainer_argv"]]
     cfg = from_args(argv)
@@ -362,7 +403,9 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch):
     model = create_model(cfg.arch, num_classes=cfg.num_classes,
                          dtype=compute_dtype(cfg), remat=True,
                          flash=True).clone(
-        layers=4, expert_share=(0, 4), vocab_share=(0, 4))
+        layers=cfg.layers,
+        expert_share=_parse_share(cfg.expert_share, "--expert-share"),
+        vocab_share=_parse_share(cfg.vocab_share, "--vocab-share"))
     ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32,
                                sharding=NamedSharding(mesh, P("data")))
     lr = jax.ShapeDtypeStruct((), jnp.float32,
@@ -374,10 +417,16 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch):
     compiled = make_train_step(mesh, model, cfg).lower(
         _abstract_state(model, cfg, mesh), ids, ids, lr).compile()
     ma = compiled.memory_analysis()
-    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            + ma.temp_size_in_bytes - ma.alias_size_in_bytes) < 11.2 * 2**30
+    step_bytes = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    print(f"{name}: step {step_bytes} bytes = {step_bytes / 2**30:.4f} GiB "
+          f"(arguments {ma.argument_size_in_bytes}, temporaries "
+          f"{ma.temp_size_in_bytes}, aliased {ma.alias_size_in_bytes})")
+    assert step_bytes < most_gib * 2**30
     text = compiled.as_text()
-    pairs = 2 * cfg.seq_len * 8
+    diffusion = model.objective == "block_diffusion"
+    positions = cfg.seq_len * (2 if diffusion else 1)
+    pairs = 2 * positions * 8
     assert not re.search(rf"= (?:bf16|f32)\[{pairs},\d+\]\S* copy\(", text)
     # around the attention kernels (PR 33): the row statistics carry the
     # group on their minor dimension and delta is taken in the dQ pass, so
@@ -387,7 +436,9 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch):
     assert sum("tpu_custom_call" in line for line in under) == 3 * 4
     for line in under:
         made = line.split(" = ", 1)[-1].split("(", 1)[0]
-        assert not re.search(rf"f32\[[\d,]*{cfg.seq_len},1\]", made), \
+        assert not re.search(rf"f32\[[\d,]*{positions},1\]", made), \
             line[:200]
-        assert not re.search(rf"f32\[2,(32,{cfg.seq_len}|{cfg.seq_len},32),"
-                             r"128\]", made), line[:200]
+    if diffusion:
+        # no [.., 2L, 2L] and no [.., 2L, L] tensor anywhere in the step
+        assert not re.search(
+            rf"\[(?:\d+,)*{positions},(?:{positions}|{cfg.seq_len})\]", text)

@@ -1,5 +1,7 @@
-"""A causal decoder of tokens with sparse experts and mixed attention: the
-first language model of the zoo (the zoo's other families classify images).
+"""A decoder of tokens with sparse experts and mixed attention: the
+language models of the zoo (the zoo's other families classify images),
+trained to predict the next id under a causal mask or by diffusion over
+blocks; which, the registered model says (``objective``).
 
 A layer is ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``:
 grouped-query attention (RMSNorm over ``head_dim`` on q and k, then RoPE by
@@ -7,6 +9,25 @@ the table of the layer's type: ``sliding_attention`` sees the nearest
 ``sliding_window`` keys, ``full_attention`` all before it), and a top-k
 layer of SwiGLU experts (``parallel/moe.py::moe_topk_held``). The model is an
 embedding, the layers, a final RMSNorm and an untied head.
+
+**Diffusion over blocks** (``objective="block_diffusion"``; SDAR,
+arXiv:2510.06303, trained as BD3-LM's vectorised form, arXiv:2503.09573,
+with LLaDA's masking and ``1 / t`` weights, arXiv:2502.09992). A row ``x_0``
+of ``L`` ids in blocks of ``block_length``: each block draws ``t ~ U[eps,
+1]``, each of its positions is masked with probability ``t`` (``x_t``:
+``MASK`` there, the id elsewhere; ``block_noise``). One pass sees ``[x_t ;
+x_0]``, ``2 L`` positions that count ``0 .. L - 1`` twice, under the mask
+``block_diffusion = (L, block_length)`` that attention is handed as a
+statement (``parallel/ring_attention.py::block_diffusion_mask``), never as
+an array: a noised position sees its own noised block and the clean blocks
+before it. The head reads the noised half only, and the loss is ``sum(m / t
+* cross entropy against x_0) / (rows * L)``: a masked position's logits
+predict that position's id, no shift. The draws come from a non-trainable
+leaf of the state, ``batch_stats["noise_key"]`` (a raw ``uint32[2]`` key,
+drawn at initialisation and split every training step), so a restored run
+draws the masks it would have drawn. Without ``targets`` such a model reads
+its row under the clean copy's rule alone (causal by blocks), as generation
+does.
 
 **A deployment's share.** The published widths live with the registered
 name (``mellum2_12b_a2_5b``). What one chip of a deployment holds arrives as
@@ -22,7 +43,8 @@ and activations in ``dtype``.
 
 Given ``targets`` the model takes the loss itself (``ops.lm_head_loss``: the
 head and the cross entropy a chunk of positions at a time) and returns a
-``Scored``; without, logits ``[rows, T, vocabulary held]``.
+``Scored``; without, logits ``[rows, T, vocabulary held]``. (Under diffusion
+the row is its own target: ``targets`` only says that a loss is wanted.)
 """
 
 from __future__ import annotations
@@ -31,6 +53,7 @@ from typing import Any, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from tpudist.obs import scopes
@@ -65,12 +88,35 @@ def _share(count: int, share: tuple[int, int], what: str) -> tuple[int, int]:
     return i * (count // n), count // n
 
 
+def block_noise(key: jax.Array, tokens: jax.Array, block: int, eps: float,
+                mask_id: int):
+    """One step's noise of training by diffusion over blocks, from the raw
+    ``uint32[2]`` key the state holds: ``(the key the state holds next, x_t,
+    weights, masked)`` for ``tokens`` [rows, L]. ``next, use = split(key)``;
+    ``k_t, k_m = split(use)``; a block's ``t = uniform(k_t, [rows, ceil(L /
+    block)], eps, 1)``; position ``i`` is masked where ``uniform(k_m, [rows,
+    L]) < t`` of its block; ``x_t`` holds ``mask_id`` there; ``weights = 1 /
+    t`` there, 0 elsewhere. All float32: a reference that follows this
+    derivation draws the same masks bit for bit."""
+    rows, length = tokens.shape
+    carry, use = jax.random.split(key)
+    k_t, k_m = jax.random.split(use)
+    t = jax.random.uniform(k_t, (rows, -(-length // block)), jnp.float32,
+                           minval=eps, maxval=1.0)
+    t = jnp.repeat(t, block, axis=1)[:, :length]
+    masked = jax.random.uniform(k_m, (rows, length), jnp.float32) < t
+    return (carry, jnp.where(masked, mask_id, tokens),
+            jnp.where(masked, 1.0 / t, 0.0), masked)
+
+
 class GroupedQueryAttention(nn.Module):
     num_heads: int
     num_kv_heads: int
     head_dim: int
     rope_parameters: Any                 # this layer type's entry
     window: Optional[int] = None
+    # (L, block): the row is a noised copy of L ids, then the clean ids
+    block_diffusion: Optional[tuple] = None
     eps: float = 1e-6
     dtype: Any = None
     flash: bool = False
@@ -79,6 +125,13 @@ class GroupedQueryAttention(nn.Module):
     def __call__(self, x: jax.Array) -> jax.Array:
         b, t, _ = x.shape
         dt = self.dtype or x.dtype
+        positions, mask = t, dict(causal=True, window=self.window)
+        if self.block_diffusion is not None:
+            mask = dict(block_diffusion=tuple(self.block_diffusion))
+            noisy = self.block_diffusion[0]
+            # each copy counts its positions from 0
+            positions = np.concatenate([np.arange(noisy),
+                                        np.arange(t - noisy)])
 
         def proj(heads, name):
             return nn.Dense(heads * self.head_dim, use_bias=False, dtype=dt,
@@ -87,7 +140,8 @@ class GroupedQueryAttention(nn.Module):
         q = proj(self.num_heads, "q_proj")
         k = proj(self.num_kv_heads, "k_proj")
         v = proj(self.num_kv_heads, "v_proj")
-        cos, sin = rope.tables(dict(self.rope_parameters), self.head_dim, t)
+        cos, sin = rope.tables(dict(self.rope_parameters), self.head_dim,
+                               positions)
         q = rope.apply(RMSNorm(self.eps, name="q_norm")(q).astype(dt),
                        cos, sin)
         k = rope.apply(RMSNorm(self.eps, name="k_norm")(k).astype(dt),
@@ -97,10 +151,9 @@ class GroupedQueryAttention(nn.Module):
             # only, so the XLA path, and no kernel is built for that length)
             from tpudist.ops.pallas import flash_attention
             with jax.named_scope(scopes.ATTN_FUSED):
-                out = flash_attention(q, k, v, causal=True,
-                                      window=self.window)
+                out = flash_attention(q, k, v, **mask)
         else:
-            out = attention(q, k, v, causal=True, window=self.window)
+            out = attention(q, k, v, **mask)
         return nn.Dense(x.shape[-1], use_bias=False, dtype=dt,
                         kernel_init=_init, name="o_proj")(
                             out.reshape(b, t, -1))
@@ -172,6 +225,10 @@ class MoEDecoder(nn.Module):
     layers: int = 0                      # leading layers kept (0: all)
     expert_share: tuple = (0, 1)         # (i, n): the i-th of n holders
     vocab_share: tuple = (0, 1)
+    # what it is trained to do
+    objective: str = "next_id"           # | "block_diffusion"
+    block_length: int = 0                # positions a block (diffusion)
+    noise_eps: float = 1e-3              # the least t a block draws
     # how it runs
     dtype: Any = None
     flash: bool = False                  # the Pallas streaming kernel
@@ -185,6 +242,11 @@ class MoEDecoder(nn.Module):
         return _share(self.vocab_size, tuple(self.vocab_share),
                       "vocabulary")[1]
 
+    @property
+    def mask_id(self) -> int:
+        """The id a noised position holds: the last of the slice held."""
+        return self.vocab_held - 1
+
     def example_input(self) -> jax.Array:
         """What ``create_train_state`` initialises on: one short row."""
         return jnp.zeros((1, 16), jnp.int32)
@@ -192,24 +254,56 @@ class MoEDecoder(nn.Module):
     def attention_workloads(self, seq_len: int) -> list[dict]:
         """The attention shapes a step runs, one a layer type kept."""
         kept = self.layer_types[:self.layers or self.num_layers]
-        return [dict(seq=seq_len, heads=self.num_heads,
-                     kv_heads=self.num_kv_heads, head_dim=self.head_dim,
-                     causal=True,
+        shape = dict(heads=self.num_heads, kv_heads=self.num_kv_heads,
+                     head_dim=self.head_dim)
+        if self.objective == "block_diffusion":
+            # one mask whatever the layer's type: the doubled row's
+            return [dict(shape, seq=2 * seq_len, causal=False, window=None,
+                         block_diffusion=(seq_len, self.block_length))]
+        return [dict(shape, seq=seq_len, causal=True,
                      window=(self.sliding_window
                              if kind == "sliding_attention" else None))
                 for kind in dict.fromkeys(kept)]
 
     @nn.compact
     def __call__(self, tokens: jax.Array, train: bool = False,
-                 targets: Optional[jax.Array] = None):
+                 targets: Optional[jax.Array] = None,
+                 noised: Optional[jax.Array] = None):
+        """``noised`` (diffusion only, for a caller that noises the row
+        itself): the copy ``x_t`` of ``tokens`` to run beside it; the logits
+        returned are the noised half's."""
         dt = self.dtype or jnp.float32
         first_expert, held = _share(self.num_experts,
                                     tuple(self.expert_share), "experts")
         kept = self.layers or self.num_layers
+        counters, mask, weights = {}, None, None
+        length = tokens.shape[1]
+        if self.objective == "block_diffusion":
+            key = self.variable(
+                "batch_stats", "noise_key", lambda: _raw_key(
+                    self.make_rng("params")))
+            with jax.named_scope(scopes.BD_NOISE):
+                if targets is not None and noised is None:
+                    carry, noised, weights, masked = block_noise(
+                        key.value, tokens, self.block_length,
+                        self.noise_eps, self.mask_id)
+                    counters = {
+                        scopes.BD_MASKED: jnp.mean(
+                            masked.astype(jnp.float32)),
+                        scopes.BD_WEIGHT: jnp.sum(weights) / masked.size}
+                    if train and self.is_mutable_collection("batch_stats"):
+                        key.value = carry
+                # [x_t ; x_0] under the doubled row's mask; a row alone
+                # under the clean copy's rule
+                mask = (0 if noised is None else length, self.block_length)
+                if noised is not None:
+                    tokens = jnp.concatenate([noised, tokens], axis=1)
+        elif self.objective != "next_id" or noised is not None:
+            raise ValueError(f"objective {self.objective!r} (next_id | "
+                             f"block_diffusion; noised: {noised is not None})")
         with jax.named_scope(scopes.LM_EMBED):
             x = nn.Embed(self.vocab_held, self.hidden_size,
                          embedding_init=_init, dtype=dt, name="embed")(tokens)
-        counters = {}
         for i, kind in enumerate(self.layer_types[:kept]):
             if kind not in ("sliding_attention", "full_attention"):
                 raise ValueError(f"layer {i}: unknown layer type {kind!r}")
@@ -230,7 +324,7 @@ class MoEDecoder(nn.Module):
                           rope_parameters=self.rope_parameters[kind],
                           window=(self.sliding_window
                                   if kind == "sliding_attention" else None),
-                          flash=self.flash),
+                          block_diffusion=mask, flash=self.flash),
                 experts=dict(num_experts=self.num_experts,
                              top_k=self.experts_per_token,
                              width=self.expert_width,
@@ -238,6 +332,8 @@ class MoEDecoder(nn.Module):
                 eps=self.rms_norm_eps, dtype=dt, name=f"layer_{i}")(x)
             counters.update({f"{k}.layer_{i}": v
                              for k, v in layer_counters.items()})
+        if noised is not None:
+            x = x[:, :length]            # the head reads the noised half
         x = RMSNorm(self.rms_norm_eps, name="norm")(x).astype(dt)
         head = self.param("head", _init,
                           (self.hidden_size, self.vocab_held), jnp.float32)
@@ -245,8 +341,20 @@ class MoEDecoder(nn.Module):
             with jax.named_scope(scopes.LM_HEAD):
                 return jnp.dot(x, head.astype(dt),
                                preferred_element_type=jnp.float32)
-        loss, acc1 = lm_head_loss(x, head, targets, self.loss_chunk)
+        if weights is not None:
+            # a masked position's logits against that position's clean id,
+            # m / t each, over rows x L
+            targets = tokens[:, length:]
+        loss, acc1 = lm_head_loss(x, head, targets, self.loss_chunk,
+                                  weights=weights)
         return Scored(loss, acc1, counters)
+
+
+def _raw_key(key: jax.Array) -> jax.Array:
+    """A key as the raw ``uint32[2]`` a state can hold as numbers."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return key
 
 
 def _own(kw: dict) -> dict:
@@ -292,3 +400,37 @@ def mellum2_tiny(dtype: Any = None, **kw) -> MoEDecoder:
         layer_types=("sliding_attention", "full_attention") * 2,
         rope_parameters=published.rope_parameters, sliding_window=8,
         rms_norm_eps=1e-6, dtype=dtype, **_own(kw))
+
+
+def sdar_30b_a3b(dtype: Any = None, **kw) -> MoEDecoder:
+    """SDAR-30B-A3B-Chat (JetLM; ``config.json`` of
+    huggingface.co/JetLM/SDAR-30B-A3B-Chat, ``model_type`` ``sdar_moe``): 48
+    layers of hidden 2,048, 32 query heads over 4 key-value heads of 128,
+    every layer 128 experts of width 768 with 8 a token (no dense layer, no
+    shared expert), full attention with plain RoPE (theta 1e6), vocabulary
+    151,936, untied. Trained by diffusion over blocks (arXiv:2510.06303);
+    the block length 4 is the released Chat model's default, ``eps`` 1e-3
+    LLaDA's (neither is in the config)."""
+    return MoEDecoder(
+        vocab_size=151936, hidden_size=2048, num_layers=48, num_heads=32,
+        num_kv_heads=4, head_dim=128, num_experts=128, experts_per_token=8,
+        expert_width=768, layer_types=("full_attention",) * 48,
+        rope_parameters={"full_attention": {"rope_type": "default",
+                                            "rope_theta": 1000000}},
+        sliding_window=0, rms_norm_eps=1e-6, objective="block_diffusion",
+        block_length=4, noise_eps=1e-3, dtype=dtype, **_own(kw))
+
+
+def sdar_tiny(dtype: Any = None, **kw) -> MoEDecoder:
+    """The CPU tests' twin of the model above at toy widths (hidden 64, 8
+    heads over 2 of 16, 16 experts of width 32 with 2 a token, blocks of 4,
+    256 ids), as ``mellum2_tiny`` is of its model: never a benchmark
+    configuration."""
+    kw.setdefault("loss_chunk", 64)
+    return MoEDecoder(
+        vocab_size=256, hidden_size=64, num_layers=4, num_heads=8,
+        num_kv_heads=2, head_dim=16, num_experts=16, experts_per_token=2,
+        expert_width=32, layer_types=("full_attention",) * 4,
+        rope_parameters=sdar_30b_a3b().rope_parameters, sliding_window=0,
+        rms_norm_eps=1e-6, objective="block_diffusion", block_length=4,
+        noise_eps=1e-3, dtype=dtype, **_own(kw))

@@ -45,6 +45,9 @@ MOE_COMBINE = "moe_combine"            # weighted sum back to tokens
 # a language model's ends
 LM_EMBED = "lm_embed"                  # the embedding's rows
 LM_HEAD = "lm_head"                    # the output head's product, a chunk
+# training by diffusion over blocks, inside the forward scope: the draws of
+# t and of the masked positions, x_t, the doubled row, the loss's weights
+BD_NOISE = "bd_noise"
 
 # -- counters a model hands the step ----------------------------------------
 # One scalar a layer and a step (`<name>.layer_<l>`), through the step's
@@ -52,7 +55,10 @@ LM_HEAD = "lm_head"                    # the output head's product, a chunk
 MOE_PAIRS = "moe_pairs"                # pairs the held experts computed
 MOE_LOAD = "moe_load_max_over_mean"    # the fullest held expert over the mean
 MOE_WALKED = "moe_rows_walked"         # buffer rows the block loops touched
-MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED)
+# one scalar a step (no layer): training by diffusion over blocks
+BD_MASKED = "bd_masked_share"          # masked positions over rows x L
+BD_WEIGHT = "bd_weight_sum"            # sum of masked / t over rows x L
+MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED, BD_MASKED, BD_WEIGHT)
 
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)

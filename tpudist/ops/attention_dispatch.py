@@ -40,6 +40,8 @@ CLIENT = "attention_dispatch"
 NAMES = ("flash", "xla")
 # what the event says of each attention workload's programs (``program``)
 PROGRAM_FIELDS = ("heads_per_program", "block_q", "block_k", "band_fill")
+# and of a program under the mask of training by diffusion over blocks
+MASK_FIELDS = ("mask", "block_length")
 
 # Re-exported so existing callers (bench_flash's timing rows, tests, tools)
 # keep ONE surface; these ARE the generic layer's objects — no copies.
@@ -57,22 +59,26 @@ clear_cache = partial(dispatch.clear_cache, CLIENT)
 
 def shape_key(batch: int, seq: int, heads: int, head_dim: int, dtype,
               train: bool, causal: bool, kv_heads: Optional[int] = None,
-              window: Optional[int] = None) -> str:
+              window: Optional[int] = None,
+              block_diffusion: Optional[tuple] = None) -> str:
     """The dispatch identity: the exact attention workload. ``dtype`` may be
     a jnp/numpy dtype, scalar type, or string — normalized to the canonical
     dtype name so every spelling of bfloat16 keys the same cache entry.
     Fewer key-value heads than query heads and a window are part of the
-    identity (``_kv4``, ``_w1024`` after the head count and the mask); a
-    workload with neither keeps the key it always had."""
+    identity (``_kv4``, ``_w1024`` after the head count and the mask), and
+    so is the mask of training by diffusion over blocks (``bd4`` for blocks
+    of 4 where ``causal`` / ``full`` stands; ``seq`` is then the doubled
+    row's); a workload with none of them keeps the key it always had."""
     try:
         import numpy as np
         name = np.dtype(dtype).name
     except TypeError:
         name = getattr(dtype, "name", None) or str(dtype)
     grouped = f"_kv{kv_heads}" if kv_heads not in (None, heads) else ""
+    mask = (f"bd{block_diffusion[1]}" if block_diffusion is not None
+            else "causal" if causal else "full")
     return (f"b{batch}_t{seq}_h{heads}{grouped}_d{head_dim}_{name}_"
-            f"{'train' if train else 'eval'}_"
-            f"{'causal' if causal else 'full'}"
+            f"{'train' if train else 'eval'}_{mask}"
             + (f"_w{window}" if window is not None else ""))
 
 
@@ -237,6 +243,9 @@ def event_fields(decision: dict) -> dict:
         # one entry an attention workload, in the shape keys' order
         for f in PROGRAM_FIELDS:
             out[f] = [p[f] for p in decision["programs"]]
+        for f in MASK_FIELDS:       # where a program's mask is of its own kind
+            if any(f in p for p in decision["programs"]):
+                out[f] = [p.get(f) for p in decision["programs"]]
     if decision.get("cache_hit"):
         out["cache_hit"] = 1
     if decision.get("reason"):

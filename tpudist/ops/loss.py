@@ -16,7 +16,8 @@ class Scored(NamedTuple):
     logits (a language model given ``targets``: its logits are never
     whole). ``cross_entropy_loss`` and ``ops.accuracy`` read it as they
     read logits; ``counters`` ride the step's metrics to the drain."""
-    loss: jax.Array            # mean over every position, float32
+    loss: jax.Array            # float32: the mean over every position, or
+                               # what a weighted objective sums (lm_head_loss)
     acc1: jax.Array            # top-1 of the target, percent
     counters: dict             # name -> scalar, a step
 
@@ -52,15 +53,22 @@ def cross_entropy_loss(logits: jax.Array | Scored, targets: jax.Array,
 
 
 def lm_head_loss(hidden: jax.Array, kernel: jax.Array, targets: jax.Array,
-                 chunk: int = 2048) -> tuple[jax.Array, jax.Array]:
-    """Output head and next-id cross entropy of a language model, taken
-    ``chunk`` positions at a time so that the logits are never whole
+                 chunk: int = 2048, weights: jax.Array | None = None,
+                 normaliser: float | None = None
+                 ) -> tuple[jax.Array, jax.Array]:
+    """Output head and cross entropy of a language model, taken ``chunk``
+    positions at a time so that the logits are never whole
     (``[16384, 24576]`` float32 would be 1.6 GB, twice with the cotangent):
     ``hidden`` [rows, T, d] x ``kernel`` [d, V] against ``targets``
     [rows, T]. Each chunk's logits are made again in the backward pass
     (``jax.checkpoint``), products in ``hidden``'s dtype accumulated in
-    float32, the loss in float32. Returns (mean loss over all rows x T
-    positions, top-1 accuracy in percent)."""
+    float32, the loss in float32.
+
+    Without ``weights``: (mean loss over all rows x T positions, top-1
+    accuracy in percent over them). With ``weights`` [rows, T] float32 (a
+    diffusion objective's ``masked / t``): the sum of ``weight x cross
+    entropy`` over ``normaliser`` (rows x T where not given), and the
+    accuracy over the positions whose weight is not zero (0 where none)."""
     rows, t, d = hidden.shape
     n = rows * t
     # the largest chunk up to the asked size that divides the positions
@@ -69,18 +77,29 @@ def lm_head_loss(hidden: jax.Array, kernel: jax.Array, targets: jax.Array,
 
     @jax.checkpoint
     def one(carry, xs):
-        h, y = xs
+        h, y, *weight = xs
         with jax.named_scope(scopes.LM_HEAD):
             logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
         with jax.named_scope(scopes.LOSS):
             nll = (jax.nn.logsumexp(logits, axis=-1)
                    - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0])
+            if weight:
+                nll = nll * weight[0]
         with jax.named_scope(scopes.METRICS):
-            hits = jnp.sum(jnp.argmax(logits, axis=-1) == y)
+            hit = jnp.argmax(logits, axis=-1) == y
+            if weight:
+                hit &= weight[0] != 0
+            hits = jnp.sum(hit)
         return (carry[0] + jnp.sum(nll), carry[1] + hits), None
 
+    xs = (hidden.reshape(n // chunk, chunk, d),
+          targets.reshape(n // chunk, chunk))
+    if weights is not None:
+        xs += (weights.astype(jnp.float32).reshape(n // chunk, chunk),)
     (total, hits), _ = jax.lax.scan(
-        one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
-        (hidden.reshape(n // chunk, chunk, d),
-         targets.reshape(n // chunk, chunk)))
-    return total / n, hits.astype(jnp.float32) * (100.0 / n)
+        one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)), xs)
+    if weights is None:
+        return total / n, hits.astype(jnp.float32) * (100.0 / n)
+    scored = jnp.maximum(jnp.sum(weights != 0), 1).astype(jnp.float32)
+    return (total / (normaliser or n),
+            hits.astype(jnp.float32) * 100.0 / scored)

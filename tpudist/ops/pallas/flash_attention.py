@@ -58,6 +58,11 @@ hierarchy):
   nothing, its offset staying where it was.
 - the per-row logsumexp leaves as [B, Hkv, T, G] float32 (the members on the
   minor dimension: the block's full dimension).
+- a third shape of what a resident block needs (rev 6): under the mask of
+  training by diffusion over blocks (``block_diffusion = (L, block)``: a
+  noised and a clean copy of a row in one self-attention) it is two pieces
+  of the streamed side, not one band (``_DiffusionBand``); the same
+  kernels walk the first piece's steps and then the second's.
 
 Streaming backward (the two-pass schedule):
 
@@ -130,7 +135,9 @@ _LANES = 128
 #          query heads; blocks from (T, window, group); no mask inside the
 #          band; steps that slide with a window's band (element offsets);
 #          delta taken in the dQ pass; K scaled in the dKV pass.
-KERNEL_REV = 5
+#   rev 6: the streaming schedule takes the mask of training by diffusion
+#          over blocks: two pieces of the streamed side a resident block.
+KERNEL_REV = 6
 
 # the streaming forward's results, as ``jax.ad_checkpoint`` names them
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
@@ -181,6 +188,8 @@ class _Band:
     the two aligned blocks of 1,024 its band straddles. A step past the
     high end runs nothing, and its offset stays at the last step's, so the
     pipeline fetches nothing for it either."""
+
+    copies = 1          # one row of positions (``_DiffusionBand`` lays two)
 
     def __init__(self, *, causal, window, block_q, block_k, q_len, k_len,
                  stream="k"):
@@ -305,6 +314,176 @@ class _Band:
             allowed = int(np.maximum(
                 np.minimum(last, self.k_len - 1) - first + 1, 0).sum())
         return allowed / (self.pairs() * self.bq * self.bk)
+
+
+def _pick(cond, a, b):
+    """``a if cond else b`` for a static condition, ``jnp.where`` for one on
+    the device (inside a kernel or an index map)."""
+    if isinstance(cond, (bool, np.bool_)):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+_FAR = 1 << 30      # further than any block number: a bound that never binds
+
+
+class _DiffusionBand:
+    """``_Band``'s statements for the mask of training by diffusion over
+    blocks (``parallel/ring_attention.py::block_diffusion_mask``): a
+    self-attention over ``2 L`` positions, a noised copy of a row then the
+    clean row, in blocks of ``block`` positions. With ``n()`` a position's
+    block within its copy, a noisy query sees the noisy keys of its own
+    block and the clean keys of the blocks before it, a clean query the
+    clean keys up to its own block's end, and no query a noisy key of
+    another block: ``L (L + block)`` scores of ``4 L^2``.
+
+    Each copy is padded apart to ``half``, a multiple of both blocks
+    (``copies = 2``: ``_fit``), so a tile lies in one copy on either side.
+    What a resident block needs of the streamed side is then two PIECES, not
+    one band: a noisy q block its own diagonal blocks of the noisy keys and
+    a prefix of the clean ones, a clean k block (the dKV pass) the clean
+    rows from its diagonal on and the noisy rows past it; a clean q block
+    and a noisy k block need one. ``pieces`` states them in blocks of the
+    streamed side, the grid's steps walk the first piece and then the
+    second, and a step past both runs nothing and fetches nothing, as under
+    ``_Band``. A tile inside a piece builds no mask; the mask is built on
+    the tiles a block boundary of the mask crosses (every tile of the noisy
+    diagonal, the last of a clean prefix) and on a ragged length's last."""
+
+    causal, window, copies, masks = False, None, 2, True
+
+    def __init__(self, *, length, block, block_q, block_k, stream="k"):
+        self.length, self.block, self.stream = length, block, stream
+        self.bq = min(block_q, _ceil_to(length, 8))
+        self.bk = min(block_k, _ceil_to(length, 8))
+        self.half = _ceil_to(length, math.lcm(self.bq, self.bk))
+        self.q_len = self.k_len = 2 * length
+        self.tq_pad = self.tk_pad = 2 * self.half
+        self._res, self.b = ((self.bq, self.bk) if stream == "k"
+                             else (self.bk, self.bq))
+        self.n = 2 * (self.half // self._res)
+        self.granule = math.gcd(self.b, _LANES)
+        self.steps = max(1, max(a[1] + b[1] for a, b in
+                                map(self.pieces, range(self.n))))
+
+    def _n(self, x):
+        """The block of position ``x`` within its copy."""
+        if isinstance(x, (int, np.integer)):
+            return x // self.block
+        if self.block & (self.block - 1) == 0:       # vectors shift on the VPU
+            return jax.lax.shift_right_logical(
+                x, jnp.full_like(x, self.block.bit_length() - 1))
+        return x // self.block
+
+    def pieces(self, i):
+        """((first, count), (first, count)): the two runs of streamed blocks
+        (numbered over both copies) that resident block ``i`` holds an
+        allowed score with; a count of 0 where a piece is empty."""
+        per_half, ln, bl = self.half // self._res, self.length, self.block
+        noisy = i < per_half
+        lo = (i - _pick(noisy, 0, per_half)) * self._res
+        hi = _min(lo + self._res, ln)                # past its last true row
+        first, end = self._n(lo) * bl, _min((self._n(hi - 1) + 1) * bl, ln)
+        if self.stream == "k":
+            # noisy rows: their own blocks' noisy keys, then the clean keys
+            # before the last row's block; clean rows: the clean keys up to
+            # their last block's end
+            a = _pick(noisy, 0, 1), _pick(noisy, first, 0), end
+            b = 1, 0, _pick(noisy, self._n(hi - 1) * bl, 0)
+        else:
+            # noisy keys: the noisy rows of their own blocks; clean keys:
+            # the clean rows from their first block on, then the noisy rows
+            # past it
+            a = _pick(noisy, 0, 1), first, _pick(noisy, end, ln)
+            b = 0, first + bl, _pick(noisy, 0, ln)
+
+        def run(copy, lo_, hi_):
+            count = _pick((hi_ > lo_) & (lo < ln),
+                          (hi_ - 1) // self.b - lo_ // self.b + 1, 0)
+            return copy * (self.half // self.b) + lo_ // self.b, count
+        return run(*a), run(*b)
+
+    def step(self, i, s):
+        """(position of grid step ``s`` of resident block ``i``, whether it
+        runs, the position to fetch)."""
+        (first_a, count_a), (first_b, count_b) = self.pieces(i)
+
+        def at(step):
+            return jnp.where(step < count_a, first_a + step,
+                             first_b + step - count_a)
+        g = self.granule
+        fetch = jnp.clip(at(jnp.minimum(s, count_a + count_b - 1))
+                         * (self.b // g), 0, (self.tk_pad - self.b) // g) * g
+        return at(s) * self.b, s < count_a + count_b, fetch
+
+    def _tile(self, row0, col0):
+        """(first row and first key within their copies, and the bounds
+        ``lo, hi`` of the tile's rule: a query of block ``n`` sees the keys
+        of blocks ``n + lo .. n + hi``)."""
+        clean_q, clean_k = row0 >= self.half, col0 >= self.half
+        lo = _pick(clean_k, -_FAR, _pick(clean_q, _FAR, 0))
+        hi = _pick(clean_k, _pick(clean_q, 0, -1), _pick(clean_q, -_FAR, 0))
+        return (row0 - _pick(clean_q, self.half, 0),
+                col0 - _pick(clean_k, self.half, 0), lo, hi)
+
+    def interior(self, row0, col0):
+        """Whether every score of the tile at (row0, col0) is one the mask
+        allows: no padded key, and its first and last key's blocks inside
+        what its last true row and its first row may see."""
+        r, c, lo, hi = self._tile(row0, col0)
+        last = _min(r + self.bq, self.length) - 1
+        return ((c + self.bk <= self.length)
+                & (self._n(c) >= self._n(last) + lo)
+                & (self._n(c + self.bk - 1) <= self._n(r) + hi))
+
+    def valid(self, row0, col0):
+        """The mask of the tile at (row0, col0) (padded q rows need none:
+        ``_Band.valid``)."""
+        r, c, lo, hi = self._tile(row0, col0)
+        shape = (self.bq, self.bk)
+        cols = c + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        n_q = self._n(r + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+        n_k = self._n(cols)
+        valid = jnp.logical_and(n_k >= n_q + lo, n_k <= n_q + hi)
+        if self.half != self.length:
+            valid = jnp.logical_and(valid, cols < self.length)
+        return valid
+
+    def tiles(self) -> list[tuple[int, int]]:
+        """(first row, first key) of every tile that runs (static)."""
+        out = []
+        for i in range(self.n):
+            for first, count in self.pieces(i):
+                for s in range(first, first + count):
+                    at = (i * self._res, s * self.b)
+                    out.append(at if self.stream == "k" else at[::-1])
+        return out
+
+    def pairs(self) -> int:
+        return len(self.tiles())
+
+    def fill(self) -> float:
+        """Scores the mask allows (``L (L + block)`` where the blocks tile
+        the row) over scores the programs run (static)."""
+        ends = np.minimum((np.arange(self.length) // self.block + 1)
+                          * self.block, self.length)
+        return 2 * int(ends.sum()) / (self.pairs() * self.bq * self.bk)
+
+
+class _Mask(NamedTuple):
+    """The mask a streaming call states (static): causality, a window of it,
+    or diffusion over blocks ``(L, block)``."""
+    causal: bool = False
+    window: int | None = None
+    block_diffusion: tuple | None = None
+
+    def band(self, block_q, block_k, q_len, k_len, stream="k"):
+        if self.block_diffusion is not None:
+            length, block = self.block_diffusion
+            return _DiffusionBand(length=length, block=block, block_q=block_q,
+                                  block_k=block_k, stream=stream)
+        return _Band(causal=self.causal, window=self.window, block_q=block_q,
+                     block_k=block_k, q_len=q_len, k_len=k_len, stream=stream)
 
 
 def _ceil_to(x: int, m: int) -> int:
@@ -478,10 +657,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "block_q", "block_k", "block_q_bwd", "block_k_bwd",
-    "interpret"))
+    "causal", "window", "block_diffusion", "block_q", "block_k",
+    "block_q_bwd", "block_k_bwd", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, window: int | None = None,
+                    block_diffusion: tuple | None = None,
                     block_q: int | None = None, block_k: int | None = None,
                     block_q_bwd: int | None = None,
                     block_k_bwd: int | None = None,
@@ -496,7 +676,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     VMEM). ``window`` (static,
     with ``causal``) keeps, of the keys a query may see, the nearest
     ``window``; blocks wholly outside the band are neither run nor fetched
-    (``_Band``).
+    (``_Band``). ``block_diffusion = (L, block)`` (static) states the mask
+    of training by diffusion over blocks instead: a self-attention over ``2
+    L`` positions, the noised copy first (``_DiffusionBand``; the XLA
+    ``attention`` takes the same statement).
 
     Numerics: fp32 online softmax, MXU matmuls in the input dtype with fp32
     accumulation — same contract as the pure-XLA ``attention`` it replaces.
@@ -522,6 +705,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         raise ValueError(f"{q.shape[2]} query heads cannot share "
                          f"{k.shape[2]} key-value heads (k {k.shape}, "
                          f"v {v.shape})")
+    if block_diffusion is not None and (
+            causal or not q.shape[1] == k.shape[1] == 2 * block_diffusion[0]):
+        raise ValueError(
+            f"block_diffusion={block_diffusion} states the whole mask of a "
+            f"self-attention over twice its length: no causal, no window "
+            f"(q {q.shape}, k {k.shape})")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     rule = _default_blocks(q.shape[1], k.shape[1], window,
@@ -531,7 +720,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         (block_q or rule.fwd[0], block_k or rule.fwd[1]),
         (bwd_q or rule.dq[0], bwd_k or rule.dq[1]),
         (bwd_q or rule.dkv[0], bwd_k or rule.dkv[1]))
-    return _flash_vjp(q, k, v, causal, window, blocks, interpret)
+    return _flash_vjp(q, k, v, _Mask(causal, window, block_diffusion),
+                      blocks, interpret)
 
 
 def flash_attention_spmd(qkv: jax.Array, causal: bool = False, **kw):
@@ -579,13 +769,13 @@ def flash_attention_spmd(qkv: jax.Array, causal: bool = False, **kw):
                          check_vma=False)(qkv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_vjp(q, k, v, causal, window, blocks, interpret):
-    return _flash_forward(q, k, v, causal, window, blocks.fwd, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_vjp(q, k, v, mask, blocks, interpret):
+    return _flash_forward(q, k, v, mask, blocks.fwd, interpret)[0]
 
 
-def _flash_vjp_fwd(q, k, v, causal, window, blocks, interpret):
-    o, lse = _flash_forward(q, k, v, causal, window, blocks.fwd, interpret)
+def _flash_vjp_fwd(q, k, v, mask, blocks, interpret):
+    o, lse = _flash_forward(q, k, v, mask, blocks.fwd, interpret)
     # named, so that a caller that rematerialises its layer can keep the
     # kernel's two results (``jax.checkpoint_policies.save_only_these_names(
     # *SAVED_BY_NAME)``) and not run the forward kernel a second time
@@ -594,10 +784,10 @@ def _flash_vjp_fwd(q, k, v, causal, window, blocks, interpret):
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, window, blocks, interpret, res, g):
+def _flash_vjp_bwd(mask, blocks, interpret, res, g):
     q, k, v, o, lse = res
-    return _flash_backward(q, k, v, o, lse, g, causal, window, blocks.dq,
-                           blocks.dkv, interpret)
+    return _flash_backward(q, k, v, o, lse, g, mask, blocks.dq, blocks.dkv,
+                           interpret)
 
 
 _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -627,19 +817,37 @@ def _stream_cost(band: _Band, products: int, b, h, d, isz, arrays: int,
 # projection wrote them, the decoder's step lost 44 ms to the layouts XLA
 # then chose around the calls: PERF.md section 6, PR 33); rows padded to the
 # block multiple (padded keys are masked inside the kernel, padded q rows
-# drop on exit).
+# drop on exit). Under the diffusion mask the positions are two copies of a
+# row, each padded apart (``copies``).
 
-def _operand(x, hkv: int, t_pad: int):
+def _fit(x, axis: int, t: int, t_pad: int, copies: int = 1):
+    """``x`` whose ``axis`` holds ``copies`` runs of positions one after
+    another: each cut to its first ``t / copies`` and padded with zeros to
+    ``t_pad / copies`` (nothing moves where the lengths are already so)."""
+    have = x.shape[axis] // copies
+    t, t_pad = t // copies, t_pad // copies
+    if have == t == t_pad:
+        return x
+    lead = x.shape[:axis]
+    x = x.reshape(lead + (copies, have) + x.shape[axis + 1:])
+    x = jax.lax.slice_in_dim(x, 0, t, axis=axis + 1)
+    if t_pad != t:
+        x = jnp.pad(x, [(0, 0)] * (axis + 1) + [(0, t_pad - t)]
+                    + [(0, 0)] * (x.ndim - axis - 2))
+    return x.reshape(lead + (copies * t_pad,) + x.shape[axis + 2:])
+
+
+def _operand(x, hkv: int, t_pad: int, copies: int = 1):
     b, t, h, d = x.shape
     x = jnp.moveaxis(x, 1, 2).reshape(b, hkv, h // hkv, t, d)
-    return x if t_pad == t else jnp.pad(
-        x, ((0, 0),) * 3 + ((0, t_pad - t), (0, 0)))
+    return _fit(x, 3, t, t_pad, copies)
 
 
-def _result(x, t: int):
+def _result(x, t: int, copies: int = 1):
     """A kernel's result, laid as ``_operand`` lays it, as [B, T, h, D]."""
     b, hkv, members, _, d = x.shape
-    return jnp.moveaxis(x[:, :, :, :t].reshape(b, hkv * members, t, d), 1, 2)
+    return jnp.moveaxis(_fit(x, 3, t, t, copies).reshape(
+        b, hkv * members, t, d), 1, 2)
 
 
 def _block_view(rows: int, members: int, d: int):
@@ -673,12 +881,11 @@ _GRID_SEMANTICS = pltpu.CompilerParams(
     vmem_limit_bytes=32 * 2**20)
 
 
-def _flash_forward(q, k, v, causal, window, blocks, interpret):
+def _flash_forward(q, k, v, mask, blocks, interpret):
     b, t, h, d = q.shape
     tk, hkv = k.shape[1], k.shape[2]
     group = h // hkv
-    band = _Band(causal=causal, window=window, block_q=blocks[0],
-                 block_k=blocks[1], q_len=t, k_len=tk)
+    band = mask.band(blocks[0], blocks[1], t, tk)
 
     def at_q(b_, hk, iq, s):
         return b_, hk, iq * band.bq
@@ -711,9 +918,10 @@ def _flash_forward(q, k, v, causal, window, blocks, interpret):
         cost_estimate=_stream_cost(band, 2, b, h, d, q.dtype.itemsize,
                                    arrays=4, rows=1),
         interpret=interpret,
-    )(_operand(q, hkv, band.tq_pad), _operand(k, hkv, band.tk_pad),
-      _operand(v, hkv, band.tk_pad))
-    return _result(out, t), lse
+    )(_operand(q, hkv, band.tq_pad, band.copies),
+      _operand(k, hkv, band.tk_pad, band.copies),
+      _operand(v, hkv, band.tk_pad, band.copies))
+    return _result(out, t, band.copies), lse
 
 
 def _masked_scores(s, row0, col0, *, keys_axis: int = 1, **mask):
@@ -840,15 +1048,13 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _rows(x, t: int, t_pad: int):
+def _rows(x, t: int, t_pad: int, copies: int = 1):
     """A [B, Hkv, T', G] row statistic at another pass's padded length."""
-    x = x[:, :, :t]
-    return x if t_pad == t else jnp.pad(
-        x, ((0, 0), (0, 0), (0, t_pad - t), (0, 0)))
+    return _fit(x, 2, t, t_pad, copies)
 
 
-def _flash_backward(q, k, v, o, lse, g, causal, window, blocks_dq,
-                    blocks_dkv, interpret):
+def _flash_backward(q, k, v, o, lse, g, mask, blocks_dq, blocks_dkv,
+                    interpret):
     """Two-pass flash backward (see module docstring): a dQ pass parallel
     over q blocks, which also takes ``delta = rowsum(dO ∘ O)``, and a dKV
     pass parallel over KV blocks, sharing the saved ``lse`` and delta."""
@@ -864,10 +1070,10 @@ def _flash_backward(q, k, v, o, lse, g, causal, window, blocks_dq,
     # matmuls, so clamp those rows to 0 — with the clamp their contributions
     # cancel exactly (zero dO/delta rows), which is why the backward kernels
     # need no q-row mask.
-    lse = jnp.where(lse[:, :, :t] <= NEG_INF / 2, 0.0, lse[:, :, :t])
-
-    band = _Band(causal=causal, window=window, block_q=blocks_dq[0],
-                 block_k=blocks_dq[1], q_len=t, k_len=tk)
+    band = mask.band(blocks_dq[0], blocks_dq[1], t, tk)
+    copies = band.copies
+    lse = _rows(lse, t, t, copies)
+    lse = jnp.where(lse <= NEG_INF / 2, 0.0, lse)
 
     def at_q(b_, hk, iq, s):
         return b_, hk, iq * band.bq
@@ -878,7 +1084,7 @@ def _flash_backward(q, k, v, o, lse, g, causal, window, blocks_dq,
     q_spec = _operand_spec(band.bq, group, d, at_q)
     kv_spec = _operand_spec(band.bk, 1, d, at_k)
     row_spec = _row_spec(band.bq, group, at_q)
-    qt, ot, dot = (_operand(x, hkv, band.tq_pad) for x in (q, o, g))
+    qt, ot, dot = (_operand(x, hkv, band.tq_pad, copies) for x in (q, o, g))
     dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, band=band, group=group,
                           scale=scale),
@@ -899,14 +1105,14 @@ def _flash_backward(q, k, v, o, lse, g, causal, window, blocks_dq,
         # dQ out, the logsumexp in and delta out
         cost_estimate=_stream_cost(band, 2, b, h, d, isz, arrays=6, rows=2),
         interpret=interpret,
-    )(qt, _operand(k, hkv, band.tk_pad), _operand(v, hkv, band.tk_pad), ot,
-      dot, _rows(lse, t, band.tq_pad))
-    dq = _result(dq, t)
+    )(qt, _operand(k, hkv, band.tk_pad, copies),
+      _operand(v, hkv, band.tk_pad, copies), ot, dot,
+      _rows(lse, t, band.tq_pad, copies))
+    dq = _result(dq, t, copies)
 
     # dKV: one program a key-value head and k block; the q rows in its band
     # stream past it, the whole group in each step
-    band = _Band(causal=causal, window=window, block_q=blocks_dkv[0],
-                 block_k=blocks_dkv[1], q_len=t, k_len=tk, stream="q")
+    band = mask.band(blocks_dkv[0], blocks_dkv[1], t, tk, stream="q")
 
     def own_k(b_, hk, ik, s):
         return b_, hk, ik * band.bk
@@ -933,10 +1139,12 @@ def _flash_backward(q, k, v, o, lse, g, causal, window, blocks_dq,
         # the model's work: dV and dK
         cost_estimate=_stream_cost(band, 2, b, h, d, isz, arrays=6, rows=2),
         interpret=interpret,
-    )(_operand(k, hkv, band.tk_pad), _operand(v, hkv, band.tk_pad),
-      _operand(q, hkv, band.tq_pad), _operand(g, hkv, band.tq_pad),
-      _rows(lse, t, band.tq_pad), _rows(delta, t, band.tq_pad))
-    return dq, _result(dk, tk), _result(dv, tk)
+    )(_operand(k, hkv, band.tk_pad, copies),
+      _operand(v, hkv, band.tk_pad, copies),
+      _operand(q, hkv, band.tq_pad, copies),
+      _operand(g, hkv, band.tq_pad, copies),
+      _rows(lse, t, band.tq_pad, copies), _rows(delta, t, band.tq_pad, copies))
+    return dq, _result(dk, tk, copies), _result(dv, tk, copies)
 
 
 # -- whole-sequence schedule --------------------------------------------------
@@ -1008,14 +1216,18 @@ def schedule_for(seq: int, heads: int, head_dim: int, dtype) -> str:
 
 def program_plan(seq: int, heads: int, head_dim: int, dtype, *,
                  kv_heads: int | None = None, causal: bool = False,
-                 window: int | None = None, fused: bool = False) -> dict:
+                 window: int | None = None,
+                 block_diffusion: tuple | None = None,
+                 fused: bool = False) -> dict:
     """How far the kernels engage at a static self-attention shape, for the
     dispatch line and its telemetry event: the ``schedule`` (the fused entry
     chooses; split operands stream), the query heads a program holds, its
     blocks (the forward's), and ``band_fill`` = scores the mask allows over
-    scores the forward's programs run."""
+    scores the forward's programs run. Under ``block_diffusion = (L,
+    block)`` ``seq`` is the ``2 L`` positions of the doubled row."""
     kv_heads = kv_heads or heads
     if (fused and kv_heads == heads and window is None
+            and block_diffusion is None
             and schedule_for(seq, heads, head_dim, dtype) == WHOLE_SEQ):
         fill = (seq + 1) / (2 * seq) if causal else 1.0
         return {"schedule": WHOLE_SEQ, "heads_per_program": _head_group(
@@ -1023,11 +1235,14 @@ def program_plan(seq: int, heads: int, head_dim: int, dtype, *,
                 "block_q": seq, "block_k": seq, "band_fill": round(fill, 4)}
     group = heads // kv_heads
     block_q, block_k = _default_blocks(seq, seq, window, group).fwd
-    band = _Band(causal=causal, window=window, block_q=block_q,
-                 block_k=block_k, q_len=seq, k_len=seq)
-    return {"schedule": STREAMING, "heads_per_program": group,
+    band = _Mask(causal, window, block_diffusion).band(
+        block_q, block_k, seq, seq)
+    plan = {"schedule": STREAMING, "heads_per_program": group,
             "block_q": band.bq, "block_k": band.bk,
             "band_fill": round(band.fill(), 4)}
+    if block_diffusion is not None:
+        plan.update(mask="block_diffusion", block_length=block_diffusion[1])
+    return plan
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))

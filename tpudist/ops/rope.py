@@ -53,12 +53,16 @@ def inv_freq(params: dict, head_dim: int) -> tuple[np.ndarray, float]:
     return plain / factor * ramp + plain * (1.0 - ramp), float(attention_factor)
 
 
-def tables(params: dict, head_dim: int, length: int
+def tables(params: dict, head_dim: int, positions
            ) -> tuple[np.ndarray, np.ndarray]:
-    """cos, sin [length, head_dim] float32 for positions 0 .. length - 1,
-    each frequency twice (the halves that rotate-half pairs)."""
+    """cos, sin [T, head_dim] float32, each frequency twice (the halves that
+    rotate-half pairs), for the ``positions`` of a row's T ids: a length
+    (positions 0 .. T - 1) or the positions themselves (a static sequence:
+    a row that holds two copies of a document counts 0 .. L - 1 twice)."""
     freq, factor = inv_freq(params, head_dim)
-    angle = np.arange(length, dtype=np.float64)[:, None] * freq[None, :]
+    if isinstance(positions, (int, np.integer)):
+        positions = np.arange(positions)
+    angle = np.asarray(positions, np.float64)[:, None] * freq[None, :]
     angle = np.concatenate([angle, angle], axis=-1)
     return ((np.cos(angle) * factor).astype(np.float32),
             (np.sin(angle) * factor).astype(np.float32))

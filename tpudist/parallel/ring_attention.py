@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from tpudist.obs import scopes
@@ -36,18 +37,46 @@ from tpudist.obs import scopes
 NEG_INF = -1e30
 
 
+def block_diffusion_mask(t: int, noisy: int, block: int):
+    """The mask of training by diffusion over blocks, [t, t] bool (numpy): a
+    self-attention row whose first ``noisy`` positions are a noised copy of
+    a row and whose other ``t - noisy`` are the clean row itself (``t = 2 *
+    noisy`` in training; ``noisy = 0`` is the clean row alone, as generation
+    reads it). With ``n(i)`` the block (``block`` positions) of a position
+    within its copy, query ``i`` sees key ``j`` where
+
+    - both noisy: ``n(j) == n(i)`` (its own block, both ways);
+    - ``i`` noisy, ``j`` clean: ``n(j) < n(i)`` (the clean blocks before);
+    - both clean: ``n(j) <= n(i)`` (causal by blocks);
+    - ``i`` clean, ``j`` noisy: never."""
+    at = np.arange(t)
+    clean = at >= noisy
+    n = (at - np.where(clean, noisy, 0)) // block
+    q_clean, k_clean, n_q, n_k = (clean[:, None], clean[None, :],
+                                  n[:, None], n[None, :])
+    return np.where(q_clean, k_clean & (n_k <= n_q),
+                    np.where(k_clean, n_k < n_q, n_k == n_q))
+
+
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal: bool = False, window: Optional[int] = None) -> jax.Array:
+              causal: bool = False, window: Optional[int] = None,
+              block_diffusion: Optional[tuple] = None) -> jax.Array:
     """Plain softmax attention; fp32 softmax. ``q`` [B, T, H, D]; ``k`` and
     ``v`` [B, Tk, Hkv, D], where ``Hkv`` divides ``H``: query head ``j``
     reads key-value head ``j // (H // Hkv)`` (grouped-query attention).
     ``window`` (with ``causal``) keeps, of the keys a query may see, the
-    nearest ``window``. The scores are whole, [B, H, T, Tk] float32: the
+    nearest ``window``. ``block_diffusion = (L, block)`` states the mask of
+    training by diffusion over blocks instead (``block_diffusion_mask``: a
+    self-attention whose first ``L`` positions are the noised copy).
+    The scores are whole, [B, H, T, Tk] float32: the
     Pallas kernel (``ops/pallas/flash_attention.py``) takes the same
     arguments where they do not fit."""
     if window is not None and not causal:
         raise ValueError("a window is the nearest keys of a causal mask: "
                          "pass causal=True with window")
+    if block_diffusion is not None and (causal or q.shape[1] != k.shape[1]):
+        raise ValueError("block_diffusion states the whole mask of a "
+                         "self-attention: no causal, no window, one length")
     d = q.shape[-1]
     group = q.shape[2] // k.shape[2]
     if group > 1:
@@ -63,6 +92,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 mask &= ~jnp.tril(jnp.ones((tq, tk), bool),
                                   k=tk - tq - window)
             s = jnp.where(mask, s, NEG_INF)
+        if block_diffusion is not None:
+            s = jnp.where(block_diffusion_mask(s.shape[-1], *block_diffusion),
+                          s, NEG_INF)
     with jax.named_scope(scopes.ATTN_SOFTMAX):
         p = jax.nn.softmax(s, axis=-1)
     with jax.named_scope(scopes.ATTN_VALUES):

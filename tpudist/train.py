@@ -189,6 +189,21 @@ def create_train_state(rng: jax.Array, model: nn.Module, cfg: Config,
                       dynamic_scale=ds, ema_params=ema)
 
 
+def _is_count(x) -> bool:
+    """A leaf of the statistics that is no statistic: an integer (the raw
+    key of a model's noise), alike on every replica by construction."""
+    return jnp.issubdtype(x.dtype, jnp.integer)
+
+
+def mean_stats(stats: Any, axis_name: str) -> Any:
+    """The replicas' mean of the running statistics; an integer leaf (a raw
+    PRNG key the model advances) is replicated, never averaged."""
+    if not any(map(_is_count, jax.tree_util.tree_leaves(stats))):
+        return jax.lax.pmean(stats, axis_name=axis_name)   # as it ever was
+    return jax.tree_util.tree_map(
+        lambda x: x if _is_count(x) else jax.lax.pmean(x, axis_name), stats)
+
+
 def update_ema(cfg: Config, ema: Any, new_params: Any,
                new_stats: Any) -> Any:
     """torchvision-style model EMA over params AND BN buffers
@@ -200,7 +215,7 @@ def update_ema(cfg: Config, ema: Any, new_params: Any,
     d = cfg.model_ema_decay
     with jax.named_scope(scopes.OPTIMIZER):
         return jax.tree_util.tree_map(
-            lambda e, x: d * e + (1.0 - d) * x, ema,
+            lambda e, x: x if _is_count(x) else d * e + (1.0 - d) * x, ema,
             {"params": new_params, "batch_stats": new_stats})
 
 
@@ -392,7 +407,7 @@ def make_train_step(mesh: Mesh, model: nn.Module, cfg: Config,
         # averaging is strictly more faithful to the data).
         # (Scopes = trace labels only; see _loss_fn.)
         with jax.named_scope(scopes.GRAD_REDUCE):
-            new_stats = jax.lax.pmean(new_stats, axis_name=data_axis)
+            new_stats = mean_stats(new_stats, data_axis)
 
         with jax.named_scope(scopes.OPTIMIZER):
             tx_state = state.opt_state

@@ -851,6 +851,8 @@ class Trainer:
             msg += (f", heads_per_program {p['heads_per_program']} "
                     f"block_q {p['block_q']} block_k {p['block_k']} "
                     f"band_fill {p['band_fill']}")
+            if "mask" in p:
+                msg += f" mask {p['mask']} block_length {p['block_length']}"
         if dec.get("reason"):
             msg += f": {dec['reason']}"
         if dec.get("flash_ms") is not None:
@@ -876,7 +878,8 @@ class Trainer:
         keys = [attention_dispatch.shape_key(
                     cfg.per_device_batch_size, w["seq"], w["heads"],
                     w["head_dim"], compute_dtype(cfg), not cfg.evaluate,
-                    w["causal"], kv_heads=w["kv_heads"], window=w["window"])
+                    w["causal"], kv_heads=w["kv_heads"], window=w["window"],
+                    block_diffusion=w.get("block_diffusion"))
                 for w in workloads]
         dec = {"kernel": kernel, "mode": cfg.flash, "source": "forced",
                "key": ",".join(keys),
@@ -890,7 +893,8 @@ class Trainer:
             dec["programs"] = [attention_dispatch.program(
                 w["seq"], w["heads"], w["head_dim"], compute_dtype(cfg),
                 kv_heads=w["kv_heads"], causal=w["causal"],
-                window=w["window"]) for w in workloads]
+                window=w["window"],
+                block_diffusion=w.get("block_diffusion")) for w in workloads]
             dec["schedule"] = dec["programs"][0]["schedule"]
         dec["reason"] = "; ".join(filter(None, [dec.get("reason"),
                                                 dec["key"]]))
