@@ -4,7 +4,9 @@ arithmetic, collected in tier-1: the cases of
 with no `Trainer`), loaded from that file so that there is one copy of them,
 and the readers' cases of `selftest/test_sdar_cpu.py` (its `TIER1`: the
 count of the block-diffusion mask's pairs, a share above 100, the plan's
-fill, the noise's scope).
+fill, the noise's scope) and of `selftest/test_nemotron3_cpu.py` (the cell's
+files against the mix's needs, the Mamba mixers' and the shared expert's
+scopes, the carry counter, and nothing from a program without them).
 The rest of `benchmarks/chip/selftest/` builds trainers for minutes and
 stays run by path. Below them: what the configurations' `trainer_argv` pins
 against the program's defaults."""
@@ -34,6 +36,13 @@ _spec = importlib.util.spec_from_file_location(
 _sdar = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_sdar)
 globals().update({test.__name__: test for test in _sdar.TIER1})
+
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_test_nemotron3_cpu", os.path.join(os.path.dirname(_PATH),
+                                                 "test_nemotron3_cpu.py"))
+_nemotron3 = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_nemotron3)
+globals().update({test.__name__: test for test in _nemotron3.TIER1})
 
 
 def test_attn_bd_fill_reader(monkeypatch, capsys):
@@ -97,6 +106,13 @@ _NOT_DEFAULTS = {
     ("sdar_30b_ep8", "--remat"):
         ("remat", "32,768 positions a step through every layer: only "
                   "rematerialised do the temporaries fit beside the state"),
+    ("nemotron3_nano_ep16", "--flash"):
+        ("flash", "a decoder has no start-up probe, `auto` is XLA's path, and "
+                  "XLA's scores at 8,192 tokens would be 17 GB"),
+    ("nemotron3_nano_ep16", "--remat"):
+        ("remat", "a Mamba block's backward keeps its decays and chunk "
+                  "states: only one block at a time do they fit beside 7.45 "
+                  "GiB of state"),
 }
 
 
@@ -135,7 +151,7 @@ def test_a_pin_writes_a_default_out_and_no_more(name, argv, flag):
 def test_every_exception_names_a_pin_that_is_written_out():
     written = {(p.values[0], p.values[2]) for p in _pins()}
     assert set(_NOT_DEFAULTS) <= written
-    assert len(written) == 44
+    assert len(written) == 55
 
 
 def test_fused_bn_takes_off_and_nothing_else(capsys):

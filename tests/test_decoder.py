@@ -1033,3 +1033,4 @@ def test_the_reference_seats_the_mask_tokens_experts(held, share):
             assert set(order[-(held - 1):]) == set(mine) - {z["first"]}
             assert sorted(map(tuple, np.asarray(router.T))) == sorted(
                 map(tuple, np.asarray(was.T)))
+

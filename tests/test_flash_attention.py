@@ -446,6 +446,43 @@ def test_grouped_program_matches_attention(group, window, t, d):
         np.testing.assert_allclose(a, b, atol=3e-5)
 
 
+@pytest.mark.parametrize("kv_heads,group", [(1, 16), (2, 16), (1, 24),
+                                            (1, 12)])
+def test_a_group_of_sixteen_is_split_over_programs_of_eight(kv_heads, group):
+    """32 query heads over 2 key-value heads: a group of sixteen (or any
+    multiple of eight) runs as programs of eight over repeated k and v, and
+    is ``attention``'s result and gradients, dK and dV summed over the
+    copies; a group that eight does not divide stays one program."""
+    fa = importlib.import_module("tpudist.ops.pallas.flash_attention")
+    assert [fa._programs_of(g) for g in (1, 4, 8, 12, 16, 24, 32)] == [
+        1, 1, 1, 1, 2, 3, 4]
+    plan = fa.program_plan(8192, 32, 128, jnp.bfloat16, kv_heads=2,
+                           causal=True)
+    assert (plan["heads_per_program"], plan["block_q"], plan["block_k"]) == (
+        8, 512, 1024)
+    assert plan == fa.program_plan(8192, 32, 128, jnp.bfloat16, kv_heads=4,
+                                   causal=True)
+    t, d = 37, 16
+    ks = jax.random.split(jax.random.PRNGKey(group + kv_heads), 4)
+    q = jax.random.normal(ks[0], (1, t, group * kv_heads, d))
+    k = jax.random.normal(ks[1], (1, t, kv_heads, d))
+    v = jax.random.normal(ks[2], (1, t, kv_heads, d))
+    g = jax.random.normal(ks[3], q.shape)
+
+    def both(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) * g), argnums=(0, 1, 2))(
+                q, k, v)
+
+    got, got_grads = both(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=16, block_k=16))
+    want, want_grads = both(lambda q, k, v: attention(q, k, v, causal=True))
+    assert abs(float(got) - float(want)) < 2e-3
+    for a, b in zip(got_grads, want_grads):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
 @pytest.mark.parametrize("kv_heads", [1, 3])
 def test_grouped_program_full_and_cross_lengths(kv_heads):
     """No mask at all (non-causal, exact tiling), a ragged key length

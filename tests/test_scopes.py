@@ -190,6 +190,116 @@ def test_decoder_scopes_are_named_forward_and_backward(decoder_step, scope,
         assert any("/layer_1/" in n for n in named)
 
 
+def _nemotron_step(mesh8):
+    """(compiled DP step, its HLO text) of `nemotron3_tiny` (all five
+    blocks: two Mamba-2 mixers, two expert blocks, one attention),
+    rematerialised, attention on the streaming kernel; the cache off."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from tpudist.models import create_model
+    from tpudist.train import (compute_dtype, create_train_state,
+                               make_train_step)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        cfg = Config(arch="nemotron3_tiny", batch_size=16, seq_len=32,
+                     optimizer="adamw", use_amp=True, seed=0).finalize(8)
+        model = create_model(cfg.arch, dtype=compute_dtype(cfg),
+                             expert_share=(0, 4), flash=True, remat=True,
+                             loss_chunk=16)
+        state = create_train_state(jax.random.PRNGKey(0), model, cfg)
+        rows = jax.ShapeDtypeStruct((16, 32), jnp.int32)
+        compiled = make_train_step(mesh8, model, cfg).lower(
+            state, rows, rows, jnp.float32(0.1)).compile()
+        return compiled, compiled.as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def nemotron_step(mesh8):
+    compiled, text = _nemotron_step(mesh8)
+    out = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if " = " in line and name is not None \
+                and "jit(step)/" in name.group(1) \
+                and not any(p in line for p in PLUMBING):
+            out.append((line, name.group(1)))
+    return compiled, out
+
+
+def test_every_device_op_of_the_mixer_blocks_step_has_a_scope(nemotron_step):
+    named = [n for _, n in nemotron_step[1]]
+    assert len(named) > 1000
+    unscoped = [n for n in named if phase_of(n) is None]
+    assert len(unscoped) <= 0.01 * len(named), sorted(set(unscoped))[:20]
+
+
+@pytest.mark.parametrize("scope,within", [
+    (scopes.SSM_MIXER, "/layer_0/mixer/"),
+    (scopes.SSM_IN_PROJ, f"/mixer/{scopes.SSM_MIXER}/"),
+    (scopes.SSM_CONV, f"/mixer/{scopes.SSM_MIXER}/"),
+    (scopes.SSM_SCAN, f"/mixer/{scopes.SSM_MIXER}/"),
+    (scopes.SSM_GATE_NORM, f"/mixer/{scopes.SSM_MIXER}/"),
+    (scopes.SSM_OUT_PROJ, f"/mixer/{scopes.SSM_MIXER}/"),
+    (scopes.MOE_SHARED, "/layer_1/mixer/"),
+    (scopes.MOE_ROUTER, "/layer_4/mixer/"),
+    (scopes.MOE_EXPERTS, "/layer_1/mixer/"),
+    (scopes.ATTN_FUSED, "/layer_3/mixer/")])
+def test_mixer_scopes_are_named_forward_and_backward(nemotron_step, scope,
+                                                     within):
+    """What `ssm_ms`, `ssd_scan_ms`, `moe_shared_ms` and `moe_ms` of the
+    chip benchmark sum: a Mamba mixer's five parts lie inside `ssm_mixer`
+    under the block's name, the shared expert beside the routed path's four
+    scopes, all inside the forward scope, plain and transposed."""
+    named = [n for _, n in nemotron_step[1]
+             if f"/{scope}/" in n and within in n and scopes.FORWARD in n]
+    assert any(phase_of(n) == "fwd" for n in named), scope
+    assert any(phase_of(n) == "bwd" for n in named), scope
+    if scope == scopes.SSM_SCAN:
+        # the pass between chunks is a loop of the scan's own
+        assert any(" while(" in line and f"/{scope}/" in n
+                   for line, n in nemotron_step[1])
+        # both Mamba blocks
+        assert any("/layer_2/" in n for n in named)
+    if scope == scopes.MOE_SHARED:
+        routed = (scopes.MOE_ROUTER, scopes.MOE_DISPATCH, scopes.MOE_EXPERTS,
+                  scopes.MOE_COMBINE)
+        assert not any(f"/{r}/" in n for n in named for r in routed)
+
+
+def test_the_new_scopes_change_no_compiled_flop_or_byte(mesh8, nemotron_step,
+                                                        monkeypatch):
+    """A scope is HLO metadata: the step compiled with every
+    `jax.named_scope` taken out costs what the named one costs."""
+    import contextlib
+    named = nemotron_step[0].cost_analysis()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare, text = _nemotron_step(mesh8)
+    assert f"/{scopes.SSM_SCAN}/" not in text
+    assert f"/{scopes.MOE_SHARED}/" not in text
+    bare = bare.cost_analysis()
+    for key in ("flops", "bytes accessed", "transcendentals"):
+        assert named[key] == bare[key], key
+    assert named["flops"] > 0
+
+
+def test_the_mixer_counters_are_a_models_counters():
+    from tpudist.trainer import _MetricDrain
+    from tpudist.utils import AverageMeter
+    assert {scopes.SSM_DT, scopes.SSM_CARRY} <= set(scopes.MODEL_COUNTERS)
+    assert (scopes.SSM_DT, scopes.SSM_CARRY) == ("ssm_dt_mean",
+                                                 "ssm_chunk_carry_min")
+    drain = _MetricDrain({"loss": AverageMeter("Loss")})
+    name = f"{scopes.SSM_CARRY}.layer_0"
+    before = len(telemetry.counters().get(name, []))
+    drain.push({"loss": 1.0, name: 0.25}, n=2, step=3)
+    drain.drain()
+    assert telemetry.counters()[name][before:] == [0.25]
+
+
 def test_the_noise_of_block_diffusion_has_its_scope(decoder_step):
     """`bd_noise` (what `bd_noise_ms` of the chip benchmark sums) lies
     inside the forward scope of a step trained by diffusion over blocks,

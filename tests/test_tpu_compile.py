@@ -48,7 +48,10 @@ FLASH_SHAPES = ((128, 197, 12, 64),      # ViT-B/16 @224
 # The streaming schedule at a decoder's shape (batch, tokens, query heads,
 # key-value heads, head_dim, window): two sequences of 8,192 with 32 heads
 # over 4 of 128, a sliding-window layer and a full one.
-FLASH_GQA_SHAPES = ((2, 8192, 32, 4, 128, 1024), (2, 8192, 32, 4, 128, None))
+# the last: a group of sixteen query heads (32 over 2), split over two
+# programs of eight
+FLASH_GQA_SHAPES = ((2, 8192, 32, 4, 128, 1024), (2, 8192, 32, 4, 128, None),
+                    (2, 8192, 32, 2, 128, None))
 # The same schedule under the mask of training by diffusion over blocks
 # (batch, positions of the doubled row, query heads, key-value heads,
 # head_dim, block length): two rows of 8,192 ids, each a noised and a
@@ -155,6 +158,11 @@ _KERNEL_CASES = (
     + [pytest.param(("grouped", 131072, 16, k, n), bwd,
                     id=f"grouped_{k}x{n}_{'fwdbwd' if bwd else 'fwd'}")
        for k, n in ((2304, 896), (896, 2304)) for bwd in (False, True)]
+    # an expert width that is no whole number of lane tiles (1,856 = 14.5 x
+    # 128; 98,304 rows, 8 experts held): tiles of 640 overhang its end
+    + [pytest.param(("grouped", 98304, 8, k, n), bwd,
+                    id=f"grouped_{k}x{n}_{'fwdbwd' if bwd else 'fwd'}")
+       for k, n in ((2688, 1856), (1856, 2688)) for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -370,7 +378,8 @@ def test_vit_b16_flash_step_compiles_for_v5e(topo, monkeypatch, tp):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("name,most_gib", [
-    ("mellum2_12b_ep4", 11.2), ("sdar_30b_ep8", 14.5)])
+    ("mellum2_12b_ep4", 11.2), ("sdar_30b_ep8", 14.5),
+    ("nemotron3_nano_ep16", 15.0)])
 def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     """The whole step of a benchmark's decoder cell, as its configuration's
     ``trainer_argv`` builds it (two rows of 8,192 ids, ``--remat``, the
@@ -383,7 +392,11 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     SDAR-30B-A3B-Chat, trained by diffusion over blocks: 4 layers, 16 of
     128 experts, an eighth of the vocabulary, each row a noised and a clean
     copy, 16,384 positions): it fits too, and no score tensor of the doubled
-    row by itself or by a row is in the step.
+    row by itself or by a row is in the step. ``nemotron3_nano_ep16`` (one
+    chip's share of Nemotron-3-Nano-30B-A3B: the first nine blocks, four of
+    them Mamba-2 mixers by the chunked scan, one attention with a group of
+    sixteen split over two programs of eight, 8 of 128 experts beside the
+    shared one, an eighth of the vocabulary): it fits.
 
     Inside each rematerialised layer no loop copies a pair buffer (a carry
     that XLA could not update in place cost 29 ms a step a loop, on the
@@ -428,14 +441,15 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     text = compiled.as_text()
     diffusion = model.objective == "block_diffusion"
     positions = cfg.seq_len * (2 if diffusion else 1)
-    pairs = 2 * positions * 8
+    pairs = 2 * positions * model.experts_per_token
     assert not re.search(rf"= (?:bf16|f32)\[{pairs},\d+\]\S* copy\(", text)
     # around the attention kernels (PR 33): the row statistics carry the
     # group on their minor dimension and delta is taken in the dQ pass, so
     # under ``attn_fused`` no [..., T, 1] float32 column (lane-padded 128 x
     # in HBM) is kept and XLA makes no float32 copy of q, o or dO
     under = [line for line in text.splitlines() if "/attn_fused/" in line]
-    assert sum("tpu_custom_call" in line for line in under) == 3 * 4
+    assert sum("tpu_custom_call" in line for line in under) == 3 * len(
+        [k for k in model.layer_types[:cfg.layers] if "attention" in k])
     for line in under:
         made = line.split(" = ", 1)[-1].split("(", 1)[0]
         assert not re.search(rf"f32\[[\d,]*{positions},1\]", made), \

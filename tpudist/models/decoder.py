@@ -1,14 +1,29 @@
-"""A decoder of tokens with sparse experts and mixed attention: the
+"""A decoder of tokens with sparse experts and mixed sequence mixers: the
 language models of the zoo (the zoo's other families classify images),
 trained to predict the next id under a causal mask or by diffusion over
 blocks; which, the registered model says (``objective``).
 
-A layer is ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``:
-grouped-query attention (RMSNorm over ``head_dim`` on q and k, then RoPE by
-the table of the layer's type: ``sliding_attention`` sees the nearest
-``sliding_window`` keys, ``full_attention`` all before it), and a top-k
-layer of SwiGLU experts (``parallel/moe.py::moe_topk_held``). The model is an
-embedding, the layers, a final RMSNorm and an untied head.
+The model is an embedding, the layers, a final RMSNorm and an untied head. A
+layer is one of the kinds of ``LAYER_KINDS``, as the registered model's
+``layer_types`` lists them:
+
+- a pair, ``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))``
+  (``sliding_attention``, ``full_attention``): grouped-query attention
+  (RMSNorm over ``head_dim`` on q and k, then RoPE by the table of the
+  layer's type: ``sliding_attention`` sees the nearest ``sliding_window``
+  keys, ``full_attention`` all before it), and a top-k layer of experts
+  (``parallel/moe.py::moe_topk_held``);
+- one mixer behind one norm, ``x + Mixer(RMSNorm(x))`` (``mamba``, ``moe``,
+  ``attention``; a model that publishes its layers as a string of letters
+  names them ``M``, ``E``, ``*``: ``layer_types_of``): a Mamba-2 mixer
+  (``Mamba2Mixer``, the chunked scan of ``ops/ssd.py``), a top-k layer of
+  experts, or causal grouped-query attention over all earlier keys.
+
+What the attention does to q and k (q/k RMSNorm, RoPE or neither: a model
+whose Mamba layers carry position rotates nothing), the router's rule (a
+softmax's, or sigmoid scores with a bias that chooses and a scaling factor),
+the experts' body (SwiGLU, or ungated ``relu^2``) and a shared expert beside
+the routed ones are statements of the registered model, never flags.
 
 **Diffusion over blocks** (``objective="block_diffusion"``; SDAR,
 arXiv:2510.06303, trained as BD3-LM's vectorised form, arXiv:2503.09573,
@@ -57,12 +72,29 @@ import numpy as np
 from flax import linen as nn
 
 from tpudist.obs import scopes
-from tpudist.ops import rope
+from tpudist.ops import rope, ssd
 from tpudist.ops.loss import Scored, lm_head_loss
-from tpudist.parallel.moe import moe_topk_held
+from tpudist.parallel.moe import moe_topk_held, shared_expert
 from tpudist.parallel.ring_attention import attention
 
 _init = nn.initializers.normal(stddev=0.02)
+
+# a layer's kind: the two pairs of attention and experts, and the three
+# blocks of one mixer; the letters a published pattern string names them by
+PAIR_KINDS = ("sliding_attention", "full_attention")
+PATTERN_KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
+LAYER_KINDS = PAIR_KINDS + tuple(PATTERN_KINDS.values())
+
+
+def layer_types_of(pattern: str) -> tuple[str, ...]:
+    """The layer kinds of a published pattern string, a letter a block."""
+    unknown = sorted(set(pattern) - set(PATTERN_KINDS))
+    if unknown:
+        raise ValueError(
+            f"layer pattern {pattern!r}: unknown letter(s) {unknown} (known: "
+            + ", ".join(f"{k!r} = {v}" for k, v in PATTERN_KINDS.items())
+            + ")")
+    return tuple(PATTERN_KINDS[c] for c in pattern)
 
 
 class RMSNorm(nn.Module):
@@ -113,7 +145,10 @@ class GroupedQueryAttention(nn.Module):
     num_heads: int
     num_kv_heads: int
     head_dim: int
-    rope_parameters: Any                 # this layer type's entry
+    rope_parameters: Any                 # this layer type's entry; None: no
+    #                                      rotation (position comes from
+    #                                      elsewhere in the model)
+    qk_norm: bool = True                 # RMSNorm over head_dim on q and k
     window: Optional[int] = None
     # (L, block): the row is a noised copy of L ids, then the clean ids
     block_diffusion: Optional[tuple] = None
@@ -140,12 +175,13 @@ class GroupedQueryAttention(nn.Module):
         q = proj(self.num_heads, "q_proj")
         k = proj(self.num_kv_heads, "k_proj")
         v = proj(self.num_kv_heads, "v_proj")
-        cos, sin = rope.tables(dict(self.rope_parameters), self.head_dim,
-                               positions)
-        q = rope.apply(RMSNorm(self.eps, name="q_norm")(q).astype(dt),
-                       cos, sin)
-        k = rope.apply(RMSNorm(self.eps, name="k_norm")(k).astype(dt),
-                       cos, sin)
+        if self.qk_norm:
+            q = RMSNorm(self.eps, name="q_norm")(q).astype(dt)
+            k = RMSNorm(self.eps, name="k_norm")(k).astype(dt)
+        if self.rope_parameters is not None:
+            cos, sin = rope.tables(dict(self.rope_parameters), self.head_dim,
+                                   positions)
+            q, k = rope.apply(q, cos, sin), rope.apply(k, cos, sin)
         if self.flash and not self.is_initializing():
             # (initialisation runs eagerly on a short example row: shapes
             # only, so the XLA path, and no kernel is built for that length)
@@ -160,33 +196,153 @@ class GroupedQueryAttention(nn.Module):
 
 
 class SparseExperts(nn.Module):
-    """The held experts of a top-k layer and the router over all of them."""
+    """The held experts of a top-k layer, the router over all of them and,
+    where the model has one, the shared expert every token visits (whole on
+    every holder). ``router="sigmoid"`` reads a bias that chooses and does
+    not weigh, ``e_score_correction_bias``: a non-trainable leaf (in
+    ``batch_stats``), zero at initialisation, which a restored state brings
+    with it and nothing here updates (the rate of the balancing rule that
+    moves it is no part of a published config)."""
     num_experts: int
     top_k: int
     width: int
     first_expert: int
     held: int
+    router: str = "softmax"              # | "sigmoid" (parallel/moe.py)
+    routed_scaling: float = 1.0
+    act: str = "swiglu"                  # | "relu2": ungated, no ``gate``
+    shared_width: int = 0                # the shared expert's; 0: none
     dtype: Any = None
 
     @nn.compact
     def __call__(self, normed: jax.Array):
         b, t, d = normed.shape
         dt = self.dtype or normed.dtype
-        params = {
-            "router": self.param("router", _init, (d, self.num_experts),
-                                 jnp.float32),
-            "gate": self.param("gate", _init, (self.held, d, self.width),
-                               jnp.float32),
-            "up": self.param("up", _init, (self.held, d, self.width),
-                             jnp.float32),
-            "down": self.param("down", _init, (self.held, self.width, d),
-                               jnp.float32),
-        }
+        if self.act not in ("swiglu", "relu2"):
+            raise ValueError(f"expert body {self.act!r} (swiglu | relu2)")
+
+        def held(name, *shape):
+            return self.param(name, _init, (self.held, *shape), jnp.float32)
+        params = {"router": self.param("router", _init,
+                                       (d, self.num_experts), jnp.float32)}
+        if self.act == "swiglu":
+            params["gate"] = held("gate", d, self.width)
+            params["up"] = held("up", d, self.width)
+        else:
+            # [held, width, d], as a Linear's weight lies ([out, in]): both
+            # of an expert's matrices then end in the hidden size (a width
+            # of 1,856 is no whole number of lane tiles:
+            # ops/pallas/grouped_matmul.py::_transposed says what that cost)
+            params["up"] = held("up", self.width, d)
+        params["down"] = held("down", self.width, d)
+        if self.router == "sigmoid":
+            params["router_bias"] = self.variable(
+                "batch_stats", "e_score_correction_bias", jnp.zeros,
+                (self.num_experts,), jnp.float32).value
         flat = normed.reshape(b * t, d)
         y, counters = moe_topk_held(
             params, flat.astype(dt), top_k=self.top_k,
-            first_expert=self.first_expert, router_input=flat)
+            first_expert=self.first_expert, router_input=flat,
+            rule=self.router, scale=self.routed_scaling)
+        if self.shared_width:
+            y = y + shared_expert(
+                {"up": self.param("shared_up", _init,
+                                  (d, self.shared_width), jnp.float32),
+                 "down": self.param("shared_down", _init,
+                                    (self.shared_width, d), jnp.float32)},
+                flat.astype(dt))
         return y.reshape(b, t, d), counters
+
+
+def _dt_bias_init(low: float, high: float, floor: float):
+    """``dt_bias`` such that ``softplus(dt_bias)`` is log-uniform in [low,
+    high], floored (Mamba's initialisation)."""
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                     * (np.log(high) - np.log(low)) + np.log(low))
+        dt = jnp.maximum(dt, floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
+
+
+def _conv_init(taps: int):
+    """torch's ``Conv1d`` default for a depthwise kernel of ``taps``: U(-1 /
+    sqrt(taps), 1 / sqrt(taps)), kernel and bias alike."""
+    bound = 1.0 / np.sqrt(taps)
+
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """A Mamba-2 mixer (arXiv:2405.21060; ``ops/ssd.py`` has the equations
+    and what is float32). ``in_proj`` -> the gate ``z`` [H P], ``xBC`` [H P
+    + 2 G N] and ``dt`` [H]; a causal depthwise convolution with bias over
+    ``xBC``, then SiLU; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``
+    a head; the selective recurrence by a chunked scan, plus ``D x``; ``y <-
+    RMSNorm_by_group(y * silu(z)) * w``; ``out_proj``. No bias but the
+    convolution's. Returns (y, counters): ``ssm_dt_mean``,
+    ``ssm_chunk_carry_min``."""
+    num_heads: int                       # H
+    head_dim: int                        # P
+    state: int                           # N
+    groups: int                          # G: heads that share B and C
+    conv: int                            # the convolution's taps
+    chunk: int
+    time_step: tuple                     # (min, max, floor) of dt at init
+    out_scale: float = 1.0               # on out_proj's initial N(0, 0.02)
+    eps: float = 1e-5
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        b, t, d = x.shape
+        dt_ = self.dtype or x.dtype
+        heads, inner = self.num_heads, self.num_heads * self.head_dim
+        gn = self.groups * self.state
+        with jax.named_scope(scopes.SSM_MIXER):
+            with jax.named_scope(scopes.SSM_IN_PROJ):
+                proj = nn.Dense(2 * inner + 2 * gn + heads, use_bias=False,
+                                dtype=dt_, kernel_init=_init,
+                                name="in_proj")(x)
+            z, xbc, dt_raw = jnp.split(proj, [inner, 2 * inner + 2 * gn],
+                                       axis=-1)
+            kernel = self.param("conv_kernel", _conv_init(self.conv),
+                                (self.conv, inner + 2 * gn), jnp.float32)
+            bias = self.param("conv_bias", _conv_init(self.conv),
+                              (inner + 2 * gn,), jnp.float32)
+            with jax.named_scope(scopes.SSM_CONV):
+                xbc = jax.nn.silu(ssd.causal_conv1d(xbc, kernel, bias)
+                                  ).astype(dt_)
+            xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+            dt_bias = self.param("dt_bias", _dt_bias_init(*self.time_step),
+                                 (heads,), jnp.float32)
+            a_log = self.param(
+                "A_log", lambda key, shape, dtype: jnp.log(
+                    jnp.arange(1, shape[0] + 1, dtype=dtype)),
+                (heads,), jnp.float32)
+            skip = self.param("D", nn.initializers.ones, (heads,),
+                              jnp.float32)
+            with jax.named_scope(scopes.SSM_SCAN):
+                step = jax.nn.softplus(dt_raw.astype(jnp.float32) + dt_bias)
+                y, carry_min = ssd.ssd_scan(
+                    xs.reshape(b, t, heads, self.head_dim), step,
+                    -jnp.exp(a_log), bm.reshape(b, t, self.groups, self.state),
+                    cm.reshape(b, t, self.groups, self.state), skip,
+                    self.chunk)
+            weight = self.param("norm_scale", nn.initializers.ones,
+                                (inner,), jnp.float32)
+            with jax.named_scope(scopes.SSM_GATE_NORM):
+                y = ssd.gated_group_norm(y.reshape(b, t, inner), z, weight,
+                                         self.groups, self.eps).astype(dt_)
+            with jax.named_scope(scopes.SSM_OUT_PROJ):
+                out = nn.Dense(
+                    d, use_bias=False, dtype=dt_,
+                    kernel_init=nn.initializers.normal(
+                        stddev=0.02 * self.out_scale), name="out_proj")(y)
+        return out, {scopes.SSM_DT: jnp.mean(step),
+                     scopes.SSM_CARRY: carry_min}
 
 
 class DecoderLayer(nn.Module):
@@ -206,6 +362,31 @@ class DecoderLayer(nn.Module):
         return x + y, counters
 
 
+class MixerBlock(nn.Module):
+    """``x + Mixer(RMSNorm(x))``: one mixer behind one norm (``kind``:
+    ``mamba`` | ``moe`` | ``attention``; ``mixer``: its module's fields)."""
+    kind: str
+    mixer: dict
+    eps: float
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        dt = self.dtype or x.dtype
+        y = RMSNorm(self.eps, name="norm")(x)
+        counters = {}
+        if self.kind == "mamba":
+            y, counters = Mamba2Mixer(**self.mixer, eps=self.eps, dtype=dt,
+                                      name="mixer")(y.astype(dt))
+        elif self.kind == "moe":
+            y, counters = SparseExperts(**self.mixer, dtype=dt,
+                                        name="mixer")(y)
+        else:
+            y = GroupedQueryAttention(**self.mixer, eps=self.eps, dtype=dt,
+                                      name="mixer")(y.astype(dt))
+        return x + y, counters
+
+
 class MoEDecoder(nn.Module):
     # published
     vocab_size: int
@@ -217,10 +398,19 @@ class MoEDecoder(nn.Module):
     num_experts: int
     experts_per_token: int
     expert_width: int
-    layer_types: Sequence[str]
-    rope_parameters: Any                 # {layer type: its parameters}
+    layer_types: Sequence[str]           # of LAYER_KINDS, a layer each
+    rope_parameters: Any                 # {layer type: its parameters};
+    #                                      a type without an entry rotates
+    #                                      nothing
     sliding_window: int
     rms_norm_eps: float = 1e-6
+    qk_norm: bool = True                 # RMSNorm on q and k in attention
+    router: str = "softmax"              # | "sigmoid": scores, a bias that
+    routed_scaling: float = 1.0          # chooses, this factor on weights
+    expert_act: str = "swiglu"           # | "relu2" (ungated)
+    shared_width: int = 0                # a shared expert's width; 0: none
+    mamba: Any = None                    # a Mamba-2 mixer's published sizes
+    #                                      (``Mamba2Mixer``'s fields)
     # this holder's share of a deployment
     layers: int = 0                      # leading layers kept (0: all)
     expert_share: tuple = (0, 1)         # (i, n): the i-th of n holders
@@ -252,8 +442,11 @@ class MoEDecoder(nn.Module):
         return jnp.zeros((1, 16), jnp.int32)
 
     def attention_workloads(self, seq_len: int) -> list[dict]:
-        """The attention shapes a step runs, one a layer type kept."""
-        kept = self.layer_types[:self.layers or self.num_layers]
+        """The attention shapes a step runs, one a layer type kept that
+        has attention (none for a share without such a layer)."""
+        kept = [kind for kind in
+                self.layer_types[:self.layers or self.num_layers]
+                if kind in PAIR_KINDS + ("attention",)]
         shape = dict(heads=self.num_heads, kv_heads=self.num_kv_heads,
                      head_dim=self.head_dim)
         if self.objective == "block_diffusion":
@@ -304,32 +497,41 @@ class MoEDecoder(nn.Module):
         with jax.named_scope(scopes.LM_EMBED):
             x = nn.Embed(self.vocab_held, self.hidden_size,
                          embedding_init=_init, dtype=dt, name="embed")(tokens)
+        rope_of = dict(self.rope_parameters or {})
+        attn = dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+                    head_dim=self.head_dim, qk_norm=self.qk_norm,
+                    block_diffusion=mask, flash=self.flash)
+        experts = dict(num_experts=self.num_experts,
+                       top_k=self.experts_per_token, width=self.expert_width,
+                       first_expert=first_expert, held=held,
+                       router=self.router,
+                       routed_scaling=self.routed_scaling,
+                       act=self.expert_act, shared_width=self.shared_width)
         for i, kind in enumerate(self.layer_types[:kept]):
-            if kind not in ("sliding_attention", "full_attention"):
-                raise ValueError(f"layer {i}: unknown layer type {kind!r}")
-            layer = DecoderLayer
+            if kind not in LAYER_KINDS:
+                raise ValueError(f"layer {i}: unknown layer type {kind!r} "
+                                 f"(one of {', '.join(LAYER_KINDS)})")
+            of_kind = dict(attn, rope_parameters=rope_of.get(kind), window=(
+                self.sliding_window if kind == "sliding_attention" else None))
+            if kind in PAIR_KINDS:
+                layer, fields = DecoderLayer, dict(attn=of_kind,
+                                                   experts=experts)
+            else:
+                layer, fields = MixerBlock, dict(kind=kind, mixer={
+                    "mamba": self.mamba, "moe": experts,
+                    "attention": of_kind}[kind])
             if self.remat:
                 # everything of a layer is made again in the backward pass
                 # but the attention kernel's two results (0.4 GB a layer at
                 # two sequences of 8,192): its forward runs once
                 from tpudist.ops.pallas.flash_attention import SAVED_BY_NAME
                 layer = nn.remat(
-                    DecoderLayer,
+                    layer,
                     policy=jax.checkpoint_policies.save_only_these_names(
                         *SAVED_BY_NAME))
             x, layer_counters = layer(
-                attn=dict(num_heads=self.num_heads,
-                          num_kv_heads=self.num_kv_heads,
-                          head_dim=self.head_dim,
-                          rope_parameters=self.rope_parameters[kind],
-                          window=(self.sliding_window
-                                  if kind == "sliding_attention" else None),
-                          block_diffusion=mask, flash=self.flash),
-                experts=dict(num_experts=self.num_experts,
-                             top_k=self.experts_per_token,
-                             width=self.expert_width,
-                             first_expert=first_expert, held=held),
-                eps=self.rms_norm_eps, dtype=dt, name=f"layer_{i}")(x)
+                **fields, eps=self.rms_norm_eps, dtype=dt,
+                name=f"layer_{i}")(x)
             counters.update({f"{k}.layer_{i}": v
                              for k, v in layer_counters.items()})
         if noised is not None:
@@ -434,3 +636,53 @@ def sdar_tiny(dtype: Any = None, **kw) -> MoEDecoder:
         rope_parameters=sdar_30b_a3b().rope_parameters, sliding_window=0,
         rms_norm_eps=1e-6, objective="block_diffusion", block_length=4,
         noise_eps=1e-3, dtype=dtype, **_own(kw))
+
+
+NEMOTRON3_NANO_PATTERN = ("MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*"
+                          "EMEMEMEME")
+
+
+def nemotron3_nano_30b_a3b(dtype: Any = None, **kw) -> MoEDecoder:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (``config.json`` of
+    huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16, ``model_type``
+    ``nemotron_h``): 52 blocks of hidden 2,688, one mixer each behind one
+    RMSNorm (eps 1e-5), in the published pattern (23 ``M`` Mamba-2, 23 ``E``
+    experts, 6 ``*`` attention). Mamba-2: 64 heads of 64, state 128, 8
+    groups, a width-4 convolution, chunks of 128. Attention: 32 query heads
+    over 2 key-value heads of 128, causal, no rotation and no q/k norm (the
+    Mamba layers carry position). Experts: 128 of width 1,856 with 6 a
+    token, ungated ``relu^2``, routed by sigmoid scores with a correction
+    bias that chooses (zero here) and the factor 2.5, beside one shared
+    expert of width 3,712. Vocabulary 131,072, untied. Mamba's ``out_proj``
+    starts at N(0, 0.02) / sqrt(52) (``rescale_prenorm_residual``)."""
+    return MoEDecoder(
+        vocab_size=131072, hidden_size=2688, num_layers=52, num_heads=32,
+        num_kv_heads=2, head_dim=128, num_experts=128, experts_per_token=6,
+        expert_width=1856, layer_types=layer_types_of(NEMOTRON3_NANO_PATTERN),
+        rope_parameters=None, sliding_window=0, rms_norm_eps=1e-5,
+        qk_norm=False, router="sigmoid", routed_scaling=2.5,
+        expert_act="relu2", shared_width=3712,
+        mamba=dict(num_heads=64, head_dim=64, state=128, groups=8, conv=4,
+                   chunk=128, time_step=(0.001, 0.1, 1e-4),
+                   out_scale=52 ** -0.5),
+        dtype=dtype, **_own(kw))
+
+
+def nemotron3_tiny(dtype: Any = None, **kw) -> MoEDecoder:
+    """The CPU tests' twin of the model above at toy widths (hidden 64; the
+    pattern ``MEM*E``; Mamba-2 with 8 heads of 8, state 16, 2 groups, chunks
+    of 8; 16 query heads over 1 key-value head of 16, a group of sixteen as
+    the model's; 16 experts of width 32 with 2 a token beside a shared one
+    of 64; 256 ids): never a benchmark configuration."""
+    kw.setdefault("loss_chunk", 64)
+    return MoEDecoder(
+        vocab_size=256, hidden_size=64, num_layers=5, num_heads=16,
+        num_kv_heads=1, head_dim=16, num_experts=16, experts_per_token=2,
+        expert_width=32, layer_types=layer_types_of("MEM*E"),
+        rope_parameters=None, sliding_window=0, rms_norm_eps=1e-5,
+        qk_norm=False, router="sigmoid", routed_scaling=2.5,
+        expert_act="relu2", shared_width=64,
+        mamba=dict(num_heads=8, head_dim=8, state=16, groups=2, conv=4,
+                   chunk=8, time_step=(0.001, 0.1, 1e-4),
+                   out_scale=5 ** -0.5),
+        dtype=dtype, **_own(kw))

@@ -42,6 +42,17 @@ MOE_ROUTER = "moe_router"              # softmax over all experts, top k
 MOE_DISPATCH = "moe_dispatch"          # sort the held pairs, take their rows
 MOE_EXPERTS = "moe_experts"            # grouped products over the held
 MOE_COMBINE = "moe_combine"            # weighted sum back to tokens
+MOE_SHARED = "moe_shared"              # the dense expert every token visits
+# a Mamba-2 mixer (models/decoder.py::Mamba2Mixer, ops/ssd.py), inside the
+# forward scope under the block's name; the five parts lie within the whole
+SSM_MIXER = "ssm_mixer"
+SSM_IN_PROJ = "ssm_in_proj"            # z, xBC and dt in one product
+SSM_CONV = "ssm_conv"                  # causal depthwise convolution + SiLU
+SSM_SCAN = "ssm_scan"                  # decay, within-chunk products, chunk
+#                                        states, the pass between chunks, the
+#                                        carried part, D x
+SSM_GATE_NORM = "ssm_gate_norm"        # y * silu(z), RMSNorm by group
+SSM_OUT_PROJ = "ssm_out_proj"
 # a language model's ends
 LM_EMBED = "lm_embed"                  # the embedding's rows
 LM_HEAD = "lm_head"                    # the output head's product, a chunk
@@ -55,10 +66,16 @@ BD_NOISE = "bd_noise"
 MOE_PAIRS = "moe_pairs"                # pairs the held experts computed
 MOE_LOAD = "moe_load_max_over_mean"    # the fullest held expert over the mean
 MOE_WALKED = "moe_rows_walked"         # buffer rows the block loops touched
+# a Mamba-2 mixer: one scalar a block and a step
+SSM_DT = "ssm_dt_mean"                 # mean of softplus(dt + dt_bias)
+SSM_CARRY = "ssm_chunk_carry_min"      # least exp(sum of dt A over a chunk)
+#                                        over heads and chunks: at 0 nothing
+#                                        of a state outlives a chunk there
 # one scalar a step (no layer): training by diffusion over blocks
 BD_MASKED = "bd_masked_share"          # masked positions over rows x L
 BD_WEIGHT = "bd_weight_sum"            # sum of masked / t over rows x L
-MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED, BD_MASKED, BD_WEIGHT)
+MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED, SSM_DT, SSM_CARRY,
+                  BD_MASKED, BD_WEIGHT)
 
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)

@@ -615,6 +615,21 @@ def _default_blocks(t: int, tk: int, window: int | None,
     return _Blocks((512, 1024), (512, 512), (512, 1024))
 
 
+# The most query heads a streaming program holds. A larger group is split
+# over programs of this many: k and v are repeated ``group / 8`` times on the
+# head axis, so each copy serves eight query heads and the kernels are the
+# ones measured at eight (JAX's transpose of the repeat sums dK and dV over
+# the copies). Sixteen members in one program need more than the 32 MiB of
+# VMEM a program may hold (refused on the chip at 2 x 8,192, 32 heads over 2
+# of 128), and the dKV kernel's unrolled members would be twice its code.
+_MAX_GROUP = 8
+
+
+def _programs_of(group: int) -> int:
+    """Programs a key-value head's group of query heads is split over."""
+    return group // _MAX_GROUP if group % _MAX_GROUP == 0 else 1
+
+
 def _band_steps(window: int, resident: int, most: int = 1280) -> int:
     """The streamed side's block under a window: what a resident block's
     band spans (``window + resident - 1`` positions), in as few equal steps
@@ -826,7 +841,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``Hkv`` divides ``H``: query head ``j`` reads key-value head ``j // (H //
     Hkv)`` (grouped-query attention; one program holds a key-value head's
     whole group of query heads, and the backward sums dK / dV over it in
-    VMEM). ``window`` (static,
+    VMEM; a group of sixteen or more is split over programs of eight:
+    ``_MAX_GROUP``). ``window`` (static,
     with ``causal``) keeps, of the keys a query may see, the nearest
     ``window``; blocks wholly outside the band are neither run nor fetched
     (``_Band``). ``block_diffusion = (L, block)`` (static) states the mask
@@ -866,6 +882,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             f"(q {q.shape}, k {k.shape})")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    split = _programs_of(q.shape[2] // k.shape[2])
+    if split > 1:
+        k, v = jnp.repeat(k, split, axis=2), jnp.repeat(v, split, axis=2)
     rule = _default_blocks(q.shape[1], k.shape[1], window,
                            q.shape[2] // k.shape[2])
     bwd_q, bwd_k = block_q_bwd or block_q, block_k_bwd or block_k
@@ -1395,6 +1414,7 @@ def program_plan(seq: int, heads: int, head_dim: int, dtype, *,
                     seq, heads, head_dim, jnp.dtype(dtype).itemsize),
                 "block_q": seq, "block_k": seq, "band_fill": round(fill, 4)}
     group = heads // kv_heads
+    group //= _programs_of(group)
     block_q, block_k = _default_blocks(seq, seq, window, group).fwd
     band = _Mask(causal, window, block_diffusion).band(
         block_q, block_k, seq, seq)

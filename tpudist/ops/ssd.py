@@ -1,0 +1,148 @@
+"""The pieces of a Mamba-2 mixer (arXiv:2405.21060) that are not a dense
+product: the causal depthwise convolution over time, the selective
+state-space recurrence in its chunked form (state-space duality), and the
+gated group norm. Plain ``jax.numpy``, differentiated by JAX; no kernel.
+
+The recurrence, for one head with a scalar ``A < 0``, a state ``h`` [P, N],
+``x_t`` [P], ``B_t`` and ``C_t`` [N] (a group of heads shares B and C),
+``dt_t > 0``:
+
+    h_t = exp(dt_t A) h_(t-1) + dt_t x_t B_t^T,     y_t = h_t C_t + D x_t
+
+**The chunked form** (``ssd_scan``). With ``a_t = dt_t A`` and ``cum_i`` the
+running sum of ``a`` inside a chunk of ``Q`` positions:
+
+- within a chunk, ``y_i += sum over j <= i of (C_i . B_j) exp(cum_i - cum_j)
+  dt_j x_j``: a [Q, Q] product of C and B a group, times the lower-triangular
+  decay a head, times the chunk's ``dt x`` (three matrix products);
+- a chunk's own state, ``S_c = sum over j of exp(cum_last - cum_j) dt_j x_j
+  B_j^T`` [P, N] a head;
+- a pass over the chunks of a row carries states forward: the state that
+  enters chunk c + 1 is ``exp(cum_last of c)`` times the one that entered c,
+  plus ``S_c`` (``lax.scan``: the one sequential part, ``T / Q`` turns);
+- the carried state's part, ``y_i += exp(cum_i) C_i . (state that entered)``.
+
+**Precision.** ``dt``, ``A``, every running sum of ``dt A`` and every ``exp``
+of one are float32: a decay is a product of up to ``Q`` factors, and a
+rounded exponent is a relative error in all of them at once. The products
+between C, B, x and the states run in the activations' dtype and accumulate
+in float32; the carried state is float32 between chunks. The exponent is
+always a difference that is <= 0 (``cum_i - cum_j`` with ``j <= i``, masked
+BEFORE the exp), so nothing overflows whatever ``dt`` is.
+
+A length that the chunk does not divide is padded on the right with ``dt =
+0`` and ``x = B = C = 0`` (a position that neither decays nor writes the
+state) and cut afterwards.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+def causal_conv1d(x: jax.Array, kernel: jax.Array,
+                  bias: jax.Array) -> jax.Array:
+    """Depthwise causal convolution over time: ``y_t = bias + sum over k of
+    kernel[k] x_(t - K + 1 + k)`` for ``x`` [B, T, C], ``kernel`` [K, C]
+    (``kernel[K - 1]`` weighs the position itself, as torch's ``conv1d``
+    with ``padding = K - 1`` cut to T), zeros before the row. float32 out:
+    K shifted multiply-adds, no [T, K, C] window."""
+    taps, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    y = bias.astype(jnp.float32)
+    for k in range(taps):
+        y = y + padded[:, k:k + t].astype(jnp.float32) * kernel[k]
+    return y
+
+
+def gated_group_norm(y: jax.Array, z: jax.Array, weight: jax.Array,
+                     groups: int, eps: float) -> jax.Array:
+    """``RMSNorm_by_group(y * silu(z)) * weight`` in float32: the gate
+    first, then the norm over each of ``groups`` equal runs of the last
+    axis."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    by_group = g.reshape(*g.shape[:-1], groups, -1)
+    by_group = by_group * lax.rsqrt(
+        jnp.mean(jnp.square(by_group), axis=-1, keepdims=True) + eps)
+    return by_group.reshape(g.shape) * weight
+
+
+def _running_sum(da: jax.Array) -> jax.Array:
+    """The running sum of ``dt A`` inside a chunk, float32. (A function of
+    its own so that the benchmark's control can take it, and with it every
+    ``exp`` below, in bfloat16: ``selftest/bf16_decay_on_chip.py``.)"""
+    return jnp.cumsum(da, axis=-1)
+
+
+def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+             c: jax.Array, d: jax.Array, chunk: int):
+    """The recurrence of the module's docstring in its chunked form.
+
+    ``x`` [B, T, H, P]; ``dt`` [B, T, H] float32, positive; ``a`` [H]
+    float32, negative; ``b``, ``c`` [B, T, G, N] (G divides H: head ``h``
+    reads group ``h // (H / G)``); ``d`` [H]. Returns ``(y [B, T, H, P]
+    float32, carry_min)``: ``carry_min`` is the least ``exp(sum of dt A over
+    a chunk)`` over rows, chunks and heads, what of a state outlives one
+    chunk where it fades fastest."""
+    bsz, t, heads, p = x.shape
+    groups, n = b.shape[2], b.shape[3]
+    rep = heads // groups
+    pad = -t % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+                       for v in (x, dt, b, c))
+    nc = (t + pad) // chunk
+    dtype = x.dtype
+    f32 = jnp.float32
+
+    xc = x.reshape(bsz, nc, chunk, groups, rep, p)
+    bc = b.reshape(bsz, nc, chunk, groups, n)
+    cc = c.reshape(bsz, nc, chunk, groups, n)
+    # [B, chunks, H, Q]: the running sum lies on the minor axis
+    dtc = jnp.moveaxis(dt.astype(f32).reshape(bsz, nc, chunk, heads), 2, 3)
+    cum = _running_sum(dtc * a.astype(f32)[:, None])
+    last = cum[..., -1:]
+
+    def by_position(v):
+        """[B, chunks, H, Q] -> [B, chunks, Q, G, H / G, 1]."""
+        return jnp.moveaxis(v, 2, 3).reshape(bsz, nc, chunk, groups, rep, 1)
+
+    # within a chunk: (C B^T) a group, times the decay a head, times dt x
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(lower, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf)).astype(f32)
+    scores = jnp.einsum("bzign,bzjgn->bzgij", cc, bc,
+                        preferred_element_type=f32)
+    mixed = (scores[:, :, :, None]
+             * decay.reshape(bsz, nc, groups, rep, chunk, chunk)).astype(dtype)
+    xdt = xc.astype(f32) * by_position(dtc)
+    y = jnp.einsum("bzgrij,bzjgrp->bzigrp", mixed, xdt.astype(dtype),
+                   preferred_element_type=f32)
+
+    # a chunk's own state: what its positions leave at its end
+    to_end = jnp.exp(last - cum).astype(f32)
+    states = jnp.einsum("bzjgn,bzjgrp->bzgrpn", bc,
+                        (xdt * by_position(to_end)).astype(dtype),
+                        preferred_element_type=f32)
+
+    # the pass between chunks: the state that enters each, float32
+    chunk_decay = jnp.exp(last[..., 0]).astype(f32)          # [B, chunks, H]
+    keep = chunk_decay.reshape(bsz, nc, groups, rep, 1, 1)
+
+    def carry(state, turn):
+        kept, own = turn
+        return kept * state + own, state
+    _, entered = lax.scan(
+        carry, jnp.zeros_like(states[:, 0]),
+        (jnp.moveaxis(keep, 1, 0), jnp.moveaxis(states, 1, 0)))
+    entered = jnp.moveaxis(entered, 0, 1)
+
+    # the carried state's part
+    y = y + jnp.einsum("bzign,bzgrpn->bzigrp", cc, entered.astype(dtype),
+                       preferred_element_type=f32) * by_position(
+                           jnp.exp(cum).astype(f32))
+    y = y.reshape(bsz, t + pad, heads, p)[:, :t]
+    y = y + x[:, :t].astype(f32) * d.astype(f32)[:, None]
+    return y, jnp.min(chunk_decay)

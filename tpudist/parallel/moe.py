@@ -22,13 +22,19 @@ top-1 routing, cf. Fedus et al., and the Mesh-TF capacity formulation):
 single-device reference (same routing math, no capacity drop when C covers
 all tokens) used by tests and small-scale runs.
 
-``moe_topk_held`` (last section) is the other layer: softmax over all
-experts, the k largest renormalised, no capacity and no dropped pair, for a
-holder of ``n`` consecutive experts of ``E`` that computes its own experts'
-part of the result (grouped products over the experts held:
-``ops/pallas/grouped_matmul.py``). On one chip it
-runs without an exchange; the exchange across the chips that share a layer
-is not written yet.
+``moe_topk_held`` (last section) is the other layer: a score over all
+experts, the k largest chosen and their weights renormalised, no capacity
+and no dropped pair, for a holder of ``n`` consecutive experts of ``E`` that
+computes its own experts' part of the result (grouped products over the
+experts held: ``ops/pallas/grouped_matmul.py``). Two router rules
+(``route_topk``: a softmax's, or sigmoid scores with a bias that chooses and
+does not weigh, and a scaling factor) and two expert bodies (SwiGLU, gated:
+``gate`` / ``up`` / ``down``; ``relu(x up^T)^2 down``, ungated: ``up`` /
+``down``) go through the same pair buffer, grouped products and walks; which,
+the registered model says. ``shared_expert`` is the dense expert every token
+visits, which every holder computes whole. On one chip the layer runs without
+an exchange; the exchange across the chips that share a layer is not written
+yet.
 """
 
 from __future__ import annotations
@@ -161,20 +167,40 @@ def make_moe(mesh: Mesh, expert_axis: str = "expert",
 
 # -- top-k routing over all experts, a share of them held ---------------------
 
-def route_topk(u: jax.Array, router: jax.Array, top_k: int):
-    """``p = softmax(u router)`` over all experts in float32 (the product at
-    full precision: a near-tie decides which expert runs), the ``top_k``
-    largest and their weights ``p_e / sum of the top_k``. u [T, d] ->
-    (experts [T, k] int32, weights [T, k] float32)."""
+ROUTER_RULES = ("softmax", "sigmoid")
+
+
+def route_topk(u: jax.Array, router: jax.Array, top_k: int, *,
+               rule: str = "softmax", bias: jax.Array | None = None,
+               scale: float = 1.0):
+    """The ``top_k`` experts of each token and their weights, from logits
+    ``u router`` over all experts in float32 (the product at full precision:
+    a near-tie decides which expert runs). u [T, d] -> (experts [T, k]
+    int32, weights [T, k] float32).
+
+    ``softmax``: ``p = softmax(logits)``, the ``top_k`` largest, ``w_e = p_e
+    / sum of the top_k``. ``sigmoid``: ``s = sigmoid(logits)``, the ``top_k``
+    largest of ``s + bias`` (``bias`` [E]: it moves the choice and never the
+    weight), ``w_e = scale * s_e / (sum of the chosen s + 1e-20)``."""
     logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    _, experts = lax.top_k(probs, top_k)
+    if rule == "softmax":
+        probs = ranked = jax.nn.softmax(logits, axis=-1)
+    elif rule == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        ranked = probs if bias is None else probs + lax.stop_gradient(bias)
+    else:
+        raise ValueError(f"router rule {rule!r} (one of {ROUTER_RULES})")
+    _, experts = lax.top_k(ranked, top_k)
     # the chosen probabilities, read through a one-hot: the transpose is a
     # sum where top_k's own would scatter T x k scalars
     chosen = jax.nn.one_hot(experts, probs.shape[-1], dtype=probs.dtype)
     top = jnp.sum(probs[:, None, :] * chosen, axis=-1)
-    return experts, top / jnp.sum(top, axis=-1, keepdims=True)
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    if rule == "sigmoid":
+        total = total + 1e-20
+    weights = top / total
+    return experts, weights if scale == 1.0 else weights * scale
 
 
 class _Pairs(NamedTuple):
@@ -272,39 +298,42 @@ def _take_pairs(u, pairs: _Pairs):
         return _walk(pairs.rows, like, lambda at, _: u[at(pairs.tok)])
 
 
-def _product(a, w, load):
+def _product(a, w, load, transposed=False):
+    """``a @ w[g]`` a group; ``transposed``: ``w`` lies [G, out, in]."""
     # Pallas is imported where a layer is traced, not with the package
     from tpudist.ops.pallas.grouped_matmul import grouped_matmul
-    return grouped_matmul(a, w, load)
+    return grouped_matmul(a, w, load, transpose_rhs=transposed)
 
 
-def _transposes(a, w, load, g):
+def _transposes(a, w, load, g, transposed=False):
     """The grouped product's two cotangents, handed on together: the
     weights' is then computed where the rows' is, and not at the
     scheduler's leisure with both of its [C, .] operands kept until then (a
     whole step's peak read 14.0 GiB for 11.97)."""
     # the product's own transposes (its forward, unused here, is dropped)
-    _, transposed = jax.vjp(lambda a, w: _product(a, w, load), a, w)
-    return lax.optimization_barrier(transposed(g))
+    _, back = jax.vjp(lambda a, w: _product(a, w, load, transposed), a, w)
+    return lax.optimization_barrier(back(g))
 
 
-@jax.custom_vjp
-def _project_pairs(u, w, pairs: _Pairs, load):
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _project_pairs(u, w, pairs: _Pairs, load, transposed=False):
     """The grouped product of the pairs' rows ``u[tok]`` with their
-    experts' ``w``. The rows are not kept for the transposes but taken
-    again (one more gather of the filled blocks): kept, they would hold
-    [C, d] through the whole of the layer's backward. The cotangent of
-    ``u`` sums a token's rows in float32."""
-    return _project_pairs_fwd(u, w, pairs, load)[0]
+    experts' ``w`` ([n, d, f]; [n, f, d] where ``transposed``). The rows are
+    not kept for the transposes but taken again (one more gather of the
+    filled blocks): kept, they would hold [C, d] through the whole of the
+    layer's backward. The cotangent of ``u`` sums a token's rows in
+    float32."""
+    return _project_pairs_fwd(u, w, pairs, load, transposed)[0]
 
 
-def _project_pairs_fwd(u, w, pairs, load):
-    return _product(_take_pairs(u, pairs), w, load), (u, w, pairs, load)
+def _project_pairs_fwd(u, w, pairs, load, transposed):
+    return (_product(_take_pairs(u, pairs), w, load, transposed),
+            (u, w, pairs, load))
 
 
-def _project_pairs_bwd(res, g):
+def _project_pairs_bwd(transposed, res, g):
     u, w, pairs, load = res
-    dx, dw = _transposes(_take_pairs(u, pairs), w, load, g)
+    dx, dw = _transposes(_take_pairs(u, pairs), w, load, g, transposed)
     with jax.named_scope(scopes.MOE_DISPATCH):
         du = _sum_by_token(dx, pairs)
     return du, dw, None, None
@@ -347,6 +376,46 @@ def _swiglu_bwd(res, dh):
 
 
 _swiglu.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
+@jax.custom_vjp
+def _relu2(up, rows):
+    """``relu(up)^2`` in float32 of ``up`` [C, f], over the ``rows`` rows
+    that hold a pair, as ``_swiglu`` walks its own."""
+    return _relu2_fwd(up, rows)[0]
+
+
+def _relu2_fwd(up, rows):
+    def act(at, filled):
+        return jnp.where(filled, jnp.square(
+            jax.nn.relu(at(up).astype(jnp.float32))), 0.0)
+    return _walk(rows, up, act), (up, rows)
+
+
+def _relu2_bwd(res, dh):
+    up, rows = res
+
+    def act_t(at, filled):
+        return jnp.where(filled, 2.0 * jax.nn.relu(
+            at(up).astype(jnp.float32)) * at(dh).astype(jnp.float32), 0.0)
+    return _walk(rows, up, act_t), None
+
+
+_relu2.defvjp(_relu2_fwd, _relu2_bwd)
+
+
+def shared_expert(params: dict, u: jax.Array) -> jax.Array:
+    """The dense expert every token visits, ``relu(u up)^2 down`` (``up``
+    [d, f], ``down`` [f, d]; products in ``u``'s dtype, the activation in
+    float32): whole on every holder of a layer, so counted once where the
+    holders' parts are summed."""
+    with jax.named_scope(scopes.MOE_SHARED):
+        dt = u.dtype
+        h = jnp.dot(u, params["up"].astype(dt),
+                    preferred_element_type=jnp.float32)
+        h = jnp.square(jax.nn.relu(h)).astype(dt)
+        return jnp.dot(h, params["down"].astype(dt),
+                       preferred_element_type=jnp.float32).astype(dt)
 
 
 @jax.custom_vjp
@@ -399,19 +468,24 @@ _combine_pairs.defvjp(_combine_pairs_fwd, _combine_pairs_bwd)
 
 def moe_topk_held(params: dict, u: jax.Array, *, top_k: int,
                   first_expert: int = 0,
-                  router_input: jax.Array | None = None):
+                  router_input: jax.Array | None = None,
+                  rule: str = "softmax", scale: float = 1.0):
     """The part of a top-k expert layer that the holder of experts
     ``first_expert .. first_expert + n - 1`` computes.
 
-    ``params``: ``router`` [d, E] over ALL experts, ``gate`` / ``up``
-    [n, d, f] and ``down`` [n, f, d] of the n held. ``u`` [T, d] (the
-    products run in its dtype, accumulated in float32); ``router_input``,
-    where given, is what the router reads instead (the same values before
-    they were rounded to ``u``'s dtype). Every token routes over all E
-    experts (``route_topk``); a (token, expert) pair whose expert is held
-    here gets a row in the pair buffer, sorted by expert, and the result is
-    ``sum over a token's held pairs of w * (silu(x gate_e) * (x up_e))
-    down_e``. The weights stay normalised over all k chosen, held or not;
+    ``params``: ``router`` [d, E] over ALL experts; of the n held, ``gate``
+    / ``up`` [n, d, f] and ``down`` [n, f, d] where the experts are gated
+    (SwiGLU), or ``up`` [n, f, d] (as a Linear's weight lies, [out, in]) and
+    ``down`` [n, f, d] where they are not (``relu(x up_e^T)^2 down_e``: no
+    ``gate``); ``router_bias`` [E] where the rule takes one. ``u`` [T, d]
+    (the products run in its dtype, accumulated in float32);
+    ``router_input``, where given, is what the router reads instead (the
+    same values before they were rounded to ``u``'s dtype). Every token
+    routes over all E experts (``route_topk`` by ``rule`` and ``scale``); a
+    (token, expert) pair whose expert is held here gets a row in the pair
+    buffer, sorted by expert, and the result is ``sum over a token's held
+    pairs of w * (silu(x gate_e) * (x up_e)) down_e`` (or the ungated
+    body's). The weights stay normalised over all k chosen, held or not;
     what the absent experts would add is left out; a token none of whose k
     is held gets zero.
 
@@ -427,12 +501,12 @@ def moe_topk_held(params: dict, u: jax.Array, *, top_k: int,
     over the mean), ``moe_rows_walked`` (rows of the buffer the block loops
     touched: the pairs, rounded up to a block)."""
     t, _ = u.shape
-    n = params["gate"].shape[0]
+    n = params["down"].shape[0]
     n_pairs = t * top_k
     with jax.named_scope(scopes.MOE_ROUTER):
         experts, weights = route_topk(
             u if router_input is None else router_input, params["router"],
-            top_k)
+            top_k, rule=rule, bias=params.get("router_bias"), scale=scale)
     with jax.named_scope(scopes.MOE_DISPATCH):
         local = experts - first_expert
         held = (local >= 0) & (local < n)
@@ -454,9 +528,14 @@ def moe_topk_held(params: dict, u: jax.Array, *, top_k: int,
                 t, top_k))
     with jax.named_scope(scopes.MOE_EXPERTS):
         dt = u.dtype
-        # gate and up in one product: one cotangent for the pairs' rows
-        gate_up = jnp.concatenate([params["gate"], params["up"]], axis=2)
-        h = _swiglu(_project_pairs(u, gate_up.astype(dt), pairs, load), rows)
+        if "gate" in params:
+            # gate and up in one product: one cotangent for the pairs' rows
+            gate_up = jnp.concatenate([params["gate"], params["up"]], axis=2)
+            h = _swiglu(_project_pairs(u, gate_up.astype(dt), pairs, load),
+                        rows)
+        else:
+            h = _relu2(_project_pairs(u, params["up"].astype(dt), pairs,
+                                      load, True), rows)
         out = _grouped(h, params["down"].astype(dt), load)
     with jax.named_scope(scopes.MOE_COMBINE):
         y = _combine_pairs(out, jnp.where(held, weights, 0.0), pairs)

@@ -195,13 +195,25 @@ def _is_count(x) -> bool:
     return jnp.issubdtype(x.dtype, jnp.integer)
 
 
+# Leaves of the statistics that no forward pass writes, by the name their
+# model gives them: a router's correction bias (models/decoder.py), alike on
+# every replica and to stay so to the bit (the replicas' mean of eight equal
+# float32 values is not always that value).
+CONSTANT_STATS = ("e_score_correction_bias",)
+
+
 def mean_stats(stats: Any, axis_name: str) -> Any:
     """The replicas' mean of the running statistics; an integer leaf (a raw
-    PRNG key the model advances) is replicated, never averaged."""
-    if not any(map(_is_count, jax.tree_util.tree_leaves(stats))):
+    PRNG key the model advances) and a constant (``CONSTANT_STATS``) are
+    replicated, never averaged."""
+    def kept(path, x):
+        return _is_count(x) or getattr(path[-1], "key", None) in CONSTANT_STATS
+    flat = jax.tree_util.tree_leaves_with_path(stats)
+    if not any(kept(path, x) for path, x in flat):
         return jax.lax.pmean(stats, axis_name=axis_name)   # as it ever was
-    return jax.tree_util.tree_map(
-        lambda x: x if _is_count(x) else jax.lax.pmean(x, axis_name), stats)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: x if kept(path, x) else jax.lax.pmean(x, axis_name),
+        stats)
 
 
 def update_ema(cfg: Config, ema: Any, new_params: Any,

@@ -500,7 +500,8 @@ class Trainer:
                            vocab_share=(0, 1)):
             raise ValueError(
                 f"--layers / --expert-share / --vocab-share state a holder's "
-                f"share of a model of tokens; '{cfg.arch}' is none")
+                f"share of a model of tokens (the mellum2, sdar and "
+                f"nemotron3 families); '{cfg.arch}' is none")
         # Measurement-honest attention dispatch (VERDICT r5 weak #2):
         # resolve --flash OUTSIDE any trace. `auto` micro-benchmarks
         # flash-vs-XLA on the attached chip at the exact workload shape
@@ -895,7 +896,8 @@ class Trainer:
                 kv_heads=w["kv_heads"], causal=w["causal"],
                 window=w["window"],
                 block_diffusion=w.get("block_diffusion")) for w in workloads]
-            dec["schedule"] = dec["programs"][0]["schedule"]
+            if dec["programs"]:       # a share may keep no attention
+                dec["schedule"] = dec["programs"][0]["schedule"]
         dec["reason"] = "; ".join(filter(None, [dec.get("reason"),
                                                 dec["key"]]))
         return self._announce_flash_decision(dec)
