@@ -63,6 +63,21 @@ def pytest_configure(config):
 import pytest  # noqa: E402
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _free_compiled_programs():
+    """Drop jax's caches of traced and compiled programs after each test
+    file. A worker that had run ``test_flash_attention.py``, ``test_ops.py``
+    and ``test_ring_attention.py`` segfaulted in XLA's CPU compiler at
+    ``test_remat.py``'s first compile, every time and on PR 38's tree too
+    (PR 39's new files moved pytest-xdist's schedule onto that order): a
+    process that keeps every file's executables alive is what this
+    runtime does not survive (the note on donation above is the same
+    class)."""
+    yield
+    import jax
+    jax.clear_caches()
+
+
 @pytest.fixture(scope="session")
 def devices():
     import jax

@@ -8,7 +8,7 @@ how a kernel backward that 21 interpret-mode tests passed was refused at
 every width on the real target (PR 21).
 
 - the Pallas kernels at real widths, forward and forward+backward (tier-1,
-  well under a second each);
+  well under a second each), the chunked scan's pair among them;
 - the whole train-step programs the chip smoke runs (``slow``): resnet18
   on one and on four described chips, ViT-B/16 with flash on one chip and
   dp x tp over the 2x2.
@@ -57,6 +57,8 @@ FLASH_GQA_SHAPES = ((2, 8192, 32, 4, 128, 1024), (2, 8192, 32, 4, 128, None),
 # head_dim, block length): two rows of 8,192 ids, each a noised and a
 # clean copy.
 FLASH_BD_SHAPES = ((2, 16384, 32, 4, 128, 4),)
+SSD_SCAN_SHAPES = ((2, 8192, 64, 64, 8, 128, 128),
+                   (1, 1000, 64, 64, 8, 128, 128))
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -128,6 +130,17 @@ def _grouped_fn(bwd: bool):
     return jax.grad(f, argnums=(0, 1)) if bwd else f
 
 
+def _ssd_scan_fn(bwd: bool, chunk: int):
+    """``ssd.ssd_scan`` as the mixer calls it (the test steers
+    ``jax.default_backend``, which decides whether the pair is compiled)."""
+    from tpudist.ops import ssd
+
+    def f(x, dt, a, b, c, d):
+        return ssd.ssd_scan(x, dt, a, b, c, d, chunk)[0].sum()
+
+    return jax.grad(f, argnums=tuple(range(6))) if bwd else f
+
+
 def _flash_qkv_fn(bwd: bool, causal: bool):
     from tpudist.ops.pallas.flash_attention import flash_attention_qkv
 
@@ -163,6 +176,12 @@ _KERNEL_CASES = (
     + [pytest.param(("grouped", 98304, 8, k, n), bwd,
                     id=f"grouped_{k}x{n}_{'fwdbwd' if bwd else 'fwd'}")
        for k, n in ((2688, 1856), (1856, 2688)) for bwd in (False, True)]
+    # the Mamba-2 chunked scan at the published shape (rows, positions,
+    # heads, head_dim, groups, state, chunk): two rows of 8,192, then a
+    # length the chunk does not divide
+    + [pytest.param(("ssd_scan",) + shape, bwd,
+                    id=f"ssd_scan_t{shape[1]}_{'fwdbwd' if bwd else 'fwd'}")
+       for shape in SSD_SCAN_SHAPES for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -171,7 +190,7 @@ _KERNEL_CASES = (
 
 
 @pytest.mark.parametrize("case,bwd", _KERNEL_CASES)
-def test_kernel_compiles_for_v5e(topo, case, bwd):
+def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
     one = SingleDeviceSharding(topo.devices[0])
 
     def S(shape, dtype):
@@ -181,6 +200,13 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
         b, t, h, d, causal = case[1:]
         args = [S((b, t, h, 3, d), jnp.bfloat16)]
         fn = _flash_qkv_fn(bwd, causal)
+    elif case[0] == "ssd_scan":
+        b, t, h, p, g, n, chunk = case[1:]
+        args = [S((b, t, h, p), jnp.bfloat16), S((b, t, h), jnp.float32),
+                S((h,), jnp.float32), S((b, t, g, n), jnp.bfloat16),
+                S((b, t, g, n), jnp.bfloat16), S((h,), jnp.float32)]
+        fn = _ssd_scan_fn(bwd, chunk)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     elif case[0] == "grouped":
         _, rows, groups, k, n = case
         args = [S((rows, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
@@ -198,6 +224,20 @@ def test_kernel_compiles_for_v5e(topo, case, bwd):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    if case[0] == "ssd_scan":
+        # the forward alone; or the forward that keeps the entering states
+        # and the backward. No [.., Q, Q] decay of a head is in the program
+        # outside them, and what the kernels claim is the four products
+        # (backward: each one's two transposes and the two recomputed)
+        import re
+        assert text.count("tpu_custom_call") == (2 if bwd else 1)
+        assert not re.search(rf"\[[\d,]*{h},{chunk},{chunk}\]", text)
+        products = 2 * b * -(-t // chunk) * chunk * (
+            g * chunk * n + h * (chunk * p + 2 * p * n))
+        flops = compiled.cost_analysis()["flops"]
+        assert products * (3.3 if bwd else 1) <= flops <= products * (
+            3.6 if bwd else 1.1)
+        return
     if case[0] == "grouped":
         # the product; or its two transposes (dx, dw: a sum's gradient
         # needs no forward)

@@ -551,6 +551,19 @@ class MoEDecoder(nn.Module):
                                   weights=weights)
         return Scored(loss, acc1, counters)
 
+    # (below ``__call__``: the lines above are in every compiled step's
+    # source locations, and a Mosaic kernel's payload carries them into the
+    # compile cache's key)
+    def scan_plan(self, rows: int, seq_len: int) -> Optional[dict]:
+        """The plan of the Mamba blocks' chunked scan at ``rows`` rows of
+        ``seq_len`` ids (``ssd.scan_plan``: which program runs, read from
+        the shape); None for a share that keeps no such block."""
+        if "mamba" not in self.layer_types[:self.layers or self.num_layers]:
+            return None
+        m = self.mamba
+        return ssd.scan_plan(rows, seq_len, m["num_heads"], m["head_dim"],
+                             m["groups"], m["state"], m["chunk"])
+
 
 def _raw_key(key: jax.Array) -> jax.Array:
     """A key as the raw ``uint32[2]`` a state can hold as numbers."""

@@ -1,7 +1,9 @@
 """The pieces of a Mamba-2 mixer (arXiv:2405.21060) that are not a dense
 product: the causal depthwise convolution over time, the selective
 state-space recurrence in its chunked form (state-space duality), and the
-gated group norm. Plain ``jax.numpy``, differentiated by JAX; no kernel.
+gated group norm. Plain ``jax.numpy``, differentiated by JAX, but for the
+scan at shapes that are whole lane tiles, which runs as a Pallas kernel pair
+(``ops/pallas/ssd_scan.py``; ``scan_plan`` says which from the shape).
 
 The recurrence, for one head with a scalar ``A < 0``, a state ``h`` [P, N],
 ``x_t`` [P], ``B_t`` and ``C_t`` [N] (a group of heads shares B and C),
@@ -19,7 +21,8 @@ running sum of ``a`` inside a chunk of ``Q`` positions:
   B_j^T`` [P, N] a head;
 - a pass over the chunks of a row carries states forward: the state that
   enters chunk c + 1 is ``exp(cum_last of c)`` times the one that entered c,
-  plus ``S_c`` (``lax.scan``: the one sequential part, ``T / Q`` turns);
+  plus ``S_c`` (the one sequential part, ``T / Q`` turns: a ``lax.scan``
+  here, the grid's innermost axis with the state in VMEM in the kernel);
 - the carried state's part, ``y_i += exp(cum_i) C_i . (state that entered)``.
 
 **Precision.** ``dt``, ``A``, every running sum of ``dt A`` and every ``exp``
@@ -76,6 +79,34 @@ def _running_sum(da: jax.Array) -> jax.Array:
     return jnp.cumsum(da, axis=-1)
 
 
+LANES = 128
+
+
+def scan_plan(rows: int, t: int, heads: int, p: int, groups: int, n: int,
+              chunk: int) -> dict:
+    """Which program ``ssd_scan`` runs at a shape, read from the shape alone:
+    the Pallas kernel pair (``ops/pallas/ssd_scan.py``) where the chunk, the
+    state size and a group's ``heads x P`` lanes are whole numbers of lane
+    tiles (and a head's P divides a tile or is whole tiles), the ``jax.numpy``
+    form otherwise, with why. ``programs`` is the kernel's grid a block."""
+    rep = heads // groups
+    width = max(p, LANES)
+    why = None
+    if chunk % LANES:
+        why = f"a chunk of {chunk} is no whole number of lane tiles"
+    elif n % LANES:
+        why = f"a state of {n} is no whole number of lane tiles"
+    elif width % p or (rep * p) % width:
+        why = (f"a group's {rep} heads of {p} are no whole number of lane "
+               f"tiles")
+    plan = dict(kernel="xla" if why else "pallas", chunk=chunk,
+                heads_per_program=rep,
+                programs=rows * groups * -(-t // chunk))
+    if why:
+        plan["reason"] = why
+    return plan
+
+
 def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
              c: jax.Array, d: jax.Array, chunk: int):
     """The recurrence of the module's docstring in its chunked form.
@@ -85,25 +116,63 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     reads group ``h // (H / G)``); ``d`` [H]. Returns ``(y [B, T, H, P]
     float32, carry_min)``: ``carry_min`` is the least ``exp(sum of dt A over
     a chunk)`` over rows, chunks and heads, what of a state outlives one
-    chunk where it fades fastest."""
+    chunk where it fades fastest.
+
+    One algorithm, two programs of it, chosen by ``scan_plan`` from the
+    shape: the kernel pair or the ``jax.numpy`` form below (the fallback, and
+    what the kernel's tests are held to)."""
     bsz, t, heads, p = x.shape
     groups, n = b.shape[2], b.shape[3]
-    rep = heads // groups
     pad = -t % chunk
     if pad:
         x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
                        for v in (x, dt, b, c))
     nc = (t + pad) // chunk
+    f32 = jnp.float32
+    # [B, chunks, H, Q]: the running sum lies on the minor axis
+    dtc = jnp.moveaxis(dt.astype(f32).reshape(bsz, nc, chunk, heads), 2, 3)
+    cum = _running_sum(dtc * a.astype(f32)[:, None])
+    chunk_decay = jnp.exp(cum[..., -1]).astype(f32)          # [B, chunks, H]
+    if scan_plan(bsz, t, heads, p, groups, n, chunk)["kernel"] == "pallas":
+        y = _by_kernel(x, b, c, d, dtc, cum, chunk_decay)
+    else:
+        y = _by_fusions(x, b, c, d, dtc, cum, chunk_decay)
+    return y[:, :t], jnp.min(chunk_decay)
+
+
+def _by_kernel(x, b, c, d, dtc, cum, chunk_decay):
+    """``y`` by the kernel pair: x, B and C as they lie ([B, T, lanes]), the
+    chunk's scalars a group, and a head's chunk decay and ``D`` over its P
+    lanes (what the kernel multiplies whole lane tiles by)."""
+    from tpudist.ops.pallas.ssd_scan import scan_chunks
+    bsz, tp, heads, p = x.shape
+    groups = b.shape[2]
+    f32 = jnp.float32
+
+    def by_group(v):
+        return v.astype(f32).reshape(bsz, v.shape[1], groups, heads // groups,
+                                     v.shape[3])
+    y = scan_chunks(
+        x.reshape(bsz, tp, heads * p), b.reshape(bsz, tp, -1),
+        c.reshape(bsz, tp, -1), by_group(dtc), by_group(cum),
+        jnp.repeat(chunk_decay, p, axis=-1)[:, :, None],
+        jnp.repeat(d.astype(f32), p)[None])
+    return y.reshape(bsz, tp, heads, p)
+
+
+def _by_fusions(x, b, c, d, dtc, cum, chunk_decay):
+    """``y`` by ``jax.numpy``, differentiated by JAX."""
+    bsz, tp, heads, p = x.shape
+    groups, n = b.shape[2], b.shape[3]
+    rep = heads // groups
+    nc, chunk = cum.shape[1], cum.shape[3]
     dtype = x.dtype
     f32 = jnp.float32
+    last = cum[..., -1:]
 
     xc = x.reshape(bsz, nc, chunk, groups, rep, p)
     bc = b.reshape(bsz, nc, chunk, groups, n)
     cc = c.reshape(bsz, nc, chunk, groups, n)
-    # [B, chunks, H, Q]: the running sum lies on the minor axis
-    dtc = jnp.moveaxis(dt.astype(f32).reshape(bsz, nc, chunk, heads), 2, 3)
-    cum = _running_sum(dtc * a.astype(f32)[:, None])
-    last = cum[..., -1:]
 
     def by_position(v):
         """[B, chunks, H, Q] -> [B, chunks, Q, G, H / G, 1]."""
@@ -128,7 +197,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
                         preferred_element_type=f32)
 
     # the pass between chunks: the state that enters each, float32
-    chunk_decay = jnp.exp(last[..., 0]).astype(f32)          # [B, chunks, H]
     keep = chunk_decay.reshape(bsz, nc, groups, rep, 1, 1)
 
     def carry(state, turn):
@@ -143,6 +211,5 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     y = y + jnp.einsum("bzign,bzgrpn->bzigrp", cc, entered.astype(dtype),
                        preferred_element_type=f32) * by_position(
                            jnp.exp(cum).astype(f32))
-    y = y.reshape(bsz, t + pad, heads, p)[:, :t]
-    y = y + x[:, :t].astype(f32) * d.astype(f32)[:, None]
-    return y, jnp.min(chunk_decay)
+    y = y.reshape(bsz, tp, heads, p)
+    return y + x.astype(f32) * d.astype(f32)[:, None]

@@ -138,6 +138,10 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     # cache / measured). Emitted once per Trainer construction for vit*
     # archs so summarize and the regression gate cover kernel choice.
     "attention_dispatch": ("kernel", "mode", "source"),
+    # The Mamba blocks' chunked scan (tpudist/ops/ssd.py::scan_plan): which
+    # program the shape takes ("pallas" | "xla", with a ``reason`` where it
+    # is "xla"). Emitted once per Trainer construction where a block has one.
+    "ssm_scan": ("kernel", "chunk", "heads_per_program", "programs"),
     # Gradient-compression resolution (tpudist/ops/comm_dispatch): which
     # wire format --compress-grads resolved to ("int8" | "dense"), on what
     # evidence, with the dense-equivalent gradient payload bytes summarize

@@ -515,6 +515,9 @@ class Trainer:
         mark(scopes.INIT_OTHER)
         if takes_flash(cfg.arch) and not self.uses_seq_axis:
             self.flash_decision = self._resolve_flash_dispatch()
+        if hasattr(self.model, "scan_plan"):
+            self._announce_scan_plan(self.model.scan_plan(
+                cfg.per_device_batch_size, cfg.seq_len))
         seed = cfg.seed if cfg.seed is not None else 0
         mark(scopes.INIT_DISPATCH)
         if self.uses_seq_axis or self.uses_expert_axis or self.uses_pipe_axis:
@@ -865,6 +868,18 @@ class Trainer:
             self.telemetry.emit("attention_dispatch",
                                 **attention_dispatch.event_fields(dec))
         return dec
+
+    def _announce_scan_plan(self, plan: Optional[dict]) -> None:
+        """The log line and telemetry event of the Mamba blocks' chunked
+        scan (``ssd.scan_plan``: read from the shape, nothing to decide)."""
+        if plan is None:
+            return
+        self.log(f"=> ssm scan: {plan['kernel']} (chunk {plan['chunk']}, "
+                 f"heads_per_program {plan['heads_per_program']}, programs "
+                 f"{plan['programs']} a block"
+                 + (f": {plan['reason']}" if "reason" in plan else "") + ")")
+        if self.telemetry is not None:
+            self.telemetry.emit("ssm_scan", **plan)
 
     def _forced_flash_decision(self) -> dict:
         """The attention decision of a family without a start-up probe (a
