@@ -68,6 +68,11 @@ def test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs(
     assert new[0]["moves"] == "train_img_per_s_chip"
     assert callable(_nemotron3.reader("ssd_scan_roofline").read)
     bench["per_layer"].remove(new[0])
+    # PR 40's seven (held below, `test_the_tracing_entries_are_listed`): six
+    # list the cell
+    later = [m for m in bench["per_layer"] if m["name"] in _TRACING]
+    assert sum(_nemotron3.CELL in m["workloads"] for m in later) == 6
+    bench["per_layer"] = [m for m in bench["per_layer"] if m not in later]
     load = _nemotron3.load
     monkeypatch.setattr(
         _nemotron3, "load",
@@ -104,6 +109,211 @@ def test_attn_bd_fill_reader(monkeypatch, capsys):
         attention_dispatch, "program",
         lambda seq, heads, head_dim, dtype, *, kv_heads=None, causal=False,
         window=None: {})
+    assert m.read(ctx) is None
+
+
+# -- PR 40: the readers of the blocks' leaf scopes, of the loop's activities
+# and of the compile listener ------------------------------------------------
+_TOKENS = ["mellum2_12b_ep4_staged_8k", "sdar_30b_ep8_staged_8k",
+           "nemotron3_nano_ep16_staged_8k"]
+_ALL = ["resnet18_staged", "vit_b16_staged"] + _TOKENS
+_ATTN = "step program: attention blocks"
+# name -> (unit, source, layer, workloads)
+_TRACING = {
+    "attn_mixer_ms": ("ms", "device_trace", _ATTN, _TOKENS),
+    "attn_proj_ms": ("ms", "device_trace", _ATTN, _TOKENS),
+    "attn_qk_rope_ms": ("ms", "device_trace", _ATTN, _TOKENS[:2]),
+    "block_norm_ms": ("ms", "device_trace", "step program", _TOKENS),
+    "step_unitemised_ms": ("ms", "device_trace", "step program", _TOKENS),
+    "loop_host_max_ms": ("ms", "program_span", "trainer loop", _ALL),
+    "window_compile_count": ("count", "program_counter", "trainer loop",
+                             _ALL),
+}
+_DEVICE_READERS = [n for n, v in _TRACING.items() if v[1] == "device_trace"]
+reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
+                           _nemotron3.scopes_of)
+
+
+@pytest.mark.parametrize("name", _TRACING)
+def test_the_tracing_entries_are_listed(name):
+    """`BENCHMARK.json` lists each of the seven once, at the end, with the
+    cells where its reader finds something to read."""
+    bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
+    assert [m["name"] for m in bench["per_layer"][-7:]] == list(_TRACING)
+    entry, = [m for m in bench["per_layer"] if m["name"] == name]
+    unit, source, layer, workloads = _TRACING[name]
+    assert entry == {"name": name, "unit": unit, "better": "lower",
+                     "source": source, "layer": layer,
+                     "moves": "train_img_per_s_chip", "workloads": workloads}
+    assert callable(reader(name).read)
+
+
+def test_step_unitemised_holds_the_programs_list():
+    from tpudist.obs import scopes
+    assert reader("step_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS
+    assert reader("loop_host_max_ms").ACTIVITIES == scopes.LOOP_ACTIVITIES
+    assert reader("attn_mixer_ms").PARTS == (
+        scopes.ATTN_QKV_PROJ, scopes.ATTN_QK_NORM_ROPE, scopes.ATTN_FUSED,
+        scopes.ATTN_OUT_PROJ)
+    from tpudist import telemetry
+    assert reader("window_compile_count").COMPILE == telemetry.COMPILE_EVENT
+
+
+_FWD = "jit(step)/jvp(tpudist_forward)/MoEDecoder/"
+_BWD = ("jit(step)/transpose(jvp(tpudist_forward))/MoEDecoder/"
+        "jvp(tpudist_forward)/MoEDecoder/checkpoint/")
+_MIX = "layer_1/self_attention/attn_mixer/"
+
+
+def _block_scopes():
+    return scopes_of(
+        (1.0, "fwd", _FWD + _MIX + "attn_qkv_proj/q_proj/dot_general"),
+        (2.0, "bwd", _BWD + "rematted_computation/" + _MIX
+         + "attn_qkv_proj/k_proj/dot_general"),
+        (4.0, "fwd", _FWD + _MIX + "attn_qk_norm_rope/q_norm/rsqrt"),
+        (8.0, "bwd", _BWD + _MIX + "attn_qk_norm_rope/mul"),
+        (16.0, "fwd", _FWD + _MIX + "attn_fused/pallas_call"),
+        (32.0, "bwd", _BWD + _MIX + "attn_out_proj/o_proj/dot_general"),
+        (64.0, "fwd", _FWD + _MIX + "transpose"),        # under no part
+        (128.0, "layout_copy", _FWD + _MIX + "attn_fused/x"),   # not named
+        (256.0, "fwd", _FWD + "layer_1/block_norm/input_norm/rsqrt"),
+        (512.0, "bwd", _BWD + "layer_1/block_norm/add_any"),
+        (1024.0, "fwd", _FWD + "layer_1/moe/moe_experts/pallas_call"),
+        (2048.0, "fwd", _FWD + "while/body/dynamic_slice"),    # unitemised
+        (4096.0, "opt", "jit(step)/tpudist_optimizer/mul"),
+        (8192.0, "bwd", "jit(step)/transpose(jvp(tpudist_forward))/"
+         "MoEDecoder/while/body/closed_call/tpudist_loss/reduce_sum"))
+
+
+_HAND_MADE = {"attn_mixer_ms": 127.0, "attn_proj_ms": 35.0,
+              "attn_qk_rope_ms": 12.0, "block_norm_ms": 768.0,
+              "step_unitemised_ms": 2112.0}
+
+
+@pytest.mark.parametrize("name", _DEVICE_READERS)
+def test_block_scope_readers_on_hand_made_scopes(monkeypatch, capsys, name):
+    from harness import scope_reduce
+    scopes = dict(_block_scopes(), busy_step_ms=16383.0)
+    monkeypatch.setattr(scope_reduce, "step_scopes", lambda ctx: scopes)
+    config = _nemotron3.load(_nemotron3.CHIP, "configs",
+                             "mellum2_12b_ep4.json")
+    ctx = {"config": config, "batch": 2, "chips": 1,
+           "peak": {"flops_per_s_bf16": 197e12}}
+    assert reader(name).read(ctx) == _HAND_MADE[name]
+    out = capsys.readouterr().out.splitlines()
+    if name == "attn_mixer_ms":
+        assert said(out, "attn_mixer_ms")[0] == {
+            "attn_qkv_proj": 3.0, "attn_qk_norm_rope": 12.0,
+            "attn_fused": 16.0, "attn_out_proj": 32.0, "other_ms": 64.0,
+            "attn_mixer_ms": 127.0}
+    elif name == "attn_proj_ms":
+        line = said(out, "attn_proj_ms")[0]
+        assert (line["attn_qkv_proj"], line["attn_out_proj"]) == (3.0, 32.0)
+        # four layers, forward + rematerialised forward + backward:
+        # 2 x 16,384 x 2,304 x 9,216 = 0.70 TFLOP a layer forward
+        assert line["products_flops"] == 16 * 2 * 16384 * 2304 * 9216
+        assert line["products_least_ms"] == pytest.approx(56.5, abs=0.1)
+    elif name == "step_unitemised_ms":
+        line = said(out, "step_unitemised")[0]
+        assert line["operations"] == 2
+        assert [row[1] for row in line["longest"]] == [2048.0, 64.0]
+        assert line["longest"][0][2].endswith("while/body/dynamic_slice")
+    # a step without the scopes (a classifier, the parent commit's
+    # decoder), or no scopes at all: no metric, and no error
+    older = scopes_of(
+        (3.0, "fwd", _FWD + "layer_1/self_attention/q_proj/dot_general"),
+        (5.0, "fwd", _FWD + "layer_1/self_attention/attn_fused/pallas_call"),
+        (7.0, "fwd", _FWD + "layer_1/input_norm/rsqrt"))
+    for found in (older, None):
+        monkeypatch.setattr(scope_reduce, "step_scopes", lambda ctx: found)
+        assert reader(name).read(ctx) is None
+
+
+@pytest.mark.parametrize("cell,flops", [
+    ("mellum2_12b_ep4", 16 * 2 * 16384 * 2304 * 9216),
+    ("sdar_30b_ep8", 16 * 2 * 32768 * 2048 * 9216),
+    ("nemotron3_nano_ep16", 4 * 2 * 16384 * 2688 * 8704)])
+def test_attn_proj_counts_at_the_cells_shapes(cell, flops):
+    """The four products of every attention block over the step's
+    positions, four times (the forward, the rematerialised forward, about
+    twice transposed): 11.1, 19.8 and 3.07 TFLOP a step."""
+    config = _nemotron3.load(_nemotron3.CHIP, "configs", cell + ".json")
+    assert reader("attn_proj_ms").products_flops(config, 2) == flops
+
+
+@pytest.mark.parametrize("name", _DEVICE_READERS)
+def test_block_scope_readers_on_a_recorded_chip_trace(monkeypatch, name):
+    """`selftest/recorded_scopes.json.gz` is a `resnet18_staged` step of a
+    program without the scopes: left out, not wrong."""
+    import gzip
+    from harness import scope_reduce
+    with gzip.open(os.path.join(os.path.dirname(_PATH),
+                                "recorded_scopes.json.gz"), "rt") as f:
+        rec = json.load(f)
+    found = scope_reduce.by_scope(rec, rec["scopes"], rec["module"])
+    assert found["steps"] == 3 and len(found["ops"]) > 100
+    monkeypatch.setattr(scope_reduce, "step_scopes", lambda ctx: found)
+    assert reader(name).read({"config": {}, "batch": 1200, "chips": 1,
+                              "peak": {"flops_per_s_bf16": 197e12}}) is None
+
+
+def test_loop_host_reader_on_hand_made_rows(capsys):
+    m = reader("loop_host_max_ms")
+    ms = 1_000_000
+    rows = [["bench.window", 10 * ms, 1000 * ms],
+            ["tpudist.loop_hooks", 5 * ms, 900 * ms],    # before the window
+            ["tpudist.loop_prologue", 11 * ms, 3 * ms],
+            ["tpudist.loop_hooks", 20 * ms, 1 * ms],
+            ["tpudist.loop_hooks", 40 * ms, 301 * ms],
+            ["tpudist.loop_meters", 400 * ms, 2 * ms],
+            ["tpudist.loop_log", 410 * ms, 5 * ms],
+            ["tpudist.loop_epoch_end", 1005 * ms, 50 * ms]]   # cut at its end
+    by_name, value = m.longest(rows)
+    assert value == 301.0
+    assert by_name["tpudist.loop_hooks"] == [2, 151.0, 301.0]
+    assert by_name["tpudist.loop_epoch_end"] == [1, 5.0, 5.0]
+    assert list(by_name) == sorted(m.ACTIVITIES)
+    # no window span: every span of theirs counts
+    assert m.longest(rows[1:])[1] == 900.0
+
+
+def test_loop_host_reader_on_a_recorded_chip_trace():
+    """`selftest/recorded_trace.json.gz` is a window of a program without
+    the five spans: left out, not wrong."""
+    import gzip
+    with gzip.open(os.path.join(os.path.dirname(_PATH),
+                                "recorded_trace.json.gz"), "rt") as f:
+        rec = json.load(f)
+    assert any(r[0] == "tpudist.prefetch" for r in rec["host"])
+    assert reader("loop_host_max_ms").longest(rec["host"]) == (None, None)
+
+
+def test_compile_count_reader_reads_the_programs_listener(monkeypatch,
+                                                          capsys):
+    from tpudist import telemetry
+    m = reader("window_compile_count")
+    kept = [
+        {"event": telemetry.COMPILE_EVENT, "seconds": 20.0, "t_end": 90.0,
+         "step": 0},                                    # before the window
+        {"event": telemetry.CACHE_READ_EVENT, "seconds": 0.5, "t_end": 120.0,
+         "step": 7},                # inside a compile request: not counted
+        {"event": telemetry.COMPILE_EVENT, "seconds": 0.6, "t_end": 120.1,
+         "step": 7},
+        {"event": telemetry.COMPILE_EVENT, "seconds": 3.0, "t_end": 140.0,
+         "step": None}]                                  # the reference's
+    monkeypatch.setattr(telemetry, "_compile_events", kept)
+    ctx = {"t_open": 100.0, "t_close": 130.0}
+    assert m.read(ctx) == 1
+    line = said(capsys.readouterr().out.splitlines(), "compiles")[0]
+    assert line["events"] == 4 and line["window_compile_count"] == 1
+    assert line["by_step"]["7"] == {"cache_read": 1, "cache_read_s": 0.5,
+                                    "compile": 1, "compile_s": 0.6}
+    assert [ev["step"] for ev in line["in_window"]] == [7, 7]
+    assert line["in_window"][1]["t_end"] == pytest.approx(20.1)
+    # a window that built nothing reads 0, not nothing
+    assert m.read({"t_open": 200.0, "t_close": 230.0}) == 0
+    # a program without the listener (the parent commit): no metric
+    monkeypatch.delattr(telemetry, "compile_events")
     assert m.read(ctx) is None
 
 

@@ -130,12 +130,16 @@ def test_attention_stages_are_named(compiled_steps):
                    for n in names), stage
 
 
-@pytest.fixture(scope="module", params=["mellum2_tiny", "sdar_tiny"])
-def decoder_step(mesh8, request):
-    """[(HLO line, op_name)] of the DP step of a tiny decoder of tokens
-    (models/decoder.py: trained to predict the next id, and by diffusion
-    over blocks), every layer rematerialised and its attention on the
-    streaming kernel, as the chip benchmark's cells run the large ones."""
+TOKEN_ARCHS = ("mellum2_tiny", "sdar_tiny", "nemotron3_tiny")
+
+
+def _token_step(mesh8, arch, remat=True):
+    """(compiled DP step, its HLO text) of a tiny decoder of tokens
+    (models/decoder.py): `mellum2_tiny` and `sdar_tiny` (trained to predict
+    the next id, and by diffusion over blocks; two layers), `nemotron3_tiny`
+    (all five blocks: two Mamba-2 mixers, two expert blocks, one attention);
+    attention on the streaming kernel and, as the chip benchmark's cells run
+    the large ones, every layer rematerialised; the cache off."""
     from jax.experimental.compilation_cache import compilation_cache
     from tpudist.models import create_model
     from tpudist.train import (compute_dtype, create_train_state,
@@ -143,18 +147,25 @@ def decoder_step(mesh8, request):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        cfg = Config(arch=request.param, batch_size=16, seq_len=32,
+        cfg = Config(arch=arch, batch_size=16, seq_len=32,
                      optimizer="adamw", use_amp=True, seed=0).finalize(8)
-        model = create_model(cfg.arch, dtype=compute_dtype(cfg), layers=2,
-                             expert_share=(0, 4), flash=True, remat=True,
+        share = {} if arch == "nemotron3_tiny" else {"layers": 2}
+        model = create_model(cfg.arch, dtype=compute_dtype(cfg), **share,
+                             expert_share=(0, 4), flash=True, remat=remat,
                              loss_chunk=16)   # four turns of the head's loop
         state = create_train_state(jax.random.PRNGKey(0), model, cfg)
         rows = jax.ShapeDtypeStruct((16, 32), jnp.int32)
-        text = make_train_step(mesh8, model, cfg).lower(
-            state, rows, rows, jnp.float32(0.1)).compile().as_text()
+        compiled = make_train_step(mesh8, model, cfg).lower(
+            state, rows, rows, jnp.float32(0.1)).compile()
+        return compiled, compiled.as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         compilation_cache.reset_cache()
+
+
+def _named_rows(text):
+    """[(HLO line, op_name)] of the instructions the program's own code
+    produced (as `compiled_steps` keeps them)."""
     out = []
     for line in text.splitlines():
         name = re.search(r'op_name="([^"]*)"', line)
@@ -163,6 +174,25 @@ def decoder_step(mesh8, request):
                 and not any(p in line for p in PLUMBING):
             out.append((line, name.group(1)))
     return out
+
+
+@pytest.fixture(scope="module")
+def token_steps(mesh8):
+    """(arch, remat) -> (compiled DP step, its named rows), compiled once a
+    module."""
+    kept = {}
+
+    def get(arch, remat=True):
+        if (arch, remat) not in kept:
+            compiled, text = _token_step(mesh8, arch, remat)
+            kept[arch, remat] = (compiled, _named_rows(text))
+        return kept[arch, remat]
+    return get
+
+
+@pytest.fixture(scope="module", params=["mellum2_tiny", "sdar_tiny"])
+def decoder_step(token_steps, request):
+    return token_steps(request.param)[1]
 
 
 def test_every_device_op_of_the_decoder_step_has_a_scope(decoder_step):
@@ -190,43 +220,9 @@ def test_decoder_scopes_are_named_forward_and_backward(decoder_step, scope,
         assert any("/layer_1/" in n for n in named)
 
 
-def _nemotron_step(mesh8):
-    """(compiled DP step, its HLO text) of `nemotron3_tiny` (all five
-    blocks: two Mamba-2 mixers, two expert blocks, one attention),
-    rematerialised, attention on the streaming kernel; the cache off."""
-    from jax.experimental.compilation_cache import compilation_cache
-    from tpudist.models import create_model
-    from tpudist.train import (compute_dtype, create_train_state,
-                               make_train_step)
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        cfg = Config(arch="nemotron3_tiny", batch_size=16, seq_len=32,
-                     optimizer="adamw", use_amp=True, seed=0).finalize(8)
-        model = create_model(cfg.arch, dtype=compute_dtype(cfg),
-                             expert_share=(0, 4), flash=True, remat=True,
-                             loss_chunk=16)
-        state = create_train_state(jax.random.PRNGKey(0), model, cfg)
-        rows = jax.ShapeDtypeStruct((16, 32), jnp.int32)
-        compiled = make_train_step(mesh8, model, cfg).lower(
-            state, rows, rows, jnp.float32(0.1)).compile()
-        return compiled, compiled.as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
-
-
 @pytest.fixture(scope="module")
-def nemotron_step(mesh8):
-    compiled, text = _nemotron_step(mesh8)
-    out = []
-    for line in text.splitlines():
-        name = re.search(r'op_name="([^"]*)"', line)
-        if " = " in line and name is not None \
-                and "jit(step)/" in name.group(1) \
-                and not any(p in line for p in PLUMBING):
-            out.append((line, name.group(1)))
-    return compiled, out
+def nemotron_step(token_steps):
+    return token_steps("nemotron3_tiny")
 
 
 def test_every_device_op_of_the_mixer_blocks_step_has_a_scope(nemotron_step):
@@ -277,9 +273,102 @@ def test_the_new_scopes_change_no_compiled_flop_or_byte(mesh8, nemotron_step,
     named = nemotron_step[0].cost_analysis()
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    bare, text = _nemotron_step(mesh8)
+    bare, text = _token_step(mesh8, "nemotron3_tiny")
     assert f"/{scopes.SSM_SCAN}/" not in text
     assert f"/{scopes.MOE_SHARED}/" not in text
+    bare = bare.cost_analysis()
+    for key in ("flops", "bytes accessed", "transcendentals"):
+        assert named[key] == bare[key], key
+    assert named["flops"] > 0
+
+
+BLOCK_SCOPES = (scopes.ATTN_MIXER, scopes.ATTN_QKV_PROJ,
+                scopes.ATTN_QK_NORM_ROPE, scopes.ATTN_OUT_PROJ,
+                scopes.BLOCK_NORM)
+
+
+def _under(op_name, scope):
+    """`scope` is, or is wrapped in, a path element of `op_name` (the chip
+    benchmark's `harness/scope_sum.py::under`)."""
+    return any(scope == part or f"({scope})" in part
+               for part in op_name.replace(";", "/").split("/"))
+
+
+@pytest.mark.parametrize("scope", BLOCK_SCOPES)
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])
+@pytest.mark.parametrize("arch", TOKEN_ARCHS)
+def test_a_blocks_parts_are_named_forward_and_backward(token_steps, arch,
+                                                       remat, scope):
+    """What `attn_mixer_ms`, `attn_proj_ms`, `attn_qk_rope_ms` and
+    `block_norm_ms` of the chip benchmark sum: everything a decoder block
+    does outside its kernels lies under a leaf scope inside the forward
+    scope, plain and transposed, rematerialised or not; attention's parts
+    lie within `attn_mixer`, and an attention that neither norms nor
+    rotates q and k holds nothing under that part."""
+    named = [n for _, n in token_steps(arch, remat)[1]
+             if _under(n, scope) and scopes.FORWARD in n]
+    if arch == "nemotron3_tiny" and scope == scopes.ATTN_QK_NORM_ROPE:
+        assert not named
+        return
+    bwd = [n for n in named if phase_of(n) == "bwd"]
+    assert bwd, scope
+    if remat:
+        # the transposed copy sits behind the layer's checkpoint, and so
+        # does the forward made again: of a small part's two equal forwards
+        # XLA keeps one, under either name
+        assert any("/checkpoint/" in n for n in bwd)
+        assert any(phase_of(n) == "fwd" or "/rematted_computation/" in n
+                   for n in named), scope
+    if not remat or scope in (scopes.ATTN_MIXER, scopes.BLOCK_NORM):
+        assert any(phase_of(n) == "fwd" for n in named), scope
+    if scope == scopes.BLOCK_NORM:
+        norms = (("/norm/",) if arch == "nemotron3_tiny"
+                 else ("/input_norm/", "/post_norm/"))
+        for norm in norms + (f"MoEDecoder/{scope}/norm/",):
+            assert any(norm in n for n in named), norm
+        assert not any(_under(n, scopes.ATTN_MIXER) for n in named)
+    elif scope == scopes.ATTN_MIXER:
+        where = "/mixer/" if arch == "nemotron3_tiny" else "/self_attention/"
+        assert all(f"{where}{scope}/" in n for n in named)
+        assert any(_under(n, scopes.ATTN_FUSED) for n in named)
+    else:
+        assert all(_under(n, scopes.ATTN_MIXER) for n in named)
+        proj = {scopes.ATTN_QKV_PROJ: ("q_proj", "k_proj", "v_proj"),
+                scopes.ATTN_OUT_PROJ: ("o_proj",),
+                scopes.ATTN_QK_NORM_ROPE: ("q_norm", "k_norm")}[scope]
+        for name in proj:
+            assert any(f"/{scope}/{name}/" in n for n in named), name
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])
+@pytest.mark.parametrize("arch", TOKEN_ARCHS)
+def test_step_parts_cover_the_named_operations(token_steps, arch, remat):
+    """`scopes.STEP_PARTS` itemises a decoder's step: at most 3 % of its
+    named operations lie under none of the parts (a block's counters, the
+    head's loop plumbing, the cotangents' sums at a fan-out), what
+    `step_unitemised_ms` of the chip benchmark times."""
+    named = [n for _, n in token_steps(arch, remat)[1]]
+    missed = [n for n in named
+              if not any(_under(n, part) for part in scopes.STEP_PARTS)]
+    assert len(missed) <= 0.03 * len(named), sorted(set(missed))[:20]
+    # a part is a leaf: none lies within another
+    assert len(set(scopes.STEP_PARTS)) == len(scopes.STEP_PARTS)
+    assert scopes.ATTN_MIXER not in scopes.STEP_PARTS
+    assert scopes.SSM_MIXER not in scopes.STEP_PARTS
+
+
+@pytest.mark.parametrize("arch", ["mellum2_tiny", "sdar_tiny"])
+def test_the_block_scopes_change_no_compiled_flop_or_byte(mesh8, token_steps,
+                                                          monkeypatch, arch):
+    """As the mixer blocks' case above, for the pairs of attention and
+    experts: `attn_mixer`, its parts and `block_norm` are metadata."""
+    import contextlib
+    named = token_steps(arch, False)[0].cost_analysis()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare, text = _token_step(mesh8, arch, remat=False)
+    for scope in BLOCK_SCOPES:
+        assert f"/{scope}/" not in text, scope
     bare = bare.cost_analysis()
     for key in ("flops", "bytes accessed", "transcendentals"):
         assert named[key] == bare[key], key
@@ -392,33 +481,40 @@ def test_phase_of(op_name, phase):
 
 
 # --- host spans of a loop turn ----------------------------------------------
-@pytest.fixture(scope="module")
-def loop_capture(tmp_path_factory):
-    """Host annotation rows [name, start_ns, end_ns] of a three-step run
-    under the profiler, and the python thread's line they were on."""
+def _capture_loop(tmp, inject="", loader_of=None):
+    """Host annotation rows [name, start_ns, end_ns] of a three-step epoch
+    under the profiler (the python thread's line they were on), and the
+    trainer that ran it. `loader_of(trainer, batches)` may wrap the list of
+    batches; `inject` arms a fault (tpudist/faults.py) for the run."""
+    from tpudist import faults
     from tpudist.trainer import Trainer
-    tmp = tmp_path_factory.mktemp("spans")
     cfg = Config(arch="resnet18", num_classes=8, image_size=32, batch_size=16,
                  epochs=1, lr=0.02, workers=0, print_freq=1, synthetic=True,
                  use_amp=False, telemetry=False, outpath=str(tmp / "out"),
-                 overwrite="delete", seed=0)
-    trainer = Trainer(cfg, writer=None)
-    rng = np.random.default_rng(0)
-
-    class Loader(list):
-        pass
-
-    batches = Loader(
-        (rng.standard_normal((16, 32, 32, 3)).astype(np.float32),
-         rng.integers(0, 8, size=(16,)).astype(np.int32)) for _ in range(3))
-    trainer.train_epoch(batches[:1], 0, 0.02)            # compile outside
-    jax.block_until_ready(trainer.state)
-    jax.profiler.start_trace(str(tmp / "trace"))
+                 overwrite="delete", seed=0, inject=inject)
     try:
-        trainer.train_epoch(batches, 0, 0.02)
+        trainer = Trainer(cfg, writer=None)
+        rng = np.random.default_rng(0)
+
+        class Loader(list):
+            pass
+
+        batches = Loader(
+            (rng.standard_normal((16, 32, 32, 3)).astype(np.float32),
+             rng.integers(0, 8, size=(16,)).astype(np.int32))
+            for _ in range(3))
+        trainer.train_epoch(batches[:1], 0, 0.02)        # compile outside
         jax.block_until_ready(trainer.state)
+        if loader_of is not None:
+            batches = loader_of(trainer, batches)
+        jax.profiler.start_trace(str(tmp / "trace"))
+        try:
+            trainer.train_epoch(batches, 0, 0.02)
+            jax.block_until_ready(trainer.state)
+        finally:
+            jax.profiler.stop_trace()
     finally:
-        jax.profiler.stop_trace()
+        faults.configure("")
     path = next((tmp / "trace").rglob("*.xplane.pb"))
     data = jax.profiler.ProfileData.from_file(str(path))
     lines = {}
@@ -432,20 +528,37 @@ def loop_capture(tmp_path_factory):
             if rows:
                 lines[line.name] = sorted(rows, key=lambda r: (r[1], -r[2]))
     assert len(lines) == 1, list(lines)      # the loop runs on one thread
-    return next(iter(lines.values()))
+    return next(iter(lines.values())), trainer
+
+
+@pytest.fixture(scope="module")
+def loop_capture(tmp_path_factory):
+    return _capture_loop(tmp_path_factory.mktemp("spans"))[0]
 
 
 @pytest.mark.parametrize("name,at_least", [
     (scopes.SPAN_LOADER_NEXT, 3), (scopes.SPAN_DISPATCH, 3),
     (scopes.SPAN_DRAIN_READY, 3), (scopes.SPAN_LOOP_HOST, 7),
-    (scopes.SPAN_PREFETCH, 3), (scopes.SPAN_H2D, 3), (scopes.STEP, 3)])
+    (scopes.SPAN_PREFETCH, 3), (scopes.SPAN_H2D, 3), (scopes.STEP, 3),
+    (scopes.SPAN_LOOP_PROLOGUE, 1), (scopes.SPAN_LOOP_HOOKS, 3),
+    (scopes.SPAN_LOOP_METERS, 3), (scopes.SPAN_LOOP_LOG, 3),
+    (scopes.SPAN_LOOP_EPOCH_END, 1)])
 def test_loop_turn_holds_span(loop_capture, name, at_least):
     assert sum(r[0] == name for r in loop_capture) >= at_least
 
 
+def _top_level(rows):
+    """The rows no other row contains."""
+    return [r for r in rows
+            if not any(p is not r and p[1] <= r[1] and r[2] <= p[2]
+                       for p in rows)]
+
+
 def test_loop_spans_nest_properly(loop_capture):
     """A child lies inside its parent, siblings are disjoint: self time =
-    span minus children."""
+    span minus children. The loop's own activities lie beside `loop_host`,
+    inside nothing: the span that overlaps an idle gap most is handed it,
+    and a parent would outlast them."""
     stack = []
     for name, start, end in loop_capture:
         while stack and stack[-1][2] <= start:
@@ -460,22 +573,98 @@ def test_loop_spans_nest_properly(loop_capture):
         for _, start, end in (r for r in loop_capture if r[0] == child):
             assert any(p[0] == parent and p[1] <= start and end <= p[2]
                        for p in loop_capture), (child, parent)
+    top = _top_level(loop_capture)
+    assert {r[0] for r in top} == {
+        scopes.SPAN_LOOP_HOST, scopes.STEP, scopes.SPAN_METRIC_DRAIN,
+        *scopes.LOOP_ACTIVITIES}
+    assert all(r in top for r in loop_capture
+               if r[0] in scopes.LOOP_ACTIVITIES)
 
 
 def test_loop_turn_is_covered(loop_capture):
-    """From the first loop_host span of a turn to the end of its last, at
-    least 95 % of the host's time lies inside loop_host or the step
-    annotation (the rest nest inside those two)."""
-    top = [r for r in loop_capture
-           if r[0] in (scopes.SPAN_LOOP_HOST, scopes.STEP)]
-    steps = [i for i, r in enumerate(top) if r[0] == scopes.STEP]
-    assert len(steps) == 3
-    for i in steps:
-        before, step, after = top[i - 1], top[i], top[i + 1]
-        assert before[0] == after[0] == scopes.SPAN_LOOP_HOST
-        turn = after[2] - before[1]
-        covered = sum(r[2] - r[1] for r in (before, step, after))
+    """From the `loop_host` span that takes a turn's batch to the next
+    turn's, at least 95 % of the host's time lies inside a top-level span
+    of the program's: `loop_host`, the step annotation, the loop's
+    activities, the metric drain (the rest nest inside those)."""
+    top = _top_level(loop_capture)
+    # a turn starts where loop_host hands over to the hooks; the epoch's
+    # last loop_host span finds the loader empty
+    starts = [i for i, r in enumerate(top[:-1])
+              if r[0] == scopes.SPAN_LOOP_HOST
+              and top[i + 1][0] == scopes.SPAN_LOOP_HOOKS]
+    assert len(starts) == 3
+    ends = starts[1:] + [max(i for i, r in enumerate(top)
+                             if r[0] == scopes.SPAN_LOOP_HOST)]
+    for first, last in zip(starts, ends):
+        assert scopes.STEP in [r[0] for r in top[first:last]]
+        turn = top[last][1] - top[first][1]
+        covered = sum(r[2] - r[1] for r in top[first:last])
         assert covered >= 0.95 * turn, (covered, turn)
+
+
+@pytest.fixture(scope="module")
+def stalled_capture(tmp_path_factory):
+    """A captured epoch (global steps 1-3) in which the host stalls once
+    (`slow_peer` armed for step 2 alone, 300 ms) and a function is jitted on
+    a fresh shape while the loader hands out the third batch."""
+    seen = []
+
+    def loader_of(trainer, batches):
+        class Loader(list):
+            def __iter__(self):
+                for k, batch in enumerate(list.__iter__(self)):
+                    if k == 2:
+                        seen.append(trainer.global_step)
+                        jax.jit(lambda x: 2.0 * x + 1.0)(
+                            np.zeros((3, 5, 7), np.float32))
+                    yield batch
+        return Loader(batches)
+
+    before = len(telemetry.compile_events())
+    rows, trainer = _capture_loop(tmp_path_factory.mktemp("stall"),
+                                  inject="slow_peer:ms=300@step=2",
+                                  loader_of=loader_of)
+    log = open(os.path.join(trainer.cfg.outpath, "experiment.log")).read()
+    return dict(rows=rows, seen=seen, log=log,
+                events=telemetry.compile_events()[before:])
+
+
+def test_a_stall_is_named_by_the_loops_activity(stalled_capture):
+    """The longest of the loop's activities in the captured epoch is the
+    hooks' span of the stalled turn, and it holds the whole stall: what
+    `loop_host_max_ms` of the chip benchmark reads. No span of the
+    program's lies inside it or around it, so it is what overlaps the idle
+    gap the stall leaves on the device most."""
+    rows = stalled_capture["rows"]
+    own = [r for r in rows if r[0] in scopes.LOOP_ACTIVITIES]
+    name, start, end = stalled = max(own, key=lambda r: r[2] - r[1])
+    assert name == scopes.SPAN_LOOP_HOOKS
+    assert end - start >= 300e6
+    assert max(r[2] - r[1] for r in own if r is not stalled) < 150e6
+    assert not [r for r in rows if start < r[1] and r[2] < end]
+    assert stalled in _top_level(rows)
+
+
+@pytest.mark.parametrize("where", ["compile_events", "log"])
+def test_a_compile_between_two_steps_is_seen(stalled_capture, where):
+    """`telemetry.compile_events()` keeps the compile with the trainer's
+    step at the time, and the trainer logs it: it ended after the first
+    dispatch."""
+    step, = stalled_capture["seen"]
+    assert step in (1, 2)            # the prefetcher stages a turn ahead
+    if where == "log":
+        assert (f"=> compile after step {step}: {telemetry.COMPILE_EVENT} "
+                in stalled_capture["log"])
+        return
+    compiles = [ev for ev in stalled_capture["events"]
+                if ev["event"] == telemetry.COMPILE_EVENT
+                and ev["step"] == step]
+    assert compiles, stalled_capture["events"]
+    assert all(ev["seconds"] > 0 and ev["t_end"] <= time.perf_counter()
+               for ev in compiles)
+    # a copy: the kept events are the module's own
+    stalled_capture["events"][0]["step"] = -1
+    assert all(ev["step"] != -1 for ev in telemetry.compile_events())
 
 
 # --- set-up phases -----------------------------------------------------------

@@ -172,27 +172,34 @@ class GroupedQueryAttention(nn.Module):
             return nn.Dense(heads * self.head_dim, use_bias=False, dtype=dt,
                             kernel_init=_init, name=name)(x).reshape(
                                 b, t, heads, self.head_dim)
-        q = proj(self.num_heads, "q_proj")
-        k = proj(self.num_kv_heads, "k_proj")
-        v = proj(self.num_kv_heads, "v_proj")
-        if self.qk_norm:
-            q = RMSNorm(self.eps, name="q_norm")(q).astype(dt)
-            k = RMSNorm(self.eps, name="k_norm")(k).astype(dt)
-        if self.rope_parameters is not None:
-            cos, sin = rope.tables(dict(self.rope_parameters), self.head_dim,
-                                   positions)
-            q, k = rope.apply(q, cos, sin), rope.apply(k, cos, sin)
-        if self.flash and not self.is_initializing():
-            # (initialisation runs eagerly on a short example row: shapes
-            # only, so the XLA path, and no kernel is built for that length)
-            from tpudist.ops.pallas import flash_attention
-            with jax.named_scope(scopes.ATTN_FUSED):
-                out = flash_attention(q, k, v, **mask)
-        else:
-            out = attention(q, k, v, **mask)
-        return nn.Dense(x.shape[-1], use_bias=False, dtype=dt,
-                        kernel_init=_init, name="o_proj")(
-                            out.reshape(b, t, -1))
+        # a part of a block each (`scopes.STEP_PARTS`); the moves into the
+        # kernels' layout read under whichever part roots their fusion
+        with jax.named_scope(scopes.ATTN_MIXER):
+            with jax.named_scope(scopes.ATTN_QKV_PROJ):
+                q = proj(self.num_heads, "q_proj")
+                k = proj(self.num_kv_heads, "k_proj")
+                v = proj(self.num_kv_heads, "v_proj")
+            with jax.named_scope(scopes.ATTN_QK_NORM_ROPE):
+                if self.qk_norm:
+                    q = RMSNorm(self.eps, name="q_norm")(q).astype(dt)
+                    k = RMSNorm(self.eps, name="k_norm")(k).astype(dt)
+                if self.rope_parameters is not None:
+                    cos, sin = rope.tables(dict(self.rope_parameters),
+                                           self.head_dim, positions)
+                    q, k = rope.apply(q, cos, sin), rope.apply(k, cos, sin)
+            if self.flash and not self.is_initializing():
+                # (initialisation runs eagerly on a short example row:
+                # shapes only, so the XLA path, and no kernel is built for
+                # that length)
+                from tpudist.ops.pallas import flash_attention
+                with jax.named_scope(scopes.ATTN_FUSED):
+                    out = flash_attention(q, k, v, **mask)
+            else:
+                out = attention(q, k, v, **mask)
+            with jax.named_scope(scopes.ATTN_OUT_PROJ):
+                return nn.Dense(x.shape[-1], use_bias=False, dtype=dt,
+                                kernel_init=_init, name="o_proj")(
+                                    out.reshape(b, t, -1))
 
 
 class SparseExperts(nn.Module):
@@ -240,17 +247,21 @@ class SparseExperts(nn.Module):
                 "batch_stats", "e_score_correction_bias", jnp.zeros,
                 (self.num_experts,), jnp.float32).value
         flat = normed.reshape(b * t, d)
+
+        def cast():
+            # the cast behind the block's norm (the router reads float32)
+            with jax.named_scope(scopes.BLOCK_NORM):
+                return flat.astype(dt)
         y, counters = moe_topk_held(
-            params, flat.astype(dt), top_k=self.top_k,
-            first_expert=self.first_expert, router_input=flat,
-            rule=self.router, scale=self.routed_scaling)
+            params, cast(), top_k=self.top_k, first_expert=self.first_expert,
+            router_input=flat, rule=self.router, scale=self.routed_scaling)
         if self.shared_width:
             y = y + shared_expert(
                 {"up": self.param("shared_up", _init,
                                   (d, self.shared_width), jnp.float32),
                  "down": self.param("shared_down", _init,
                                     (self.shared_width, d), jnp.float32)},
-                flat.astype(dt))
+                cast())
         return y.reshape(b, t, d), counters
 
 
@@ -306,8 +317,8 @@ class Mamba2Mixer(nn.Module):
                 proj = nn.Dense(2 * inner + 2 * gn + heads, use_bias=False,
                                 dtype=dt_, kernel_init=_init,
                                 name="in_proj")(x)
-            z, xbc, dt_raw = jnp.split(proj, [inner, 2 * inner + 2 * gn],
-                                       axis=-1)
+                z, xbc, dt_raw = jnp.split(
+                    proj, [inner, 2 * inner + 2 * gn], axis=-1)
             kernel = self.param("conv_kernel", _conv_init(self.conv),
                                 (self.conv, inner + 2 * gn), jnp.float32)
             bias = self.param("conv_bias", _conv_init(self.conv),
@@ -315,7 +326,7 @@ class Mamba2Mixer(nn.Module):
             with jax.named_scope(scopes.SSM_CONV):
                 xbc = jax.nn.silu(ssd.causal_conv1d(xbc, kernel, bias)
                                   ).astype(dt_)
-            xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+                xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
             dt_bias = self.param("dt_bias", _dt_bias_init(*self.time_step),
                                  (heads,), jnp.float32)
             a_log = self.param(
@@ -354,12 +365,16 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array):
         dt = self.dtype or x.dtype
-        y = RMSNorm(self.eps, name="input_norm")(x).astype(dt)
-        x = x + GroupedQueryAttention(**self.attn, eps=self.eps, dtype=dt,
-                                      name="self_attention")(y)
-        y = RMSNorm(self.eps, name="post_norm")(x)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            y = RMSNorm(self.eps, name="input_norm")(x).astype(dt)
+        y = GroupedQueryAttention(**self.attn, eps=self.eps, dtype=dt,
+                                  name="self_attention")(y)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            x = x + y
+            y = RMSNorm(self.eps, name="post_norm")(x)
         y, counters = SparseExperts(**self.experts, dtype=dt, name="moe")(y)
-        return x + y, counters
+        with jax.named_scope(scopes.BLOCK_NORM):
+            return x + y, counters
 
 
 class MixerBlock(nn.Module):
@@ -373,18 +388,22 @@ class MixerBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array):
         dt = self.dtype or x.dtype
-        y = RMSNorm(self.eps, name="norm")(x)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            y = RMSNorm(self.eps, name="norm")(x)
+            if self.kind != "moe":       # the router reads the float32 norm
+                y = y.astype(dt)
         counters = {}
         if self.kind == "mamba":
             y, counters = Mamba2Mixer(**self.mixer, eps=self.eps, dtype=dt,
-                                      name="mixer")(y.astype(dt))
+                                      name="mixer")(y)
         elif self.kind == "moe":
             y, counters = SparseExperts(**self.mixer, dtype=dt,
                                         name="mixer")(y)
         else:
             y = GroupedQueryAttention(**self.mixer, eps=self.eps, dtype=dt,
-                                      name="mixer")(y.astype(dt))
-        return x + y, counters
+                                      name="mixer")(y)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            return x + y, counters
 
 
 class MoEDecoder(nn.Module):
@@ -536,7 +555,8 @@ class MoEDecoder(nn.Module):
                              for k, v in layer_counters.items()})
         if noised is not None:
             x = x[:, :length]            # the head reads the noised half
-        x = RMSNorm(self.rms_norm_eps, name="norm")(x).astype(dt)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            x = RMSNorm(self.rms_norm_eps, name="norm")(x).astype(dt)
         head = self.param("head", _init,
                           (self.hidden_size, self.vocab_held), jnp.float32)
         if targets is None:

@@ -10,8 +10,8 @@ model hands the step:
   an op traced under `tpudist_forward` reads `jvp(tpudist_forward)` in the
   forward pass and `transpose(jvp(tpudist_forward))` in the backward.
 - **host spans** (`jax.profiler.TraceAnnotation`, `tpudist.*`): every host
-  microsecond of a trainer loop turn lies inside exactly one of them; a span
-  costs a flag test when no trace is live.
+  microsecond of a trainer loop turn lies inside exactly one top-level one;
+  a span costs a flag test when no trace is live.
 - **set-up phases** (`telemetry.record_phase`, `init.*`): seconds of
   `Trainer.__init__`, read back with `telemetry.phases()`.
 
@@ -36,6 +36,16 @@ ATTN_SOFTMAX = "attn_softmax"
 ATTN_VALUES = "attn_values"
 # the Pallas attention kernels, forward and backward, in their place
 ATTN_FUSED = "attn_fused"
+# a decoder's attention (models/decoder.py::GroupedQueryAttention), inside
+# the forward scope under the block's name; the three parts below and
+# `attn_fused` (or the XLA path's three stages) lie within the whole
+ATTN_MIXER = "attn_mixer"
+ATTN_QKV_PROJ = "attn_qkv_proj"        # q, k, v products, the cut into heads
+ATTN_QK_NORM_ROPE = "attn_qk_norm_rope"  # q / k RMSNorm, the tables, RoPE
+ATTN_OUT_PROJ = "attn_out_proj"
+# a decoder block's own norms (and the decoder's last) with the cast behind
+# each, and the residual sums
+BLOCK_NORM = "block_norm"
 # a top-k expert layer (parallel/moe.py::moe_topk_held), inside the forward
 # scope under the layer's name
 MOE_ROUTER = "moe_router"              # softmax over all experts, top k
@@ -80,6 +90,15 @@ MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED, SSM_DT, SSM_CARRY,
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)
 
+# every leaf part a train step's device time may lie under: what a named
+# operation of a decoder's step is under none of is unitemised
+# (`step_unitemised_ms` of the chip benchmark holds its own copy of this)
+STEP_PARTS = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, MOE_SHARED,
+              SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE_NORM, SSM_OUT_PROJ,
+              ATTN_QKV_PROJ, ATTN_QK_NORM_ROPE, ATTN_FUSED, ATTN_OUT_PROJ,
+              BLOCK_NORM, LM_EMBED, LM_HEAD, LOSS, BD_NOISE, GRAD_REDUCE,
+              OPTIMIZER, METRICS)
+
 # -- host spans of a loop turn ----------------------------------------------
 STEP = "train"                          # StepTraceAnnotation: h2d + dispatch
 SPAN_LOADER_NEXT = "tpudist.loader_next"
@@ -88,7 +107,18 @@ SPAN_PREFETCH = "tpudist.prefetch"      # loader_next + h2d of batch N+1
 SPAN_DISPATCH = "tpudist.dispatch"
 SPAN_DRAIN_READY = "tpudist.drain_ready"
 SPAN_METRIC_DRAIN = "tpudist.metric_drain"
-SPAN_LOOP_HOST = "tpudist.loop_host"    # hooks before the step, meters after
+SPAN_LOOP_HOST = "tpudist.loop_host"    # what is left of a turn: taking a
+#                                         staged batch, the poke, the push
+# the loop's own activities, spans beside loop_host (not inside it: the span
+# that overlaps an idle gap most is handed it, and a parent outlasts its
+# child); none of them waits on the device
+SPAN_LOOP_PROLOGUE = "tpudist.loop_prologue"    # meters, drain, prefetcher
+SPAN_LOOP_HOOKS = "tpudist.loop_hooks"  # profiler, watchdog, doctor, faults
+SPAN_LOOP_METERS = "tpudist.loop_meters"        # counters, telemetry emit
+SPAN_LOOP_LOG = "tpudist.loop_log"      # the progress line: file + console
+SPAN_LOOP_EPOCH_END = "tpudist.loop_epoch_end"  # summary, scalar writes
+LOOP_ACTIVITIES = (SPAN_LOOP_PROLOGUE, SPAN_LOOP_HOOKS, SPAN_LOOP_METERS,
+                   SPAN_LOOP_LOG, SPAN_LOOP_EPOCH_END)
 
 # -- set-up phases (init.*: sum = Trainer.__init__'s wall time) -------------
 INIT_MESH = "init.mesh"

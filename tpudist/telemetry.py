@@ -41,6 +41,7 @@ import math
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Iterable, Optional
 
@@ -348,6 +349,55 @@ def counters() -> dict[str, list]:
     """Every counter's readings, oldest first, a step each (a copy). Like
     ``phases()`` it needs no ``--telemetry``: the chip benchmark reads it."""
     return {k: list(v) for k, v in _counters.items()}
+
+
+# The two jax.monitoring durations that mean "a program was built for the
+# backend": the compile request itself (served by the compiler or by the
+# persistent cache), and inside it the cache's read where that served it.
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_compile_events: list[dict] = []
+_compile_observer = None            # a weak reference to a bound method
+_compile_listening = False
+
+
+def _on_duration(event: str, seconds: float, **_) -> None:
+    """jax.monitoring's duration listener: runs where something compiles,
+    never on a turn that compiles nothing."""
+    if event not in (COMPILE_EVENT, CACHE_READ_EVENT):
+        return
+    ev = {"event": event, "seconds": float(seconds),
+          "t_end": time.perf_counter(), "step": None}
+    observer = _compile_observer() if _compile_observer is not None else None
+    if observer is not None:
+        observer(ev)
+    _compile_events.append(ev)
+    if len(_compile_events) > _COUNTER_KEEP:
+        del _compile_events[:len(_compile_events) - _COUNTER_KEEP]
+
+
+def watch_compiles(observer) -> None:
+    """Keep every backend compile and compile-cache read of the process
+    from now on (``compile_events()``). ``observer`` (a bound method, held
+    weakly; the newest call's wins) is handed each event before it is kept
+    and may fill in its ``step``. The listener is registered with jax once
+    a process."""
+    global _compile_observer, _compile_listening
+    if not _compile_listening:
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _compile_listening = True
+    _compile_observer = weakref.WeakMethod(observer)
+
+
+def compile_events() -> list[dict]:
+    """``{"event", "seconds", "t_end", "step"}`` of every backend compile
+    and compile-cache read since ``watch_compiles()``, oldest first, the
+    newest ``_COUNTER_KEEP``: jax's event name, its duration, ``time.
+    perf_counter()`` at its end and the trainer's ``global_step`` then (None
+    with no trainer alive). Copies. Like ``counters()`` it needs no
+    ``--telemetry``."""
+    return [dict(ev) for ev in _compile_events]
 
 
 def phases() -> dict[str, float]:

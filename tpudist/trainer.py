@@ -180,6 +180,13 @@ class Trainer:
 
     def __init__(self, cfg: Config, mesh=None, writer: Any = "auto"):
         self.cfg = cfg
+        # every compile of the process from here on is kept with the step it
+        # fell in (telemetry.compile_events()); one after the first dispatch
+        # is logged (_on_compile reads these three)
+        self.global_step = 0
+        self._train_dispatched = False
+        self._resolving_flops = False
+        telemetry_lib.watch_compiles(self._on_compile)
         # Set-up phases (scopes.INIT_*): mark(name) books the seconds since
         # the last mark, so the phases sum to this constructor's wall time.
         t_mark = time.monotonic()
@@ -360,7 +367,6 @@ class Trainer:
         # Per-step MFU inputs, resolved lazily on the first train step.
         self._flops_per_step = None
         self._peak_flops = None
-        self._train_dispatched = False
 
         # Parallelism mode is a config state of this one trainer (VERDICT r1
         # weak #2), derived by the single parallelism plane (ISSUE 12,
@@ -736,7 +742,6 @@ class Trainer:
         self.best_acc1 = 0.0
         self.rows_per_s = None        # the last train epoch's rows a second
         self.start_epoch = cfg.start_epoch
-        self.global_step = 0
         # Elastic continuation state: a checkpointed mid-epoch sample cursor
         # (set by load() from an emergency save) and this epoch's running
         # global-sample consumption (what the next emergency save records).
@@ -983,6 +988,23 @@ class Trainer:
                 fields["step"] = step
             self.telemetry.emit("fault", point=point, **fields)
 
+    def _on_compile(self, ev: dict) -> None:
+        """telemetry.watch_compiles' observer: runs where something compiles
+        (a retraced step, an eval step's first call, a stray eager op), so a
+        turn that compiles nothing pays nothing. A compile that ends after
+        the first dispatch is one a steady run should not have: it is logged
+        and, with --telemetry, emitted. The cost analysis' own compile
+        (``_resolve_step_flops``) is booked there."""
+        ev["step"] = step = self.global_step
+        if not self._train_dispatched or self._resolving_flops \
+                or ev["event"] != telemetry_lib.COMPILE_EVENT:
+            return
+        self.log(f"=> compile after step {step}: {ev['event']} "
+                 f"{ev['seconds']:.2f} s")
+        if self.telemetry is not None:
+            self.telemetry.note_compile(ev["seconds"], phase="after_dispatch",
+                                        step=step, event=ev["event"])
+
     def _resolve_step_flops(self, images, labels, lr_arr) -> None:
         """Per-device FLOPs of the compiled train step via
         ``.lower().compile().cost_analysis()`` (the same path
@@ -997,6 +1019,7 @@ class Trainer:
         t0 = time.time()
         flops = None
         intro: dict = {}
+        self._resolving_flops = True
         try:
             compiled = self.train_step.lower(
                 self.state, images, labels, lr_arr).compile()
@@ -1025,6 +1048,8 @@ class Trainer:
         except Exception as e:
             self.log(f"=> telemetry: step lowering for cost analysis failed "
                      f"({e!r}) — per-step MFU will not be reported")
+        finally:
+            self._resolving_flops = False
         self._flops_per_step = flops
         self._peak_flops = telemetry_lib.resolve_peak_flops(
             jax.devices()[0].device_kind)
@@ -1385,12 +1410,6 @@ class Trainer:
     # -- epoch loops (reference train()/validate()) ------------------------
     def train_epoch(self, loader, epoch: int, lr: float) -> tuple[float, float]:
         cfg = self.cfg
-        batch_time = AverageMeter("Time", ":6.3f")
-        data_time = AverageMeter("Data", ":6.3f")
-        losses = AverageMeter("Loss", ":.4e")
-        top1 = AverageMeter("Acc@1", ":6.2f")
-        progress = ProgressMeter(len(loader), [batch_time, data_time, losses, top1],
-                                 prefix=f"Epoch[{epoch}]:\t")
         # Async metric drain (--async-drain, default on): metrics copy
         # device→host asynchronously at dispatch and materialize one step
         # late, while the NEXT step computes — the drain leaves the
@@ -1398,12 +1417,6 @@ class Trainer:
         # averages are exact; the console line trails by one step).
         async_drain = bool(getattr(cfg, "async_drain", True))
         doctor = self.doctor
-        drain = _MetricDrain({"loss": losses, "acc1": top1},
-                             lag=1 if async_drain else 0,
-                             observer=(doctor.on_metrics
-                                       if doctor is not None else None))
-        lr_arr = jax.numpy.asarray(lr, jax.numpy.float32)
-
         tel = self.telemetry
         # Sample-cursor accounting: start from the continuation offset when
         # this epoch resumes mid-way (set in fit() from the checkpoint's
@@ -1418,13 +1431,31 @@ class Trainer:
         # exposed remainder and the hidden work is reported as the step's
         # prefetch_s bucket (overlap-aware accounting; see telemetry.step).
         # Host spans (scopes.SPAN_*): every host microsecond of a loop
-        # turn lies inside exactly one tpudist.* annotation — loop_host
-        # before and after the step annotation, the others nested inside —
-        # so a device gap a trace cannot attribute is outside this loop.
+        # turn lies inside exactly one top-level tpudist.* annotation or
+        # the step annotation, so a device gap a trace cannot attribute is
+        # outside this loop. The loop's own activities (scopes.
+        # LOOP_ACTIVITIES: prologue, hooks, meters, log, epoch_end; none
+        # waits on the device) are spans BESIDE loop_host, not inside it:
+        # a trace reduction hands an idle gap to the span that overlaps it
+        # most, and a parent always outlasts its child, so a stall inside a
+        # nested span would read under the catch-all. loop_host is what is
+        # left: taking a staged batch, the poke, the metrics' push.
         # An annotation costs a flag test when no trace is live.
         span = jax.profiler.TraceAnnotation
         pf = None
-        with span(scopes.SPAN_LOOP_HOST):
+        with span(scopes.SPAN_LOOP_PROLOGUE):
+            batch_time = AverageMeter("Time", ":6.3f")
+            data_time = AverageMeter("Data", ":6.3f")
+            losses = AverageMeter("Loss", ":.4e")
+            top1 = AverageMeter("Acc@1", ":6.2f")
+            progress = ProgressMeter(
+                len(loader), [batch_time, data_time, losses, top1],
+                prefix=f"Epoch[{epoch}]:\t")
+            drain = _MetricDrain({"loss": losses, "acc1": top1},
+                                 lag=1 if async_drain else 0,
+                                 observer=(doctor.on_metrics
+                                           if doctor is not None else None))
+            lr_arr = jax.numpy.asarray(lr, jax.numpy.float32)
             if getattr(cfg, "device_prefetch", True):
                 from tpudist.dist import DevicePrefetcher
                 pf = DevicePrefetcher(loader, self.mesh, self.batch_axes)
@@ -1448,36 +1479,42 @@ class Trainer:
                 now = time.time()
                 data_time.update(now - end)
                 data_s = now - t_prev     # loader wait incl. prior-step residue
+                step_num = self.global_step
+            with span(scopes.SPAN_LOOP_HOOKS):
                 self.profiler.step(self.global_step)
                 if self.blackbox is not None:
-                    # Consumes an armed deep capture / manual flag; idle cost
-                    # is two attribute reads (no lock, no clock — NUM01).
+                    # Consumes an armed deep capture / manual flag; idle
+                    # cost is two attribute reads (no lock, no clock —
+                    # NUM01).
                     self.blackbox.poll(self.global_step)
                 # Kick BEFORE dispatch too: the first step blocks on XLA
                 # compilation, so the full timeout budget must start here.
                 self._kick()
-                # Step boundary: the in-flight step has drained — act on a
-                # pending SIGTERM/SIGINT now (fit() writes the emergency
+                # Step boundary: the in-flight step has drained — act on
+                # a pending SIGTERM/SIGINT now (fit() writes the emergency
                 # checkpoint), and consult the hot-loop fault points.
                 if self.preemption is not None:
                     self.preemption.check()
                 if doctor is not None:
                     # Deliver a pending rollback decision (raises
-                    # RollbackRequested — fit() restores last-verified-good and
-                    # replays the epoch minus the poisoned window), then run
-                    # the periodic SDC probe. Both happen HERE, at the step
-                    # boundary where the in-flight step has drained: the probe
-                    # digests a settled state, and a rollback never tears a
+                    # RollbackRequested — fit() restores
+                    # last-verified-good and replays the epoch minus the
+                    # poisoned window), then run the periodic SDC probe.
+                    # Both happen HERE, at the step boundary where the
+                    # in-flight step has drained: the probe digests a
+                    # settled state, and a rollback never tears a
                     # dispatched step.
                     doctor.check_response()
                     if doctor.should_probe(self.global_step):
                         self._kick()
-                        if doctor.probe(self.global_step, self.state) == "evict":
+                        if doctor.probe(self.global_step,
+                                        self.state) == "evict":
                             self.log_all(
-                                f"=> doctor: this rank's replicated state is "
-                                f"minority-divergent in {doctor.sdc_windows} "
-                                f"consecutive probes — silent data corruption "
-                                f"on this host; self-quarantining (exit "
+                                f"=> doctor: this rank's replicated "
+                                f"state is minority-divergent in "
+                                f"{doctor.sdc_windows} consecutive probes "
+                                f"— silent data corruption on this host; "
+                                f"self-quarantining (exit "
                                 f"{faults.SDC_EXIT_CODE}, no checkpoint "
                                 f"written)")
                             raise SystemExit(faults.SDC_EXIT_CODE)
@@ -1485,17 +1522,17 @@ class Trainer:
                 faults.maybe_slow_peer(self.global_step)
                 faults.maybe_straggle(self.global_step)
                 if faults.armed("bitflip"):
-                    # SDC injection: corrupt this rank's live params in place —
-                    # nothing non-finite, only the cross-replica digest probe
-                    # can see it.
+                    # SDC injection: corrupt this rank's live params in
+                    # place — nothing non-finite, only the cross-replica
+                    # digest probe can see it.
                     self.state = faults.maybe_bitflip(self.global_step,
                                                       self.state)
                 if faults.armed("lossbomb"):
-                    # Health injection: poison the head so the loss spikes
-                    # (finite) — the EWMA detector, not the sentinel, must act.
+                    # Health injection: poison the head so the loss
+                    # spikes (finite) — the EWMA detector, not the
+                    # sentinel, must act.
                     self.state = faults.maybe_lossbomb(self.global_step,
                                                        self.state)
-                step_num = self.global_step
             # StepTraceAnnotation groups this step's device ops under one
             # labeled row in XProf/Perfetto when --profile is capturing.
             with jax.profiler.StepTraceAnnotation(scopes.STEP,
@@ -1544,56 +1581,62 @@ class Trainer:
                         t_do = time.time()
                         drain.drain_ready()
                         drain_ovl_s = time.time() - t_do
+            with span(scopes.SPAN_LOOP_METERS):
                 self.global_step += 1
                 self._epoch_consumed += local_bs * self.data_world
                 self._kick()
                 batch_time.update(time.time() - end)
                 end = time.time()
-                drain_s = 0.0
-                if i % cfg.print_freq == 0:
-                    with span(scopes.SPAN_METRIC_DRAIN):
-                        t_d = time.time()
-                        # Async mode keeps the one-step lag even at display
-                        # time — a full drain here would block on the step
-                        # just dispatched, re-exposing exactly the sync this
-                        # flag removes. The console line trails by one step.
-                        drain.drain_ready() if async_drain else drain.drain()
-                        drain_s = time.time() - t_d
+            drain_s = 0.0
+            if i % cfg.print_freq == 0:
+                with span(scopes.SPAN_METRIC_DRAIN):
+                    t_d = time.time()
+                    # Async mode keeps the one-step lag even at display
+                    # time — a full drain here would block on the step
+                    # just dispatched, re-exposing exactly the sync this
+                    # flag removes. The console line trails by one step.
+                    drain.drain_ready() if async_drain else drain.drain()
+                    drain_s = time.time() - t_d
+                with span(scopes.SPAN_LOOP_LOG):
                     self.log(progress.display(i))
-                if tel is not None:
+            if tel is not None:
+                with span(scopes.SPAN_LOOP_METERS):
                     step_s = time.time() - t_prev
                     mfu = None
                     if not first_dispatch and self._flops_per_step \
                             and self._peak_flops:
-                        mfu = self._flops_per_step / (step_s * self._peak_flops)
-                    # First dispatch blocked on trace+XLA compile: accounted as
-                    # compile, not productive step time.
+                        mfu = self._flops_per_step / (
+                            step_s * self._peak_flops)
+                    # First dispatch blocked on trace+XLA compile:
+                    # accounted as compile, not productive step time.
                     tel.step(step=step_num, epoch=epoch, data_s=data_s,
-                             h2d_s=h2d_s, compute_s=compute_s, drain_s=drain_s,
-                             step_s=step_s,
-                             compile_s=compute_s if first_dispatch else 0.0,
+                             h2d_s=h2d_s, compute_s=compute_s,
+                             drain_s=drain_s, step_s=step_s,
+                             compile_s=(compute_s if first_dispatch
+                                        else 0.0),
                              mfu=mfu, prefetch_s=prefetch_s,
                              drain_ovl_s=drain_ovl_s)
                     if first_dispatch:
-                        # AFTER the step event so its one-off cost lands in the
-                        # compile bucket, not in this step's step_s (the program
-                        # is already warm in the executable cache when one is
-                        # configured).
+                        # AFTER the step event so its one-off cost lands
+                        # in the compile bucket, not in this step's step_s
+                        # (the program is already warm in the executable
+                        # cache when one is configured).
                         self._resolve_step_flops(images, labels, lr_arr)
-                        # Reset the METER clock too: without this the next
-                        # step's data_time/batch_time console meters would
-                        # absorb the cost-analysis compile as phantom data wait.
+                        # Reset the METER clock too: without this the
+                        # next step's data_time/batch_time console meters
+                        # would absorb the cost-analysis compile as
+                        # phantom data wait.
                         end = time.time()
-                t_prev = time.time()
+            t_prev = time.time()
         with span(scopes.SPAN_METRIC_DRAIN):
             drain.drain()
-        if doctor is not None:
-            # A spike surfacing in the epoch-end flush must act BEFORE this
-            # epoch's validate/save — otherwise the poisoned weights get
-            # checkpointed first and only un-written one epoch later.
-            doctor.check_response()
-        self.profiler.epoch_end()
-        with span(scopes.SPAN_LOOP_HOST):
+        with span(scopes.SPAN_LOOP_EPOCH_END):
+            if doctor is not None:
+                # A spike surfacing in the epoch-end flush must act BEFORE
+                # this epoch's validate/save — otherwise the poisoned weights
+                # get checkpointed first and only un-written one epoch later.
+                doctor.check_response()
+            self.profiler.epoch_end()
             rate = ""
             if batch_time.sum > 0:
                 # rows through optimizer steps over the loop's wall time;
