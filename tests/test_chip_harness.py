@@ -8,7 +8,9 @@ fill, the noise's scope) and of `selftest/test_nemotron3_cpu.py` (the cell's
 files against the mix's needs, the Mamba mixers' and the shared expert's
 scopes, the carry counter, and nothing from a program without them) and of
 `selftest/test_ssd_roofline_cpu.py` (the scan's counts at the cell's shape,
-its share on hand-made scopes, a share above 100).
+its share on hand-made scopes, a share above 100) and of
+`selftest/test_qk_rope_roofline_cpu.py` (the q / k pass's counts at both
+cells' shapes, its share on hand-made scopes, nothing from the hybrid's).
 The rest of `benchmarks/chip/selftest/` builds trainers for minutes and
 stays run by path. Below them: what the configurations' `trainer_argv` pins
 against the program's defaults."""
@@ -52,6 +54,13 @@ _spec = importlib.util.spec_from_file_location(
 _ssd_roofline = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_ssd_roofline)
 globals().update({test.__name__: test for test in _ssd_roofline.TIER1})
+
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_test_qk_rope_roofline_cpu", os.path.join(
+        os.path.dirname(_PATH), "test_qk_rope_roofline_cpu.py"))
+_qk_roofline = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_qk_roofline)
+globals().update({test.__name__: test for test in _qk_roofline.TIER1})
 
 
 def test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs(
@@ -136,10 +145,12 @@ reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
 
 @pytest.mark.parametrize("name", _TRACING)
 def test_the_tracing_entries_are_listed(name):
-    """`BENCHMARK.json` lists each of the seven once, at the end, with the
-    cells where its reader finds something to read."""
+    """`BENCHMARK.json` lists each of the seven once, behind what PR 39
+    had (PR 41's `attn_qk_rope_roofline` follows them), with the cells where
+    its reader finds something to read."""
     bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
-    assert [m["name"] for m in bench["per_layer"][-7:]] == list(_TRACING)
+    assert [m["name"] for m in bench["per_layer"][-8:]] == list(_TRACING) + [
+        "attn_qk_rope_roofline"]
     entry, = [m for m in bench["per_layer"] if m["name"] == name]
     unit, source, layer, workloads = _TRACING[name]
     assert entry == {"name": name, "unit": unit, "better": "lower",

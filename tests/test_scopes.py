@@ -133,13 +133,14 @@ def test_attention_stages_are_named(compiled_steps):
 TOKEN_ARCHS = ("mellum2_tiny", "sdar_tiny", "nemotron3_tiny")
 
 
-def _token_step(mesh8, arch, remat=True):
+def _token_step(mesh8, arch, remat=True, seq_len=32, **fields):
     """(compiled DP step, its HLO text) of a tiny decoder of tokens
     (models/decoder.py): `mellum2_tiny` and `sdar_tiny` (trained to predict
     the next id, and by diffusion over blocks; two layers), `nemotron3_tiny`
     (all five blocks: two Mamba-2 mixers, two expert blocks, one attention);
     attention on the streaming kernel and, as the chip benchmark's cells run
-    the large ones, every layer rematerialised; the cache off."""
+    the large ones, every layer rematerialised; the cache off. ``fields``
+    replace the tiny model's own (a published head size)."""
     from jax.experimental.compilation_cache import compilation_cache
     from tpudist.models import create_model
     from tpudist.train import (compute_dtype, create_train_state,
@@ -147,14 +148,15 @@ def _token_step(mesh8, arch, remat=True):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        cfg = Config(arch=arch, batch_size=16, seq_len=32,
+        cfg = Config(arch=arch, batch_size=16, seq_len=seq_len,
                      optimizer="adamw", use_amp=True, seed=0).finalize(8)
         share = {} if arch == "nemotron3_tiny" else {"layers": 2}
         model = create_model(cfg.arch, dtype=compute_dtype(cfg), **share,
                              expert_share=(0, 4), flash=True, remat=remat,
                              loss_chunk=16)   # four turns of the head's loop
+        model = model.clone(**fields)
         state = create_train_state(jax.random.PRNGKey(0), model, cfg)
-        rows = jax.ShapeDtypeStruct((16, 32), jnp.int32)
+        rows = jax.ShapeDtypeStruct((16, seq_len), jnp.int32)
         compiled = make_train_step(mesh8, model, cfg).lower(
             state, rows, rows, jnp.float32(0.1)).compile()
         return compiled, compiled.as_text()
@@ -355,6 +357,72 @@ def test_step_parts_cover_the_named_operations(token_steps, arch, remat):
     assert len(set(scopes.STEP_PARTS)) == len(scopes.STEP_PARTS)
     assert scopes.ATTN_MIXER not in scopes.STEP_PARTS
     assert scopes.SSM_MIXER not in scopes.STEP_PARTS
+
+
+@pytest.fixture(scope="module")
+def laid_step(mesh8):
+    """The named rows of `mellum2_tiny`'s step at the published head size
+    (128: whole lane tiles) on rows of 128 ids under a window of 32: q's and
+    k's norm and rotation run as the Pallas pass (`qk_plan` says so), which
+    the tiny twins' heads of 16 never take; rematerialised, as the cells
+    run."""
+    from tpudist.models import create_model
+    fields = dict(head_dim=128, sliding_window=32)
+    plans = create_model("mellum2_tiny", flash=True).clone(
+        **fields).qk_plans(2, 128)
+    assert [p["kernel"] for p in plans] == ["pallas"], plans
+    return _named_rows(_token_step(mesh8, "mellum2_tiny", seq_len=128,
+                                   **fields)[1])
+
+
+def _pass_calls(rows):
+    """The operations of the pass's two jitted calls (on the chip one Mosaic
+    call each; interpreted here, a loop over the grid)."""
+    return [n for _, n in rows if "/jit(_forward)" in n
+            or "/jit(_backward)" in n]
+
+
+@pytest.mark.parametrize("call,phase,within", [
+    ("jit(_forward)", "fwd", "/layer_"),
+    ("jit(_forward)", "bwd", "/checkpoint/rematted_computation/layer_"),
+    ("jit(_backward)", "bwd", "/checkpoint/layer_")],
+    ids=["forward", "rematerialised", "transposed"])
+def test_the_qk_pass_reads_under_its_scope(laid_step, call, phase, within):
+    """What `attn_qk_rope_ms` and `attn_qk_rope_roofline` of the chip
+    benchmark time: the pass's forward, its forward made again behind the
+    layer's checkpoint and its transposed call all lie under
+    `attn_qk_norm_rope` within `attn_mixer`, in both layers."""
+    named = [n for n in _pass_calls(laid_step)
+             if f"/{call}" in n and within in n and phase_of(n) == phase]
+    assert named, (call, phase)
+    for layer in ("/layer_0/", "/layer_1/"):
+        assert any(layer in n for n in named), layer
+    assert all(f"/self_attention/{scopes.ATTN_MIXER}/"
+               f"{scopes.ATTN_QK_NORM_ROPE}/{call}" in n for n in named)
+
+
+def test_the_qk_pass_leaves_the_attention_kernels_scope_alone(laid_step):
+    """None of the pass lies under `attn_fused` (the two attention
+    rooflines' denominators hold what they held, less q's and k's moves),
+    the kernels' own calls still do, and the step stays itemised: nothing
+    unscoped, `STEP_PARTS` as it was."""
+    calls = _pass_calls(laid_step)
+    assert calls and all(_under(n, scopes.ATTN_QK_NORM_ROPE)
+                         and not _under(n, scopes.ATTN_FUSED) for n in calls)
+    fused = [n for _, n in laid_step if _under(n, scopes.ATTN_FUSED)]
+    assert any("flash_attention_laid" in n and phase_of(n) == "fwd"
+               for n in fused)
+    assert any("flash_attention_laid" in n and phase_of(n) == "bwd"
+               for n in fused)
+    assert not any(_under(n, scopes.ATTN_QK_NORM_ROPE) for n in fused)
+    named = [n for _, n in laid_step]
+    unscoped = [n for n in named if phase_of(n) is None]
+    assert len(unscoped) <= 0.01 * len(named), sorted(set(unscoped))[:20]
+    missed = [n for n in named
+              if not any(_under(n, part) for part in scopes.STEP_PARTS)]
+    assert len(missed) <= 0.03 * len(named), sorted(set(missed))[:20]
+    assert len(scopes.STEP_PARTS) == 22
+    assert scopes.ATTN_QK_NORM_ROPE in scopes.STEP_PARTS
 
 
 @pytest.mark.parametrize("arch", ["mellum2_tiny", "sdar_tiny"])

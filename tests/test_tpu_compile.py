@@ -59,6 +59,9 @@ FLASH_GQA_SHAPES = ((2, 8192, 32, 4, 128, 1024), (2, 8192, 32, 4, 128, None),
 FLASH_BD_SHAPES = ((2, 16384, 32, 4, 128, 4),)
 SSD_SCAN_SHAPES = ((2, 8192, 64, 64, 8, 128, 128),
                    (1, 1000, 64, 64, 8, 128, 128))
+# q / k RMSNorm and RoPE as one pass (rows, positions, heads, key-value
+# heads, head_dim): the two claimed cells' shapes
+QK_NORM_ROPE_SHAPES = ((2, 8192, 32, 4, 128), (2, 16384, 32, 4, 128))
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -141,6 +144,20 @@ def _ssd_scan_fn(bwd: bool, chunk: int):
     return jax.grad(f, argnums=tuple(range(6))) if bwd else f
 
 
+def _qk_norm_rope_fn(bwd: bool, t: int, d: int, kv_heads: int):
+    from tpudist.ops import rope
+    from tpudist.ops.pallas.qk_norm_rope import qk_norm_rope
+    cos, sin = rope.tables({"rope_type": "default", "rope_theta": 1e6}, d, t)
+
+    def f(q, k, q_scale, k_scale):
+        ql, kl = qk_norm_rope(q, k, kv_heads=kv_heads, q_scale=q_scale,
+                              k_scale=k_scale, cos=cos, sin=sin,
+                              interpret=False)
+        return ql.astype(jnp.float32).sum() + kl.astype(jnp.float32).sum()
+
+    return jax.grad(f, argnums=(0, 1, 2, 3)) if bwd else f
+
+
 def _flash_qkv_fn(bwd: bool, causal: bool):
     from tpudist.ops.pallas.flash_attention import flash_attention_qkv
 
@@ -182,6 +199,10 @@ _KERNEL_CASES = (
     + [pytest.param(("ssd_scan",) + shape, bwd,
                     id=f"ssd_scan_t{shape[1]}_{'fwdbwd' if bwd else 'fwd'}")
        for shape in SSD_SCAN_SHAPES for bwd in (False, True)]
+    + [pytest.param(("qk_norm_rope",) + shape, bwd,
+                    id=f"qk_norm_rope_t{shape[1]}_"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for shape in QK_NORM_ROPE_SHAPES for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -207,6 +228,12 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
                 S((b, t, g, n), jnp.bfloat16), S((h,), jnp.float32)]
         fn = _ssd_scan_fn(bwd, chunk)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    elif case[0] == "qk_norm_rope":
+        b, t, h, hkv, d = case[1:]
+        args = [S((b, t, h * d), jnp.bfloat16), S((b, t, hkv * d),
+                                                  jnp.bfloat16),
+                S((d,), jnp.float32), S((d,), jnp.float32)]
+        fn = _qk_norm_rope_fn(bwd, t, d, hkv)
     elif case[0] == "grouped":
         _, rows, groups, k, n = case
         args = [S((rows, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
@@ -237,6 +264,19 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
         flops = compiled.cost_analysis()["flops"]
         assert products * (3.3 if bwd else 1) <= flops <= products * (
             3.6 if bwd else 1.1)
+        return
+    if case[0] == "qk_norm_rope":
+        # the forward; or the backward alone (a sum's gradient needs no
+        # forward). Nothing is transposed around them: q and k arrive as
+        # the projections wrote them and leave as the attention kernels
+        # read them. What the calls claim to move is q and k twice
+        # (forward) or three times, and the tables
+        import re
+        assert text.count("tpu_custom_call") == 1
+        assert not re.search(r" transpose\(| copy\(", text)
+        moved = (3 if bwd else 2) * b * t * (h + hkv) * d * 2 + 8 * t * d
+        assert compiled.cost_analysis()["bytes accessed"] >= moved
+        assert f'"bytes_accessed":"{moved}"' in text
         return
     if case[0] == "grouped":
         # the product; or its two transposes (dx, dw: a sum's gradient

@@ -101,10 +101,14 @@ class RMSNorm(nn.Module):
     eps: float = 1e-6
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
-        """float32 in and out of the arithmetic; the caller casts."""
+    def __call__(self, x: jax.Array, weight_only: bool = False) -> jax.Array:
+        """float32 in and out of the arithmetic; the caller casts.
+        ``weight_only``: the weight alone, for a caller whose kernel norms
+        (``ops/pallas/qk_norm_rope.py``)."""
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
+        if weight_only:
+            return scale
         x = x.astype(jnp.float32)
         return x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps
@@ -179,27 +183,59 @@ class GroupedQueryAttention(nn.Module):
                 q = proj(self.num_heads, "q_proj")
                 k = proj(self.num_kv_heads, "k_proj")
                 v = proj(self.num_kv_heads, "v_proj")
+            # (initialisation runs eagerly on a short example row: shapes
+            # only, so the XLA path, and no kernel is built for that length)
+            flash = self.flash and not self.is_initializing()
+            # one Pallas pass that writes q and k where the attention
+            # kernels read them, where the shape allows (read from it,
+            # never chosen); else these lines, and XLA's move behind them
+            laid = self.qk_plan(b, t, flash)["kernel"] == "pallas"
             with jax.named_scope(scopes.ATTN_QK_NORM_ROPE):
-                if self.qk_norm:
-                    q = RMSNorm(self.eps, name="q_norm")(q).astype(dt)
-                    k = RMSNorm(self.eps, name="k_norm")(k).astype(dt)
+                norms = [RMSNorm(self.eps, name=f"{n}_norm")
+                         for n in "qk"] if self.qk_norm else None
+                cos = sin = None
                 if self.rope_parameters is not None:
                     cos, sin = rope.tables(dict(self.rope_parameters),
                                            self.head_dim, positions)
-                    q, k = rope.apply(q, cos, sin), rope.apply(k, cos, sin)
-            if self.flash and not self.is_initializing():
-                # (initialisation runs eagerly on a short example row:
-                # shapes only, so the XLA path, and no kernel is built for
-                # that length)
-                from tpudist.ops.pallas import flash_attention
+                if laid:
+                    from tpudist.ops.pallas.qk_norm_rope import qk_norm_rope
+                    q_scale, k_scale = ([norm(x, weight_only=True)
+                                         for norm, x in zip(norms, (q, k))]
+                                        if norms else (None, None))
+                    q, k = qk_norm_rope(
+                        q.reshape(b, t, -1), k.reshape(b, t, -1),
+                        kv_heads=self.num_kv_heads, q_scale=q_scale,
+                        k_scale=k_scale, cos=cos, sin=sin, eps=self.eps)
+                else:
+                    if norms:
+                        q, k = norms[0](q).astype(dt), norms[1](k).astype(dt)
+                    if cos is not None:
+                        q, k = (rope.apply(q, cos, sin),
+                                rope.apply(k, cos, sin))
+            if flash:
+                from tpudist.ops.pallas import (flash_attention,
+                                                flash_attention_laid)
                 with jax.named_scope(scopes.ATTN_FUSED):
-                    out = flash_attention(q, k, v, **mask)
+                    out = (flash_attention_laid if laid
+                           else flash_attention)(q, k, v, **mask)
             else:
                 out = attention(q, k, v, **mask)
             with jax.named_scope(scopes.ATTN_OUT_PROJ):
                 return nn.Dense(x.shape[-1], use_bias=False, dtype=dt,
                                 kernel_init=_init, name="o_proj")(
                                     out.reshape(b, t, -1))
+
+    def qk_plan(self, rows: int, seq_len: int, flash: bool) -> dict:
+        """Which program norms and rotates this layer's q and k at ``rows``
+        rows of ``seq_len`` positions (``qk_norm_rope.qk_plan``: read from
+        the shape and the layer's fields)."""
+        from tpudist.ops.pallas.qk_norm_rope import qk_plan
+        return qk_plan(
+            rows, seq_len, self.num_heads, self.num_kv_heads, self.head_dim,
+            norm=self.qk_norm, rotate=self.rope_parameters is not None,
+            flash=flash, window=self.window,
+            block_diffusion=(None if self.block_diffusion is None
+                             else tuple(self.block_diffusion)))
 
 
 class SparseExperts(nn.Module):
@@ -516,10 +552,6 @@ class MoEDecoder(nn.Module):
         with jax.named_scope(scopes.LM_EMBED):
             x = nn.Embed(self.vocab_held, self.hidden_size,
                          embedding_init=_init, dtype=dt, name="embed")(tokens)
-        rope_of = dict(self.rope_parameters or {})
-        attn = dict(num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
-                    head_dim=self.head_dim, qk_norm=self.qk_norm,
-                    block_diffusion=mask, flash=self.flash)
         experts = dict(num_experts=self.num_experts,
                        top_k=self.experts_per_token, width=self.expert_width,
                        first_expert=first_expert, held=held,
@@ -530,8 +562,7 @@ class MoEDecoder(nn.Module):
             if kind not in LAYER_KINDS:
                 raise ValueError(f"layer {i}: unknown layer type {kind!r} "
                                  f"(one of {', '.join(LAYER_KINDS)})")
-            of_kind = dict(attn, rope_parameters=rope_of.get(kind), window=(
-                self.sliding_window if kind == "sliding_attention" else None))
+            of_kind = self._attention_of(kind, mask)
             if kind in PAIR_KINDS:
                 layer, fields = DecoderLayer, dict(attn=of_kind,
                                                    experts=experts)
@@ -583,6 +614,35 @@ class MoEDecoder(nn.Module):
         m = self.mamba
         return ssd.scan_plan(rows, seq_len, m["num_heads"], m["head_dim"],
                              m["groups"], m["state"], m["chunk"])
+
+    def _attention_of(self, kind: str, mask: Optional[tuple]) -> dict:
+        """``GroupedQueryAttention``'s fields in a layer of ``kind``."""
+        return dict(
+            num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
+            head_dim=self.head_dim, qk_norm=self.qk_norm,
+            block_diffusion=mask, flash=self.flash,
+            rope_parameters=dict(self.rope_parameters or {}).get(kind),
+            window=(self.sliding_window if kind == "sliding_attention"
+                    else None))
+
+    def qk_plans(self, rows: int, seq_len: int) -> list[dict]:
+        """The plans of q's and k's norm and rotation in a training step of
+        ``rows`` rows of ``seq_len`` ids (``GroupedQueryAttention.qk_plan``:
+        which program runs, read from the shape), one a layer type kept
+        that has attention, those that differ."""
+        t, mask = seq_len, None
+        if self.objective == "block_diffusion":
+            t, mask = 2 * seq_len, (seq_len, self.block_length)
+        plans = []
+        for kind in dict.fromkeys(
+                self.layer_types[:self.layers or self.num_layers]):
+            if kind in PAIR_KINDS + ("attention",):
+                plan = GroupedQueryAttention(
+                    **self._attention_of(kind, mask), parent=None).qk_plan(
+                        rows, t, self.flash)
+                if plan not in plans:
+                    plans.append(plan)
+        return plans
 
 
 def _raw_key(key: jax.Array) -> jax.Array:

@@ -7,4 +7,5 @@ interpreter-mode fallback so the same kernels run in CPU tests.
 """
 
 from tpudist.ops.pallas.flash_attention import (  # noqa: F401
-    flash_attention, flash_attention_qkv, flash_attention_spmd)
+    flash_attention, flash_attention_laid, flash_attention_qkv,
+    flash_attention_spmd)

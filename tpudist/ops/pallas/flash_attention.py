@@ -28,11 +28,15 @@ hierarchy):
   (``_Band``: block offsets are elements, ``pl.Element``, not block
   multiples), so a q block walks the keys its rows may see and little
   more (``_default_blocks`` chooses the blocks from the static shape).
-- operands lie as [B, Hkv, G, T, D] (``_operand``): a block is G tiles of
-  (rows, D), each whole rows of one head. (Read as [B, T, H*D], where the
-  projections wrote them, the kernels ran as fast alone, but the decoder's
-  step lost 44 ms to the layouts XLA then gave the norm and RoPE around
-  the calls: measured in PR 33 and taken out.)
+- operands lie as [B, Hkv, G, T, D]: a block is G tiles of (rows, D), each
+  whole rows of one head. Who writes them there: for a decoder layer that
+  norms or rotates q and k, the Pallas pass that does so
+  (``ops/pallas/qk_norm_rope.py``, through ``flash_attention_laid``: q and k
+  arrive laid, dQ and dK leave laid); for v, o and dO, and for q and k of
+  every other caller, XLA by ``_operand`` / ``_result``. (Read as [B, T,
+  H*D], where the projections wrote them, the kernels ran as fast alone,
+  but the decoder's step lost 44 ms to the layouts XLA then gave the norm
+  and RoPE around the calls: measured in PR 33 and taken out.)
 - Q stays resident in VMEM for all k steps of a q block (scaled by the
   softmax temperature once, into scratch, at the block's first step: S =
   (scale·Q)Kᵀ needs no per-tile VPU rescale); K/V blocks stream HBM→VMEM via
@@ -896,6 +900,58 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       blocks, interpret)
 
 
+def why_not_laid(t: int, group: int, window: int | None = None,
+                 block_diffusion: tuple | None = None) -> str | None:
+    """Why a caller cannot write q and k of a self-attention over ``t``
+    positions into the kernels' layout itself (``flash_attention_laid``),
+    None where it can (static, from the shape): one array then serves all
+    three passes, so none of them may pad a row, and one program has to
+    hold a key-value head's whole group."""
+    if _programs_of(group) > 1:
+        return (f"a group of {group} query heads is split over "
+                f"{_programs_of(group)} attention programs")
+    padded = (f"a row of {t} positions is padded in one of the three "
+              f"attention passes")
+    if block_diffusion is not None and 2 * block_diffusion[0] != t:
+        return padded           # not the doubled row the mask states
+    mask = _Mask(block_diffusion is None, window, block_diffusion)
+    blocks = _default_blocks(t, t, window, group)
+    bands = (mask.band(*blocks.fwd, t, t), mask.band(*blocks.dq, t, t),
+             mask.band(*blocks.dkv, t, t, stream="q"))
+    if any(not band.tq_pad == t == band.tk_pad for band in bands):
+        return padded
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "window", "block_diffusion", "interpret"))
+def flash_attention_laid(q: jax.Array, k: jax.Array, v: jax.Array,
+                         causal: bool = False, window: int | None = None,
+                         block_diffusion: tuple | None = None,
+                         interpret: bool | None = None):
+    """``flash_attention`` for a caller that has written q and k where the
+    kernels read them: ``q`` [B, Hkv, G, T, D] and ``k`` [B, Hkv, 1, T, D]
+    (``ops/pallas/qk_norm_rope.py`` writes them so); ``v`` [B, T, Hkv, D] as
+    ever; returns [B, T, H, D]. The cotangents of q and k leave laid too, as
+    the dQ and dKV kernels write them. A self-attention at the shape's own
+    blocks, of a length that no pass pads and a group one program holds
+    (``why_not_laid``): anything else is refused."""
+    b, hkv, group, t, d = q.shape
+    mask = _Mask(causal, window, block_diffusion)
+    why = why_not_laid(t, group, window, block_diffusion)
+    if k.shape != (b, hkv, 1, t, d) or v.shape != (b, t, hkv, d) or (
+            causal if block_diffusion is not None
+            else window is not None and not causal):
+        why = "not one self-attention's operands under a mask it takes"
+    if why:
+        raise ValueError(f"laid operands q {q.shape}, k {k.shape} with v "
+                         f"{v.shape} under {mask}: {why}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _flash_vjp(q, k, v, mask, _default_blocks(t, t, window, group),
+                      interpret)
+
+
 def flash_attention_spmd(qkv: jax.Array, causal: bool = False, **kw):
     """``flash_attention_qkv`` that composes with the GSPMD (jit + sharding
     rules) path. ``qkv`` is the fused projection
@@ -991,7 +1047,11 @@ def _stream_cost(band: _Band, products: int, b, h, d, isz, arrays: int,
 # then chose around the calls: PERF.md section 6, PR 33); rows padded to the
 # block multiple (padded keys are masked inside the kernel, padded q rows
 # drop on exit). Under the diffusion mask the positions are two copies of a
-# row, each padded apart (``copies``).
+# row, each padded apart (``copies``). Since PR 41 q and k of a decoder layer
+# that norms or rotates them do NOT come this way: the pass that norms and
+# rotates writes them laid (``flash_attention_laid``): ``_operand`` hands an
+# array of that rank on as it is, and ``_operand`` / ``_result`` move v, o,
+# dO and dV, and everything of the callers that hand [B, T, H, D] over.
 
 def _fit(x, axis: int, t: int, t_pad: int, copies: int = 1):
     """``x`` whose ``axis`` holds ``copies`` runs of positions one after
@@ -1011,6 +1071,8 @@ def _fit(x, axis: int, t: int, t_pad: int, copies: int = 1):
 
 
 def _operand(x, hkv: int, t_pad: int, copies: int = 1):
+    if x.ndim == 5:             # laid by its caller, which pads no row
+        return x
     b, t, h, d = x.shape
     x = jnp.moveaxis(x, 1, 2).reshape(b, hkv, h // hkv, t, d)
     return _fit(x, 3, t, t_pad, copies)
@@ -1021,6 +1083,16 @@ def _result(x, t: int, copies: int = 1):
     b, hkv, members, _, d = x.shape
     return jnp.moveaxis(_fit(x, 3, t, t, copies).reshape(
         b, hkv * members, t, d), 1, 2)
+
+
+def _heads_of(q, k):
+    """(B, T, H, D, Tk, Hkv, whether they came laid) of a call's q and k:
+    [B, T, heads, D], or laid [B, Hkv, members, T, D]."""
+    if q.ndim == 5:
+        b, hkv, group, t, d = q.shape
+        return b, t, hkv * group, d, k.shape[3], hkv, True
+    b, t, h, d = q.shape
+    return b, t, h, d, k.shape[1], k.shape[2], False
 
 
 def _block_view(rows: int, members: int, d: int):
@@ -1055,8 +1127,7 @@ _GRID_SEMANTICS = pltpu.CompilerParams(
 
 
 def _flash_forward(q, k, v, mask, blocks, interpret):
-    b, t, h, d = q.shape
-    tk, hkv = k.shape[1], k.shape[2]
+    b, t, h, d, tk, hkv, _ = _heads_of(q, k)
     group = h // hkv
     band = mask.band(blocks[0], blocks[1], t, tk)
 
@@ -1237,9 +1308,10 @@ def _flash_backward(q, k, v, o, lse, g, mask, blocks_dq, blocks_dkv,
                     interpret):
     """Two-pass flash backward (see module docstring): a dQ pass parallel
     over q blocks, which also takes ``delta = rowsum(dO ∘ O)``, and a dKV
-    pass parallel over KV blocks, sharing the saved ``lse`` and delta."""
-    b, t, h, d = q.shape
-    tk, hkv = k.shape[1], k.shape[2]
+    pass parallel over KV blocks, sharing the saved ``lse`` and delta. For
+    q and k that came laid (``flash_attention_laid``: neither pass pads a
+    row) dQ and dK are handed back as the kernels write them."""
+    b, t, h, d, tk, hkv, laid = _heads_of(q, k)
     group = h // hkv
     scale = 1.0 / (d ** 0.5)
     isz = q.dtype.itemsize
@@ -1288,7 +1360,8 @@ def _flash_backward(q, k, v, o, lse, g, mask, blocks_dq, blocks_dkv,
     )(qt, _operand(k, hkv, band.tk_pad, copies),
       _operand(v, hkv, band.tk_pad, copies), ot, dot,
       _rows(lse, t, band.tq_pad, copies))
-    dq = _result(dq, t, copies)
+    if not laid:
+        dq = _result(dq, t, copies)
 
     # dKV: one program a key-value head and k block; the q rows in its band
     # stream past it, the whole group in each step
@@ -1324,7 +1397,8 @@ def _flash_backward(q, k, v, o, lse, g, mask, blocks_dq, blocks_dkv,
       _operand(q, hkv, band.tq_pad, copies),
       _operand(g, hkv, band.tq_pad, copies),
       _rows(lse, t, band.tq_pad, copies), _rows(delta, t, band.tq_pad, copies))
-    return dq, _result(dk, tk, copies), _result(dv, tk, copies)
+    return (dq, dk if laid else _result(dk, tk, copies),
+            _result(dv, tk, copies))
 
 
 # -- whole-sequence schedule --------------------------------------------------

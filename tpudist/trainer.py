@@ -524,6 +524,9 @@ class Trainer:
         if hasattr(self.model, "scan_plan"):
             self._announce_scan_plan(self.model.scan_plan(
                 cfg.per_device_batch_size, cfg.seq_len))
+        if hasattr(self.model, "qk_plans"):
+            self._announce_qk_plans(self.model.qk_plans(
+                cfg.per_device_batch_size, cfg.seq_len))
         seed = cfg.seed if cfg.seed is not None else 0
         mark(scopes.INIT_DISPATCH)
         if self.uses_seq_axis or self.uses_expert_axis or self.uses_pipe_axis:
@@ -885,6 +888,18 @@ class Trainer:
                  + (f": {plan['reason']}" if "reason" in plan else "") + ")")
         if self.telemetry is not None:
             self.telemetry.emit("ssm_scan", **plan)
+
+    def _announce_qk_plans(self, plans: list) -> None:
+        """The log line and telemetry event of q's and k's norm and rotation
+        in the attention blocks (``qk_norm_rope.qk_plan``: read from the
+        shape, nothing to decide), one a plan that differs."""
+        for plan in plans:
+            self.log(f"=> attn q/k: {plan['kernel']} (" + (
+                plan["reason"] if "reason" in plan else
+                f"rows_per_program {plan['rows_per_program']}, programs "
+                f"{plan['programs']} a layer") + ")")
+            if self.telemetry is not None:
+                self.telemetry.emit("attn_qk", **plan)
 
     def _forced_flash_decision(self) -> dict:
         """The attention decision of a family without a start-up probe (a
