@@ -10,7 +10,10 @@ scopes, the carry counter, and nothing from a program without them) and of
 `selftest/test_ssd_roofline_cpu.py` (the scan's counts at the cell's shape,
 its share on hand-made scopes, a share above 100) and of
 `selftest/test_qk_rope_roofline_cpu.py` (the q / k pass's counts at both
-cells' shapes, its share on hand-made scopes, nothing from the hybrid's).
+cells' shapes, its share on hand-made scopes, nothing from the hybrid's) and
+of `selftest/test_ouro_cpu.py` (the looped cell's files and lists, the two
+looped rooflines' counts a layer AND a pass, the dense feed-forward's, the
+exit's and the loop's scopes, the exit counter).
 The rest of `benchmarks/chip/selftest/` builds trainers for minutes and
 stays run by path. Below them: what the configurations' `trainer_argv` pins
 against the program's defaults."""
@@ -61,6 +64,13 @@ _spec = importlib.util.spec_from_file_location(
 _qk_roofline = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_qk_roofline)
 globals().update({test.__name__: test for test in _qk_roofline.TIER1})
+
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_test_ouro_cpu", os.path.join(os.path.dirname(_PATH),
+                                            "test_ouro_cpu.py"))
+_ouro = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_ouro)
+globals().update({test.__name__: test for test in _ouro.TIER1})
 
 
 def test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs(
@@ -126,17 +136,22 @@ def test_attn_bd_fill_reader(monkeypatch, capsys):
 _TOKENS = ["mellum2_12b_ep4_staged_8k", "sdar_30b_ep8_staged_8k",
            "nemotron3_nano_ep16_staged_8k"]
 _ALL = ["resnet18_staged", "vit_b16_staged"] + _TOKENS
+_LOOPED = [_ouro.CELL]          # PR 42's cell, behind the accepted ones
 _ATTN = "step program: attention blocks"
 # name -> (unit, source, layer, workloads)
 _TRACING = {
-    "attn_mixer_ms": ("ms", "device_trace", _ATTN, _TOKENS),
-    "attn_proj_ms": ("ms", "device_trace", _ATTN, _TOKENS),
-    "attn_qk_rope_ms": ("ms", "device_trace", _ATTN, _TOKENS[:2]),
-    "block_norm_ms": ("ms", "device_trace", "step program", _TOKENS),
+    "attn_mixer_ms": ("ms", "device_trace", _ATTN, _TOKENS + _LOOPED),
+    "attn_proj_ms": ("ms", "device_trace", _ATTN, _TOKENS + _LOOPED),
+    "attn_qk_rope_ms": ("ms", "device_trace", _ATTN, _TOKENS[:2] + _LOOPED),
+    "block_norm_ms": ("ms", "device_trace", "step program",
+                      _TOKENS + _LOOPED),
+    # its list of parts is the benchmark's copy and lacks the looped
+    # cell's three: that cell lists `loop_unitemised_ms`
     "step_unitemised_ms": ("ms", "device_trace", "step program", _TOKENS),
-    "loop_host_max_ms": ("ms", "program_span", "trainer loop", _ALL),
+    "loop_host_max_ms": ("ms", "program_span", "trainer loop",
+                         _ALL + _LOOPED),
     "window_compile_count": ("count", "program_counter", "trainer loop",
-                             _ALL),
+                             _ALL + _LOOPED),
 }
 _DEVICE_READERS = [n for n, v in _TRACING.items() if v[1] == "device_trace"]
 reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
@@ -146,11 +161,11 @@ reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
 @pytest.mark.parametrize("name", _TRACING)
 def test_the_tracing_entries_are_listed(name):
     """`BENCHMARK.json` lists each of the seven once, behind what PR 39
-    had (PR 41's `attn_qk_rope_roofline` follows them), with the cells where
-    its reader finds something to read."""
+    had (PR 41's `attn_qk_rope_roofline` follows them, then PR 42's seven),
+    with the cells where its reader finds something to read."""
     bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
-    assert [m["name"] for m in bench["per_layer"][-8:]] == list(_TRACING) + [
-        "attn_qk_rope_roofline"]
+    assert [m["name"] for m in bench["per_layer"][-15:]] == list(
+        _TRACING) + ["attn_qk_rope_roofline"] + list(_ouro.NEW)
     entry, = [m for m in bench["per_layer"] if m["name"] == name]
     unit, source, layer, workloads = _TRACING[name]
     assert entry == {"name": name, "unit": unit, "better": "lower",
@@ -161,7 +176,12 @@ def test_the_tracing_entries_are_listed(name):
 
 def test_step_unitemised_holds_the_programs_list():
     from tpudist.obs import scopes
-    assert reader("step_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS
+    # the benchmark's copy is the list as PR 41 left it; PR 42's three
+    # scopes lie behind it, in the program's list and in the new reader's
+    assert reader("step_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS[:22]
+    assert scopes.STEP_PARTS[22:] == (scopes.DENSE_MLP, scopes.LOOP_EXIT,
+                                      scopes.LOOP_CARRY)
+    assert reader("loop_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS
     assert reader("loop_host_max_ms").ACTIVITIES == scopes.LOOP_ACTIVITIES
     assert reader("attn_mixer_ms").PARTS == (
         scopes.ATTN_QKV_PROJ, scopes.ATTN_QK_NORM_ROPE, scopes.ATTN_FUSED,
@@ -364,6 +384,12 @@ _NOT_DEFAULTS = {
         ("remat", "a Mamba block's backward keeps its decays and chunk "
                   "states: only one block at a time do they fit beside 7.45 "
                   "GiB of state"),
+    ("ouro_2_6b_pp8", "--flash"):
+        ("flash", "a decoder has no start-up probe, `auto` is XLA's path, and "
+                  "XLA's scores at 8,192 tokens would be 4.3 GB a layer pass"),
+    ("ouro_2_6b_pp8", "--remat"):
+        ("remat", "24 layer passes a step: only rematerialised do their "
+                  "temporaries fit beside 6.12 GB of state"),
 }
 
 
@@ -402,7 +428,7 @@ def test_a_pin_writes_a_default_out_and_no_more(name, argv, flag):
 def test_every_exception_names_a_pin_that_is_written_out():
     written = {(p.values[0], p.values[2]) for p in _pins()}
     assert set(_NOT_DEFAULTS) <= written
-    assert len(written) == 55
+    assert len(written) == 66
 
 
 def test_fused_bn_takes_off_and_nothing_else(capsys):

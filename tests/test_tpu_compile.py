@@ -48,10 +48,10 @@ FLASH_SHAPES = ((128, 197, 12, 64),      # ViT-B/16 @224
 # The streaming schedule at a decoder's shape (batch, tokens, query heads,
 # key-value heads, head_dim, window): two sequences of 8,192 with 32 heads
 # over 4 of 128, a sliding-window layer and a full one.
-# the last: a group of sixteen query heads (32 over 2), split over two
-# programs of eight
+# then a group of sixteen query heads (32 over 2), split over two programs
+# of eight, and a group of one (16 over 16, one row: the looped cell's)
 FLASH_GQA_SHAPES = ((2, 8192, 32, 4, 128, 1024), (2, 8192, 32, 4, 128, None),
-                    (2, 8192, 32, 2, 128, None))
+                    (2, 8192, 32, 2, 128, None), (1, 8192, 16, 16, 128, None))
 # The same schedule under the mask of training by diffusion over blocks
 # (batch, positions of the doubled row, query heads, key-value heads,
 # head_dim, block length): two rows of 8,192 ids, each a noised and a
@@ -62,6 +62,9 @@ SSD_SCAN_SHAPES = ((2, 8192, 64, 64, 8, 128, 128),
 # q / k RMSNorm and RoPE as one pass (rows, positions, heads, key-value
 # heads, head_dim): the two claimed cells' shapes
 QK_NORM_ROPE_SHAPES = ((2, 8192, 32, 4, 128), (2, 16384, 32, 4, 128))
+# the same pass with a rotation and no norm, at a group of one (the looped
+# cell's: one row, 16 heads over 16)
+QK_ROPE_SHAPES = ((1, 8192, 16, 16, 128),)
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -144,18 +147,19 @@ def _ssd_scan_fn(bwd: bool, chunk: int):
     return jax.grad(f, argnums=tuple(range(6))) if bwd else f
 
 
-def _qk_norm_rope_fn(bwd: bool, t: int, d: int, kv_heads: int):
+def _qk_norm_rope_fn(bwd: bool, t: int, d: int, kv_heads: int,
+                     norm: bool = True):
     from tpudist.ops import rope
     from tpudist.ops.pallas.qk_norm_rope import qk_norm_rope
     cos, sin = rope.tables({"rope_type": "default", "rope_theta": 1e6}, d, t)
 
-    def f(q, k, q_scale, k_scale):
+    def f(q, k, q_scale=None, k_scale=None):
         ql, kl = qk_norm_rope(q, k, kv_heads=kv_heads, q_scale=q_scale,
                               k_scale=k_scale, cos=cos, sin=sin,
                               interpret=False)
         return ql.astype(jnp.float32).sum() + kl.astype(jnp.float32).sum()
 
-    return jax.grad(f, argnums=(0, 1, 2, 3)) if bwd else f
+    return jax.grad(f, argnums=tuple(range(4 if norm else 2))) if bwd else f
 
 
 def _flash_qkv_fn(bwd: bool, causal: bool):
@@ -203,6 +207,10 @@ _KERNEL_CASES = (
                     id=f"qk_norm_rope_t{shape[1]}_"
                        f"{'fwdbwd' if bwd else 'fwd'}")
        for shape in QK_NORM_ROPE_SHAPES for bwd in (False, True)]
+    + [pytest.param(("qk_rope",) + shape, bwd,
+                    id=f"qk_rope_t{shape[1]}_h{shape[2]}_kv{shape[3]}_"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for shape in QK_ROPE_SHAPES for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -228,12 +236,13 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
                 S((b, t, g, n), jnp.bfloat16), S((h,), jnp.float32)]
         fn = _ssd_scan_fn(bwd, chunk)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    elif case[0] == "qk_norm_rope":
+    elif case[0] in ("qk_norm_rope", "qk_rope"):
         b, t, h, hkv, d = case[1:]
+        norm = case[0] == "qk_norm_rope"
         args = [S((b, t, h * d), jnp.bfloat16), S((b, t, hkv * d),
-                                                  jnp.bfloat16),
-                S((d,), jnp.float32), S((d,), jnp.float32)]
-        fn = _qk_norm_rope_fn(bwd, t, d, hkv)
+                                                  jnp.bfloat16)] + [
+            S((d,), jnp.float32)] * (2 * norm)
+        fn = _qk_norm_rope_fn(bwd, t, d, hkv, norm)
     elif case[0] == "grouped":
         _, rows, groups, k, n = case
         args = [S((rows, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
@@ -459,11 +468,12 @@ def test_vit_b16_flash_step_compiles_for_v5e(topo, monkeypatch, tp):
 @pytest.mark.slow
 @pytest.mark.parametrize("name,most_gib", [
     ("mellum2_12b_ep4", 11.2), ("sdar_30b_ep8", 14.5),
-    ("nemotron3_nano_ep16", 15.0)])
+    ("nemotron3_nano_ep16", 15.0), ("ouro_2_6b_pp8", 15.0)])
 def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     """The whole step of a benchmark's decoder cell, as its configuration's
-    ``trainer_argv`` builds it (two rows of 8,192 ids, ``--remat``, the
-    streaming attention kernel); its bytes are printed (``-s``).
+    ``trainer_argv`` builds it (its ``per_chip_batch`` rows of 8,192 ids,
+    ``--remat``, the streaming attention kernel); its bytes are printed
+    (``-s``).
 
     ``mellum2_12b_ep4`` (one chip's share of Mellum2-12B-A2.5B: 4 layers, 16
     of 64 experts, a quarter of the vocabulary): it fits, 11.04 GiB of the
@@ -476,7 +486,11 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     chip's share of Nemotron-3-Nano-30B-A3B: the first nine blocks, four of
     them Mamba-2 mixers by the chunked scan, one attention with a group of
     sixteen split over two programs of eight, 8 of 128 experts beside the
-    shared one, an eighth of the vocabulary): it fits.
+    shared one, an eighth of the vocabulary): it fits. ``ouro_2_6b_pp8`` (one
+    pipeline stage's share of Ouro-2.6B: 6 dense layers run four times by a
+    scan over the passes, one row): it fits, 13.15 GiB, with one pass's 18
+    attention calls in the program (not 72), and its cost analysis counts
+    the scan's body once (27.5 TFLOP of some 107).
 
     Inside each rematerialised layer no loop copies a pair buffer (a carry
     that XLA could not update in place cost 29 ms a step a loop, on the
@@ -491,8 +505,10 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "chip", "configs",
                            name + ".json")) as f:
-        argv = [str(a).format(batch=2, seed=0, outpath="unused")
-                for a in json.load(f)["trainer_argv"]]
+        config = json.load(f)
+    rows = config["per_chip_batch"]
+    argv = [str(a).format(batch=rows, seed=0, outpath="unused")
+            for a in config["trainer_argv"]]
     cfg = from_args(argv)
     mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
     model = create_model(cfg.arch, num_classes=cfg.num_classes,
@@ -501,7 +517,7 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
         layers=cfg.layers,
         expert_share=_parse_share(cfg.expert_share, "--expert-share"),
         vocab_share=_parse_share(cfg.vocab_share, "--vocab-share"))
-    ids = jax.ShapeDtypeStruct((2, cfg.seq_len), jnp.int32,
+    ids = jax.ShapeDtypeStruct((rows, cfg.seq_len), jnp.int32,
                                sharding=NamedSharding(mesh, P("data")))
     lr = jax.ShapeDtypeStruct((), jnp.float32,
                               sharding=NamedSharding(mesh, P()))
@@ -514,9 +530,12 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     ma = compiled.memory_analysis()
     step_bytes = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     print(f"{name}: step {step_bytes} bytes = {step_bytes / 2**30:.4f} GiB "
           f"(arguments {ma.argument_size_in_bytes}, temporaries "
-          f"{ma.temp_size_in_bytes}, aliased {ma.alias_size_in_bytes})")
+          f"{ma.temp_size_in_bytes}, aliased {ma.alias_size_in_bytes}); "
+          f"cost analysis {cost.get('flops')} flops")
     assert step_bytes < most_gib * 2**30
     text = compiled.as_text()
     diffusion = model.objective == "block_diffusion"
@@ -531,6 +550,11 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     assert sum("tpu_custom_call" in line for line in under) == 3 * len(
         [k for k in model.layer_types[:cfg.layers] if "attention" in k])
     for line in under:
+        if model.num_heads == model.num_kv_heads:
+            # a group of one IS a minor dimension of 1: the logsumexp and
+            # delta of such a call lie lane-padded, 64 MiB each at 16 heads
+            # of 8,192 positions (docs/ATTENTION.md, "A group of one")
+            break
         made = line.split(" = ", 1)[-1].split("(", 1)[0]
         assert not re.search(rf"f32\[[\d,]*{positions},1\]", made), \
             line[:200]

@@ -118,6 +118,8 @@ register_model("sdar_30b_a3b", _decoder_mod.sdar_30b_a3b)
 register_model("sdar_tiny", _decoder_mod.sdar_tiny)
 register_model("nemotron3_nano_30b_a3b", _decoder_mod.nemotron3_nano_30b_a3b)
 register_model("nemotron3_tiny", _decoder_mod.nemotron3_tiny)
+register_model("ouro_2_6b", _decoder_mod.ouro_2_6b)
+register_model("ouro_tiny", _decoder_mod.ouro_tiny)
 
 
 def model_names() -> list[str]:
@@ -128,12 +130,12 @@ def model_names() -> list[str]:
 # (models/resnet.py, models/vit.py). The single source of truth for every
 # entry point (trainer, bench.py, direct create_model callers).
 REMAT_FAMILIES = ("resnet", "resnext", "wide_resnet", "vit_b", "vit_l",
-                  "vit_h", "mellum2", "sdar", "nemotron3")
+                  "vit_h", "mellum2", "sdar", "nemotron3", "ouro")
 # Families whose attention can run the Pallas kernel (``--flash``), and of
 # them the ones whose ``--flash auto`` has a start-up probe (a fused
 # projection of equal head counts; a decoder's grouped, windowed attention
 # has none yet: ``auto`` there is the XLA path).
-FLASH_FAMILIES = ("vit", "mellum2", "sdar", "nemotron3")
+FLASH_FAMILIES = ("vit", "mellum2", "sdar", "nemotron3", "ouro")
 FLASH_PROBE_FAMILIES = ("vit",)
 
 

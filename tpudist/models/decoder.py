@@ -12,7 +12,10 @@ layer is one of the kinds of ``LAYER_KINDS``, as the registered model's
   (RMSNorm over ``head_dim`` on q and k, then RoPE by the table of the
   layer's type: ``sliding_attention`` sees the nearest ``sliding_window``
   keys, ``full_attention`` all before it), and a top-k layer of experts
-  (``parallel/moe.py::moe_topk_held``);
+  (``parallel/moe.py::moe_topk_held``); in a model that states a
+  ``dense_width`` the pair's second half is a dense SwiGLU of that width
+  and each half lies between two norms, ``h = x + RMSNorm(Attn(RMSNorm(
+  x)))``, ``y = h + RMSNorm(MLP(RMSNorm(h)))`` (``DenseLayer``);
 - one mixer behind one norm, ``x + Mixer(RMSNorm(x))`` (``mamba``, ``moe``,
   ``attention``; a model that publishes its layers as a string of letters
   names them ``M``, ``E``, ``*``: ``layer_types_of``): a Mamba-2 mixer
@@ -22,8 +25,26 @@ layer is one of the kinds of ``LAYER_KINDS``, as the registered model's
 What the attention does to q and k (q/k RMSNorm, RoPE or neither: a model
 whose Mamba layers carry position rotates nothing), the router's rule (a
 softmax's, or sigmoid scores with a bias that chooses and a scaling factor),
-the experts' body (SwiGLU, or ungated ``relu^2``) and a shared expert beside
-the routed ones are statements of the registered model, never flags.
+the experts' body (SwiGLU, or ungated ``relu^2``), a shared expert beside
+the routed ones, a dense feed-forward in their place and how often the
+layers run (below) are statements of the registered model, never flags.
+
+**Layers run several times** (``loop_steps = T > 1``; Ouro,
+arXiv:2510.25741; docs/LOOPED.md). The kept layers and the final norm are
+one pass, run ``T`` times over the same leaves: ``h_t = Norm_f(Layers(h_{t
+- 1}))``, ``h_0`` the embedding's rows, and ``h_t`` is read by the head and
+by an exit gate, ``lambda_t = sigmoid(w_g . h_t + b_g)`` a position, AND
+goes on into pass ``t + 1``. With ``S_1 = 1``, ``S_{t + 1} = S_t (1 -
+lambda_t)`` a position exits at pass ``t`` with probability ``p_t =
+lambda_t S_t`` (``p_T = S_T``: what is left), and the loss is the mean over
+rows x L of ``sum_t p_t CE(h_t W_head, y) - exit_beta H(p)``, ``H(p) = -
+sum_t p_t log p_t``: the expected cross entropy over the exit step, less an
+entropy bonus under a uniform prior. One ``nn.scan`` over the passes with
+the parameters broadcast: a pass is traced once, ``S_t`` and the sums ride
+the carry, and each pass takes its ``lm_head_loss(h_t, head, y, weights =
+p_t)`` where its ``h_t`` is made (the weights are differentiated: the gate
+learns through them). Top-1 accuracy is the last pass's; without
+``targets`` the logits are.
 
 **Diffusion over blocks** (``objective="block_diffusion"``; SDAR,
 arXiv:2510.06303, trained as BD3-LM's vectorised form, arXiv:2503.09573,
@@ -53,8 +74,8 @@ each layer's experts and the vocabulary's rows between them. The router
 keeps its published width and its experts per token; the layer computes the
 held experts' part of the result; ids and logits are over the rows held.
 
-Precision: float32 parameters, norms, router, softmaxes and loss; products
-and activations in ``dtype``.
+Precision: float32 parameters, norms, router, exit gate, softmaxes and loss;
+products and activations in ``dtype``.
 
 Given ``targets`` the model takes the loss itself (``ops.lm_head_loss``: the
 head and the cross entropy a chunk of positions at a time) and returns a
@@ -413,6 +434,52 @@ class DecoderLayer(nn.Module):
             return x + y, counters
 
 
+class DenseMLP(nn.Module):
+    """A dense SwiGLU, ``down(silu(gate(x)) * up(x))``, no bias."""
+    width: int
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        dt = self.dtype or x.dtype
+
+        def proj(features, name):
+            return nn.Dense(features, use_bias=False, dtype=dt,
+                            kernel_init=_init, name=name)
+        with jax.named_scope(scopes.DENSE_MLP):
+            h = jax.nn.silu(proj(self.width, "gate_proj")(x)) * proj(
+                self.width, "up_proj")(x)
+            return proj(x.shape[-1], "down_proj")(h)
+
+
+class DenseLayer(nn.Module):
+    """Attention and a dense SwiGLU, each between two RMSNorms: ``h = x +
+    RMSNorm(Attn(RMSNorm(x)))``, ``y = h + RMSNorm(MLP(RMSNorm(h)))``; four
+    norms, each with its own weight. No counters."""
+    attn: dict
+    width: int
+    eps: float
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array):
+        dt = self.dtype or x.dtype
+
+        def norm(name, y):
+            return RMSNorm(self.eps, name=name)(y)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            y = norm("input_norm", x).astype(dt)
+        y = GroupedQueryAttention(**self.attn, eps=self.eps, dtype=dt,
+                                  name="self_attention")(y)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            # the sum in float32, one rounding into the stream
+            x = (x + norm("attn_out_norm", y)).astype(dt)
+            y = norm("post_norm", x).astype(dt)
+        y = DenseMLP(self.width, dtype=dt, name="mlp")(y)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            return (x + norm("mlp_out_norm", y)).astype(dt), {}
+
+
 class MixerBlock(nn.Module):
     """``x + Mixer(RMSNorm(x))``: one mixer behind one norm (``kind``:
     ``mamba`` | ``moe`` | ``attention``; ``mixer``: its module's fields)."""
@@ -466,6 +533,15 @@ class MoEDecoder(nn.Module):
     shared_width: int = 0                # a shared expert's width; 0: none
     mamba: Any = None                    # a Mamba-2 mixer's published sizes
     #                                      (``Mamba2Mixer``'s fields)
+    dense_width: int = 0                 # a pair's second half is a dense
+    #                                      SwiGLU of this width between two
+    #                                      norms, as its attention then is
+    #                                      (``DenseLayer``); 0: the experts
+    loop_steps: int = 1                  # T: passes over the kept layers and
+    #                                      the final norm, the same leaves;
+    #                                      above 1 a head and an exit gate
+    #                                      read every pass
+    exit_beta: float = 0.0               # on the exit distribution's entropy
     # this holder's share of a deployment
     layers: int = 0                      # leading layers kept (0: all)
     expert_share: tuple = (0, 1)         # (i, n): the i-th of n holders
@@ -558,32 +634,46 @@ class MoEDecoder(nn.Module):
                        router=self.router,
                        routed_scaling=self.routed_scaling,
                        act=self.expert_act, shared_width=self.shared_width)
-        for i, kind in enumerate(self.layer_types[:kept]):
-            if kind not in LAYER_KINDS:
-                raise ValueError(f"layer {i}: unknown layer type {kind!r} "
-                                 f"(one of {', '.join(LAYER_KINDS)})")
-            of_kind = self._attention_of(kind, mask)
-            if kind in PAIR_KINDS:
-                layer, fields = DecoderLayer, dict(attn=of_kind,
-                                                   experts=experts)
-            else:
-                layer, fields = MixerBlock, dict(kind=kind, mixer={
-                    "mamba": self.mamba, "moe": experts,
-                    "attention": of_kind}[kind])
-            if self.remat:
-                # everything of a layer is made again in the backward pass
-                # but the attention kernel's two results (0.4 GB a layer at
-                # two sequences of 8,192): its forward runs once
-                from tpudist.ops.pallas.flash_attention import SAVED_BY_NAME
-                layer = nn.remat(
-                    layer,
-                    policy=jax.checkpoint_policies.save_only_these_names(
-                        *SAVED_BY_NAME))
-            x, layer_counters = layer(
-                **fields, eps=self.rms_norm_eps, dtype=dt,
-                name=f"layer_{i}")(x)
-            counters.update({f"{k}.layer_{i}": v
-                             for k, v in layer_counters.items()})
+        def run_layers(x):
+            """The kept layers, once: (x, their counters). (Called in a pass
+            of ``_looped`` too: a layer's parent is the module that runs.)"""
+            counters = {}
+            for i, kind in enumerate(self.layer_types[:kept]):
+                if kind not in LAYER_KINDS:
+                    raise ValueError(
+                        f"layer {i}: unknown layer type {kind!r} (one of "
+                        f"{', '.join(LAYER_KINDS)})")
+                of_kind = self._attention_of(kind, mask)
+                if kind in PAIR_KINDS and self.dense_width:
+                    layer, fields = DenseLayer, dict(attn=of_kind,
+                                                     width=self.dense_width)
+                elif kind in PAIR_KINDS:
+                    layer, fields = DecoderLayer, dict(attn=of_kind,
+                                                       experts=experts)
+                else:
+                    layer, fields = MixerBlock, dict(kind=kind, mixer={
+                        "mamba": self.mamba, "moe": experts,
+                        "attention": of_kind}[kind])
+                if self.remat:
+                    # everything of a layer is made again in the backward
+                    # pass but the attention kernel's two results (0.4 GB a
+                    # layer at two sequences of 8,192): its forward runs once
+                    from tpudist.ops.pallas.flash_attention import (
+                        SAVED_BY_NAME)
+                    layer = nn.remat(
+                        layer,
+                        policy=jax.checkpoint_policies.save_only_these_names(
+                            *SAVED_BY_NAME))
+                x, layer_counters = layer(
+                    **fields, eps=self.rms_norm_eps, dtype=dt,
+                    name=f"layer_{i}")(x)
+                counters.update({f"{k}.layer_{i}": v
+                                 for k, v in layer_counters.items()})
+            return x, counters
+        if self.loop_steps > 1:
+            return _looped(self, run_layers, x, targets, dt)
+        x, layer_counters = run_layers(x)
+        counters.update(layer_counters)
         if noised is not None:
             x = x[:, :length]            # the head reads the noised half
         with jax.named_scope(scopes.BLOCK_NORM):
@@ -643,6 +733,70 @@ class MoEDecoder(nn.Module):
                 if plan not in plans:
                     plans.append(plan)
         return plans
+
+
+# the least probability whose logarithm the exit entropy takes: a gate that
+# saturates in float32 leaves 0 of a position, and 0 log 0 is 0
+_EXIT_TINY = 1e-30
+
+
+def _looped(model: "MoEDecoder", run_layers, x: jax.Array,
+            targets: Optional[jax.Array], dt):
+    """``model.loop_steps`` passes over the kept layers and the final norm,
+    the same leaves every pass, from the embedding's rows ``x`` (the
+    module's docstring has the equations). One ``nn.scan`` with the
+    parameters broadcast, so a pass is traced once; the carry is ``h_t``,
+    ``S_t`` [rows, L] and three sums (the loss, the expected exit step, the
+    exit distribution's negative entropy), and each pass takes its weighted
+    head loss where its ``h_t`` is made."""
+    steps = model.loop_steps
+    if model.objective != "next_id" or not model.dense_width:
+        raise ValueError(
+            f"loop_steps {steps}: layers that run several times are dense "
+            f"layers trained on the next id (their exit loss has no place "
+            f"for a layer's counters or a noised copy)")
+    head = model.param("head", _init,
+                       (model.hidden_size, model.vocab_held), jnp.float32)
+
+    def one_pass(m, carry, t):
+        x, survive, sums = carry
+        x, _ = run_layers(x)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            normed = RMSNorm(m.rms_norm_eps, name="norm")(x)
+            x = normed.astype(dt)
+        with jax.named_scope(scopes.LOOP_EXIT):
+            # the gate reads the float32 norm at full precision, as a
+            # router does
+            leave = jax.nn.sigmoid(nn.Dense(
+                1, dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+                kernel_init=_init, name="exit_gate")(normed)[..., 0])
+            p = jnp.where(t == steps, survive, survive * leave)
+        if targets is None:
+            return (x, survive, sums), None
+        loss, acc1 = lm_head_loss(x, head, targets, m.loss_chunk, weights=p)
+        with jax.named_scope(scopes.LOOP_EXIT):
+            plogp = jnp.mean(p * jnp.log(jnp.maximum(p, _EXIT_TINY)))
+            sums = (sums[0] + loss + m.exit_beta * plogp,
+                    sums[1] + t * jnp.mean(p), sums[2] + plogp)
+            survive = survive * (1.0 - leave)
+        return (x, survive, sums), acc1
+
+    zero = jnp.zeros((), jnp.float32)
+    # the loop's own work reads under this scope and no other part's: a
+    # pass's saved results stacked and taken back, the tied leaves'
+    # gradients summed over the passes
+    with jax.named_scope(scopes.LOOP_CARRY):
+        (x, _, (loss, exit_step, neg_entropy)), acc1 = nn.scan(
+            one_pass, variable_broadcast="params",
+            split_rngs={"params": False}, length=steps)(
+                model, (x, jnp.ones(x.shape[:2], jnp.float32),
+                        (zero, zero, zero)), jnp.arange(1, steps + 1))
+    if targets is None:
+        with jax.named_scope(scopes.LM_HEAD):
+            return jnp.dot(x, head.astype(dt),
+                           preferred_element_type=jnp.float32)
+    return Scored(loss, acc1[-1], {scopes.LOOP_EXPECTED_EXIT: exit_step,
+                                   scopes.LOOP_EXIT_ENTROPY: -neg_entropy})
 
 
 def _raw_key(key: jax.Array) -> jax.Array:
@@ -779,3 +933,37 @@ def nemotron3_tiny(dtype: Any = None, **kw) -> MoEDecoder:
                    chunk=8, time_step=(0.001, 0.1, 1e-4),
                    out_scale=5 ** -0.5),
         dtype=dtype, **_own(kw))
+
+
+def ouro_2_6b(dtype: Any = None, **kw) -> MoEDecoder:
+    """Ouro-2.6B (ByteDance; ``config.json`` of
+    huggingface.co/ByteDance/Ouro-2.6B, ``model_type`` ``ouro``; the looped
+    language model of arXiv:2510.25741): 48 layers of hidden 2,048 run
+    ``total_ut_steps`` = 4 times over the same weights, 16 query heads over
+    16 key-value heads of 128 (a group of one), plain RoPE (theta 1e6), no
+    q/k norm, a dense SwiGLU of width 5,632, each half of a layer between
+    two RMSNorms (eps 1e-6); vocabulary 49,152, untied; a head and an exit
+    gate read every pass. ``exit_beta`` 0.1 is the paper's first stage as
+    remembered (no key of the config)."""
+    return MoEDecoder(
+        vocab_size=49152, hidden_size=2048, num_layers=48, num_heads=16,
+        num_kv_heads=16, head_dim=128, num_experts=0, experts_per_token=0,
+        expert_width=0, layer_types=("full_attention",) * 48,
+        rope_parameters={"full_attention": {"rope_type": "default",
+                                            "rope_theta": 1000000}},
+        sliding_window=0, rms_norm_eps=1e-6, qk_norm=False, dense_width=5632,
+        loop_steps=4, exit_beta=0.1, dtype=dtype, **_own(kw))
+
+
+def ouro_tiny(dtype: Any = None, **kw) -> MoEDecoder:
+    """The CPU tests' twin of the model above at toy widths (hidden 64, 4
+    heads over 4 of 16, a dense width of 96, 3 layers run 4 times, 256
+    ids): never a benchmark configuration."""
+    kw.setdefault("loss_chunk", 64)
+    return MoEDecoder(
+        vocab_size=256, hidden_size=64, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=16, num_experts=0, experts_per_token=0,
+        expert_width=0, layer_types=("full_attention",) * 3,
+        rope_parameters=ouro_2_6b().rope_parameters, sliding_window=0,
+        rms_norm_eps=1e-6, qk_norm=False, dense_width=96, loop_steps=4,
+        exit_beta=0.1, dtype=dtype, **_own(kw))

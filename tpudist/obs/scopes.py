@@ -53,6 +53,9 @@ MOE_DISPATCH = "moe_dispatch"          # sort the held pairs, take their rows
 MOE_EXPERTS = "moe_experts"            # grouped products over the held
 MOE_COMBINE = "moe_combine"            # weighted sum back to tokens
 MOE_SHARED = "moe_shared"              # the dense expert every token visits
+# a dense feed-forward in the experts' place (models/decoder.py::DenseMLP):
+# the gate, up and down products and the SwiGLU between them
+DENSE_MLP = "dense_mlp"
 # a Mamba-2 mixer (models/decoder.py::Mamba2Mixer, ops/ssd.py), inside the
 # forward scope under the block's name; the five parts lie within the whole
 SSM_MIXER = "ssm_mixer"
@@ -69,6 +72,16 @@ LM_HEAD = "lm_head"                    # the output head's product, a chunk
 # training by diffusion over blocks, inside the forward scope: the draws of
 # t and of the masked positions, x_t, the doubled row, the loss's weights
 BD_NOISE = "bd_noise"
+# layers that run several times (models/decoder.py::_looped), inside the
+# forward scope, a pass: the exit gate's product and sigmoid, the exit
+# distribution p_t and what survives it, its entropy, the sums the passes
+# carry (each pass's weighted head loss stays under `lm_head` / the loss)
+LOOP_EXIT = "loop_exit"
+# the loop over the passes itself: every operation of a pass lies within it
+# (under its own part as well); what lies under it and under no other part is
+# the loop's own work: a pass's saved results stacked and taken back in the
+# backward pass, the tied leaves' gradients summed over the passes
+LOOP_CARRY = "loop_carry"
 
 # -- counters a model hands the step ----------------------------------------
 # One scalar a layer and a step (`<name>.layer_<l>`), through the step's
@@ -84,20 +97,28 @@ SSM_CARRY = "ssm_chunk_carry_min"      # least exp(sum of dt A over a chunk)
 # one scalar a step (no layer): training by diffusion over blocks
 BD_MASKED = "bd_masked_share"          # masked positions over rows x L
 BD_WEIGHT = "bd_weight_sum"            # sum of masked / t over rows x L
+# one scalar a step (no layer): layers that run several times
+LOOP_EXPECTED_EXIT = "loop_expected_exit"  # mean over positions of
+#                                        sum_t t p_t, in passes
+LOOP_EXIT_ENTROPY = "loop_exit_entropy"    # mean over positions of H(p), nats
 MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED, SSM_DT, SSM_CARRY,
-                  BD_MASKED, BD_WEIGHT)
+                  BD_MASKED, BD_WEIGHT, LOOP_EXPECTED_EXIT,
+                  LOOP_EXIT_ENTROPY)
 
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)
 
 # every leaf part a train step's device time may lie under: what a named
 # operation of a decoder's step is under none of is unitemised
-# (`step_unitemised_ms` of the chip benchmark holds its own copy of this)
+# (`step_unitemised_ms` of the chip benchmark holds its own copy of the first
+# 22; `loop_unitemised_ms` adds the three behind them). A part is a leaf but
+# the last, the passes' loop, which every other part of a pass lies within:
+# a reader that sums parts hands an operation to the first it is under
 STEP_PARTS = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, MOE_SHARED,
               SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE_NORM, SSM_OUT_PROJ,
               ATTN_QKV_PROJ, ATTN_QK_NORM_ROPE, ATTN_FUSED, ATTN_OUT_PROJ,
               BLOCK_NORM, LM_EMBED, LM_HEAD, LOSS, BD_NOISE, GRAD_REDUCE,
-              OPTIMIZER, METRICS)
+              OPTIMIZER, METRICS, DENSE_MLP, LOOP_EXIT, LOOP_CARRY)
 
 # -- host spans of a loop turn ----------------------------------------------
 STEP = "train"                          # StepTraceAnnotation: h2d + dispatch
