@@ -11,6 +11,8 @@ scopes, the carry counter, and nothing from a program without them) and of
 its share on hand-made scopes, a share above 100) and of
 `selftest/test_qk_rope_roofline_cpu.py` (the q / k pass's counts at both
 cells' shapes, its share on hand-made scopes, nothing from the hybrid's) and
+of `selftest/test_ssm_conv_roofline_cpu.py` (the convolution pass's counts at
+the hybrid cell's shape, its share and its time on hand-made scopes) and
 of `selftest/test_ouro_cpu.py` (the looped cell's files and lists, the two
 looped rooflines' counts a layer AND a pass, the dense feed-forward's, the
 exit's and the loop's scopes, the exit counter).
@@ -66,6 +68,13 @@ _spec.loader.exec_module(_qk_roofline)
 globals().update({test.__name__: test for test in _qk_roofline.TIER1})
 
 _spec = importlib.util.spec_from_file_location(
+    "chipbench_test_ssm_conv_roofline_cpu", os.path.join(
+        os.path.dirname(_PATH), "test_ssm_conv_roofline_cpu.py"))
+_conv_roofline = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_conv_roofline)
+globals().update({test.__name__: test for test in _conv_roofline.TIER1})
+
+_spec = importlib.util.spec_from_file_location(
     "chipbench_test_ouro_cpu", os.path.join(os.path.dirname(_PATH),
                                             "test_ouro_cpu.py"))
 _ouro = importlib.util.module_from_spec(_spec)
@@ -77,16 +86,20 @@ def test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs(
         monkeypatch):
     """`selftest/test_nemotron3_cpu.py`'s case of the same name, which
     counts the per-layer metrics that list the hybrid's cell (20): since PR
-    39 `ssd_scan_roofline` lists it too. That file is the benchmark's and
-    is not edited by a `perf_opt` PR (run by path its count fails: PERF.md
-    section 7); here the case runs whole on the list without the new entry,
-    and the new entry is held beside it."""
+    39 `ssd_scan_roofline` lists it too, since PR 43 `ssm_conv_ms` and
+    `ssm_conv_roofline`. That file is the benchmark's and is not edited by a
+    `perf_opt` PR (run by path its count fails: PERF.md section 7); here
+    the case runs whole on the list without the new entries, and the new
+    entries are held beside it."""
     bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
-    new = [m for m in bench["per_layer"] if m["name"] == "ssd_scan_roofline"]
-    assert [m["workloads"] for m in new] == [[_nemotron3.CELL]]
-    assert new[0]["moves"] == "train_img_per_s_chip"
-    assert callable(_nemotron3.reader("ssd_scan_roofline").read)
-    bench["per_layer"].remove(new[0])
+    names = ("ssd_scan_roofline", "ssm_conv_ms", "ssm_conv_roofline")
+    new = [m for m in bench["per_layer"] if m["name"] in names]
+    assert [m["name"] for m in new] == list(names)
+    assert [m["workloads"] for m in new] == [[_nemotron3.CELL]] * 3
+    for m in new:
+        assert m["moves"] == "train_img_per_s_chip"
+        assert callable(_nemotron3.reader(m["name"]).read)
+        bench["per_layer"].remove(m)
     # PR 40's seven (held below, `test_the_tracing_entries_are_listed`): six
     # list the cell
     later = [m for m in bench["per_layer"] if m["name"] in _TRACING]
@@ -97,6 +110,28 @@ def test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs(
         _nemotron3, "load",
         lambda *path: bench if path[-1] == "BENCHMARK.json" else load(*path))
     _nemotron3.TIER1[0]()
+
+
+def test_the_looped_cells_files_parse_and_its_metrics_are_listed(
+        monkeypatch):
+    """`selftest/test_ouro_cpu.py`'s case of the same name, which holds PR
+    42's seven entries to the end of the per-layer list: since PR 43
+    `ssm_conv_ms` and `ssm_conv_roofline` lie behind them (new entries go
+    last). That file is the benchmark's and is not edited by a `perf_opt`
+    PR (run by path that line fails: PERF.md section 7); here the case runs
+    whole on the list without the two, which
+    `test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs`
+    holds to the end of the list and `test_ssm_conv_readers` to their
+    fields."""
+    bench = _ouro.load(_ouro.ROOT, "BENCHMARK.json")
+    assert [m["name"] for m in bench["per_layer"][-2:]] == [
+        "ssm_conv_ms", "ssm_conv_roofline"]
+    del bench["per_layer"][-2:]
+    load = _ouro.load
+    monkeypatch.setattr(
+        _ouro, "load",
+        lambda *path: bench if path[-1] == "BENCHMARK.json" else load(*path))
+    _ouro.TIER1[0]()
 
 
 def test_attn_bd_fill_reader(monkeypatch, capsys):
@@ -161,11 +196,13 @@ reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
 @pytest.mark.parametrize("name", _TRACING)
 def test_the_tracing_entries_are_listed(name):
     """`BENCHMARK.json` lists each of the seven once, behind what PR 39
-    had (PR 41's `attn_qk_rope_roofline` follows them, then PR 42's seven),
-    with the cells where its reader finds something to read."""
+    had (PR 41's `attn_qk_rope_roofline` follows them, then PR 42's seven
+    and PR 43's two), with the cells where its reader finds something to
+    read."""
     bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
-    assert [m["name"] for m in bench["per_layer"][-15:]] == list(
-        _TRACING) + ["attn_qk_rope_roofline"] + list(_ouro.NEW)
+    assert [m["name"] for m in bench["per_layer"][-17:]] == list(
+        _TRACING) + ["attn_qk_rope_roofline"] + list(_ouro.NEW) + [
+            "ssm_conv_ms", "ssm_conv_roofline"]
     entry, = [m for m in bench["per_layer"] if m["name"] == name]
     unit, source, layer, workloads = _TRACING[name]
     assert entry == {"name": name, "unit": unit, "better": "lower",

@@ -65,6 +65,10 @@ QK_NORM_ROPE_SHAPES = ((2, 8192, 32, 4, 128), (2, 16384, 32, 4, 128))
 # the same pass with a rotation and no norm, at a group of one (the looped
 # cell's: one row, 16 heads over 16)
 QK_ROPE_SHAPES = ((1, 8192, 16, 16, 128),)
+# the Mamba-2 mixer's convolution pass (rows, positions, the columns of
+# in_proj's result, the widths of x, B and C, taps, xBC's first column): the
+# hybrid cell's shape
+CAUSAL_CONV_SHAPES = ((2, 8192, 10304, (4096, 1024, 1024), 4, 4096),)
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -162,6 +166,17 @@ def _qk_norm_rope_fn(bwd: bool, t: int, d: int, kv_heads: int,
     return jax.grad(f, argnums=tuple(range(4 if norm else 2))) if bwd else f
 
 
+def _causal_conv_fn(bwd: bool, offset: int, widths: tuple):
+    from tpudist.ops import ssd
+
+    def f(src, kernel, bias):
+        return sum(jnp.square(v.astype(jnp.float32)).sum()
+                   for v in ssd.conv_silu_split(src, kernel, bias, offset,
+                                                widths))
+
+    return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
+
+
 def _flash_qkv_fn(bwd: bool, causal: bool):
     from tpudist.ops.pallas.flash_attention import flash_attention_qkv
 
@@ -211,6 +226,10 @@ _KERNEL_CASES = (
                     id=f"qk_rope_t{shape[1]}_h{shape[2]}_kv{shape[3]}_"
                        f"{'fwdbwd' if bwd else 'fwd'}")
        for shape in QK_ROPE_SHAPES for bwd in (False, True)]
+    + [pytest.param(("causal_conv",) + shape, bwd,
+                    id=f"causal_conv_t{shape[1]}_"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for shape in CAUSAL_CONV_SHAPES for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -243,6 +262,13 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
                                                   jnp.bfloat16)] + [
             S((d,), jnp.float32)] * (2 * norm)
         fn = _qk_norm_rope_fn(bwd, t, d, hkv, norm)
+    elif case[0] == "causal_conv":
+        b, t, columns, widths, taps, offset = case[1:]
+        args = [S((b, t, columns), jnp.bfloat16),
+                S((taps, sum(widths)), jnp.float32),
+                S((sum(widths),), jnp.float32)]
+        fn = _causal_conv_fn(bwd, offset, widths)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     elif case[0] == "grouped":
         _, rows, groups, k, n = case
         args = [S((rows, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16),
@@ -286,6 +312,19 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
         moved = (3 if bwd else 2) * b * t * (h + hkv) * d * 2 + 8 * t * d
         assert compiled.cost_analysis()["bytes accessed"] >= moved
         assert f'"bytes_accessed":"{moved}"' in text
+        return
+    if case[0] == "causal_conv":
+        # the forward; or the forward and the backward (the squares' gradient
+        # reads the results). xBC is read where it lies: no slice of the
+        # source's columns and no split of the result is in the program,
+        # and what the calls claim to move is x, B and C twice or thrice
+        import re
+        assert text.count("tpu_custom_call") == (2 if bwd else 1)
+        assert not re.search(rf"bf16\[{b},{t},{sum(widths)}\]\S* "
+                             rf"(?:slice|copy|fusion)\(", text)
+        for arrays in (2, 3)[:1 + bwd]:
+            moved = arrays * b * t * sum(widths) * 2
+            assert f'"bytes_accessed":"{moved}"' in text
         return
     if case[0] == "grouped":
         # the product; or its two transposes (dx, dw: a sum's gradient

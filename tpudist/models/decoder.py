@@ -374,16 +374,16 @@ class Mamba2Mixer(nn.Module):
                 proj = nn.Dense(2 * inner + 2 * gn + heads, use_bias=False,
                                 dtype=dt_, kernel_init=_init,
                                 name="in_proj")(x)
-                z, xbc, dt_raw = jnp.split(
-                    proj, [inner, 2 * inner + 2 * gn], axis=-1)
+                z, dt_raw = proj[..., :inner], proj[..., 2 * inner + 2 * gn:]
             kernel = self.param("conv_kernel", _conv_init(self.conv),
                                 (self.conv, inner + 2 * gn), jnp.float32)
             bias = self.param("conv_bias", _conv_init(self.conv),
                               (inner + 2 * gn,), jnp.float32)
             with jax.named_scope(scopes.SSM_CONV):
-                xbc = jax.nn.silu(ssd.causal_conv1d(xbc, kernel, bias)
-                                  ).astype(dt_)
-                xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+                # columns inner to 2 inner + 2 gn of the projection's
+                # result; x, B and C come back apart, as the scan reads them
+                xs, bm, cm = ssd.conv_silu_split(proj, kernel, bias, inner,
+                                                 (inner, gn, gn))
             dt_bias = self.param("dt_bias", _dt_bias_init(*self.time_step),
                                  (heads,), jnp.float32)
             a_log = self.param(
@@ -704,6 +704,18 @@ class MoEDecoder(nn.Module):
         m = self.mamba
         return ssd.scan_plan(rows, seq_len, m["num_heads"], m["head_dim"],
                              m["groups"], m["state"], m["chunk"])
+
+    def conv_plan(self, rows: int, seq_len: int) -> Optional[dict]:
+        """The plan of the Mamba blocks' convolution and SiLU at ``rows``
+        rows of ``seq_len`` ids (``ssd.conv_plan``: which program runs, read
+        from the shape); None for a share that keeps no such block."""
+        if "mamba" not in self.layer_types[:self.layers or self.num_layers]:
+            return None
+        m = self.mamba
+        inner, gn = m["num_heads"] * m["head_dim"], m["groups"] * m["state"]
+        return ssd.conv_plan(rows, seq_len,
+                             2 * inner + 2 * gn + m["num_heads"],
+                             (inner, gn, gn), m["conv"], inner)
 
     def _attention_of(self, kind: str, mask: Optional[tuple]) -> dict:
         """``GroupedQueryAttention``'s fields in a layer of ``kind``."""

@@ -2,8 +2,9 @@
 product: the causal depthwise convolution over time, the selective
 state-space recurrence in its chunked form (state-space duality), and the
 gated group norm. Plain ``jax.numpy``, differentiated by JAX, but for the
-scan at shapes that are whole lane tiles, which runs as a Pallas kernel pair
-(``ops/pallas/ssd_scan.py``; ``scan_plan`` says which from the shape).
+scan and the convolution at shapes that are whole lane tiles, which run as
+Pallas kernel pairs (``ops/pallas/ssd_scan.py``, ``ops/pallas/
+causal_conv.py``; ``scan_plan`` and ``conv_plan`` say which from the shape).
 
 The recurrence, for one head with a scalar ``A < 0``, a state ``h`` [P, N],
 ``x_t`` [P], ``B_t`` and ``C_t`` [N] (a group of heads shares B and C),
@@ -40,6 +41,8 @@ state) and cut afterwards.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -60,6 +63,76 @@ def causal_conv1d(x: jax.Array, kernel: jax.Array,
     return y
 
 
+LANES = 128
+# the convolution's pass (``ops/pallas/causal_conv.py``): rows of a halo
+# block (one packed bfloat16 tile, two float32 ones); rows of the block that
+# adds up d kernel (its first ``taps``) and d bias; elements of the source a
+# program holds (512 positions x 768 lanes are 0.8 MB in and out in
+# bfloat16, some 2 us at the HBM's rate against a third of one a grid step)
+CONV_HALO, CONV_SUMS = 16, 8
+_CONV_ELEMENTS = 512 * 768
+
+
+def conv_plan(rows: int, t: int, channels: int, widths: tuple, taps: int,
+              offset: int) -> dict:
+    """Which program runs the convolution and SiLU over columns ``offset``
+    to ``offset + sum(widths)`` of a ``[rows, t, channels]`` array, read
+    from the shape alone: the Pallas pass (``ops/pallas/causal_conv.py``)
+    where every width is a whole number of lane tiles, each result's columns
+    start at a whole number of its blocks and a time block of whole halos
+    divides ``t``; the ``jax.numpy`` form otherwise, with why (``reason``).
+    ``rows_per_program`` is the pass's time block, ``programs`` its grid a
+    Mamba block (one program where ``jax.numpy`` runs)."""
+    if offset + sum(widths) > channels:
+        raise ValueError(f"columns {offset} to {offset + sum(widths)} of "
+                         f"{channels}")
+    why, per, n = None, None, 1
+    if not 1 < taps <= min(CONV_HALO, CONV_SUMS - 1):
+        why = f"{taps} taps are not 2 to {min(CONV_HALO, CONV_SUMS - 1)}"
+    elif any(w % LANES or not w for w in widths):
+        bad = next(w for w in widths if w % LANES or not w)
+        why = f"a width of {bad} is no whole number of lane tiles"
+    else:
+        n = math.gcd(*(w // LANES for w in widths))
+        lanes = sum(widths) // n
+        starts = [offset + sum(widths[:i]) for i in range(len(widths))]
+        per = next((r for r in (1024, 512, 256, 128, 64, 32, 16)
+                    if t % r == 0 and r * lanes <= _CONV_ELEMENTS), None)
+        off = [(s, w // n) for s, w in zip(starts, widths) if s % (w // n)]
+        if off:
+            why = (f"columns from {off[0][0]} are no whole number of blocks "
+                   f"of {off[0][1]}")
+        elif per is None:
+            why = (f"no block of {CONV_HALO} positions or more tiles a row "
+                   f"of {t} at {lanes} lanes a program")
+    plan = dict(kernel="jax.numpy" if why else "pallas",
+                rows_per_program=t if why else per,
+                programs=1 if why else rows * n * (t // per))
+    if why:
+        plan["reason"] = why
+    return plan
+
+
+def conv_silu_split(src: jax.Array, kernel: jax.Array, bias: jax.Array,
+                    offset: int, widths: tuple) -> tuple:
+    """``silu(causal_conv1d(src[..., offset:offset + sum(widths)], kernel,
+    bias))`` in ``src``'s dtype, split into ``widths``: a Mamba-2 mixer's x,
+    B and C from ``in_proj``'s result as it lies. One algorithm, two
+    programs of it, chosen by ``conv_plan`` from the shape: the Pallas pass,
+    which reads the columns by their offset and writes the results apart, or
+    the ``jax.numpy`` form below (the fallback, and what the kernel's tests
+    are held to)."""
+    plan = conv_plan(*src.shape, widths, kernel.shape[0], offset)
+    if plan["kernel"] == "pallas":
+        from tpudist.ops.pallas.causal_conv import conv_silu_split as by_pass
+        return by_pass(src, kernel, bias, offset=offset, widths=widths,
+                       rows=plan["rows_per_program"])
+    xbc = lax.slice_in_dim(src, offset, offset + sum(widths), axis=-1)
+    y = jax.nn.silu(causal_conv1d(xbc, kernel, bias)).astype(src.dtype)
+    return tuple(jnp.split(
+        y, [sum(widths[:i]) for i in range(1, len(widths))], axis=-1))
+
+
 def gated_group_norm(y: jax.Array, z: jax.Array, weight: jax.Array,
                      groups: int, eps: float) -> jax.Array:
     """``RMSNorm_by_group(y * silu(z)) * weight`` in float32: the gate
@@ -77,9 +150,6 @@ def _running_sum(da: jax.Array) -> jax.Array:
     its own so that the benchmark's control can take it, and with it every
     ``exp`` below, in bfloat16: ``selftest/bf16_decay_on_chip.py``.)"""
     return jnp.cumsum(da, axis=-1)
-
-
-LANES = 128
 
 
 def scan_plan(rows: int, t: int, heads: int, p: int, groups: int, n: int,

@@ -143,6 +143,12 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     # program the shape takes ("pallas" | "xla", with a ``reason`` where it
     # is "xla"). Emitted once per Trainer construction where a block has one.
     "ssm_scan": ("kernel", "chunk", "heads_per_program", "programs"),
+    # The Mamba blocks' causal convolution and SiLU
+    # (tpudist/ops/ssd.py::conv_plan): the Pallas pass that reads xBC where
+    # in_proj wrote it and writes x, B and C apart ("pallas") or the
+    # jax.numpy form ("jax.numpy", with a ``reason``). Emitted once per
+    # Trainer construction where a block has one.
+    "ssm_conv": ("kernel", "rows_per_program", "programs"),
     # q's and k's RMSNorm and RoPE in the attention blocks
     # (tpudist/ops/pallas/qk_norm_rope.py::qk_plan): the Pallas pass that
     # writes them where the attention kernels read them ("pallas") or the

@@ -524,6 +524,9 @@ class Trainer:
         if hasattr(self.model, "scan_plan"):
             self._announce_scan_plan(self.model.scan_plan(
                 cfg.per_device_batch_size, cfg.seq_len))
+        if hasattr(self.model, "conv_plan"):
+            self._announce_conv_plan(self.model.conv_plan(
+                cfg.per_device_batch_size, cfg.seq_len))
         if hasattr(self.model, "qk_plans"):
             self._announce_qk_plans(self.model.qk_plans(
                 cfg.per_device_batch_size, cfg.seq_len))
@@ -889,17 +892,30 @@ class Trainer:
         if self.telemetry is not None:
             self.telemetry.emit("ssm_scan", **plan)
 
+    def _announce_pass_plan(self, said: str, event: str, per: str,
+                            plan: dict) -> None:
+        """The log line and telemetry event of an elementwise pass's plan
+        (read from the shape, nothing to decide): ``said`` in the log,
+        ``programs`` a ``per``."""
+        self.log(f"=> {said}: {plan['kernel']} (" + (
+            plan["reason"] if "reason" in plan else
+            f"rows_per_program {plan['rows_per_program']}, programs "
+            f"{plan['programs']} a {per}") + ")")
+        if self.telemetry is not None:
+            self.telemetry.emit(event, **plan)
+
+    def _announce_conv_plan(self, plan: Optional[dict]) -> None:
+        """The Mamba blocks' convolution and SiLU (``ssd.conv_plan``)."""
+        if plan is not None:
+            Trainer._announce_pass_plan(self, "ssm conv", "ssm_conv",
+                                        "block", plan)
+
     def _announce_qk_plans(self, plans: list) -> None:
-        """The log line and telemetry event of q's and k's norm and rotation
-        in the attention blocks (``qk_norm_rope.qk_plan``: read from the
-        shape, nothing to decide), one a plan that differs."""
+        """q's and k's norm and rotation in the attention blocks
+        (``qk_norm_rope.qk_plan``), one a plan that differs."""
         for plan in plans:
-            self.log(f"=> attn q/k: {plan['kernel']} (" + (
-                plan["reason"] if "reason" in plan else
-                f"rows_per_program {plan['rows_per_program']}, programs "
-                f"{plan['programs']} a layer") + ")")
-            if self.telemetry is not None:
-                self.telemetry.emit("attn_qk", **plan)
+            Trainer._announce_pass_plan(self, "attn q/k", "attn_qk", "layer",
+                                        plan)
 
     def _forced_flash_decision(self) -> dict:
         """The attention decision of a family without a start-up probe (a
