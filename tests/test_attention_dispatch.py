@@ -584,18 +584,19 @@ def test_probe_times_the_branches_the_model_calls(monkeypatch):
     assert jnp.issubdtype(flash_f(qkv).dtype, jnp.floating)
 
 
-@pytest.mark.parametrize("stale_rev", [6, 5, 4, 3, 2, 1])
+@pytest.mark.parametrize("stale_rev", [7, 6, 5, 4, 3, 2, 1])
 def test_verdict_of_an_older_kernel_rev_is_not_used(tmp_path, stale_rev):
-    """KERNEL_REV is 7 (under the mask of training by diffusion over blocks
-    the streaming kernels run the part of an edge tile that the mask
-    reaches): a rev-6 verdict in the cache, win or loss, is
-    stale — ``decide`` measures again and the trace-time ``lookup`` does
+    """KERNEL_REV is 8 (an entry for queries and keys wider than the values,
+    latent attention's training form; rev 7: under the mask of training by
+    diffusion over blocks the streaming kernels run the part of an edge
+    tile that the mask reaches): a rev-7 verdict in the cache, win or loss,
+    is stale — ``decide`` measures again and the trace-time ``lookup`` does
     not dispatch on it."""
-    assert ad.kernel_rev() == 7
+    assert ad.kernel_rev() == 8
     cache = str(tmp_path)
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
                   measure_pair=_pair(1.0, 2.0), **TPU)
-    assert d["kernel"] == "flash" and d["kernel_rev"] == 7
+    assert d["kernel"] == "flash" and d["kernel_rev"] == 8
     assert ad.lookup(*SHAPE, cache_dir=cache, **TPU) is True
     path = ad.cache_path(TPU["device_kind"], cache)
     obj = json.load(open(path))
@@ -606,7 +607,7 @@ def test_verdict_of_an_older_kernel_rev_is_not_used(tmp_path, stale_rev):
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache,
                   measure_pair=_pair(16.32, 4.55), **TPU)
     assert d["source"] == "measured" and d["kernel"] == "xla" \
-        and d["kernel_rev"] == 7
+        and d["kernel_rev"] == 8
     d = ad.decide(*SHAPE, mode="auto", cache_dir=cache, measure_pair=_boom,
                   **TPU)
     assert d["source"] == "cache" and d["kernel"] == "xla"

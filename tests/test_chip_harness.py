@@ -15,7 +15,10 @@ of `selftest/test_ssm_conv_roofline_cpu.py` (the convolution pass's counts at
 the hybrid cell's shape, its share and its time on hand-made scopes) and
 of `selftest/test_ouro_cpu.py` (the looped cell's files and lists, the two
 looped rooflines' counts a layer AND a pass, the dense feed-forward's, the
-exit's and the loop's scopes, the exit counter).
+exit's and the loop's scopes, the exit counter) and of
+`selftest/test_joyai_cpu.py` (the latent-attention cell's files and lists,
+`attn_mla_roofline`'s counts at the published shape, the new scopes' readers
+on a recorded scope table, the two losses' counter).
 The rest of `benchmarks/chip/selftest/` builds trainers for minutes and
 stays run by path. Below them: what the configurations' `trainer_argv` pins
 against the program's defaults."""
@@ -81,6 +84,31 @@ _ouro = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(_ouro)
 globals().update({test.__name__: test for test in _ouro.TIER1})
 
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_test_joyai_cpu", os.path.join(os.path.dirname(_PATH),
+                                             "test_joyai_cpu.py"))
+_joyai = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_joyai)
+globals().update({test.__name__: test for test in _joyai.TIER1})
+
+
+def _before_pr_44(bench):
+    """`BENCHMARK.json` without what PR 44 appended: its configuration, its
+    cell (last of both lists), its five per-layer entries (last five) and
+    its cell's name at the end of the lists it joined
+    (`selftest/test_joyai_cpu.py` holds all of that to the file). The older
+    cells' own cases, which count entries and hold theirs to the end of a
+    list, run on this."""
+    assert bench["workloads"][-1]["name"] == _joyai.CELL
+    assert bench["configs"][-1]["name"] == "joyai_flash_ep16"
+    assert [m["name"] for m in bench["per_layer"][-5:]] == list(_joyai.NEW)
+    del bench["workloads"][-1], bench["configs"][-1], bench["per_layer"][-5:]
+    for m in bench["per_layer"]:
+        if _joyai.CELL in m.get("workloads", ()):
+            assert m["workloads"][-1] == _joyai.CELL
+            del m["workloads"][-1]
+    return bench
+
 
 def test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs(
         monkeypatch):
@@ -91,7 +119,7 @@ def test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs(
     `perf_opt` PR (run by path its count fails: PERF.md section 7); here
     the case runs whole on the list without the new entries, and the new
     entries are held beside it."""
-    bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
+    bench = _before_pr_44(_nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json"))
     names = ("ssd_scan_roofline", "ssm_conv_ms", "ssm_conv_roofline")
     new = [m for m in bench["per_layer"] if m["name"] in names]
     assert [m["name"] for m in new] == list(names)
@@ -123,7 +151,7 @@ def test_the_looped_cells_files_parse_and_its_metrics_are_listed(
     `test_the_cells_files_parse_and_the_mix_meets_the_configurations_needs`
     holds to the end of the list and `test_ssm_conv_readers` to their
     fields."""
-    bench = _ouro.load(_ouro.ROOT, "BENCHMARK.json")
+    bench = _before_pr_44(_ouro.load(_ouro.ROOT, "BENCHMARK.json"))
     assert [m["name"] for m in bench["per_layer"][-2:]] == [
         "ssm_conv_ms", "ssm_conv_roofline"]
     del bench["per_layer"][-2:]
@@ -172,21 +200,26 @@ _TOKENS = ["mellum2_12b_ep4_staged_8k", "sdar_30b_ep8_staged_8k",
            "nemotron3_nano_ep16_staged_8k"]
 _ALL = ["resnet18_staged", "vit_b16_staged"] + _TOKENS
 _LOOPED = [_ouro.CELL]          # PR 42's cell, behind the accepted ones
+_LATENT = [_joyai.CELL]         # PR 44's, behind that
 _ATTN = "step program: attention blocks"
 # name -> (unit, source, layer, workloads)
 _TRACING = {
-    "attn_mixer_ms": ("ms", "device_trace", _ATTN, _TOKENS + _LOOPED),
-    "attn_proj_ms": ("ms", "device_trace", _ATTN, _TOKENS + _LOOPED),
-    "attn_qk_rope_ms": ("ms", "device_trace", _ATTN, _TOKENS[:2] + _LOOPED),
+    "attn_mixer_ms": ("ms", "device_trace", _ATTN,
+                      _TOKENS + _LOOPED + _LATENT),
+    "attn_proj_ms": ("ms", "device_trace", _ATTN,
+                     _TOKENS + _LOOPED + _LATENT),
+    "attn_qk_rope_ms": ("ms", "device_trace", _ATTN,
+                        _TOKENS[:2] + _LOOPED + _LATENT),
     "block_norm_ms": ("ms", "device_trace", "step program",
-                      _TOKENS + _LOOPED),
+                      _TOKENS + _LOOPED + _LATENT),
     # its list of parts is the benchmark's copy and lacks the looped
-    # cell's three: that cell lists `loop_unitemised_ms`
+    # cell's three and the latent cell's one: those cells list
+    # `loop_unitemised_ms` and `mtp_unitemised_ms`
     "step_unitemised_ms": ("ms", "device_trace", "step program", _TOKENS),
     "loop_host_max_ms": ("ms", "program_span", "trainer loop",
-                         _ALL + _LOOPED),
+                         _ALL + _LOOPED + _LATENT),
     "window_compile_count": ("count", "program_counter", "trainer loop",
-                             _ALL + _LOOPED),
+                             _ALL + _LOOPED + _LATENT),
 }
 _DEVICE_READERS = [n for n, v in _TRACING.items() if v[1] == "device_trace"]
 reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
@@ -196,13 +229,13 @@ reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
 @pytest.mark.parametrize("name", _TRACING)
 def test_the_tracing_entries_are_listed(name):
     """`BENCHMARK.json` lists each of the seven once, behind what PR 39
-    had (PR 41's `attn_qk_rope_roofline` follows them, then PR 42's seven
-    and PR 43's two), with the cells where its reader finds something to
-    read."""
+    had (PR 41's `attn_qk_rope_roofline` follows them, then PR 42's seven,
+    PR 43's two and PR 44's five), with the cells where its reader finds
+    something to read."""
     bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
-    assert [m["name"] for m in bench["per_layer"][-17:]] == list(
+    assert [m["name"] for m in bench["per_layer"][-22:]] == list(
         _TRACING) + ["attn_qk_rope_roofline"] + list(_ouro.NEW) + [
-            "ssm_conv_ms", "ssm_conv_roofline"]
+            "ssm_conv_ms", "ssm_conv_roofline"] + list(_joyai.NEW)
     entry, = [m for m in bench["per_layer"] if m["name"] == name]
     unit, source, layer, workloads = _TRACING[name]
     assert entry == {"name": name, "unit": unit, "better": "lower",
@@ -217,8 +250,11 @@ def test_step_unitemised_holds_the_programs_list():
     # scopes lie behind it, in the program's list and in the new reader's
     assert reader("step_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS[:22]
     assert scopes.STEP_PARTS[22:] == (scopes.DENSE_MLP, scopes.LOOP_EXIT,
-                                      scopes.LOOP_CARRY)
-    assert reader("loop_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS
+                                      scopes.LOOP_CARRY, scopes.MTP_MERGE)
+    # PR 44's leaf lies behind those, in the program's list and in the
+    # reader its cell lists
+    assert reader("loop_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS[:25]
+    assert reader("mtp_unitemised_ms").STEP_PARTS == scopes.STEP_PARTS
     assert reader("loop_host_max_ms").ACTIVITIES == scopes.LOOP_ACTIVITIES
     assert reader("attn_mixer_ms").PARTS == (
         scopes.ATTN_QKV_PROJ, scopes.ATTN_QK_NORM_ROPE, scopes.ATTN_FUSED,
@@ -427,6 +463,13 @@ _NOT_DEFAULTS = {
     ("ouro_2_6b_pp8", "--remat"):
         ("remat", "24 layer passes a step: only rematerialised do their "
                   "temporaries fit beside 6.12 GB of state"),
+    ("joyai_flash_ep16", "--flash"):
+        ("flash", "a decoder has no start-up probe, `auto` is XLA's path, and "
+                  "XLA's scores at 8,192 tokens would be 17 GB"),
+    ("joyai_flash_ep16", "--remat"):
+        ("remat", "six blocks of latent attention and experts at 16,384 "
+                  "positions: only rematerialised do their temporaries fit "
+                  "beside 10.14 GiB of state"),
 }
 
 
@@ -465,7 +508,7 @@ def test_a_pin_writes_a_default_out_and_no_more(name, argv, flag):
 def test_every_exception_names_a_pin_that_is_written_out():
     written = {(p.values[0], p.values[2]) for p in _pins()}
     assert set(_NOT_DEFAULTS) <= written
-    assert len(written) == 66
+    assert len(written) == 77
 
 
 def test_fused_bn_takes_off_and_nothing_else(capsys):

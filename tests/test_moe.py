@@ -157,44 +157,61 @@ def test_softmax_rule_is_what_it_was():
         top, axis=-1, keepdims=True), rtol=1e-6)
 
 
-@pytest.mark.parametrize("held", [1, 4, 16])
-def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer(held):
+@pytest.mark.parametrize("held,body", [(1, "relu2"), (4, "relu2"),
+                                       (16, "relu2"), (4, "swiglu")])
+def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer(
+        held, body):
     """The share test: the routed parts that all the holders of a layer
     compute (16 holders of 1 expert, 4 of 4, 1 of all 16), summed, plus the
     shared expert counted ONCE, equal what the plain reference gives for
     the whole layer with every expert held in one place; and each holder's
-    part is the reference's for that share."""
+    part is the reference's for that share. Both bodies: ungated ``relu^2``
+    (the hybrid's reference) and SwiGLU, the shared expert gated too (the
+    latent-attention model's)."""
     from tpudist.parallel.moe import moe_topk_held, shared_expert
-    ref = _reference("nemotron3_nano_ep16")
+    gated = body == "swiglu"
+    ref = _reference("joyai_flash_ep16" if gated else "nemotron3_nano_ep16")
     d, f, experts, k = 32, 16, 16, 3
-    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    ks = jax.random.split(jax.random.PRNGKey(0), 9)
+    # a routed expert's ``up`` lies [width, hidden] ungated, [hidden, width]
+    # beside a gate
+    wide = (experts, d, f) if gated else (experts, f, d)
     params = {"router": jax.random.normal(ks[0], (d, experts)),
-              "up": jax.random.normal(ks[1], (experts, f, d)) * 0.2,
+              "up": jax.random.normal(ks[1], wide) * 0.2,
               "down": jax.random.normal(ks[2], (experts, f, d)) * 0.2,
               "shared_up": jax.random.normal(ks[3], (d, 2 * f)) * 0.2,
               "shared_down": jax.random.normal(ks[4], (2 * f, d)) * 0.2}
+    if gated:
+        params["gate"] = jax.random.normal(ks[6], wide) * 0.2
+        params["shared_gate"] = jax.random.normal(ks[7], (d, 2 * f)) * 0.2
     bias = jax.random.normal(ks[5], (experts,)) * 0.1
     u = jax.random.normal(jax.random.PRNGKey(1), (64, d))
     z = dict(k=k, scaling=2.5)
+    routed = ("gate", "up", "down") if gated else ("up", "down")
 
     def share(lo, n):
-        return {"router": params["router"], "router_bias": bias,
-                "up": params["up"][lo:lo + n],
-                "down": params["down"][lo:lo + n]}
+        return dict({name: params[name][lo:lo + n] for name in routed},
+                    router=params["router"], router_bias=bias)
+
+    def reference(p, lo, n, shared):
+        if gated:
+            return ref.experts(u, p, bias, dict(z, first=lo, held=n),
+                               shared=shared)
+        return (ref._moe if shared else ref._routed)(
+            u, p, bias, dict(z, first=lo, held=n), None)
     with jax.default_matmul_precision("highest"):
-        whole, pairs = ref._moe(u, params, bias,
-                                dict(z, first=0, held=experts), None)
+        whole, pairs = reference(params, 0, experts, True)
         total, computed = 0.0, 0.0
         for lo in range(0, experts, held):
             y, counters = moe_topk_held(share(lo, held), u, top_k=k,
                                         first_expert=lo, rule="sigmoid",
                                         scale=2.5)
-            part, _ = ref._routed(u, share(lo, held), bias,
-                                  dict(z, first=lo, held=held), None)
+            part, _ = reference(share(lo, held), lo, held, False)
             np.testing.assert_allclose(y, part, atol=2e-5)
             total, computed = total + y, computed + counters["moe_pairs"]
-        once = shared_expert({"up": params["shared_up"],
-                              "down": params["shared_down"]}, u)
+        once = shared_expert({name[len("shared_"):]: w for name, w
+                              in params.items()
+                              if name.startswith("shared_")}, u)
     np.testing.assert_allclose(total + once, whole, atol=5e-5)
     assert float(computed) == 64 * k == float(jnp.sum(pairs))
     # the shared expert is no small part: left out, the sum misses

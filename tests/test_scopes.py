@@ -343,7 +343,7 @@ def test_a_blocks_parts_are_named_forward_and_backward(token_steps, arch,
 
 
 @pytest.mark.parametrize("remat", [True, False], ids=["remat", "plain"])
-@pytest.mark.parametrize("arch", TOKEN_ARCHS + ("ouro_tiny",))
+@pytest.mark.parametrize("arch", TOKEN_ARCHS + ("ouro_tiny", "joyai_tiny"))
 def test_step_parts_cover_the_named_operations(token_steps, arch, remat):
     """`scopes.STEP_PARTS` itemises a decoder's step: at most 3 % of its
     named operations lie under none of the parts (a block's counters, the
@@ -354,8 +354,8 @@ def test_step_parts_cover_the_named_operations(token_steps, arch, remat):
     missed = [n for n in named
               if not any(_under(n, part) for part in scopes.STEP_PARTS)]
     assert len(missed) <= 0.03 * len(named), sorted(set(missed))[:20]
-    # a part is a leaf: none lies within another (but the last, the loop
-    # over the passes, which a pass's other parts lie within)
+    # a part is a leaf: none lies within another (but the loop over the
+    # passes, which a pass's other parts lie within)
     assert len(set(scopes.STEP_PARTS)) == len(scopes.STEP_PARTS)
     assert scopes.ATTN_MIXER not in scopes.STEP_PARTS
     assert scopes.SSM_MIXER not in scopes.STEP_PARTS
@@ -434,7 +434,9 @@ def test_the_loops_own_work_reads_under_loop_carry(looped_step):
     assert any(phase_of(n) == "bwd" and "dynamic_slice" in n for n in own)
     assert any(phase_of(n) == "bwd" and n.endswith("add_any") for n in own)
     assert 0.02 * len(named) < len(own) < 0.2 * len(named)
-    assert scopes.STEP_PARTS[-1] == scopes.LOOP_CARRY
+    # behind every part a pass holds (a reader hands an operation to the
+    # first part it is under); only a later model's leaf lies behind it
+    assert scopes.STEP_PARTS[-2:] == (scopes.LOOP_CARRY, scopes.MTP_MERGE)
 
 
 def test_the_looped_scopes_change_no_compiled_flop_or_byte(mesh8, token_steps,
@@ -450,6 +452,130 @@ def test_the_looped_scopes_change_no_compiled_flop_or_byte(mesh8, token_steps,
     for key in ("flops", "bytes accessed", "transcendentals"):
         assert named[key] == bare[key], key
     assert named["flops"] > 0
+
+
+# -- latent attention and a multi-token-prediction module (PR 44):
+# `joyai_tiny`, one dense layer and one of experts under latent attention,
+# the module behind them, rematerialised ----------------------------------
+
+@pytest.fixture(scope="module")
+def latent_step(token_steps):
+    return token_steps("joyai_tiny")[1]
+
+
+def test_every_device_op_of_the_latent_step_has_a_scope(latent_step):
+    """Nothing of the new cell's step is unscoped: latent attention's two
+    low-rank paths, the dense first layer, the module and both passes
+    through the head all lie under the forward scope (plain or transposed),
+    the optimizer's or the metrics'. (No cast is hoisted here: no layer runs
+    in a loop.)"""
+    named = [n for _, n in latent_step]
+    assert len(named) > 300
+    unscoped = [n for n in named if phase_of(n) is None]
+    assert len(unscoped) <= 0.01 * len(named), sorted(set(unscoped))[:20]
+    assert not [n for n in unscoped if n.endswith("/convert_element_type")]
+
+
+@pytest.mark.parametrize("scope,within,names", [
+    (scopes.MLA_DOWN, scopes.ATTN_QKV_PROJ, ("q_a_proj", "kv_a_proj")),
+    (scopes.MLA_UP, scopes.ATTN_QKV_PROJ, ("q_b_proj", "kv_b_proj")),
+    (scopes.MLA_LATENT_NORM, scopes.ATTN_QK_NORM_ROPE,
+     ("q_a_norm", "kv_a_norm")),
+    (scopes.ATTN_QK_NORM_ROPE, scopes.ATTN_MIXER, ()),
+    (scopes.ATTN_FUSED, scopes.ATTN_MIXER, ()),
+    (scopes.ATTN_OUT_PROJ, scopes.ATTN_MIXER, ("o_proj",)),
+    (scopes.DENSE_MLP, None, ("/layer_0/mlp/", "gate_proj")),
+    (scopes.MOE_SHARED, None, ("/layer_1/moe/",))])
+def test_latent_attentions_parts_are_named_forward_and_backward(
+        latent_step, scope, within, names):
+    """What `mla_latent_ms`, `attn_proj_ms`, `attn_qk_rope_ms`,
+    `attn_mla_roofline`, `dense_mlp_ms` and `moe_shared_ms` of the chip
+    benchmark sum: every operation of latent attention lies under one of the
+    four attention parts that exist, the three new scopes within two of
+    them, plain and transposed, in the trunk's layers and in the module's
+    block alike."""
+    named = [n for _, n in latent_step
+             if _under(n, scope) and scopes.FORWARD in n]
+    assert any(phase_of(n) == "bwd" for n in named), scope
+    assert any(phase_of(n) == "fwd" or "/rematted_computation/" in n
+               for n in named), scope
+    if within is not None:
+        assert all(_under(n, within) for n in named), scope
+        assert all(_under(n, scopes.ATTN_MIXER) for n in named), scope
+        for where in ("/layer_0/", "/layer_1/", "/mtp/"):
+            assert any(where in n for n in named), (scope, where)
+    for name in names:
+        assert any(name in n for n in named), name
+    mixer = [n for _, n in latent_step if _under(n, scopes.ATTN_MIXER)]
+    parts = (scopes.ATTN_QKV_PROJ, scopes.ATTN_QK_NORM_ROPE,
+             scopes.ATTN_FUSED, scopes.ATTN_OUT_PROJ)
+    assert all(any(_under(n, p) for p in parts) for n in mixer)
+
+
+@pytest.mark.parametrize("scope,names", [
+    (scopes.MTP_MERGE, ("enorm", "hnorm", "eh_proj", "concatenate")),
+    (scopes.LM_EMBED, ()), (scopes.ATTN_QKV_PROJ, ()),
+    (scopes.ATTN_QK_NORM_ROPE, ()), (scopes.ATTN_FUSED, ()),
+    (scopes.ATTN_OUT_PROJ, ()), (scopes.MOE_ROUTER, ()),
+    (scopes.MOE_EXPERTS, ()), (scopes.MOE_SHARED, ()),
+    (scopes.BLOCK_NORM, ("/input_norm/", "/post_norm/", "/mtp/block_norm/")),
+    (scopes.LM_HEAD, ()), (scopes.LOSS, ())])
+def test_the_modules_parts_are_named_inside_mtp_module(latent_step, scope,
+                                                      names):
+    """What `mtp_ms` times and `mtp_unitemised_ms` leaves: the module lies
+    whole inside `mtp_module`, forward and backward: the next id's
+    embedding, the merge (its one leaf of its own), its block's parts under
+    their own names, its pass through the head and its loss."""
+    inside = [n for _, n in latent_step if _under(n, scopes.MTP_MODULE)]
+    named = [n for n in inside if _under(n, scope)]
+    assert any(phase_of(n) == "fwd" or "/rematted_computation/" in n
+               for n in named), scope
+    assert any(phase_of(n) == "bwd" for n in named), scope
+    for name in names:
+        assert any(name in n for n in named), name
+    if scope == scopes.MTP_MERGE:
+        # the merge is the module's and nothing else's, and no other part's
+        everywhere = [n for _, n in latent_step if _under(n, scope)]
+        assert len(everywhere) == len(named)
+        others = [p for p in scopes.STEP_PARTS if p != scope]
+        assert not any(_under(n, p) for n in named for p in others)
+    if scope in (scopes.LM_HEAD, scopes.LOSS):
+        # the main head's pass is not the module's
+        outside = [n for _, n in latent_step if _under(n, scope)
+                   and not _under(n, scopes.MTP_MODULE)]
+        assert outside
+    # under the module and no part: 3 % of its named operations at most
+    left = [n for n in inside
+            if not any(_under(n, p) for p in scopes.STEP_PARTS)]
+    assert len(left) <= 0.03 * len(inside), sorted(set(left))[:20]
+
+
+def test_the_latent_scopes_change_no_compiled_flop_or_byte(mesh8, token_steps,
+                                                           monkeypatch):
+    import contextlib
+    named = token_steps("joyai_tiny")[0].cost_analysis()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare, text = _token_step(mesh8, "joyai_tiny")
+    for scope in (scopes.MLA_DOWN, scopes.MLA_LATENT_NORM, scopes.MLA_UP,
+                  scopes.MTP_MODULE, scopes.MTP_MERGE):
+        assert f"/{scope}/" not in text, scope
+    bare = bare.cost_analysis()
+    for key in ("flops", "bytes accessed", "transcendentals"):
+        assert named[key] == bare[key], key
+    assert named["flops"] > 0
+
+
+def test_the_modules_counters_are_a_models_counters():
+    """`mtp_loss` and `lm_loss_main` ride the step's metrics to the drain as
+    the layers' counters do (`trainer._drain` keeps what starts with a name
+    of `MODEL_COUNTERS`), and the module's block's under its own layer
+    name."""
+    assert {scopes.MTP_LOSS, scopes.LM_LOSS_MAIN} <= set(
+        scopes.MODEL_COUNTERS)
+    assert f"{scopes.MOE_PAIRS}.mtp".startswith(scopes.MODEL_COUNTERS)
+    assert scopes.MTP_MERGE in scopes.STEP_PARTS
+    assert scopes.MTP_MODULE not in scopes.STEP_PARTS      # it encloses
 
 
 @pytest.fixture(scope="module")
@@ -514,7 +640,7 @@ def test_the_qk_pass_leaves_the_attention_kernels_scope_alone(laid_step):
     missed = [n for n in named
               if not any(_under(n, part) for part in scopes.STEP_PARTS)]
     assert len(missed) <= 0.03 * len(named), sorted(set(missed))[:20]
-    assert len(scopes.STEP_PARTS) == 25          # PR 42: the last three
+    assert len(scopes.STEP_PARTS) == 26          # PR 42: three; PR 44: one
     assert scopes.ATTN_QK_NORM_ROPE in scopes.STEP_PARTS
 
 

@@ -69,6 +69,10 @@ QK_ROPE_SHAPES = ((1, 8192, 16, 16, 128),)
 # in_proj's result, the widths of x, B and C, taps, xBC's first column): the
 # hybrid cell's shape
 CAUSAL_CONV_SHAPES = ((2, 8192, 10304, (4096, 1024, 1024), 4, 4096),)
+# latent attention's entry (rows, positions, heads, the keys' unrotated and
+# rotated columns, the values' columns): queries and keys of 192 against
+# values of 128, the rotated key one head for all
+FLASH_LATENT_SHAPES = ((2, 8192, 32, 128, 64, 128),)
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -177,6 +181,16 @@ def _causal_conv_fn(bwd: bool, offset: int, widths: tuple):
     return jax.grad(f, argnums=(0, 1, 2)) if bwd else f
 
 
+def _flash_latent_fn(bwd: bool):
+    from tpudist.ops.pallas import flash_attention_latent
+
+    def f(*operands):
+        return flash_attention_latent(
+            *operands, interpret=False).astype(jnp.float32).sum()
+
+    return jax.grad(f, argnums=(0, 1, 2, 3, 4)) if bwd else f
+
+
 def _flash_qkv_fn(bwd: bool, causal: bool):
     from tpudist.ops.pallas.flash_attention import flash_attention_qkv
 
@@ -230,6 +244,10 @@ _KERNEL_CASES = (
                     id=f"causal_conv_t{shape[1]}_"
                        f"{'fwdbwd' if bwd else 'fwd'}")
        for shape in CAUSAL_CONV_SHAPES for bwd in (False, True)]
+    + [pytest.param(("flash_latent",) + shape, bwd,
+                    id=f"flash_latent_t{shape[1]}_h{shape[2]}_"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for shape in FLASH_LATENT_SHAPES for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -248,6 +266,12 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
         b, t, h, d, causal = case[1:]
         args = [S((b, t, h, 3, d), jnp.bfloat16)]
         fn = _flash_qkv_fn(bwd, causal)
+    elif case[0] == "flash_latent":
+        b, t, h, dn, dr, dv = case[1:]
+        args = [S((b, t, h, dn), jnp.bfloat16), S((b, t, h, dr), jnp.bfloat16),
+                S((b, t, h, dn), jnp.bfloat16), S((b, t, dr), jnp.bfloat16),
+                S((b, t, h, dv), jnp.bfloat16)]
+        fn = _flash_latent_fn(bwd)
     elif case[0] == "ssd_scan":
         b, t, h, p, g, n, chunk = case[1:]
         args = [S((b, t, h, p), jnp.bfloat16), S((b, t, h), jnp.float32),
@@ -286,6 +310,22 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    if case[0] == "flash_latent":
+        # forward, dQ and dKV kernels; what they claim is the products over
+        # the keys' true 192 columns and the values' 128 on the tiles that
+        # run (blocks of 1,024: 36 of a head's 64), between the pairs the
+        # mask allows and an eighth more; no [B, T, H, 192] key is built
+        # (the rotated key stays [B, T, 64]) and the row statistics lie on
+        # the lanes: no [..., T, 1] float32 column, 128 x its bytes on the chip
+        import re
+        assert text.count("tpu_custom_call") == (3 if bwd else 1)
+        allowed = t * (t + 1) // 2
+        least = (3 if bwd else 1) * 2 * b * h * allowed * (dn + dr + dv)
+        assert least <= compiled.cost_analysis()["flops"] <= 1.15 * least
+        assert not re.search(rf"\[{b},(?:{t},{h}|{h},{t}),{dn + dr}\]", text)
+        assert not re.search(rf"f32\[[\d,]*{t},1\]", text)
+        assert f"f32[{b},{h},1,{t}]" in text
+        return
     if case[0] == "ssd_scan":
         # the forward alone; or the forward that keeps the entering states
         # and the backward. No [.., Q, Q] decay of a head is in the program
@@ -507,7 +547,8 @@ def test_vit_b16_flash_step_compiles_for_v5e(topo, monkeypatch, tp):
 @pytest.mark.slow
 @pytest.mark.parametrize("name,most_gib", [
     ("mellum2_12b_ep4", 11.2), ("sdar_30b_ep8", 14.5),
-    ("nemotron3_nano_ep16", 15.0), ("ouro_2_6b_pp8", 15.0)])
+    ("nemotron3_nano_ep16", 15.0), ("ouro_2_6b_pp8", 15.0),
+    ("joyai_flash_ep16", 15.0)])
 def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     """The whole step of a benchmark's decoder cell, as its configuration's
     ``trainer_argv`` builds it (its ``per_chip_batch`` rows of 8,192 ids,
@@ -586,9 +627,16 @@ def test_decoder_step_compiles_for_v5e(topo, monkeypatch, name, most_gib):
     # under ``attn_fused`` no [..., T, 1] float32 column (lane-padded 128 x
     # in HBM) is kept and XLA makes no float32 copy of q, o or dO
     under = [line for line in text.splitlines() if "/attn_fused/" in line]
-    assert sum("tpu_custom_call" in line for line in under) == 3 * len(
+    assert sum("tpu_custom_call" in line for line in under) == 3 * (len(
         [k for k in model.layer_types[:cfg.layers] if "attention" in k])
+        + model.mtp_depth)
     for line in under:
+        if model.latent:
+            # the latent entry lays its statistics on the lanes: no [..., 1]
+            # float32 column of a row's length at a group of one either
+            assert not re.search(rf"f32\[[\d,]*{positions},1\]", line.split(
+                " = ", 1)[-1].split("(", 1)[0]), line[:200]
+            continue
         if model.num_heads == model.num_kv_heads:
             # a group of one IS a minor dimension of 1: the logsumexp and
             # delta of such a call lie lane-padded, 64 MiB each at 16 heads

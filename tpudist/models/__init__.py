@@ -120,6 +120,8 @@ register_model("nemotron3_nano_30b_a3b", _decoder_mod.nemotron3_nano_30b_a3b)
 register_model("nemotron3_tiny", _decoder_mod.nemotron3_tiny)
 register_model("ouro_2_6b", _decoder_mod.ouro_2_6b)
 register_model("ouro_tiny", _decoder_mod.ouro_tiny)
+register_model("joyai_llm_flash", _decoder_mod.joyai_llm_flash)
+register_model("joyai_tiny", _decoder_mod.joyai_tiny)
 
 
 def model_names() -> list[str]:
@@ -130,12 +132,13 @@ def model_names() -> list[str]:
 # (models/resnet.py, models/vit.py). The single source of truth for every
 # entry point (trainer, bench.py, direct create_model callers).
 REMAT_FAMILIES = ("resnet", "resnext", "wide_resnet", "vit_b", "vit_l",
-                  "vit_h", "mellum2", "sdar", "nemotron3", "ouro")
+                  "vit_h", "mellum2", "sdar", "nemotron3", "ouro",
+                  "joyai")
 # Families whose attention can run the Pallas kernel (``--flash``), and of
 # them the ones whose ``--flash auto`` has a start-up probe (a fused
 # projection of equal head counts; a decoder's grouped, windowed attention
 # has none yet: ``auto`` there is the XLA path).
-FLASH_FAMILIES = ("vit", "mellum2", "sdar", "nemotron3", "ouro")
+FLASH_FAMILIES = ("vit", "mellum2", "sdar", "nemotron3", "ouro", "joyai")
 FLASH_PROBE_FAMILIES = ("vit",)
 
 

@@ -15,7 +15,12 @@ layer is one of the kinds of ``LAYER_KINDS``, as the registered model's
   (``parallel/moe.py::moe_topk_held``); in a model that states a
   ``dense_width`` the pair's second half is a dense SwiGLU of that width
   and each half lies between two norms, ``h = x + RMSNorm(Attn(RMSNorm(
-  x)))``, ``y = h + RMSNorm(MLP(RMSNorm(h)))`` (``DenseLayer``);
+  x)))``, ``y = h + RMSNorm(MLP(RMSNorm(h)))`` (``DenseLayer``); in a model
+  that states ``latent`` the pair's attention is latent attention
+  (``LatentAttention``: two low-rank paths with a norm inside each, a part
+  of a head rotated, one rotated key head for all; docs/ATTENTION.md), and
+  the first ``leading_dense`` pairs' second half is a dense SwiGLU behind
+  the same single norm;
 - one mixer behind one norm, ``x + Mixer(RMSNorm(x))`` (``mamba``, ``moe``,
   ``attention``; a model that publishes its layers as a string of letters
   names them ``M``, ``E``, ``*``: ``layer_types_of``): a Mamba-2 mixer
@@ -26,8 +31,19 @@ What the attention does to q and k (q/k RMSNorm, RoPE or neither: a model
 whose Mamba layers carry position rotates nothing), the router's rule (a
 softmax's, or sigmoid scores with a bias that chooses and a scaling factor),
 the experts' body (SwiGLU, or ungated ``relu^2``), a shared expert beside
-the routed ones, a dense feed-forward in their place and how often the
-layers run (below) are statements of the registered model, never flags.
+the routed ones (the same body), a dense feed-forward in their place, latent
+attention, a multi-token-prediction module and how often the layers run
+(below) are statements of the registered model, never flags.
+
+**A multi-token-prediction module** (``mtp_depth = 1``; DeepSeek-V3,
+arXiv:2412.19437; docs/MTP.md). Behind the kept layers, whatever ``layers``
+keeps: ``m = [RMSNorm(Emb(y)) ; RMSNorm(h_L)] W_eh`` with ``y`` the targets
+(each position's NEXT id) and ``h_L`` the layers' result before the final
+norm, one more block of its own leaves, its own norm, then the model's OWN
+head (and ``Emb`` is the model's own embedding: shared leaves, one copy).
+Position ``i`` predicts ``y_(i + 1)``; the last has none in the row (weight
+0). The loss is ``L_main + mtp_weight L_mtp``; accuracy stays the main
+head's; both cross entropies ride the counters.
 
 **Layers run several times** (``loop_steps = T > 1``; Ouro,
 arXiv:2510.25741; docs/LOOPED.md). The kept layers and the final norm are
@@ -262,7 +278,8 @@ class GroupedQueryAttention(nn.Module):
 class SparseExperts(nn.Module):
     """The held experts of a top-k layer, the router over all of them and,
     where the model has one, the shared expert every token visits (whole on
-    every holder). ``router="sigmoid"`` reads a bias that chooses and does
+    every holder; ``act``'s body at its own width: ``moe.shared_expert``
+    states both). ``router="sigmoid"`` reads a bias that chooses and does
     not weigh, ``e_score_correction_bias``: a non-trainable leaf (in
     ``batch_stats``), zero at initialisation, which a restored state brings
     with it and nothing here updates (the rate of the balancing rule that
@@ -313,12 +330,13 @@ class SparseExperts(nn.Module):
             params, cast(), top_k=self.top_k, first_expert=self.first_expert,
             router_input=flat, rule=self.router, scale=self.routed_scaling)
         if self.shared_width:
-            y = y + shared_expert(
-                {"up": self.param("shared_up", _init,
-                                  (d, self.shared_width), jnp.float32),
-                 "down": self.param("shared_down", _init,
-                                    (self.shared_width, d), jnp.float32)},
-                cast())
+            def whole(name, *shape):
+                return self.param(f"shared_{name}", _init, shape, jnp.float32)
+            shared = {"gate": whole("gate", d, self.shared_width)
+                      } if self.act == "swiglu" else {}
+            shared["up"] = whole("up", d, self.shared_width)
+            shared["down"] = whole("down", self.shared_width, d)
+            y = y + shared_expert(shared, cast())
         return y.reshape(b, t, d), counters
 
 
@@ -414,22 +432,36 @@ class Mamba2Mixer(nn.Module):
 
 
 class DecoderLayer(nn.Module):
+    """``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``: ``attn``
+    the fields of ``GroupedQueryAttention`` or of ``LatentAttention`` (told
+    apart by ``kv_rank``), ``FFN`` the experts, or with ``dense_width`` a
+    dense SwiGLU of that width (a model's leading dense layers)."""
     attn: dict
     experts: dict
     eps: float
     dtype: Any = None
+    dense_width: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array):
         dt = self.dtype or x.dtype
         with jax.named_scope(scopes.BLOCK_NORM):
             y = RMSNorm(self.eps, name="input_norm")(x).astype(dt)
-        y = GroupedQueryAttention(**self.attn, eps=self.eps, dtype=dt,
-                                  name="self_attention")(y)
+        attention_of = (LatentAttention if "kv_rank" in self.attn
+                        else GroupedQueryAttention)
+        y = attention_of(**self.attn, eps=self.eps, dtype=dt,
+                         name="self_attention")(y)
         with jax.named_scope(scopes.BLOCK_NORM):
             x = x + y
             y = RMSNorm(self.eps, name="post_norm")(x)
-        y, counters = SparseExperts(**self.experts, dtype=dt, name="moe")(y)
+            if self.dense_width:
+                y = y.astype(dt)
+        if self.dense_width:
+            y, counters = DenseMLP(self.dense_width, dtype=dt,
+                                   name="mlp")(y), {}
+        else:
+            y, counters = SparseExperts(**self.experts, dtype=dt,
+                                        name="moe")(y)
         with jax.named_scope(scopes.BLOCK_NORM):
             return x + y, counters
 
@@ -542,6 +574,15 @@ class MoEDecoder(nn.Module):
     #                                      above 1 a head and an exit gate
     #                                      read every pass
     exit_beta: float = 0.0               # on the exit distribution's entropy
+    latent: Any = None                   # latent attention's published sizes
+    #                                      (``LatentAttention``'s fields) in
+    #                                      grouped-query attention's place
+    leading_dense: tuple = (0, 0)        # (layers, width): the first pairs'
+    #                                      second half is a dense SwiGLU
+    mtp_depth: int = 0                   # multi-token-prediction modules
+    #                                      behind the kept layers (0 | 1):
+    #                                      ``MTPModule``
+    mtp_weight: float = 0.0              # lambda on the second head's loss
     # this holder's share of a deployment
     layers: int = 0                      # leading layers kept (0: all)
     expert_share: tuple = (0, 1)         # (i, n): the i-th of n holders
@@ -580,6 +621,8 @@ class MoEDecoder(nn.Module):
                 if kind in PAIR_KINDS + ("attention",)]
         shape = dict(heads=self.num_heads, kv_heads=self.num_kv_heads,
                      head_dim=self.head_dim)
+        if self.mtp_depth:
+            kept += ["full_attention"]   # the module's block has one too
         if self.objective == "block_diffusion":
             # one mask whatever the layer's type: the doubled row's
             return [dict(shape, seq=2 * seq_len, causal=False, window=None,
@@ -625,9 +668,10 @@ class MoEDecoder(nn.Module):
         elif self.objective != "next_id" or noised is not None:
             raise ValueError(f"objective {self.objective!r} (next_id | "
                              f"block_diffusion; noised: {noised is not None})")
+        embed = nn.Embed(self.vocab_held, self.hidden_size,
+                         embedding_init=_init, dtype=dt, name="embed")
         with jax.named_scope(scopes.LM_EMBED):
-            x = nn.Embed(self.vocab_held, self.hidden_size,
-                         embedding_init=_init, dtype=dt, name="embed")(tokens)
+            x = embed(tokens)
         experts = dict(num_experts=self.num_experts,
                        top_k=self.experts_per_token, width=self.expert_width,
                        first_expert=first_expert, held=held,
@@ -648,22 +692,16 @@ class MoEDecoder(nn.Module):
                     layer, fields = DenseLayer, dict(attn=of_kind,
                                                      width=self.dense_width)
                 elif kind in PAIR_KINDS:
-                    layer, fields = DecoderLayer, dict(attn=of_kind,
-                                                       experts=experts)
+                    layer, fields = DecoderLayer, dict(
+                        attn=of_kind, experts=experts)
+                    if i < self.leading_dense[0]:
+                        fields["dense_width"] = self.leading_dense[1]
                 else:
                     layer, fields = MixerBlock, dict(kind=kind, mixer={
                         "mamba": self.mamba, "moe": experts,
                         "attention": of_kind}[kind])
                 if self.remat:
-                    # everything of a layer is made again in the backward
-                    # pass but the attention kernel's two results (0.4 GB a
-                    # layer at two sequences of 8,192): its forward runs once
-                    from tpudist.ops.pallas.flash_attention import (
-                        SAVED_BY_NAME)
-                    layer = nn.remat(
-                        layer,
-                        policy=jax.checkpoint_policies.save_only_these_names(
-                            *SAVED_BY_NAME))
+                    layer = _rematerialised(layer)
                 x, layer_counters = layer(
                     **fields, eps=self.rms_norm_eps, dtype=dt,
                     name=f"layer_{i}")(x)
@@ -676,6 +714,26 @@ class MoEDecoder(nn.Module):
         counters.update(layer_counters)
         if noised is not None:
             x = x[:, :length]            # the head reads the noised half
+        second = None
+        if self.mtp_depth and (targets is not None
+                               or self.is_initializing()):
+            # the module reads the NEXT id's embedding (the targets; at
+            # initialisation, which hands none, any ids: shapes only) and
+            # the kept layers' result before the final norm
+            if self.mtp_depth != 1 or self.objective != "next_id":
+                raise ValueError(
+                    f"mtp_depth {self.mtp_depth} under {self.objective!r}: "
+                    f"one module, behind a model trained on the next id")
+            with jax.named_scope(scopes.MTP_MODULE):
+                with jax.named_scope(scopes.LM_EMBED):
+                    ahead = embed(tokens if targets is None else targets)
+                second, mtp_counters = MTPModule(
+                    block=dict(attn=self._attention_of("full_attention",
+                                                       None),
+                               experts=experts),
+                    eps=self.rms_norm_eps, remat=self.remat, dtype=dt,
+                    name="mtp")(x, ahead)
+            counters.update({f"{k}.mtp": v for k, v in mtp_counters.items()})
         with jax.named_scope(scopes.BLOCK_NORM):
             x = RMSNorm(self.rms_norm_eps, name="norm")(x).astype(dt)
         head = self.param("head", _init,
@@ -690,6 +748,23 @@ class MoEDecoder(nn.Module):
             targets = tokens[:, length:]
         loss, acc1 = lm_head_loss(x, head, targets, self.loss_chunk,
                                   weights=weights)
+        if second is not None:
+            # position i of the module predicts the id after the next,
+            # y_(i + 1): the targets shifted once more; the last position
+            # has none in the row (weight 0), so the mean is over T - 1
+            rows, t = targets.shape
+            with jax.named_scope(scopes.MTP_MODULE):
+                with jax.named_scope(scopes.LOSS):
+                    after_next = jnp.roll(targets, -1, axis=1)
+                    seen = jnp.broadcast_to(
+                        (jnp.arange(t) < t - 1).astype(jnp.float32),
+                        (rows, t))
+                mtp_loss, _ = lm_head_loss(
+                    second, head, after_next, self.loss_chunk, weights=seen,
+                    normaliser=rows * (t - 1))
+            counters[scopes.MTP_LOSS] = mtp_loss
+            counters[scopes.LM_LOSS_MAIN] = loss
+            loss = loss + self.mtp_weight * mtp_loss
         return Scored(loss, acc1, counters)
 
     # (below ``__call__``: the lines above are in every compiled step's
@@ -718,7 +793,16 @@ class MoEDecoder(nn.Module):
                              (inner, gn, gn), m["conv"], inner)
 
     def _attention_of(self, kind: str, mask: Optional[tuple]) -> dict:
-        """``GroupedQueryAttention``'s fields in a layer of ``kind``."""
+        """The attention module's fields in a layer of ``kind``:
+        ``LatentAttention``'s where the model states ``latent``, else
+        ``GroupedQueryAttention``'s."""
+        if self.latent:
+            if mask is not None or kind == "sliding_attention":
+                raise ValueError("latent attention is causal over the whole "
+                                 "row: no window, no diffusion over blocks")
+            return dict(
+                num_heads=self.num_heads, **self.latent, flash=self.flash,
+                rope_parameters=dict(self.rope_parameters or {}).get(kind))
         return dict(
             num_heads=self.num_heads, num_kv_heads=self.num_kv_heads,
             head_dim=self.head_dim, qk_norm=self.qk_norm,
@@ -733,6 +817,9 @@ class MoEDecoder(nn.Module):
         which program runs, read from the shape), one a layer type kept
         that has attention, those that differ."""
         t, mask = seq_len, None
+        if self.latent:
+            # rotates a part of a head, in jax.numpy: no pass of its own
+            return []
         if self.objective == "block_diffusion":
             t, mask = 2 * seq_len, (seq_len, self.block_length)
         plans = []
@@ -745,6 +832,122 @@ class MoEDecoder(nn.Module):
                 if plan not in plans:
                     plans.append(plan)
         return plans
+
+
+def _rematerialised(layer):
+    """``layer`` made again in the backward pass, everything of it but the
+    attention kernel's two results (0.4 GB a layer at two sequences of
+    8,192): its forward runs once."""
+    from tpudist.ops.pallas.flash_attention import SAVED_BY_NAME
+    return nn.remat(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(
+            *SAVED_BY_NAME))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434) in its
+    TRAINING form, a head of its own keys: two low-rank paths with an
+    RMSNorm inside each, ``c_q = RMSNorm(u W_qa)`` -> ``q = c_q W_qb``,
+    heads of ``[q_nope | q_rope]``; ``[c_kv | k_r] = u W_kva``, ``c_kv =
+    RMSNorm(c_kv)`` -> ``c_kv W_kvb``, heads of ``[k_nope | v]``. ``q_rope``
+    (a head) and ``k_r`` (ONE head that all share) are rotated by
+    neighbouring pairs (``rope.apply_pairs``); scores ``(q_nope k_nope^T +
+    q_rope k_r^T) / sqrt(nope_dim + rope_dim)`` under the causal mask,
+    float32 softmax, ``o = P v``, ``o W_o``. No bias. (The absorbed form,
+    one latent head of ``kv_rank + rope_dim`` for all, is serving's: 3.4 x
+    the products a pair.) With ``flash`` the kernels of
+    ``ops/pallas/mla_attention.py``, which take the five operands apart;
+    else the XLA ``attention`` over keys laid whole."""
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_parameters: Any
+    eps: float = 1e-6
+    dtype: Any = None
+    flash: bool = False
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        b, t, d = x.shape
+        dt = self.dtype or x.dtype
+        h, dn, dr, dv = (self.num_heads, self.nope_dim, self.rope_dim,
+                         self.v_dim)
+
+        def proj(features, name):
+            return nn.Dense(features, use_bias=False, dtype=dt,
+                            kernel_init=_init, name=name)
+        with jax.named_scope(scopes.ATTN_MIXER):
+            with jax.named_scope(scopes.ATTN_QKV_PROJ), jax.named_scope(
+                    scopes.MLA_DOWN):
+                c_q = proj(self.q_rank, "q_a_proj")(x)
+                c_kv = proj(self.kv_rank + dr, "kv_a_proj")(x)
+                c_kv, k_r = c_kv[..., :self.kv_rank], c_kv[..., self.kv_rank:]
+            with jax.named_scope(scopes.ATTN_QK_NORM_ROPE), jax.named_scope(
+                    scopes.MLA_LATENT_NORM):
+                c_q = RMSNorm(self.eps, name="q_a_norm")(c_q).astype(dt)
+                c_kv = RMSNorm(self.eps, name="kv_a_norm")(c_kv).astype(dt)
+            with jax.named_scope(scopes.ATTN_QKV_PROJ), jax.named_scope(
+                    scopes.MLA_UP):
+                q = proj(h * (dn + dr), "q_b_proj")(c_q).reshape(
+                    b, t, h, dn + dr)
+                kv = proj(h * (dn + dv), "kv_b_proj")(c_kv).reshape(
+                    b, t, h, dn + dv)
+                q_nope, q_rope = q[..., :dn], q[..., dn:]
+                k_nope, v = kv[..., :dn], kv[..., dn:]
+            # (initialisation runs eagerly on a short example row: the XLA
+            # path, and no kernel is built for that length)
+            flash = self.flash and not self.is_initializing()
+            with jax.named_scope(scopes.ATTN_QK_NORM_ROPE):
+                cos, sin = rope.tables(dict(self.rope_parameters), dr, t)
+                q_rope = rope.apply_pairs(q_rope, cos, sin)
+                k_r = rope.apply_pairs(k_r[:, :, None], cos, sin)
+                if not flash:
+                    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+                    k = jnp.concatenate(
+                        [k_nope, jnp.broadcast_to(k_r, q_rope.shape)],
+                        axis=-1)
+            if flash:
+                from tpudist.ops.pallas import flash_attention_latent
+                with jax.named_scope(scopes.ATTN_FUSED):
+                    out = flash_attention_latent(q_nope, q_rope, k_nope,
+                                                 k_r[:, :, 0], v)
+            else:
+                out = attention(q, k, v, causal=True)
+            with jax.named_scope(scopes.ATTN_OUT_PROJ):
+                return proj(d, "o_proj")(out.reshape(b, t, h * dv))
+
+
+class MTPModule(nn.Module):
+    """A multi-token-prediction module of depth 1 (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2): ``m = [RMSNorm_e(ahead) ; RMSNorm_h(
+    hidden)] W_eh``, ``h' = Block(m)``, returns ``RMSNorm_s(h')`` for the
+    caller's head (the model's own, and ``ahead`` rows of its own
+    embedding: shared leaves) and the block's counters. ``hidden`` is the
+    kept layers' result before the final norm, ``ahead`` the embedding of
+    each position's NEXT id; ``block`` a ``DecoderLayer``'s fields (its own
+    leaves, router, bias and held experts; positions 0 .. T - 1)."""
+    block: dict
+    eps: float
+    remat: bool = False
+    dtype: Any = None
+
+    @nn.compact
+    def __call__(self, hidden: jax.Array, ahead: jax.Array):
+        dt = self.dtype or hidden.dtype
+        with jax.named_scope(scopes.MTP_MERGE):
+            merged = jnp.concatenate(
+                [RMSNorm(self.eps, name="enorm")(ahead).astype(dt),
+                 RMSNorm(self.eps, name="hnorm")(hidden).astype(dt)], axis=-1)
+            m = nn.Dense(hidden.shape[-1], use_bias=False, dtype=dt,
+                         kernel_init=_init, name="eh_proj")(merged)
+        layer = _rematerialised(DecoderLayer) if self.remat else DecoderLayer
+        x, counters = layer(**self.block, eps=self.eps, dtype=dt,
+                            name="block")(m)
+        with jax.named_scope(scopes.BLOCK_NORM):
+            return RMSNorm(self.eps, name="norm")(x).astype(dt), counters
 
 
 # the least probability whose logarithm the exit entropy takes: a gate that
@@ -979,3 +1182,50 @@ def ouro_tiny(dtype: Any = None, **kw) -> MoEDecoder:
         rope_parameters=ouro_2_6b().rope_parameters, sliding_window=0,
         rms_norm_eps=1e-6, qk_norm=False, dense_width=96, loop_steps=4,
         exit_beta=0.1, dtype=dtype, **_own(kw))
+
+
+def joyai_llm_flash(dtype: Any = None, **kw) -> MoEDecoder:
+    """JoyAI-LLM-Flash (JD; ``config.json`` of
+    huggingface.co/jdopensource/JoyAI-LLM-Flash, ``model_type``
+    ``joyai_llm_flash``, 48B-A2.7B; the DeepSeek-V3 family's code): 40
+    layers of hidden 2,048; latent attention, ``q_lora_rank`` 1,536,
+    ``kv_lora_rank`` 512, 32 heads of 128 + 64 rotated columns (pairs
+    interleaved, theta 32e6, no scaling) against values of 128; one leading
+    dense layer (SwiGLU 7,168), then 256 routed experts of width 768 with 8
+    a token (sigmoid scores, a correction bias that chooses, weights
+    normalised over the chosen, factor 2.5) beside one shared expert;
+    ``num_nextn_predict_layers`` 1: a multi-token-prediction module that
+    shares the embedding and the head; vocabulary 129,280, untied.
+    ``mtp_weight`` 0.3 is DeepSeek-V3's first phase (no key of the
+    config)."""
+    return MoEDecoder(
+        vocab_size=129280, hidden_size=2048, num_layers=40, num_heads=32,
+        num_kv_heads=32, head_dim=192, num_experts=256, experts_per_token=8,
+        expert_width=768, layer_types=("full_attention",) * 40,
+        rope_parameters={"full_attention": {"rope_type": "default",
+                                            "rope_theta": 32000000}},
+        sliding_window=0, rms_norm_eps=1e-6, qk_norm=False, router="sigmoid",
+        routed_scaling=2.5, shared_width=768,
+        latent=dict(q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+                    v_dim=128),
+        leading_dense=(1, 7168), mtp_depth=1, mtp_weight=0.3, dtype=dtype,
+        **_own(kw))
+
+
+def joyai_tiny(dtype: Any = None, **kw) -> MoEDecoder:
+    """The CPU tests' twin of the model above at toy widths (hidden 64;
+    latent attention of ranks 48 and 32, 4 heads of 16 + 8 against values of
+    16; one dense layer of width 96, then two of 16 experts of width 32 with
+    2 a token beside a shared one; the module; 256 ids): never a benchmark
+    configuration."""
+    kw.setdefault("loss_chunk", 64)
+    return MoEDecoder(
+        vocab_size=256, hidden_size=64, num_layers=3, num_heads=4,
+        num_kv_heads=4, head_dim=24, num_experts=16, experts_per_token=2,
+        expert_width=32, layer_types=("full_attention",) * 3,
+        rope_parameters=joyai_llm_flash().rope_parameters, sliding_window=0,
+        rms_norm_eps=1e-6, qk_norm=False, router="sigmoid",
+        routed_scaling=2.5, shared_width=32,
+        latent=dict(q_rank=48, kv_rank=32, nope_dim=16, rope_dim=8, v_dim=16),
+        leading_dense=(1, 96), mtp_depth=1, mtp_weight=0.3, dtype=dtype,
+        **_own(kw))

@@ -43,6 +43,12 @@ ATTN_MIXER = "attn_mixer"
 ATTN_QKV_PROJ = "attn_qkv_proj"        # q, k, v products, the cut into heads
 ATTN_QK_NORM_ROPE = "attn_qk_norm_rope"  # q / k RMSNorm, the tables, RoPE
 ATTN_OUT_PROJ = "attn_out_proj"
+# latent attention (models/decoder.py::LatentAttention): what it adds around
+# the kernels, inner scopes of the two parts above (`mla_down` and `mla_up`
+# within `attn_qkv_proj`, `mla_latent_norm` within `attn_qk_norm_rope`)
+MLA_DOWN = "mla_down"                  # hidden -> the two latents (+ k_rope)
+MLA_LATENT_NORM = "mla_latent_norm"    # RMSNorm of each latent, the cast
+MLA_UP = "mla_up"                      # latents -> heads (q; k_nope | v)
 # a decoder block's own norms (and the decoder's last) with the cast behind
 # each, and the residual sums
 BLOCK_NORM = "block_norm"
@@ -82,6 +88,14 @@ LOOP_EXIT = "loop_exit"
 # the loop's own work: a pass's saved results stacked and taken back in the
 # backward pass, the tied leaves' gradients summed over the passes
 LOOP_CARRY = "loop_carry"
+# a multi-token-prediction module (models/decoder.py::MTPModule), inside the
+# forward scope: every operation of the module, its block, its pass through
+# the head and its loss lies within `mtp_module` (under its own part as
+# well, as a pass of the loop above does); `mtp_merge` is its one leaf of
+# its own: the two norms, the join and the product that merges the next
+# id's embedding with the trunk's last hidden state
+MTP_MODULE = "mtp_module"
+MTP_MERGE = "mtp_merge"
 
 # -- counters a model hands the step ----------------------------------------
 # One scalar a layer and a step (`<name>.layer_<l>`), through the step's
@@ -101,9 +115,13 @@ BD_WEIGHT = "bd_weight_sum"            # sum of masked / t over rows x L
 LOOP_EXPECTED_EXIT = "loop_expected_exit"  # mean over positions of
 #                                        sum_t t p_t, in passes
 LOOP_EXIT_ENTROPY = "loop_exit_entropy"    # mean over positions of H(p), nats
+# one scalar a step (no layer): a model with a multi-token-prediction module
+MTP_LOSS = "mtp_loss"                  # the second head's cross entropy (the
+#                                        id after the next), unweighted
+LM_LOSS_MAIN = "lm_loss_main"          # the next id's, beside it
 MODEL_COUNTERS = (MOE_PAIRS, MOE_LOAD, MOE_WALKED, SSM_DT, SSM_CARRY,
                   BD_MASKED, BD_WEIGHT, LOOP_EXPECTED_EXIT,
-                  LOOP_EXIT_ENTROPY)
+                  LOOP_EXIT_ENTROPY, MTP_LOSS, LM_LOSS_MAIN)
 
 DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
                  EVAL_FORWARD, SERVE_FORWARD)
@@ -111,14 +129,16 @@ DEVICE_SCOPES = (FORWARD, LOSS, GRAD_REDUCE, OPTIMIZER, METRICS,
 # every leaf part a train step's device time may lie under: what a named
 # operation of a decoder's step is under none of is unitemised
 # (`step_unitemised_ms` of the chip benchmark holds its own copy of the first
-# 22; `loop_unitemised_ms` adds the three behind them). A part is a leaf but
-# the last, the passes' loop, which every other part of a pass lies within:
-# a reader that sums parts hands an operation to the first it is under
+# 22; `loop_unitemised_ms` adds the three behind them, `mtp_unitemised_ms`
+# the one behind those). A part is a leaf but the passes' loop, which every
+# other part of a pass lies within: a reader that sums parts hands an
+# operation to the first it is under
 STEP_PARTS = (MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, MOE_SHARED,
               SSM_IN_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE_NORM, SSM_OUT_PROJ,
               ATTN_QKV_PROJ, ATTN_QK_NORM_ROPE, ATTN_FUSED, ATTN_OUT_PROJ,
               BLOCK_NORM, LM_EMBED, LM_HEAD, LOSS, BD_NOISE, GRAD_REDUCE,
-              OPTIMIZER, METRICS, DENSE_MLP, LOOP_EXIT, LOOP_CARRY)
+              OPTIMIZER, METRICS, DENSE_MLP, LOOP_EXIT, LOOP_CARRY,
+              MTP_MERGE)
 
 # -- host spans of a loop turn ----------------------------------------------
 STEP = "train"                          # StepTraceAnnotation: h2d + dispatch

@@ -152,7 +152,11 @@ _LANES = 128
 #   rev 7: under that mask a tile that an edge leaves mostly empty runs the
 #          part the mask reaches: the noised diagonal as squares of 128,
 #          the near half of a clean prefix's last tile.
-KERNEL_REV = 7
+#   rev 8: an entry for queries and keys wider than the values and a rotated
+#          key that all heads share (latent attention's training form:
+#          ``mla_attention.py``, which uses this file's band and blocks);
+#          the entries above run as they did.
+KERNEL_REV = 8
 
 # the streaming forward's results, as ``jax.ad_checkpoint`` names them
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")

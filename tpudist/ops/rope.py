@@ -8,6 +8,12 @@ between the dimensions that ``beta_fast`` and ``beta_slow`` rotations at
 ``original_max_position_embeddings`` give; cos and sin times
 ``attention_factor``). Host-side numpy: a table is a constant of the
 compiled step.
+
+A model may rotate a PART of a head (latent attention: 64 of a key's 192
+columns): the tables are then of that part's width (``tables(params, 64,
+...)``) and the caller hands ``apply`` that part alone. A model that
+publishes ``rope_interleave`` pairs neighbours, ``(x_2i, x_2i+1)``, where
+rotate-half pairs ``(x_i, x_{i + d/2})``: ``apply_pairs``.
 """
 
 from __future__ import annotations
@@ -76,3 +82,13 @@ def apply(x: jax.Array, cos, sin) -> jax.Array:
     rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
     return (x32 * cos[None, :, None, :]
             + rotated * sin[None, :, None, :]).astype(x.dtype)
+
+
+def apply_pairs(x: jax.Array, cos, sin) -> jax.Array:
+    """Rotate neighbouring pairs ``(x_2i, x_2i+1)`` of ``x`` [B, T, H, D] by
+    ``pos * inv_freq_i`` (the same tables). The rotated columns leave in the
+    order ``[evens | odds]``, which is rotate-half's: a score is a sum over
+    columns, so the order is free as long as q and k share it, and they
+    do (both come through here)."""
+    return apply(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
+                 cos, sin)

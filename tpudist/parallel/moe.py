@@ -405,17 +405,23 @@ _relu2.defvjp(_relu2_fwd, _relu2_bwd)
 
 
 def shared_expert(params: dict, u: jax.Array) -> jax.Array:
-    """The dense expert every token visits, ``relu(u up)^2 down`` (``up``
-    [d, f], ``down`` [f, d]; products in ``u``'s dtype, the activation in
-    float32): whole on every holder of a layer, so counted once where the
-    holders' parts are summed."""
+    """The dense expert every token visits: whole on every holder of a
+    layer, so counted once where the holders' parts are summed. Its body is
+    the routed experts' (here is where it is chosen, from the leaves): with
+    a ``gate`` a SwiGLU, ``(silu(u gate) * (u up)) down``; without, ungated
+    ``relu(u up)^2 down`` (``gate``, ``up`` [d, f], ``down`` [f, d]; products
+    in ``u``'s dtype, the activation in float32)."""
     with jax.named_scope(scopes.MOE_SHARED):
         dt = u.dtype
-        h = jnp.dot(u, params["up"].astype(dt),
-                    preferred_element_type=jnp.float32)
-        h = jnp.square(jax.nn.relu(h)).astype(dt)
-        return jnp.dot(h, params["down"].astype(dt),
-                       preferred_element_type=jnp.float32).astype(dt)
+
+        def product(x, name):
+            return jnp.dot(x, params[name].astype(dt),
+                           preferred_element_type=jnp.float32)
+        if "gate" in params:
+            h = (jax.nn.silu(product(u, "gate")) * product(u, "up"))
+        else:
+            h = jnp.square(jax.nn.relu(product(u, "up")))
+        return product(h.astype(dt), "down").astype(dt)
 
 
 @jax.custom_vjp

@@ -159,7 +159,7 @@ NO_TP_FAMILIES = (
     "inception", "efficientnet", "regnet", "maxvit",
     # the decoder of tokens divides a layer by expert and vocabulary share
     # (models/decoder.py), not by a 'model' axis: no rule table yet
-    "mellum2", "sdar", "nemotron3", "ouro",
+    "mellum2", "sdar", "nemotron3", "ouro", "joyai",
 )
 
 

@@ -506,8 +506,8 @@ class Trainer:
                            vocab_share=(0, 1)):
             raise ValueError(
                 f"--layers / --expert-share / --vocab-share state a holder's "
-                f"share of a model of tokens (the mellum2, sdar, nemotron3 "
-                f"and ouro families); '{cfg.arch}' is none")
+                f"share of a model of tokens (the mellum2, sdar, nemotron3, "
+                f"ouro and joyai families); '{cfg.arch}' is none")
         # Measurement-honest attention dispatch (VERDICT r5 weak #2):
         # resolve --flash OUTSIDE any trace. `auto` micro-benchmarks
         # flash-vs-XLA on the attached chip at the exact workload shape
