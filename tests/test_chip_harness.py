@@ -18,7 +18,10 @@ looped rooflines' counts a layer AND a pass, the dense feed-forward's, the
 exit's and the loop's scopes, the exit counter) and of
 `selftest/test_joyai_cpu.py` (the latent-attention cell's files and lists,
 `attn_mla_roofline`'s counts at the published shape, the new scopes' readers
-on a recorded scope table, the two losses' counter).
+on a recorded scope table, the two losses' counter) and of
+`selftest/test_mla_qk_rope_roofline_cpu.py` (the latent block's norms' and
+rotation's counts at the cell's shape against a hand count, their share on
+hand-made scopes).
 The rest of `benchmarks/chip/selftest/` builds trainers for minutes and
 stays run by path. Below them: what the configurations' `trainer_argv` pins
 against the program's defaults."""
@@ -92,13 +95,51 @@ _spec.loader.exec_module(_joyai)
 globals().update({test.__name__: test for test in _joyai.TIER1})
 
 
+_spec = importlib.util.spec_from_file_location(
+    "chipbench_test_mla_qk_rope_roofline_cpu", os.path.join(
+        os.path.dirname(_PATH), "test_mla_qk_rope_roofline_cpu.py"))
+_mla_roofline = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_mla_roofline)
+globals().update({test.__name__: test for test in _mla_roofline.TIER1})
+
+
+def _before_pr_45(bench):
+    """`BENCHMARK.json` without the one per-layer entry PR 45 appended,
+    `mla_qk_rope_roofline` (`test_mla_qk_rope_roofline_reader` holds its
+    fields and its place, last)."""
+    assert bench["per_layer"][-1]["name"] == "mla_qk_rope_roofline"
+    assert bench["per_layer"][-1]["workloads"] == [_joyai.CELL]
+    del bench["per_layer"][-1]
+    return bench
+
+
+def test_the_latent_cells_files_parse_and_its_metrics_are_listed(
+        monkeypatch):
+    """`selftest/test_joyai_cpu.py`'s case of the same name, which counts
+    the per-layer metrics that list the latent cell (29) and holds PR 44's
+    five to the end of the list: since PR 45 `mla_qk_rope_roofline` lists
+    the cell and lies behind them (new entries go last). That file is the
+    benchmark's and is not edited by a `perf_opt` PR (run by path those two
+    lines fail: PERF.md section 7); here the case runs whole on the list
+    without the entry, which `_before_pr_45` holds to the end of the
+    list."""
+    bench = _before_pr_45(_joyai.load(_joyai.ROOT, "BENCHMARK.json"))
+    load = _joyai.load
+    monkeypatch.setattr(
+        _joyai, "load",
+        lambda *path: bench if path[-1] == "BENCHMARK.json" else load(*path))
+    _joyai.TIER1[0]()
+
+
 def _before_pr_44(bench):
-    """`BENCHMARK.json` without what PR 44 appended: its configuration, its
-    cell (last of both lists), its five per-layer entries (last five) and
-    its cell's name at the end of the lists it joined
+    """`BENCHMARK.json` without what PR 45 (above) and PR 44 appended: the
+    latter's configuration, its cell (last of both lists), its five
+    per-layer entries (last five) and its cell's name at the end of the
+    lists it joined
     (`selftest/test_joyai_cpu.py` holds all of that to the file). The older
     cells' own cases, which count entries and hold theirs to the end of a
     list, run on this."""
+    bench = _before_pr_45(bench)
     assert bench["workloads"][-1]["name"] == _joyai.CELL
     assert bench["configs"][-1]["name"] == "joyai_flash_ep16"
     assert [m["name"] for m in bench["per_layer"][-5:]] == list(_joyai.NEW)
@@ -230,12 +271,13 @@ reader, said, scopes_of = (_nemotron3.reader, _nemotron3.said,
 def test_the_tracing_entries_are_listed(name):
     """`BENCHMARK.json` lists each of the seven once, behind what PR 39
     had (PR 41's `attn_qk_rope_roofline` follows them, then PR 42's seven,
-    PR 43's two and PR 44's five), with the cells where its reader finds
-    something to read."""
+    PR 43's two, PR 44's five and PR 45's one), with the cells where its
+    reader finds something to read."""
     bench = _nemotron3.load(_nemotron3.ROOT, "BENCHMARK.json")
-    assert [m["name"] for m in bench["per_layer"][-22:]] == list(
+    assert [m["name"] for m in bench["per_layer"][-23:]] == list(
         _TRACING) + ["attn_qk_rope_roofline"] + list(_ouro.NEW) + [
-            "ssm_conv_ms", "ssm_conv_roofline"] + list(_joyai.NEW)
+            "ssm_conv_ms", "ssm_conv_roofline"] + list(_joyai.NEW) + [
+                "mla_qk_rope_roofline"]
     entry, = [m for m in bench["per_layer"] if m["name"] == name]
     unit, source, layer, workloads = _TRACING[name]
     assert entry == {"name": name, "unit": unit, "better": "lower",

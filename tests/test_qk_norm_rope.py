@@ -291,6 +291,14 @@ def test_models_state_their_plans_and_the_trainer_announces_them(tmp_path):
     hybrid = create_model("nemotron3_nano_30b_a3b", layers=9,
                           flash=True).qk_plans(2, 8192)
     tiny = create_model("mellum2_tiny", flash=True).qk_plans(16, 32)
+    # latent attention: one plan for the five layers and the MTP module's
+    # block (`latent_rope.latent_plan`), at the cell's shape and at a length
+    # a pass would pad
+    latent = create_model("joyai_llm_flash", layers=5, flash=True)
+    joyai, padded = latent.qk_plans(2, 8192), latent.qk_plans(2, 8704)
+    assert joyai == [dict(kernel="pallas", rows_per_program=512,
+                          programs=128)]
+    assert [p["kernel"] for p in padded] == ["jax.numpy"]
     assert mellum2 == [dict(kernel="pallas", rows_per_program=512,
                             programs=128)]
     assert sdar == [dict(kernel="pallas", rows_per_program=512,
@@ -301,17 +309,21 @@ def test_models_state_their_plans_and_the_trainer_announces_them(tmp_path):
     lines = []
     sink = telemetry.Telemetry(str(tmp_path), heartbeat=False)
     fake = types.SimpleNamespace(log=lines.append, telemetry=sink)
-    Trainer._announce_qk_plans(fake, mellum2 + hybrid + tiny)
+    Trainer._announce_qk_plans(fake, mellum2 + hybrid + tiny + joyai
+                               + padded)
     sink.close()
     assert lines == [
         "=> attn q/k: pallas (rows_per_program 512, programs 128 a layer)",
         "=> attn q/k: jax.numpy (the layer neither norms nor rotates q and "
         "k)",
         "=> attn q/k: jax.numpy (a head of 16 is no whole number of lane "
-        "tiles)"]
+        "tiles)",
+        "=> attn q/k: pallas (rows_per_program 512, programs 128 a layer)",
+        "=> attn q/k: jax.numpy (a row of 8704 positions is padded to 9216 "
+        "(blocks of 1024 x 1024))"]
     with open(telemetry.events_path(str(tmp_path), 0)) as f:
         events = [e for e in map(json.loads, f) if e["type"] == "attn_qk"]
-    assert [e["kernel"] for e in events] == ["pallas", "jax.numpy",
-                                             "jax.numpy"]
+    assert [e["kernel"] for e in events] == [
+        "pallas", "jax.numpy", "jax.numpy", "pallas", "jax.numpy"]
     assert set(telemetry.SCHEMA["attn_qk"]) <= set(events[0])
     assert "reason" in events[1] and "reason" not in events[0]

@@ -73,6 +73,10 @@ CAUSAL_CONV_SHAPES = ((2, 8192, 10304, (4096, 1024, 1024), 4, 4096),)
 # rotated columns, the values' columns): queries and keys of 192 against
 # values of 128, the rotated key one head for all
 FLASH_LATENT_SHAPES = ((2, 8192, 32, 128, 64, 128),)
+# the same with the operands where the projections and the rotation's pass
+# wrote them, and that pass (.., the latent rank behind which kv_a_proj wrote
+# the rotated key): the latent cell's shape
+LATENT_LAID_SHAPES = ((2, 8192, 32, 128, 64, 128, 512),)
 FLASH_QKV_SHAPES = ((128, 197, 12, 64, False), (256, 197, 6, 64, False),
                     (64, 256, 12, 64, False), (64, 256, 12, 64, True),
                     (32, 577, 12, 64, False), (32, 577, 12, 64, True),
@@ -191,6 +195,31 @@ def _flash_latent_fn(bwd: bool):
     return jax.grad(f, argnums=(0, 1, 2, 3, 4)) if bwd else f
 
 
+def _flash_latent_laid_fn(bwd: bool):
+    from tpudist.ops.pallas import flash_attention_latent_laid
+
+    def f(*operands):
+        return flash_attention_latent_laid(
+            *operands, interpret=False).astype(jnp.float32).sum()
+
+    return jax.grad(f, argnums=(0, 1, 2, 3)) if bwd else f
+
+
+def _latent_rope_fn(bwd: bool, t: int, heads: int, dr: int, kv_rank: int):
+    from tpudist.ops import rope
+    from tpudist.ops.pallas.latent_rope import latent_rope
+    cos, sin = rope.tables({"rope_theta": 32000000.0}, dr, t)
+
+    def f(q, kva):
+        # (squares: a sum's cotangents are constants, and the jitted
+        # backward would then run where it is traced)
+        return sum(jnp.square(x.astype(jnp.float32)).sum()
+                   for x in latent_rope(q, kva, cos, sin, heads=heads,
+                                        kv_rank=kv_rank, interpret=False))
+
+    return jax.grad(f, argnums=(0, 1)) if bwd else f
+
+
 def _flash_qkv_fn(bwd: bool, causal: bool):
     from tpudist.ops.pallas.flash_attention import flash_attention_qkv
 
@@ -248,6 +277,11 @@ _KERNEL_CASES = (
                     id=f"flash_latent_t{shape[1]}_h{shape[2]}_"
                        f"{'fwdbwd' if bwd else 'fwd'}")
        for shape in FLASH_LATENT_SHAPES for bwd in (False, True)]
+    + [pytest.param((kind,) + shape, bwd,
+                    id=f"{kind}_t{shape[1]}_h{shape[2]}_"
+                       f"{'fwdbwd' if bwd else 'fwd'}")
+       for kind in ("flash_latent_laid", "latent_rope")
+       for shape in LATENT_LAID_SHAPES for bwd in (False, True)]
     + [pytest.param(("flash_qkv",) + shape, bwd,
                     id=f"flash_qkv_b{shape[0]}_t{shape[1]}_h{shape[2]}_"
                        f"{'causal_' if shape[4] else ''}"
@@ -272,6 +306,17 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
                 S((b, t, h, dn), jnp.bfloat16), S((b, t, dr), jnp.bfloat16),
                 S((b, t, h, dv), jnp.bfloat16)]
         fn = _flash_latent_fn(bwd)
+    elif case[0] == "flash_latent_laid":
+        b, t, h, dn, dr, dv, _ = case[1:]
+        args = [S((b, h, t, dn), jnp.bfloat16), S((b, h, t, dr), jnp.bfloat16),
+                S((b, t, h * (dn + dv)), jnp.bfloat16),
+                S((b, t, dr), jnp.bfloat16)]
+        fn = _flash_latent_laid_fn(bwd)
+    elif case[0] == "latent_rope":
+        b, t, h, dn, dr, dv, rank = case[1:]
+        args = [S((b, t, h * (dn + dr)), jnp.bfloat16),
+                S((b, t, rank + dr), jnp.bfloat16)]
+        fn = _latent_rope_fn(bwd, t, h, dr, rank)
     elif case[0] == "ssd_scan":
         b, t, h, p, g, n, chunk = case[1:]
         args = [S((b, t, h, p), jnp.bfloat16), S((b, t, h), jnp.float32),
@@ -325,6 +370,34 @@ def test_kernel_compiles_for_v5e(topo, monkeypatch, case, bwd):
         assert not re.search(rf"\[{b},(?:{t},{h}|{h},{t}),{dn + dr}\]", text)
         assert not re.search(rf"f32\[[\d,]*{t},1\]", text)
         assert f"f32[{b},{h},1,{t}]" in text
+        return
+    if case[0] == "flash_latent_laid":
+        # the same three kernels and the same claims; nothing is moved
+        # around them: k_nope and v are read and their cotangents written
+        # as one block of kv_b_proj's columns, o and dO by column block
+        import re
+        assert text.count("tpu_custom_call") == (3 if bwd else 1)
+        allowed = t * (t + 1) // 2
+        least = (3 if bwd else 1) * 2 * b * h * allowed * (dn + dr + dv)
+        assert least <= compiled.cost_analysis()["flops"] <= 1.15 * least
+        assert not re.search(r" transpose\(| pad\(| concatenate\(", text)
+        assert f"bf16[{b},{t},{h * dv}]" in text
+        if bwd:
+            assert f"bf16[{b},{t},{h * (dn + dv)}]" in text
+        return
+    if case[0] == "latent_rope":
+        # the forward and (a square's gradient reads the results) the
+        # backward. Nothing is transposed, sliced or joined around them: q
+        # arrives as q_b_proj wrote it and leaves as the attention kernels
+        # read it, the rotated key is read out of kv_a_proj's result. What
+        # a call claims to move is q and the rotated key twice, and the
+        # tables
+        import re
+        assert text.count("tpu_custom_call") == (2 if bwd else 1)
+        assert not re.search(r" transpose\(| concatenate\(| slice\(", text)
+        moved = 2 * b * t * (h * (dn + dr) + dr) * 2 + 8 * t * dr
+        assert text.count(f'"bytes_accessed":"{moved}"') == (
+            2 if bwd else 1)
         return
     if case[0] == "ssd_scan":
         # the forward alone; or the forward that keeps the entering states

@@ -813,13 +813,16 @@ class MoEDecoder(nn.Module):
 
     def qk_plans(self, rows: int, seq_len: int) -> list[dict]:
         """The plans of q's and k's norm and rotation in a training step of
-        ``rows`` rows of ``seq_len`` ids (``GroupedQueryAttention.qk_plan``:
-        which program runs, read from the shape), one a layer type kept
-        that has attention, those that differ."""
+        ``rows`` rows of ``seq_len`` ids (``GroupedQueryAttention.qk_plan``,
+        ``LatentAttention.latent_plan``: which program runs, read from the
+        shape), one a layer type kept that has attention, those that
+        differ."""
         t, mask = seq_len, None
         if self.latent:
-            # rotates a part of a head, in jax.numpy: no pass of its own
-            return []
+            # every block's (the layers' and the MTP module's) is the same
+            return [LatentAttention(
+                **self._attention_of("full_attention", None),
+                parent=None).latent_plan(rows, seq_len, self.flash)]
         if self.objective == "block_diffusion":
             t, mask = 2 * seq_len, (seq_len, self.block_length)
         plans = []
@@ -856,8 +859,13 @@ class LatentAttention(nn.Module):
     float32 softmax, ``o = P v``, ``o W_o``. No bias. (The absorbed form,
     one latent head of ``kv_rank + rope_dim`` for all, is serving's: 3.4 x
     the products a pair.) With ``flash`` the kernels of
-    ``ops/pallas/mla_attention.py``, which take the five operands apart;
-    else the XLA ``attention`` over keys laid whole."""
+    ``ops/pallas/mla_attention.py``, which take the operands apart: where
+    the shape allows (``latent_plan``) one Pallas pass rotates ``q_rope``
+    and ``k_r`` and writes q where the kernels read it
+    (``ops/pallas/latent_rope.py``), and ``k_nope``, ``v``, ``o`` and their
+    cotangents are addressed where ``kv_b_proj`` and ``o_proj`` write and
+    read them; at any other length the ``jax.numpy`` rotation and the entry
+    that pads. Else the XLA ``attention`` over keys laid whole."""
     num_heads: int
     q_rank: int
     kv_rank: int
@@ -883,25 +891,42 @@ class LatentAttention(nn.Module):
             with jax.named_scope(scopes.ATTN_QKV_PROJ), jax.named_scope(
                     scopes.MLA_DOWN):
                 c_q = proj(self.q_rank, "q_a_proj")(x)
-                c_kv = proj(self.kv_rank + dr, "kv_a_proj")(x)
-                c_kv, k_r = c_kv[..., :self.kv_rank], c_kv[..., self.kv_rank:]
+                kva = proj(self.kv_rank + dr, "kv_a_proj")(x)
+                c_kv, k_r = kva[..., :self.kv_rank], kva[..., self.kv_rank:]
             with jax.named_scope(scopes.ATTN_QK_NORM_ROPE), jax.named_scope(
                     scopes.MLA_LATENT_NORM):
                 c_q = RMSNorm(self.eps, name="q_a_norm")(c_q).astype(dt)
                 c_kv = RMSNorm(self.eps, name="kv_a_norm")(c_kv).astype(dt)
             with jax.named_scope(scopes.ATTN_QKV_PROJ), jax.named_scope(
                     scopes.MLA_UP):
-                q = proj(h * (dn + dr), "q_b_proj")(c_q).reshape(
-                    b, t, h, dn + dr)
-                kv = proj(h * (dn + dv), "kv_b_proj")(c_kv).reshape(
-                    b, t, h, dn + dv)
-                q_nope, q_rope = q[..., :dn], q[..., dn:]
-                k_nope, v = kv[..., :dn], kv[..., dn:]
+                q = proj(h * (dn + dr), "q_b_proj")(c_q)
+                kv = proj(h * (dn + dv), "kv_b_proj")(c_kv)
             # (initialisation runs eagerly on a short example row: the XLA
             # path, and no kernel is built for that length)
             flash = self.flash and not self.is_initializing()
+            cos, sin = rope.tables(dict(self.rope_parameters), dr, t)
+            # one Pallas pass that rotates q_rope and k_r and writes q where
+            # the kernels read it, which address k_nope, v, o and dO where
+            # the projections wrote them: where the shape allows (read from
+            # it, never chosen); else the lines below, and XLA's moves
+            if self.latent_plan(b, t, flash)["kernel"] == "pallas":
+                from tpudist.ops.pallas import flash_attention_latent_laid
+                from tpudist.ops.pallas.latent_rope import latent_rope
+                with jax.named_scope(scopes.ATTN_QK_NORM_ROPE):
+                    q_nope, q_rope, k_r = latent_rope(
+                        q, kva, cos, sin, heads=h, kv_rank=self.kv_rank)
+                with jax.named_scope(scopes.ATTN_FUSED):
+                    out = flash_attention_latent_laid(q_nope, q_rope, kv,
+                                                      k_r)
+                with jax.named_scope(scopes.ATTN_OUT_PROJ):
+                    return proj(d, "o_proj")(out)
+            with jax.named_scope(scopes.ATTN_QKV_PROJ), jax.named_scope(
+                    scopes.MLA_UP):
+                q = q.reshape(b, t, h, dn + dr)
+                kv = kv.reshape(b, t, h, dn + dv)
+                q_nope, q_rope = q[..., :dn], q[..., dn:]
+                k_nope, v = kv[..., :dn], kv[..., dn:]
             with jax.named_scope(scopes.ATTN_QK_NORM_ROPE):
-                cos, sin = rope.tables(dict(self.rope_parameters), dr, t)
                 q_rope = rope.apply_pairs(q_rope, cos, sin)
                 k_r = rope.apply_pairs(k_r[:, :, None], cos, sin)
                 if not flash:
@@ -918,6 +943,16 @@ class LatentAttention(nn.Module):
                 out = attention(q, k, v, causal=True)
             with jax.named_scope(scopes.ATTN_OUT_PROJ):
                 return proj(d, "o_proj")(out.reshape(b, t, h * dv))
+
+    def latent_plan(self, rows: int, seq_len: int, flash: bool) -> dict:
+        """Which program rotates this layer's q_rope and k_r and lays its
+        kernels' operands at ``rows`` rows of ``seq_len`` positions
+        (``latent_rope.latent_plan``: read from the shape and the layer's
+        fields)."""
+        from tpudist.ops.pallas.latent_rope import latent_plan
+        return latent_plan(rows, seq_len, self.num_heads, self.nope_dim,
+                           self.rope_dim, self.v_dim, self.kv_rank,
+                           flash=flash)
 
 
 class MTPModule(nn.Module):

@@ -9,4 +9,5 @@ interpreter-mode fallback so the same kernels run in CPU tests.
 from tpudist.ops.pallas.flash_attention import (  # noqa: F401
     flash_attention, flash_attention_laid, flash_attention_qkv,
     flash_attention_spmd)
-from tpudist.ops.pallas.mla_attention import flash_attention_latent  # noqa: F401,E402
+from tpudist.ops.pallas.mla_attention import (  # noqa: F401,E402
+    flash_attention_latent, flash_attention_latent_laid)

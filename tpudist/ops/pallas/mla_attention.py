@@ -25,7 +25,18 @@ What differs, beside the two products:
   padded to the block with zeros and run as if that long: a padded key lies
   behind every true query, a padded query row is dropped on the way out and
   its dO and delta are zero, so no tile needs a mask for the padding.
-- operands lie [B, H, T, D] (XLA moves them, as ``_operand`` does there).
+- two entries over the same three kernel bodies. ``flash_attention_latent``
+  takes [B, T, H, D] operands of any length: XLA moves them to [B, H, T, D]
+  and pads the row. ``flash_attention_latent_laid`` takes them where they
+  lie and moves nothing: q_nope and q_rope [B, H, T, D] as
+  ``latent_rope.py`` writes them; a head's ``[k_nope | v]`` as ONE block of
+  ``Dn + Dv`` columns of ``kv_b_proj``'s [B, T, H * (Dn + Dv)] result, which
+  the body reads as its two halves (``_keys_apart``); o and dO as column
+  block ``head`` of [B, T, H * Dv], where ``o_proj`` reads and writes; the
+  dKV pass writes a head's dk_nope and dv into one block of ``kv_b_proj``'s
+  cotangent (no join). Only the block addresses differ (``_column_spec``
+  beside ``_head_spec``). q_rope and its cotangent, 64 wide, stay [B, H, T,
+  64]: a 64-column block of a wider row is no whole lane tile.
 - the row statistics (logsumexp, delta) lie LANE-DENSE, [B, H, 1, T]
   float32: a group of one puts one float in a row of the older entries'
   [B, Hkv, T, G] layout, which the chip pads to 128 lanes (256 MiB a call at
@@ -43,8 +54,10 @@ What differs, beside the two products:
   heads, is taken in one float32 scratch tile and written once.
 
 The path is chosen from the shapes a module hands over
-(``models/decoder.py::LatentAttention`` calls this entry for its split
-operands; nothing probes or flags it). ``interpret`` off the TPU, as there.
+(``models/decoder.py::LatentAttention`` calls the addressed entry where
+``latent_rope.latent_plan`` says the shape allows it, this one for its split
+operands otherwise; nothing probes or flags it). ``interpret`` off the TPU,
+as there.
 """
 
 from __future__ import annotations
@@ -274,6 +287,15 @@ def _head_spec(rows: int, d: int, index):
                         lambda *g: (*index(*g), 0))
 
 
+def _column_spec(rows: int, d: int, index):
+    """The same of a [B, T, H * D] operand, where a projection wrote or reads
+    it: column block ``head`` of the row block (the move between the two
+    layouts is this address)."""
+    return pl.BlockSpec((None, rows, d),
+                        lambda *g: (lambda b_, h_, r: (b_, r, h_))(
+                            *index(*g)))
+
+
 def _shared_spec(rows: int, d: int, index):
     """The same of [B, T, D]: (batch, block of rows)."""
     return pl.BlockSpec((None, rows, d), lambda *g: (*index(*g), 0))
@@ -286,21 +308,65 @@ def _stat_spec(rows: int, index):
                             *index(*g)))
 
 
-def _forward(qn, qr, kn, kr, v, block, interpret):
+def _keys_apart(kernel, dn: int, at=(2,)):
+    """``kernel`` for a head's ``[k_nope | v]`` as ONE block of ``Dn + Dv``
+    columns where ``kv_b_proj`` wrote it (or reads its cotangent): each ref
+    at ``at`` is handed to the body as its two halves, with the shared key's
+    ref between them as the body's signature has it."""
+    @functools.wraps(kernel)
+    def apart(*refs, **kw):
+        refs = list(refs)
+        for i in sorted(at, reverse=True):
+            refs[i:i + 2] = [refs[i].at[:, :dn], refs[i + 1],
+                             refs[i].at[:, dn:]]
+        return kernel(*refs, **kw)
+    return apart
+
+
+def _sizes(qn, qr, keys):
+    """(B, H, T, Dn, Dr, Dv, addressed) of a call's operands; ``keys`` is
+    ``(k_nope, v)`` [B, H, T, D] each, or (``addressed``) ``(kv,)`` [B, T, H
+    * (Dn + Dv)] where ``kv_b_proj`` wrote it."""
     b, h, t, dn = qn.shape
-    dr, dv = qr.shape[-1], v.shape[-1]
+    addressed = len(keys) == 1
+    dv = keys[0].shape[-1] // h - dn if addressed else keys[1].shape[-1]
+    return b, h, t, dn, qr.shape[-1], dv, addressed
+
+
+def _key_specs(addressed: bool, rows: int, dn: int, dv: int, index):
+    """The specs of one head's keys and values, the shared key's between
+    them left to the caller: [k_nope's, v's], or (``addressed``) the one
+    block of ``Dn + Dv`` columns that holds both."""
+    if addressed:
+        return [_column_spec(rows, dn + dv, index)]
+    return [_head_spec(rows, dn, index), _head_spec(rows, dv, index)]
+
+
+def _values_spec(addressed: bool, rows: int, dv: int, index):
+    """o's and dO's spec: [B, H, T, Dv], or (``addressed``) column block
+    ``head`` of [B, T, H * Dv], where ``o_proj`` reads and writes them."""
+    return (_column_spec if addressed else _head_spec)(rows, dv, index)
+
+
+def _forward(qn, qr, keys, kr, block, interpret):
+    b, h, t, dn, dr, dv, addressed = _sizes(qn, qr, keys)
     band, own, streamed, shared = _q_grid(block, t)
     scale = 1.0 / ((dn + dr) ** 0.5)
     isz = qn.dtype.itemsize
+    key_specs = _key_specs(addressed, band.bk, dn, dv, streamed)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, band=band, scale=scale),
+        functools.partial(
+            _keys_apart(_fwd_kernel, dn) if addressed else _fwd_kernel,
+            band=band, scale=scale),
         grid=(b, h, band.n, band.steps),
         in_specs=[_head_spec(band.bq, dn, own), _head_spec(band.bq, dr, own),
-                  _head_spec(band.bk, dn, streamed),
-                  _shared_spec(band.bk, dr, shared),
-                  _head_spec(band.bk, dv, streamed)],
-        out_specs=[_head_spec(band.bq, dv, own), _stat_spec(band.bq, own)],
-        out_shape=[jax.ShapeDtypeStruct((b, h, t, dv), qn.dtype),
+                  key_specs[0], _shared_spec(band.bk, dr, shared),
+                  *key_specs[1:]],
+        out_specs=[_values_spec(addressed, band.bq, dv, own),
+                   _stat_spec(band.bq, own)],
+        out_shape=[jax.ShapeDtypeStruct(
+                       (b, t, h * dv) if addressed else (b, h, t, dv),
+                       qn.dtype),
                    jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((band.bq, dn), qn.dtype),
                         pltpu.VMEM((band.bq, dr), qn.dtype),
@@ -313,24 +379,26 @@ def _forward(qn, qr, kn, kr, v, block, interpret):
         cost_estimate=_cost(band, b, h, dn + dr + dv,
                             h * (2 * dn + dr + 2 * dv) + dr, 1, isz),
         interpret=interpret,
-    )(qn, qr, kn, kr, v)
+    )(qn, qr, keys[0], kr, *keys[1:])
 
 
-def _backward(qn, qr, kn, kr, v, o, lse, do, block, interpret):
-    b, h, t, dn = qn.shape
-    dr, dv = qr.shape[-1], v.shape[-1]
+def _backward(qn, qr, keys, kr, o, lse, do, block, interpret):
+    b, h, t, dn, dr, dv, addressed = _sizes(qn, qr, keys)
     scale = 1.0 / ((dn + dr) ** 0.5)
     isz = qn.dtype.itemsize
     band, own, streamed, shared = _q_grid(block, t)
     stat = jax.ShapeDtypeStruct((b, h, 1, t), jnp.float32)
+    key_specs = _key_specs(addressed, band.bk, dn, dv, streamed)
     dqn, dqr, delta = pl.pallas_call(
-        functools.partial(_dq_kernel, band=band, scale=scale),
+        functools.partial(
+            _keys_apart(_dq_kernel, dn) if addressed else _dq_kernel,
+            band=band, scale=scale),
         grid=(b, h, band.n, band.steps),
         in_specs=[_head_spec(band.bq, dn, own), _head_spec(band.bq, dr, own),
-                  _head_spec(band.bk, dn, streamed),
-                  _shared_spec(band.bk, dr, shared),
-                  _head_spec(band.bk, dv, streamed),
-                  _head_spec(band.bq, dv, own), _head_spec(band.bq, dv, own),
+                  key_specs[0], _shared_spec(band.bk, dr, shared),
+                  *key_specs[1:],
+                  _values_spec(addressed, band.bq, dv, own),
+                  _values_spec(addressed, band.bq, dv, own),
                   _stat_spec(band.bq, own)],
         out_specs=[_head_spec(band.bq, dn, own), _head_spec(band.bq, dr, own),
                    _stat_spec(band.bq, own)],
@@ -348,7 +416,7 @@ def _backward(qn, qr, kn, kr, v, o, lse, do, block, interpret):
         cost_estimate=_cost(band, b, h, dv + dn + dr,
                             h * (3 * dn + 2 * dr + 3 * dv) + dr, 2, isz),
         interpret=interpret,
-    )(qn, qr, kn, kr, v, o, do, lse)
+    )(qn, qr, keys[0], kr, *keys[1:], o, do, lse)
 
     band = _Band(causal=True, window=None, block_q=block[0],
                  block_k=block[1], q_len=t, k_len=t, stream="q")
@@ -362,25 +430,27 @@ def _backward(qn, qr, kn, kr, v, o, lse, do, block, interpret):
     def streamed_q(b_, ik, h_, s):
         return b_, h_, _stream(band, ik, s)
 
-    dkn, dkr, dv_ = pl.pallas_call(
-        functools.partial(_dkv_kernel, band=band, heads=h, scale=scale),
+    # k_nope's and v's specs serve their cotangents too: where the keys are
+    # addressed, one block of kv_b_proj's cotangent holds a head's both
+    key_specs = _key_specs(addressed, band.bk, dn, dv, own_k)
+    first, dkr, *second = pl.pallas_call(
+        functools.partial(
+            _keys_apart(_dkv_kernel, dn, at=(0, 7)) if addressed
+            else _dkv_kernel, band=band, heads=h, scale=scale),
         grid=(b, band.n, h, band.steps),
-        in_specs=[_head_spec(band.bk, dn, own_k),
-                  _shared_spec(band.bk, dr, own_shared),
-                  _head_spec(band.bk, dv, own_k),
+        in_specs=[key_specs[0], _shared_spec(band.bk, dr, own_shared),
+                  *key_specs[1:],
                   _head_spec(band.bq, dn, streamed_q),
                   _head_spec(band.bq, dr, streamed_q),
-                  _head_spec(band.bq, dv, streamed_q),
+                  _values_spec(addressed, band.bq, dv, streamed_q),
                   _stat_spec(band.bq, streamed_q),
                   _stat_spec(band.bq, streamed_q)],
-        out_specs=[_head_spec(band.bk, dn, own_k),
-                   _shared_spec(band.bk, dr, own_shared),
-                   _head_spec(band.bk, dv, own_k)],
-        out_shape=[jax.ShapeDtypeStruct(kn.shape, kn.dtype),
-                   jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((band.bk, dn), kn.dtype),
-                        pltpu.VMEM((band.bk, dr), kn.dtype),
+        out_specs=[key_specs[0], _shared_spec(band.bk, dr, own_shared),
+                   *key_specs[1:]],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (keys[0], kr, *keys[1:])],
+        scratch_shapes=[pltpu.VMEM((band.bk, dn), qn.dtype),
+                        pltpu.VMEM((band.bk, dr), qn.dtype),
                         pltpu.VMEM((band.bk, dn), jnp.float32),
                         pltpu.VMEM((band.bk, dr), jnp.float32),
                         pltpu.VMEM((band.bk, dv), jnp.float32)],
@@ -390,21 +460,21 @@ def _backward(qn, qr, kn, kr, v, o, lse, do, block, interpret):
         cost_estimate=_cost(band, b, h, dv + dn + dr,
                             h * (3 * dn + dr + 3 * dv) + 2 * dr, 2, isz),
         interpret=interpret,
-    )(kn, kr, v, qn, qr, do, lse, delta)
-    return dqn, dqr, dkn, dkr, dv_
+    )(keys[0], kr, *keys[1:], qn, qr, do, lse, delta)
+    return dqn, dqr, (first, *second), dkr
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _latent_vjp(qn, qr, kn, kr, v, block, interpret):
-    return _forward(qn, qr, kn, kr, v, block, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _latent_vjp(qn, qr, keys, kr, block, interpret):
+    return _forward(qn, qr, keys, kr, block, interpret)[0]
 
 
-def _latent_vjp_fwd(qn, qr, kn, kr, v, block, interpret):
-    o, lse = _forward(qn, qr, kn, kr, v, block, interpret)
+def _latent_vjp_fwd(qn, qr, keys, kr, block, interpret):
+    o, lse = _forward(qn, qr, keys, kr, block, interpret)
     # a layer that rematerialises keeps these two and runs no second forward
     o, lse = checkpoint_name(o, SAVED_BY_NAME[0]), checkpoint_name(
         lse, SAVED_BY_NAME[1])
-    return o, (qn, qr, kn, kr, v, o, lse)
+    return o, (qn, qr, keys, kr, o, lse)
 
 
 def _latent_vjp_bwd(block, interpret, res, g):
@@ -418,6 +488,19 @@ def _lay(x, t_pad: int):
     """[B, T, H, D] as the kernels read it, [B, H, t_pad, D]."""
     x = jnp.moveaxis(x, 1, 2)
     return jnp.pad(x, ((0, 0), (0, 0), (0, t_pad - x.shape[2]), (0, 0)))
+
+
+def _blocks(t: int, block_q: int | None = None, block_k: int | None = None):
+    """((block_q, block_k), the padded length) of a row of ``t``: one query
+    head a program, ``_default_blocks``' rule for a group of one; the
+    arguments are the tests' (multiples of 128)."""
+    rule = _default_blocks(t, t, None, 1).fwd
+    block = (block_q or rule[0], block_k or rule[1])
+    if any(x % _LANES for x in block):
+        raise ValueError(f"blocks {block}: whole lane tiles of 128 (the row "
+                         f"statistics lie on the lanes)")
+    return block, _ceil_to(t, max(block) if max(block) % min(block) == 0
+                           else block[0] * block[1])
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
@@ -434,7 +517,10 @@ def flash_attention_latent(q_nope: jax.Array, q_rope: jax.Array,
     k_nope^T + q_rope k_rope^T) / sqrt(Dn + Dr)``, fp32 online softmax,
     products in the operands' dtype with fp32 accumulation. Differentiable
     in all five; ``k_rope``'s cotangent is the sum over the heads. The block
-    arguments are for tests (multiples of 128): they select no path."""
+    arguments are for tests (multiples of 128): they select no path. XLA
+    moves the operands to [B, H, T, D] and pads the row to the block: the
+    entry for any length (``flash_attention_latent_laid`` for operands that
+    lie where the kernels read them)."""
     b, t, h, dn = q_nope.shape
     dr = q_rope.shape[-1]
     if (q_rope.shape != (b, t, h, dr) or k_nope.shape != q_nope.shape
@@ -445,17 +531,59 @@ def flash_attention_latent(q_nope: jax.Array, q_rope: jax.Array,
             f"(one head), v {v.shape}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    # one query head a program: ``_default_blocks``' rule for a group of one
-    rule = _default_blocks(t, t, None, 1).fwd
-    block = (block_q or rule[0], block_k or rule[1])
-    if any(x % _LANES for x in block):
-        raise ValueError(f"blocks {block}: whole lane tiles of 128 (the row "
-                         f"statistics lie on the lanes)")
-    t_pad = _ceil_to(t, max(block) if max(block) % min(block) == 0
-                     else block[0] * block[1])
+    block, t_pad = _blocks(t, block_q, block_k)
     o = _latent_vjp(
-        _lay(q_nope, t_pad), _lay(q_rope, t_pad), _lay(k_nope, t_pad),
-        jnp.pad(k_rope, ((0, 0), (0, t_pad - t), (0, 0))), _lay(v, t_pad),
-        block, interpret)
+        _lay(q_nope, t_pad), _lay(q_rope, t_pad),
+        (_lay(k_nope, t_pad), _lay(v, t_pad)),
+        jnp.pad(k_rope, ((0, 0), (0, t_pad - t), (0, 0))), block, interpret)
     return jnp.moveaxis(o[:, :, :t], 1, 2)
 
+
+def why_not_laid(t: int, block_q: int | None = None,
+                 block_k: int | None = None) -> str | None:
+    """Why a caller cannot hand a row of ``t`` positions to
+    ``flash_attention_latent_laid``, None where it can (static, from the
+    shape): one array serves all three passes where it lies, so none of them
+    may pad the row."""
+    block, t_pad = _blocks(t, block_q, block_k)
+    if t_pad != t:
+        return (f"a row of {t} positions is padded to {t_pad} (blocks of "
+                f"{block[0]} x {block[1]})")
+    return None
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k",
+                                             "interpret"))
+def flash_attention_latent_laid(q_nope: jax.Array, q_rope: jax.Array,
+                                kv: jax.Array, k_rope: jax.Array,
+                                block_q: int | None = None,
+                                block_k: int | None = None,
+                                interpret: bool | None = None) -> jax.Array:
+    """``flash_attention_latent`` for operands that lie where a projection
+    or ``latent_rope.py`` wrote them, nothing moved or padded: ``q_nope`` [B,
+    H, T, Dn] and ``q_rope`` [B, H, T, Dr] (the pass writes them so), ``kv``
+    [B, T, H * (Dn + Dv)] as ``kv_b_proj`` wrote it (a head's ``[k_nope |
+    v]`` together, fetched as one block of its columns), ``k_rope`` [B, T,
+    Dr]; returns o [B, T, H * Dv], where ``o_proj`` reads it. The cotangents
+    leave the same way: dq_nope and dq_rope laid, dkv whole (the dKV kernel
+    writes a head's two halves into one block), dO read by column. ``Dn``
+    and ``Dv`` whole lane tiles and a length no pass pads
+    (``why_not_laid``): anything else is refused."""
+    b, h, t, dn = q_nope.shape
+    dr = q_rope.shape[-1]
+    dv = kv.shape[-1] // h - dn
+    if (q_rope.shape != (b, h, t, dr) or k_rope.shape != (b, t, dr)
+            or kv.shape != (b, t, h * (dn + dv)) or dv <= 0):
+        why = "not one latent attention's operands"
+    elif dn % _LANES or dv % _LANES:
+        why = "a block of a projection's columns is whole lane tiles"
+    else:
+        why = why_not_laid(t, block_q, block_k)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if why:
+        raise ValueError(
+            f"laid operands q_nope {q_nope.shape}, q_rope {q_rope.shape}, "
+            f"kv {kv.shape}, k_rope {k_rope.shape}: {why}")
+    return _latent_vjp(q_nope, q_rope, (kv,), k_rope,
+                       _blocks(t, block_q, block_k)[0], interpret)

@@ -149,10 +149,10 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     # jax.numpy form ("jax.numpy", with a ``reason``). Emitted once per
     # Trainer construction where a block has one.
     "ssm_conv": ("kernel", "rows_per_program", "programs"),
-    # q's and k's RMSNorm and RoPE in the attention blocks
-    # (tpudist/ops/pallas/qk_norm_rope.py::qk_plan): the Pallas pass that
-    # writes them where the attention kernels read them ("pallas") or the
-    # jax.numpy form ("jax.numpy", with a ``reason``). Emitted once per
+    # q's and k's RMSNorm and RoPE in the attention blocks (qk_plan of
+    # tpudist/ops/pallas/qk_norm_rope.py; latent_plan of latent_rope.py): the
+    # Pallas pass that writes them where the attention kernels read them, or
+    # the jax.numpy form ("jax.numpy", with a ``reason``). Emitted once per
     # Trainer construction and plan that differs between layer types.
     "attn_qk": ("kernel", "rows_per_program", "programs"),
     # Gradient-compression resolution (tpudist/ops/comm_dispatch): which

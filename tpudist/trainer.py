@@ -911,8 +911,8 @@ class Trainer:
                                         "block", plan)
 
     def _announce_qk_plans(self, plans: list) -> None:
-        """q's and k's norm and rotation in the attention blocks
-        (``qk_norm_rope.qk_plan``), one a plan that differs."""
+        """q's and k's norm and rotation in the attention blocks (``qk_plan``
+        or, latent attention, ``latent_plan``), one a plan that differs."""
         for plan in plans:
             Trainer._announce_pass_plan(self, "attn q/k", "attn_qk", "layer",
                                         plan)
