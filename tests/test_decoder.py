@@ -13,6 +13,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -456,92 +457,128 @@ def test_yarn_rope_table_is_the_closed_formula():
 
 # --- loss, data, trainer ----------------------------------------------------
 
-def test_head_loss_in_chunks_is_the_whole_cross_entropy():
-    from tpudist.ops import accuracy, cross_entropy_loss, lm_head_loss
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    hidden = jax.random.normal(ks[0], (2, 37, 16))
-    kernel = jax.random.normal(ks[1], (16, 50))
-    targets = jax.random.randint(ks[2], (2, 37), 0, 50)
-
-    def whole(h, w):
-        return cross_entropy_loss(h @ w, targets)
-
-    with jax.default_matmul_precision("highest"):
-        (loss, acc), grads = jax.value_and_grad(
-            lambda h, w: lm_head_loss(h, w, targets, chunk=16),
-            argnums=(0, 1), has_aux=True)(hidden, kernel)
-        want, want_grads = jax.value_and_grad(whole, argnums=(0, 1))(
-            hidden, kernel)
-        want_acc = accuracy(hidden @ kernel, targets)
-    assert abs(float(loss) - float(want)) < 1e-5
-    assert abs(float(acc) - float(want_acc)) < 1e-4
-    for a, b in zip(grads, want_grads):
-        np.testing.assert_allclose(a, b, atol=1e-6)
-
-
-def test_head_loss_with_weights_is_the_whole_weighted_cross_entropy():
-    """With weights: sum(w * nll) over the normaliser, gradients and all;
-    the accuracy over the weighted positions. Without: to the bit what the
-    function computed before it took them (the same operations)."""
-    from tpudist.ops import lm_head_loss
+def _head_case(weighted):
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     hidden = jax.random.normal(ks[0], (2, 37, 16))
     kernel = jax.random.normal(ks[1], (16, 50))
     targets = jax.random.randint(ks[2], (2, 37), 0, 50)
     t = jax.random.uniform(ks[3], (2, 37), minval=0.1)
-    weights = jnp.where(t < 0.6, 1.0 / t, 0.0)
+    return hidden, kernel, targets, (
+        jnp.where(t < 0.6, 1.0 / t, 0.0) if weighted else None)
 
-    def whole(h, w):
+
+@pytest.mark.parametrize("weighted,fields", [
+    (False, {}), (True, {}), (True, dict(normaliser=148)),
+    (True, dict(rematerialised=True)),
+    (False, dict(rematerialised=True))],
+    ids=["plain", "weights", "weights_normaliser", "rematerialised",
+         "rematerialised_plain"])
+def test_head_loss_in_chunks_is_the_whole_cross_entropy(weighted, fields):
+    """Either form of the chunked head loss against the unchunked float32
+    cross entropy: the mean (with weights: sum(w * nll) over the normaliser)
+    and the accuracy (over the weighted positions), and at a cotangent other
+    than one the gradients of the hidden rows, of the head and of the
+    weights, which a looped model differentiates."""
+    from tpudist.ops import lm_head_loss
+    hidden, kernel, targets, weights = _head_case(weighted)
+    over = fields.get("normaliser", 74)
+    ones = jnp.ones((2, 37)) if weights is None else weights
+
+    def whole(h, w, weights):
         logp = jax.nn.log_softmax(h @ w, axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return jnp.sum(weights * nll) / 74
+        return 0.37 * jnp.sum(weights * nll) / over
+
+    def chunked(h, w, weights):
+        loss, acc = lm_head_loss(
+            h, w, targets, chunk=16, **fields,
+            **(dict(weights=weights) if weighted else {}))
+        return 0.37 * loss, acc
 
     with jax.default_matmul_precision("highest"):
         (loss, acc), grads = jax.value_and_grad(
-            lambda h, w: lm_head_loss(h, w, targets, chunk=16,
-                                      weights=weights),
-            argnums=(0, 1), has_aux=True)(hidden, kernel)
-        want, want_grads = jax.value_and_grad(whole, argnums=(0, 1))(
-            hidden, kernel)
-        hit = (jnp.argmax(hidden @ kernel, axis=-1) == targets) & (weights > 0)
-        halved, _ = lm_head_loss(hidden, kernel, targets, chunk=16,
-                                 weights=weights, normaliser=148)
-        plain = lm_head_loss(hidden, kernel, targets, chunk=16)
-        ones = lm_head_loss(hidden, kernel, targets, chunk=16,
-                            weights=jnp.ones((2, 37)))
+            chunked, argnums=(0, 1, 2), has_aux=True)(hidden, kernel, ones)
+        want, want_grads = jax.value_and_grad(whole, argnums=(0, 1, 2))(
+            hidden, kernel, ones)
+        hit = (jnp.argmax(hidden @ kernel, axis=-1) == targets) & (ones > 0)
     assert abs(float(loss) - float(want)) < 1e-5
-    assert abs(float(halved) - float(want) / 2) < 1e-5
     assert abs(float(acc) - 100.0 * float(hit.sum())
-               / float((weights > 0).sum())) < 1e-4
+               / float((ones > 0).sum())) < 1e-4
+    if not weighted:
+        want_grads = want_grads[:2] + (jnp.zeros((2, 37)),)
     for a, b in zip(grads, want_grads):
+        assert a.dtype == b.dtype and a.shape == b.shape
         np.testing.assert_allclose(a, b, atol=1e-6)
 
-    # no weights: the program before this PR, operation for operation
-    def before(hidden, kernel, targets, chunk):
-        rows, t_, d = hidden.shape
-        n = rows * t_
-        chunk = next(c for c in range(min(chunk, n), 0, -1) if n % c == 0)
 
-        @jax.checkpoint
-        def one(carry, xs):
-            h, y = xs
-            logits = jnp.dot(h, kernel, preferred_element_type=jnp.float32)
-            nll = (jax.nn.logsumexp(logits, axis=-1)
-                   - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0])
-            hits = jnp.sum(jnp.argmax(logits, axis=-1) == y)
-            return (carry[0] + jnp.sum(nll), carry[1] + hits), None
-        (total, hits), _ = jax.lax.scan(
-            one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32)),
-            (hidden.reshape(n // chunk, chunk, d),
-             targets.reshape(n // chunk, chunk)))
-        return total / n, hits.astype(jnp.float32) * (100.0 / n)
-    with jax.default_matmul_precision("highest"):
-        was = before(hidden, kernel, targets, 16)
-    assert float(plain[0]) == float(was[0]) and float(plain[1]) == float(
-        was[1])
-    # and weights of one are the plain mean
-    assert abs(float(ones[0]) - float(plain[0])) < 1e-6
-    assert abs(float(ones[1]) - float(plain[1])) < 1e-4
+def test_head_loss_takes_its_gradients_in_its_forward_loop():
+    """The mechanism, read from the compiled program: differentiated, the
+    head's loss is ONE loop of three products a chunk (the logits, d hidden,
+    d head) where the rematerialised form is two loops of four (the logits
+    made twice); in bfloat16 the two forms' gradients agree to rounding."""
+    from tpudist.ops import lm_head_loss
+    hidden, kernel, targets, _ = _head_case(False)
+    hidden = hidden.astype(jnp.bfloat16)
+
+    def grads(form):
+        return jax.jit(jax.value_and_grad(
+            lambda h, w: lm_head_loss(h, w, targets, chunk=16,
+                                      rematerialised=form)[0],
+            argnums=(0, 1)))
+
+    def counts(form):
+        text = grads(form).lower(hidden, kernel).compile().as_text()
+        return (len(re.findall(r" dot\(", text)),
+                len(re.findall(r" while\(", text)))
+
+    assert counts(False) == (3, 1)
+    assert counts(True) == (4, 2)
+    (loss, (dh, dw)), (was, (was_dh, was_dw)) = (
+        grads(False)(hidden, kernel), grads(True)(hidden, kernel))
+    assert float(loss) == float(was)
+    for a, b in ((dh, was_dh), (dw, was_dw)):
+        assert a.dtype == b.dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= 1e-2 * np.linalg.norm(b)
+
+
+def test_the_trainer_says_which_form_of_the_head_loss_runs(tmp_path):
+    """`MoEDecoder.head_plan` at the cells' shapes and what the trainer's
+    constructor logs and emits of it: the form follows from the caller (the
+    looped model's scan over passes), nothing is decided."""
+    import types
+    from tpudist import telemetry
+    from tpudist.models import create_model
+    from tpudist.trainer import Trainer
+    plans = [create_model(arch, **share).head_plan(rows, 8192)
+             for arch, rows, share in (
+                 ("mellum2_12b_a2_5b", 2, dict(layers=4)),
+                 ("joyai_llm_flash", 2, dict(layers=5)),
+                 ("ouro_2_6b", 1, dict(layers=6)))]
+    assert plans == [
+        dict(form="forward_loop", chunk=2048, chunks=8, calls=1),
+        dict(form="forward_loop", chunk=2048, chunks=8, calls=2),
+        dict(form="rematerialised", chunk=2048, chunks=4, calls=4)]
+    # the chunk rule is the loss's own: the largest divisor up to the size
+    assert create_model("sdar_tiny", loss_chunk=64).head_plan(2, 37) == dict(
+        form="forward_loop", chunk=37, chunks=2, calls=1)
+    lines = []
+    sink = telemetry.Telemetry(str(tmp_path), heartbeat=False)
+    fake = types.SimpleNamespace(log=lines.append, telemetry=sink)
+    for plan in plans:
+        Trainer._announce_head_plan(fake, plan)
+    sink.close()
+    assert lines == [
+        "=> lm head: gradients in the forward loop (chunk 2048, 8 chunks a "
+        "call, 1 call a step)",
+        "=> lm head: gradients in the forward loop (chunk 2048, 8 chunks a "
+        "call, 2 calls a step)",
+        "=> lm head: rematerialised (chunk 2048, 4 chunks a call, 4 calls a "
+        "step under the loop over passes)"]
+    with open(telemetry.events_path(str(tmp_path), 0)) as f:
+        events = [e for e in map(json.loads, f) if e["type"] == "lm_head"]
+    assert [{k: e[k] for k in telemetry.SCHEMA["lm_head"]}
+            for e in events] == plans
 
 
 def test_cross_entropy_takes_any_leading_dimensions():

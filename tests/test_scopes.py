@@ -212,10 +212,19 @@ def test_decoder_scopes_are_named_forward_and_backward(decoder_step, scope,
                                                        where):
     """A layer's parts lie under the layer's name inside the forward scope,
     plain and transposed: what `moe_ms`, `lm_head_ms` and
-    `attn_stream_roofline` of the chip benchmark sum."""
+    `attn_stream_roofline` of the chip benchmark sum. The head's loss takes
+    its gradients in its forward loop (PR 46): all three of its products a
+    chunk and `dlogits` are named in the forward pass, and the backward
+    pass's scaling by a cotangent of one is no operation."""
     named = [n for _, n in decoder_step
              if f"/{scope}/" in n and where in n and scopes.FORWARD in n]
     assert any(phase_of(n) == "fwd" for n in named), scope
+    if scope in (scopes.LM_HEAD, scopes.LOSS):
+        assert not any(phase_of(n) == "bwd" for n in named), scope
+        dots = [n for line, n in decoder_step if " dot(" in line
+                and f"/{scopes.LM_HEAD}/" in n]
+        assert len(dots) == 3 and all(phase_of(n) == "fwd" for n in dots)
+        return
     assert any(phase_of(n) == "bwd" for n in named), scope
     if where == "/moe/":
         assert any("/layer_0/" in n for n in named)
@@ -525,12 +534,20 @@ def test_the_modules_parts_are_named_inside_mtp_module(latent_step, scope,
     """What `mtp_ms` times and `mtp_unitemised_ms` leaves: the module lies
     whole inside `mtp_module`, forward and backward: the next id's
     embedding, the merge (its one leaf of its own), its block's parts under
-    their own names, its pass through the head and its loss."""
+    their own names, its pass through the head and its loss. That pass
+    takes its gradients in its forward loop (PR 46): the head's three
+    products a chunk are forward operations, and what the backward pass
+    keeps of it is the scaling by the module's weight, under the loss."""
     inside = [n for _, n in latent_step if _under(n, scopes.MTP_MODULE)]
     named = [n for n in inside if _under(n, scope)]
     assert any(phase_of(n) == "fwd" or "/rematted_computation/" in n
                for n in named), scope
-    assert any(phase_of(n) == "bwd" for n in named), scope
+    if scope == scopes.LM_HEAD:
+        dots = [n for line, n in latent_step if " dot(" in line
+                and _under(n, scope) and _under(n, scopes.MTP_MODULE)]
+        assert len(dots) == 3 and all(phase_of(n) == "fwd" for n in named)
+    else:
+        assert any(phase_of(n) == "bwd" for n in named), scope
     for name in names:
         assert any(name in n for n in named), name
     if scope == scopes.MTP_MERGE:

@@ -81,7 +81,8 @@ def test_validate_event_accepts_every_schema_type():
                "divergent": 0, "stages_total": 3, "stages_failed": 0,
                "regressions": 0, "trigger": "fault", "captured": 1,
                "chunk": 128, "heads_per_program": 8, "programs": 1024,
-               "rows_per_program": 512}
+               "rows_per_program": 512, "form": "forward_loop", "chunks": 8,
+               "calls": 1}
     for etype, required in telemetry.SCHEMA.items():
         ev = dict(base, type=etype, **{k: fillers[k] for k in required})
         telemetry.validate_event(ev)                  # must not raise

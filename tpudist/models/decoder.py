@@ -94,9 +94,11 @@ Precision: float32 parameters, norms, router, exit gate, softmaxes and loss;
 products and activations in ``dtype``.
 
 Given ``targets`` the model takes the loss itself (``ops.lm_head_loss``: the
-head and the cross entropy a chunk of positions at a time) and returns a
-``Scored``; without, logits ``[rows, T, vocabulary held]``. (Under diffusion
-the row is its own target: ``targets`` only says that a loss is wanted.)
+head and the cross entropy a chunk of positions at a time, its gradients
+taken in the same loop; rematerialised under the scan over passes:
+``head_plan``) and returns a ``Scored``; without, logits ``[rows, T,
+vocabulary held]``. (Under diffusion the row is its own target: ``targets``
+only says that a loss is wanted.)
 """
 
 from __future__ import annotations
@@ -110,7 +112,7 @@ from flax import linen as nn
 
 from tpudist.obs import scopes
 from tpudist.ops import rope, ssd
-from tpudist.ops.loss import Scored, lm_head_loss
+from tpudist.ops.loss import Scored, head_chunk, lm_head_loss
 from tpudist.parallel.moe import moe_topk_held, shared_expert
 from tpudist.parallel.ring_attention import attention
 
@@ -792,6 +794,18 @@ class MoEDecoder(nn.Module):
                              2 * inner + 2 * gn + m["num_heads"],
                              (inner, gn, gn), m["conv"], inner)
 
+    def head_plan(self, rows: int, seq_len: int) -> dict:
+        """Which form of ``ops.lm_head_loss`` a training step of ``rows``
+        rows of ``seq_len`` ids runs, by its caller: ``form`` ``forward_loop``
+        (the gradients taken in the loop over chunks) or ``rematerialised``
+        (under the scan over passes), the ``chunk``, the ``chunks`` a call
+        and the ``calls`` a step."""
+        chunk = head_chunk(rows * seq_len, self.loss_chunk)
+        looped = self.loop_steps > 1
+        return dict(form="rematerialised" if looped else "forward_loop",
+                    chunk=chunk, chunks=rows * seq_len // chunk,
+                    calls=self.loop_steps if looped else 1 + self.mtp_depth)
+
     def _attention_of(self, kind: str, mask: Optional[tuple]) -> dict:
         """The attention module's fields in a layer of ``kind``:
         ``LatentAttention``'s where the model states ``latent``, else
@@ -1023,7 +1037,10 @@ def _looped(model: "MoEDecoder", run_layers, x: jax.Array,
             p = jnp.where(t == steps, survive, survive * leave)
         if targets is None:
             return (x, survive, sums), None
-        loss, acc1 = lm_head_loss(x, head, targets, m.loss_chunk, weights=p)
+        # under the scan over passes the other form's d head would be
+        # stacked a pass (ops/loss.py)
+        loss, acc1 = lm_head_loss(x, head, targets, m.loss_chunk, weights=p,
+                                  rematerialised=True)
         with jax.named_scope(scopes.LOOP_EXIT):
             plogp = jnp.mean(p * jnp.log(jnp.maximum(p, _EXIT_TINY)))
             sums = (sums[0] + loss + m.exit_beta * plogp,

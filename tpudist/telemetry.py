@@ -155,6 +155,12 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     # the jax.numpy form ("jax.numpy", with a ``reason``). Emitted once per
     # Trainer construction and plan that differs between layer types.
     "attn_qk": ("kernel", "rows_per_program", "programs"),
+    # The output head's loss (tpudist/ops/loss.py::lm_head_loss, stated by
+    # MoEDecoder.head_plan): "forward_loop" (the gradients taken in the loop
+    # over chunks) or "rematerialised" (the caller under a scan over
+    # passes), the chunk, the chunks a call and the calls a step. Emitted
+    # once per Trainer construction of a model of tokens.
+    "lm_head": ("form", "chunk", "chunks", "calls"),
     # Gradient-compression resolution (tpudist/ops/comm_dispatch): which
     # wire format --compress-grads resolved to ("int8" | "dense"), on what
     # evidence, with the dense-equivalent gradient payload bytes summarize

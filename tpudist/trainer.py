@@ -530,6 +530,9 @@ class Trainer:
         if hasattr(self.model, "qk_plans"):
             self._announce_qk_plans(self.model.qk_plans(
                 cfg.per_device_batch_size, cfg.seq_len))
+        if hasattr(self.model, "head_plan"):
+            self._announce_head_plan(self.model.head_plan(
+                cfg.per_device_batch_size, cfg.seq_len))
         seed = cfg.seed if cfg.seed is not None else 0
         mark(scopes.INIT_DISPATCH)
         if self.uses_seq_axis or self.uses_expert_axis or self.uses_pipe_axis:
@@ -916,6 +919,21 @@ class Trainer:
         for plan in plans:
             Trainer._announce_pass_plan(self, "attn q/k", "attn_qk", "layer",
                                         plan)
+
+    def _announce_head_plan(self, plan: dict) -> None:
+        """Which form of the head's loss the step runs (``ops.lm_head_loss``:
+        it follows from the caller, nothing to decide)."""
+        def counted(n, what):
+            return f"{n} {what}{'s'[:n != 1]}"
+        runs = (f"(chunk {plan['chunk']}, "
+                f"{counted(plan['chunks'], 'chunk')} a call, "
+                f"{counted(plan['calls'], 'call')} a step")
+        self.log("=> lm head: " + (
+            f"gradients in the forward loop {runs})"
+            if plan["form"] == "forward_loop" else
+            f"rematerialised {runs} under the loop over passes)"))
+        if self.telemetry is not None:
+            self.telemetry.emit("lm_head", **plan)
 
     def _forced_flash_decision(self) -> dict:
         """The attention decision of a family without a start-up probe (a
