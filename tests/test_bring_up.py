@@ -117,6 +117,7 @@ def _stub_trainer(cfg, arch, **model_kw):
     t.logger = t.telemetry = None
     t.primary = True
     t.data_axis = "data"
+    t.trains_tokens = False
     for flag in ("uses_model_axis", "uses_seq_axis", "uses_pipe_axis",
                  "uses_expert_axis", "uses_gspmd_path"):
         setattr(t, flag, False)
@@ -140,7 +141,7 @@ def test_probe_failure_under_auto_on_tpu_propagates(family, tmp_path,
         monkeypatch.setattr(attention_dispatch, "measure_attention", _boom)
         t = _stub_trainer(Config(arch="vit_b_16", image_size=224, **base),
                           "vit_b_16")
-        resolve = t._resolve_flash_dispatch
+        resolve = t._resolve_attention
     else:
         monkeypatch.setattr(comm_dispatch, "measure_comm", _boom)
         t = _stub_trainer(Config(arch="resnet18", image_size=32,
@@ -176,7 +177,7 @@ def test_an_unwritable_verdict_store_still_builds_the_measured_kernel(
                       "vit_b_16")
     t.log = lambda line: None
     assert t.model.flash is None
-    dec = t._resolve_flash_dispatch()
+    dec = t._resolve_attention()
     assert dec["kernel"] == "flash" and dec["source"] == "measured"
     assert dec["cache_path"] is None and t.model.flash is True
     assert not os.path.exists(tmp_path / "verdicts")

@@ -261,12 +261,13 @@ def test_models_state_their_plan_and_the_trainer_announces_it(tmp_path):
     sink = telemetry.Telemetry(str(tmp_path), heartbeat=False)
     fake = types.SimpleNamespace(log=lines.append, telemetry=sink)
     for p in (published.conv_plan(2, 8192), plan, None):
-        Trainer._announce_conv_plan(fake, p)
+        if p is not None:           # `MoEDecoder.plans` leaves it out
+            Trainer._announce_plan(fake, "ssm_conv", p)
     sink.close()
     assert lines == [
-        "=> ssm conv: pallas (rows_per_program 512, programs 256 a block)",
-        "=> ssm conv: jax.numpy (a width of 64 is no whole number of lane "
-        "tiles)"]
+        "=> ssm_conv: pallas (rows_per_program 512, programs 256)",
+        "=> ssm_conv: jax.numpy (rows_per_program 32, programs 1: a width "
+        "of 64 is no whole number of lane tiles)"]
     with open(telemetry.events_path(str(tmp_path), 0)) as f:
         events = [e for e in map(json.loads, f) if e["type"] == "ssm_conv"]
     assert [e["kernel"] for e in events] == ["pallas", "jax.numpy"]

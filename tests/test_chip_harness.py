@@ -484,7 +484,7 @@ _NOT_DEFAULTS = {
         ("remat", "15.8 GiB a step without it, 11.8 with: only so it fits"),
     ("resnet18_ref", "--flash"):
         ("flash", "the field differs and the program does not: resnet18 has "
-                  "no attention (models.takes_flash)"),
+                  "no attention (its model has no `flash` field)"),
     ("sdar_30b_ep8", "--flash"):
         ("flash", "a decoder has no start-up probe, `auto` is XLA's path, and "
                   "XLA's scores over the doubled row of 16,384 positions "
@@ -543,8 +543,8 @@ def test_a_pin_writes_a_default_out_and_no_more(name, argv, flag):
     field, _why = _NOT_DEFAULTS.get((name, flag), (None, None))
     assert differs == ([field] if field else [])
     if (name, flag) == ("resnet18_ref", "--flash"):
-        from tpudist.models import takes_flash
-        assert not takes_flash(pinned["arch"])
+        from tpudist.models import create_model, model_fields
+        assert "flash" not in model_fields(create_model(pinned["arch"]))
 
 
 def test_every_exception_names_a_pin_that_is_written_out():

@@ -566,15 +566,12 @@ def test_the_trainer_says_which_form_of_the_head_loss_runs(tmp_path):
     sink = telemetry.Telemetry(str(tmp_path), heartbeat=False)
     fake = types.SimpleNamespace(log=lines.append, telemetry=sink)
     for plan in plans:
-        Trainer._announce_head_plan(fake, plan)
+        Trainer._announce_plan(fake, "lm_head", plan)
     sink.close()
     assert lines == [
-        "=> lm head: gradients in the forward loop (chunk 2048, 8 chunks a "
-        "call, 1 call a step)",
-        "=> lm head: gradients in the forward loop (chunk 2048, 8 chunks a "
-        "call, 2 calls a step)",
-        "=> lm head: rematerialised (chunk 2048, 4 chunks a call, 4 calls a "
-        "step under the loop over passes)"]
+        "=> lm_head: forward_loop (chunk 2048, chunks 8, calls 1)",
+        "=> lm_head: forward_loop (chunk 2048, chunks 8, calls 2)",
+        "=> lm_head: rematerialised (chunk 2048, chunks 4, calls 4)"]
     with open(telemetry.events_path(str(tmp_path), 0)) as f:
         events = [e for e in map(json.loads, f) if e["type"] == "lm_head"]
     assert [{k: e[k] for k in telemetry.SCHEMA["lm_head"]}
@@ -680,7 +677,7 @@ def test_a_share_is_refused_by_a_model_that_is_not_of_tokens(tmp_path):
     from tpudist.trainer import Trainer
     cfg = Config(arch="resnet18", batch_size=8, synthetic=True, layers=2,
                  outpath=str(tmp_path / "out"), overwrite="delete")
-    with pytest.raises(ValueError, match="model of tokens"):
+    with pytest.raises(ValueError, match="vocab_share, which 'resnet18'"):
         Trainer(cfg, writer=None)
 
 

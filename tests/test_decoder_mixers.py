@@ -189,7 +189,7 @@ def test_nemotron3_states_its_attention_and_what_is_not_decayed():
         layers=9, expert_share=(0, 16), vocab_share=(0, 8))
     assert published.attention_workloads(8192) == [dict(
         heads=32, kv_heads=2, head_dim=128, seq=8192, causal=True,
-        window=None)]
+        window=None, fused=False)]
     assert published.vocab_held == 16384
     # a share that keeps no attention block states none
     assert published.clone(layers=5).attention_workloads(8192) == []

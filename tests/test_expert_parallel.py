@@ -143,7 +143,8 @@ def test_trainer_rejects_ep_for_non_moe(tmp_path):
     cfg = Config(arch="vit_b_16", num_classes=8, image_size=16, batch_size=16,
                  synthetic=True, epochs=1, outpath=str(tmp_path / "out"),
                  overwrite="delete", mesh_shape=(8,), mesh_axes=["expert"])
-    with pytest.raises(ValueError, match="vit_moe"):
+    with pytest.raises(ValueError,
+                       match="'expert' sets the model's fields expert_axis"):
         Trainer(cfg, writer=None)
 
 
@@ -155,7 +156,7 @@ def test_trainer_rejects_seq_axis_for_moe(tmp_path):
                  batch_size=16, synthetic=True, epochs=1,
                  outpath=str(tmp_path / "out"), overwrite="delete",
                  mesh_shape=(2, 4), mesh_axes=["data", "seq"])
-    with pytest.raises(ValueError, match="requires a ViT"):
+    with pytest.raises(ValueError, match="'seq' sets the model's fields pool, seq_axis"):
         Trainer(cfg, writer=None)
 
 

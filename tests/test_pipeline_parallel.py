@@ -152,7 +152,7 @@ def test_trainer_rejects_seq_axis_for_pipe_arch(tmp_path):
                  batch_size=16, synthetic=True, epochs=1,
                  outpath=str(tmp_path / "out"), overwrite="delete",
                  mesh_shape=(2, 4), mesh_axes=["data", "seq"])
-    with pytest.raises(ValueError, match="requires a ViT"):
+    with pytest.raises(ValueError, match="'seq' sets the model's fields pool, seq_axis"):
         Trainer(cfg, writer=None)
 
 
@@ -162,7 +162,8 @@ def test_trainer_rejects_pp_for_non_pipe_arch(tmp_path):
                  synthetic=True, epochs=1, outpath=str(tmp_path / "out"),
                  overwrite="delete", mesh_shape=(2, 4),
                  mesh_axes=["data", "pipe"])
-    with pytest.raises(ValueError, match="vit_pipe"):
+    with pytest.raises(ValueError,
+                       match="'pipe' sets the model's fields .*pipe_axis"):
         Trainer(cfg, writer=None)
 
 

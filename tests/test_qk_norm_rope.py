@@ -309,18 +309,18 @@ def test_models_state_their_plans_and_the_trainer_announces_them(tmp_path):
     lines = []
     sink = telemetry.Telemetry(str(tmp_path), heartbeat=False)
     fake = types.SimpleNamespace(log=lines.append, telemetry=sink)
-    Trainer._announce_qk_plans(fake, mellum2 + hybrid + tiny + joyai
-                               + padded)
+    for plan in mellum2 + hybrid + tiny + joyai + padded:
+        Trainer._announce_plan(fake, "attn_qk", plan)
     sink.close()
     assert lines == [
-        "=> attn q/k: pallas (rows_per_program 512, programs 128 a layer)",
-        "=> attn q/k: jax.numpy (the layer neither norms nor rotates q and "
-        "k)",
-        "=> attn q/k: jax.numpy (a head of 16 is no whole number of lane "
-        "tiles)",
-        "=> attn q/k: pallas (rows_per_program 512, programs 128 a layer)",
-        "=> attn q/k: jax.numpy (a row of 8704 positions is padded to 9216 "
-        "(blocks of 1024 x 1024))"]
+        "=> attn_qk: pallas (rows_per_program 512, programs 128)",
+        "=> attn_qk: jax.numpy (rows_per_program 512, programs 64: the layer "
+        "neither norms nor rotates q and k)",
+        "=> attn_qk: jax.numpy (rows_per_program 32, programs 32: a head of "
+        "16 is no whole number of lane tiles)",
+        "=> attn_qk: pallas (rows_per_program 512, programs 128)",
+        "=> attn_qk: jax.numpy (rows_per_program 512, programs 136: a row of "
+        "8704 positions is padded to 9216 (blocks of 1024 x 1024))"]
     with open(telemetry.events_path(str(tmp_path), 0)) as f:
         events = [e for e in map(json.loads, f) if e["type"] == "attn_qk"]
     assert [e["kernel"] for e in events] == [

@@ -92,7 +92,7 @@ def test_remat_rejects_unsupported_arch(tmp_path):
     cfg = Config(arch="alexnet", num_classes=4, image_size=32, batch_size=16,
                  use_amp=False, seed=0, synthetic=True, epochs=1, remat=True,
                  outpath=str(tmp_path / "out"), overwrite="delete")
-    with pytest.raises(ValueError, match="--remat supports"):
+    with pytest.raises(ValueError, match="--remat sets the model's field remat, which 'alexnet'"):
         Trainer(cfg, writer=None)
 
 

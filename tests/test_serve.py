@@ -312,7 +312,7 @@ def test_registry_serve_gauges_match_events():
 
 def test_forced_flash_reaches_serving_model():
     """--flash on/off must reach the model the same way the trainer's
-    model_kwargs['flash'] does: a forced verdict with the model left at
+    `flash` field does: a forced verdict with the model left at
     flash=None would let the trace-time dispatch lookup override it (and
     make the emitted attention_dispatch event lie about the kernel)."""
     import jax.numpy as jnp

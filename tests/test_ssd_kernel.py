@@ -203,12 +203,13 @@ def test_models_state_their_plan_and_the_trainer_announces_it(tmp_path):
     sink = telemetry.Telemetry(str(tmp_path), heartbeat=False)
     fake = types.SimpleNamespace(log=lines.append, telemetry=sink)
     for p in (published.scan_plan(2, 8192), plan, None):
-        Trainer._announce_scan_plan(fake, p)
+        if p is not None:           # `MoEDecoder.plans` leaves it out
+            Trainer._announce_plan(fake, "ssm_scan", p)
     sink.close()
-    assert lines[0] == ("=> ssm scan: pallas (chunk 128, heads_per_program 8, "
-                        "programs 1024 a block)")
-    assert lines[1].startswith("=> ssm scan: xla (chunk 8, heads_per_program "
-                               "4, programs 128 a block: a chunk of 8 is no")
+    assert lines[0] == ("=> ssm_scan: pallas (chunk 128, heads_per_program 8, "
+                        "programs 1024)")
+    assert lines[1].startswith("=> ssm_scan: xla (chunk 8, heads_per_program "
+                               "4, programs 128: a chunk of 8 is no")
     assert len(lines) == 2
     with open(telemetry.events_path(str(tmp_path), 0)) as f:
         events = [e for e in map(json.loads, f) if e["type"] == "ssm_scan"]

@@ -618,7 +618,7 @@ def test_flash_flag_validation(tmp_path):
 
     base = dict(num_classes=4, image_size=32, batch_size=16, use_amp=False,
                 seed=0, synthetic=True, epochs=1, overwrite="delete")
-    with pytest.raises(ValueError, match="--flash on applies"):
+    with pytest.raises(ValueError, match="--flash on sets the model.s field flash, which .resnet18."):
         Trainer(Config(arch="resnet18", flash="on",
                        outpath=str(tmp_path / "a"), **base), writer=None)
     # 'off' is a no-op for convnets (ADVICE r3): a scripted sweep passing a

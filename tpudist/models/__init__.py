@@ -9,7 +9,7 @@ for argparse choices (``distributed.py:39-40``).
 
 from __future__ import annotations
 
-
+import dataclasses
 from typing import Any, Callable, Dict
 
 from flax import linen as nn
@@ -128,30 +128,30 @@ def model_names() -> list[str]:
     return sorted(_REGISTRY)
 
 
-# Families whose trunks take the block-granular jax.checkpoint flag
-# (models/resnet.py, models/vit.py). The single source of truth for every
-# entry point (trainer, bench.py, direct create_model callers).
-REMAT_FAMILIES = ("resnet", "resnext", "wide_resnet", "vit_b", "vit_l",
-                  "vit_h", "mellum2", "sdar", "nemotron3", "ouro",
-                  "joyai")
-# Families whose attention can run the Pallas kernel (``--flash``), and of
-# them the ones whose ``--flash auto`` has a start-up probe (a fused
-# projection of equal head counts; a decoder's grouped, windowed attention
-# has none yet: ``auto`` there is the XLA path).
-FLASH_FAMILIES = ("vit", "mellum2", "sdar", "nemotron3", "ouro", "joyai")
-FLASH_PROBE_FAMILIES = ("vit",)
+def model_fields(model: nn.Module) -> frozenset[str]:
+    """What a built model takes: the fields of its dataclass. A model says
+    there, where it is defined, that it takes ``remat``, ``flash``, a mesh
+    axis or a holder's share; nothing restates it by name."""
+    return frozenset(f.name for f in dataclasses.fields(model))
 
 
-def supports_remat(arch: str) -> bool:
-    return arch.startswith(REMAT_FAMILIES)
-
-
-def takes_flash(arch: str) -> bool:
-    return arch.startswith(FLASH_FAMILIES)
-
-
-def probes_flash(arch: str) -> bool:
-    return arch.startswith(FLASH_PROBE_FAMILIES)
+def model_with(model: nn.Module, arch: str,
+               asked: dict[str, dict[str, Any]]) -> nn.Module:
+    """``model`` told what a run wants of it: ``asked`` maps each flag or
+    mesh axis that asks to the fields it sets. Refuses, naming who asked
+    and the field ``arch`` lacks, rather than building the plain model: a
+    run that silently is not what its flags say would mislabel benchmarks
+    and mis-state the HBM / FLOPs trade."""
+    has = model_fields(model)
+    for who, fields in asked.items():
+        lacks = sorted(set(fields) - has)
+        if lacks:
+            raise ValueError(
+                f"{who} sets the model's field{'s'[:len(lacks) != 1]} "
+                f"{', '.join(lacks)}, which '{arch}' "
+                f"({type(model).__name__}) does not have")
+    fields = {k: v for wanted in asked.values() for k, v in wanted.items()}
+    return model.clone(**fields) if fields else model
 
 
 def create_model(arch: str, **kwargs: Any) -> nn.Module:
@@ -160,10 +160,8 @@ def create_model(arch: str, **kwargs: Any) -> nn.Module:
     like argparse ``choices`` did."""
     if arch not in _REGISTRY:
         raise ValueError(f"Unknown arch '{arch}'. Available: {', '.join(model_names())}")
-    if kwargs.get("remat") and not supports_remat(arch):
-        # Fail loudly here rather than letting a **kw-swallowing ctor build
-        # the plain model: a "remat" run that silently isn't would mislabel
-        # benchmarks and mis-state the HBM/FLOPs trade.
-        raise ValueError(
-            f"--remat supports archs {REMAT_FAMILIES}; got '{arch}'")
+    if kwargs.pop("remat", False):
+        # not handed to a **kw-swallowing constructor: asked of the model
+        return model_with(_REGISTRY[arch](**kwargs), arch,
+                          {"--remat": dict(remat=True)})
     return _REGISTRY[arch](**kwargs)

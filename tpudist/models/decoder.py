@@ -622,7 +622,7 @@ class MoEDecoder(nn.Module):
                 self.layer_types[:self.layers or self.num_layers]
                 if kind in PAIR_KINDS + ("attention",)]
         shape = dict(heads=self.num_heads, kv_heads=self.num_kv_heads,
-                     head_dim=self.head_dim)
+                     head_dim=self.head_dim, fused=False)
         if self.mtp_depth:
             kept += ["full_attention"]   # the module's block has one too
         if self.objective == "block_diffusion":
@@ -849,6 +849,16 @@ class MoEDecoder(nn.Module):
                 if plan not in plans:
                     plans.append(plan)
         return plans
+
+    def plans(self, rows: int, seq_len: int) -> list[tuple[str, dict]]:
+        """Every plan of a training step of ``rows`` rows of ``seq_len``
+        ids as ``(telemetry event, plan)``, in the order the trainer says
+        them; a share that keeps no such block has no such pair."""
+        pairs = [("ssm_scan", self.scan_plan(rows, seq_len)),
+                 ("ssm_conv", self.conv_plan(rows, seq_len)),
+                 *(("attn_qk", p) for p in self.qk_plans(rows, seq_len)),
+                 ("lm_head", self.head_plan(rows, seq_len))]
+        return [(event, plan) for event, plan in pairs if plan is not None]
 
 
 def _rematerialised(layer):

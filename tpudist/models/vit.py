@@ -301,6 +301,24 @@ class VisionTransformer(nn.Module):
         return nn.Dense(self.num_classes, dtype=self.dtype,
                         name="head")(pooled.astype(self.dtype or x.dtype))
 
+    def attention_workloads(self, image_size: int) -> list[dict]:
+        return vit_workloads(self, image_size)
+
+
+def vit_workloads(model, image_size: int) -> list[dict]:
+    """The attention shape a ViT's step runs on images of ``image_size``
+    pixels a side, stated as ``MoEDecoder.attention_workloads`` states a
+    decoder's: one entry, every block's. ``fused`` says it is the call of
+    equal head counts on one fused projection (``qkv_attention``) that the
+    start-up probe can time. The patchify convolution drops a remainder
+    of pixels; a class token (every ``pool`` but "gap") adds a position."""
+    tokens = (image_size // model.patch_size) ** 2
+    if getattr(model, "pool", "token") == "token":
+        tokens += 1
+    return [dict(seq=tokens, heads=model.num_heads, kv_heads=model.num_heads,
+                 head_dim=model.hidden_dim // model.num_heads, causal=False,
+                 window=None, fused=True)]
+
 
 def _vit(patch, hidden, layers, heads, mlp):
     def ctor(num_classes: int = 1000, dtype: Any = None,

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
-from tpudist.models.vit import EncoderBlock, MultiHeadAttention
+from tpudist.models.vit import EncoderBlock, MultiHeadAttention, vit_workloads
 from tpudist.parallel.moe import moe_dense, moe_spmd
 
 
@@ -159,6 +159,9 @@ class MoEVisionTransformer(nn.Module):
         x = nn.LayerNorm(dtype=jnp.float32, name="ln")(x)
         return nn.Dense(self.num_classes, dtype=self.dtype,
                         name="head")(x[:, 0].astype(self.dtype or x.dtype))
+
+    def attention_workloads(self, image_size: int) -> list[dict]:
+        return vit_workloads(self, image_size)
 
 
 def _vit_moe(patch, hidden, layers, heads, mlp):

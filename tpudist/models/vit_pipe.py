@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
-from tpudist.models.vit import EncoderBlock
+from tpudist.models.vit import EncoderBlock, vit_workloads
 
 
 class _ScanLayer(nn.Module):
@@ -187,6 +187,9 @@ class PipelinedViT(nn.Module):
         x = nn.LayerNorm(dtype=jnp.float32, name="ln")(x)
         return nn.Dense(self.num_classes, dtype=self.dtype,
                         name="head")(x[:, 0].astype(self.dtype or x.dtype))
+
+    def attention_workloads(self, image_size: int) -> list[dict]:
+        return vit_workloads(self, image_size)
 
 
 def _vit_pipe(patch, hidden, layers, heads, mlp):

@@ -26,7 +26,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from tpudist.models import create_model
+from tpudist.models import create_model, model_fields
 from tpudist.obs import scopes
 
 
@@ -70,25 +70,24 @@ def resolve_serve_flash(model, *, batch: int, image_size: int,
                         mode: str = "auto", telemetry=None,
                         log=None) -> Optional[dict]:
     """Resolve ``--flash`` for the serving workload through
-    ``ops/attention_dispatch`` — the trainer's ``_resolve_flash_dispatch``
-    with ``train=False`` and the LARGEST bucket as the batch (the shape
-    that dominates steady-state throughput). Returns the decision dict and
-    the possibly-cloned model as ``decision["model"]``; ``None`` when the
-    arch has no derivable attention shape (conv families)."""
-    patch = getattr(model, "patch_size", None)
-    heads = getattr(model, "num_heads", None)
-    hidden = getattr(model, "hidden_dim", None)
-    if not (patch and heads and hidden) or image_size % patch:
+    ``ops/attention_dispatch`` — the trainer's ``_resolve_attention`` on the
+    same ``model.attention_workloads``, with ``train=False`` and the
+    LARGEST bucket as the batch (the shape that dominates steady-state
+    throughput). Returns the decision dict and the possibly-cloned model as
+    ``decision["model"]``; ``None`` for a model that states no attention
+    the probe can time (conv families)."""
+    if "flash" not in model_fields(model):
+        return None
+    workloads = model.attention_workloads(image_size)
+    if len(workloads) != 1 or not workloads[0]["fused"]:
         return None
     from tpudist.ops import attention_dispatch
-    tokens = (image_size // patch) ** 2
-    if getattr(model, "pool", "token") == "token":
-        tokens += 1
+    w = workloads[0]
     dt = getattr(model, "dtype", jnp.bfloat16)
     try:
         dec = attention_dispatch.decide(
-            batch, tokens, heads, hidden // heads, dt,
-            train=False, mode=mode)
+            batch, w["seq"], w["heads"], w["head_dim"], dt,
+            train=False, causal=w["causal"], mode=mode)
     except Exception as e:
         if log is not None:
             log(f"=> serve attention dispatch probe failed ({e!r}) — "
@@ -96,8 +95,8 @@ def resolve_serve_flash(model, *, batch: int, image_size: int,
         return None
     out = dict(dec)
     # Clone in EVERY mode, not just auto: a forced --flash on/off must
-    # reach the model the same way the trainer forces it
-    # (model_kwargs["flash"]) — otherwise the built model keeps
+    # reach the model as the trainer's does (it sets the model's `flash`
+    # field, models.model_with) — otherwise the built model keeps
     # flash=None, the trace-time lookup decides on its own, and the
     # emitted attention_dispatch verdict lies about the kernel served.
     out["model"] = model.clone(flash=dec["kernel"] == "flash")
@@ -129,13 +128,10 @@ def load_serve_state(arch: str, checkpoint: str = "", *,
     model's dtype policy at apply time, exactly like training's forward).
     """
     model = create_model(arch, num_classes=num_classes, dtype=dtype)
-    dec = None
-    if arch.startswith("vit"):
-        dec = resolve_serve_flash(model, batch=max_batch,
-                                  image_size=image_size, mode=flash,
-                                  telemetry=telemetry, log=log)
-        if dec is not None:
-            model = dec["model"]
+    dec = resolve_serve_flash(model, batch=max_batch, image_size=image_size,
+                              mode=flash, telemetry=telemetry, log=log)
+    if dec is not None:
+        model = dec["model"]
     if checkpoint:
         from tpudist import checkpoint as ckpt_lib
         ckpt = ckpt_lib.load_checkpoint(checkpoint)

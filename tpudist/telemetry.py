@@ -136,8 +136,9 @@ SCHEMA: dict[str, tuple[str, ...]] = {
     "preempt": ("signal",),
     # Attention-backend resolution (tpudist/ops/attention_dispatch): which
     # kernel --flash resolved to, and on what evidence (forced / platform /
-    # cache / measured). Emitted once per Trainer construction for vit*
-    # archs so summarize and the regression gate cover kernel choice.
+    # cache / measured). Emitted once per Trainer construction of a model
+    # with a `flash` field so summarize and the regression gate cover
+    # kernel choice.
     "attention_dispatch": ("kernel", "mode", "source"),
     # The Mamba blocks' chunked scan (tpudist/ops/ssd.py::scan_plan): which
     # program the shape takes ("pallas" | "xla", with a ``reason`` where it

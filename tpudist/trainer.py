@@ -37,8 +37,7 @@ from tpudist.doctor.policy import RollbackRequested
 from tpudist.data import build_train_val_loaders
 from tpudist.dist import (data_rank_world, replica_rank_world,
                           shard_host_batch)
-from tpudist.models import (FLASH_FAMILIES, create_model, probes_flash,
-                            takes_flash)
+from tpudist.models import create_model, model_fields, model_with
 from tpudist.obs import scopes
 from tpudist.train import (TrainState, compute_dtype, create_train_state,
                            lr_for_epoch, make_eval_step, make_train_step)
@@ -390,39 +389,16 @@ class Trainer:
         self.pp_model_axis = self.plan.pp_model_axis
         self.uses_gspmd_path = self.plan.uses_gspmd_path
         if self.uses_model_axis and not self.uses_pipe_axis:
-            # Fail BEFORE model init: a >1 'model' axis with an arch whose
-            # rule table is empty would silently run pure DP through the
-            # GSPMD path (VERDICT r5 weak #3; plane.rules_for_mesh is the
-            # validated resolution).
+            # Before any model is built: a 'model' axis over an arch whose
+            # rule table is empty would run pure DP through the GSPMD path.
             plane.rules_for_mesh(cfg.arch, self.mesh)
-        model_kwargs = {}
+        # What the run asks of the model, by the flag or mesh axis that
+        # asks: the model's own fields answer (models.model_with refuses a
+        # field the architecture does not have, before any state is made).
+        asked = {}
         if cfg.remat:
-            # create_model validates arch support (models/__init__.py:
-            # REMAT_FAMILIES) — the raise still lands at Trainer startup,
-            # before any training (ADVICE r2: no first-save crashes an
-            # epoch in).
-            model_kwargs["remat"] = True
-        if cfg.flash == "on" and not takes_flash(cfg.arch):
-            # 'off' is a semantic no-op for convnets (nothing to disable) —
-            # rejecting it would crash scripted sweeps passing a uniform
-            # `--flash off` across mixed arch lists (ADVICE r3).
-            raise ValueError(
-                f"--flash on applies to attention archs "
-                f"({', '.join(FLASH_FAMILIES)}*); got '{cfg.arch}'")
-        if takes_flash(cfg.arch) and (cfg.flash != "auto"
-                                      or not probes_flash(cfg.arch)):
-            # r5: --flash composes with the GSPMD/TP path too —
-            # flash_attention_spmd runs the Pallas kernel in a nested
-            # manual region over the step builder's ambient mesh, so the
-            # r4 forced-off/refusal is gone. A family without a start-up
-            # probe runs the kernel only where told to (`on`).
-            model_kwargs["flash"] = cfg.flash == "on"
+            asked["--remat"] = dict(remat=True)
         if self.uses_seq_axis:
-            if (not cfg.arch.startswith("vit")
-                    or cfg.arch.startswith(("vit_moe", "vit_pipe"))):
-                raise ValueError(
-                    f"sequence parallelism (mesh axis 'seq') requires a ViT "
-                    f"arch with a token dimension; got '{cfg.arch}'")
             if self.data_axis == "seq":
                 raise ValueError(
                     "sequence parallelism needs a batch axis alongside "
@@ -442,12 +418,8 @@ class Trainer:
                     "(parallel/ring_attention.py) and does not use the "
                     "Pallas kernel. Use --flash auto or off")
             # Ring attention over the seq axis; GAP head (uniform shards).
-            model_kwargs.update(seq_axis="seq", pool="gap")
+            asked["mesh axis 'seq'"] = dict(seq_axis="seq", pool="gap")
         if self.uses_expert_axis:
-            if not cfg.arch.startswith("vit_moe"):
-                raise ValueError(
-                    f"expert parallelism (mesh axis 'expert') requires a MoE "
-                    f"arch (vit_moe_*); got '{cfg.arch}'")
             if list(cfg.mesh_axes) not in (["expert"], ["data", "expert"]):
                 raise ValueError(
                     "expert parallelism uses a pure ('expert',) mesh (the "
@@ -457,17 +429,13 @@ class Trainer:
             if cfg.pretrained:
                 raise ValueError("--pretrained is not supported for MoE "
                                  "archs (no torchvision equivalent)")
-            model_kwargs.update(expert_axis="expert",
-                                num_experts=self.mesh.shape["expert"])
+            asked["mesh axis 'expert'"] = dict(
+                expert_axis="expert", num_experts=self.mesh.shape["expert"])
             if self.ep_data_axis:
                 # dp×ep: load-balance statistics average over the whole
                 # global batch, not one data slice (models/vit_moe.py).
-                model_kwargs.update(aux_axes=("data", "expert"))
+                asked["mesh axis 'expert'"]["aux_axes"] = ("data", "expert")
         if self.uses_pipe_axis:
-            if not cfg.arch.startswith("vit_pipe"):
-                raise ValueError(
-                    f"pipeline parallelism (mesh axis 'pipe') requires a "
-                    f"pipelined arch (vit_pipe_*); got '{cfg.arch}'")
             if self.data_axis == "pipe":
                 raise ValueError(
                     "pipeline parallelism needs a batch axis alongside "
@@ -477,84 +445,63 @@ class Trainer:
                 raise ValueError(
                     "--pretrained is not supported for pipelined archs (the "
                     "nn.scan-stacked trunk has no torchvision layout)")
-            model_kwargs.update(pipe_axis="pipe",
-                                num_microbatches=cfg.microbatches)
+            asked["mesh axis 'pipe'"] = dict(
+                pipe_axis="pipe", num_microbatches=cfg.microbatches)
             if self.pp_model_axis:
-                model_kwargs.update(model_axis=self.pp_model_axis)
+                asked["mesh axis 'pipe'"]["model_axis"] = self.pp_model_axis
         # Under GSPMD the global-batch BN statistics ARE SyncBN (the
         # partitioner reduces over the whole sharded batch); the explicit
         # pmean-BN flag belongs to the shard_map path only.
         sync_bn = cfg.sync_batchnorm and not self.uses_gspmd_path
-        self.model = create_model(
+        plain = create_model(
             cfg.arch, num_classes=cfg.num_classes, dtype=compute_dtype(cfg),
-            sync_batchnorm=sync_bn, bn_axis_name=self.data_axis,
-            **model_kwargs)
-        # A model of tokens says so itself (it holds a vocabulary), and is
-        # told what this holder keeps of a deployment's model.
-        self.trains_tokens = hasattr(self.model, "vocab_held")
+            sync_batchnorm=sync_bn, bn_axis_name=self.data_axis)
+        takes = model_fields(plain)
+        if cfg.flash == "on" or (cfg.flash == "off" and "flash" in takes):
+            # 'off' asks nothing of a model without attention: a sweep may
+            # pass one `--flash off` across mixed archs. `auto` is the
+            # resolver's to set, below.
+            asked[f"--flash {cfg.flash}"] = dict(flash=cfg.flash == "on")
+        # A model of tokens holds a share of a vocabulary, and is told what
+        # this holder keeps of a deployment's model.
+        self.trains_tokens = "vocab_share" in takes
         share = dict(
             layers=cfg.layers,
             expert_share=_parse_share(cfg.expert_share, "--expert-share"),
             vocab_share=_parse_share(cfg.vocab_share, "--vocab-share"))
-        if self.trains_tokens:
-            if cfg.seq_len < 1:
-                raise ValueError(
-                    f"'{cfg.arch}' trains on rows of token ids: give "
-                    f"--seq-len (ids a row; -b counts rows)")
-            self.model = self.model.clone(**share)
-        elif share != dict(layers=0, expert_share=(0, 1),
-                           vocab_share=(0, 1)):
+        if self.trains_tokens and cfg.seq_len < 1:
             raise ValueError(
-                f"--layers / --expert-share / --vocab-share state a holder's "
-                f"share of a model of tokens (the mellum2, sdar, nemotron3, "
-                f"ouro and joyai families); '{cfg.arch}' is none")
-        # Measurement-honest attention dispatch (VERDICT r5 weak #2):
-        # resolve --flash OUTSIDE any trace. `auto` micro-benchmarks
-        # flash-vs-XLA on the attached chip at the exact workload shape
-        # (verdict cached per device_kind) and never selects a kernel that
-        # loses its own measurement; off-TPU it resolves to XLA without
-        # touching Pallas. The decision is logged and emitted as an
-        # `attention_dispatch` telemetry event so summarize and the bench
-        # history cover kernel choice. seq-axis runs skip it: their
-        # attention goes around the ring, not through the kernel.
+                f"'{cfg.arch}' trains on rows of token ids: give "
+                f"--seq-len (ids a row; -b counts rows)")
+        if self.trains_tokens or share != dict(
+                layers=0, expert_share=(0, 1), vocab_share=(0, 1)):
+            asked["--layers / --expert-share / --vocab-share"] = share
+        self.model = model_with(plain, cfg.arch, asked)
+        # Attention's kernel is resolved here, outside any trace: `auto`
+        # times the Pallas kernel against XLA attention on the attached
+        # chip at the workload's own shape (the verdict kept per
+        # device_kind) and never selects a kernel that lost; off the TPU it
+        # is the XLA path and Pallas is not touched. A seq-axis run skips
+        # it: its attention goes around the ring, not through the kernel.
         self.flash_decision = None
         mark(scopes.INIT_OTHER)
-        if takes_flash(cfg.arch) and not self.uses_seq_axis:
-            self.flash_decision = self._resolve_flash_dispatch()
-        if hasattr(self.model, "scan_plan"):
-            self._announce_scan_plan(self.model.scan_plan(
-                cfg.per_device_batch_size, cfg.seq_len))
-        if hasattr(self.model, "conv_plan"):
-            self._announce_conv_plan(self.model.conv_plan(
-                cfg.per_device_batch_size, cfg.seq_len))
-        if hasattr(self.model, "qk_plans"):
-            self._announce_qk_plans(self.model.qk_plans(
-                cfg.per_device_batch_size, cfg.seq_len))
-        if hasattr(self.model, "head_plan"):
-            self._announce_head_plan(self.model.head_plan(
-                cfg.per_device_batch_size, cfg.seq_len))
+        if "flash" in takes and not self.uses_seq_axis:
+            self.flash_decision = self._resolve_attention()
+        if self.trains_tokens:
+            for event, plan in self.model.plans(cfg.per_device_batch_size,
+                                                cfg.seq_len):
+                self._announce_plan(event, plan)
         seed = cfg.seed if cfg.seed is not None else 0
         mark(scopes.INIT_DISPATCH)
-        if self.uses_seq_axis or self.uses_expert_axis or self.uses_pipe_axis:
-            # SPMD collectives can't be traced by model.init outside
-            # shard_map: init with the unsharded twin (identical param tree —
-            # the SP model slices tokens after patchify/pos-embed; the EP
-            # twin runs experts dense/vmapped with the same stacked [E]
-            # weights).
-            twin_kwargs = dict(model_kwargs)
-            twin_kwargs.pop("seq_axis", None)
-            twin_kwargs.pop("expert_axis", None)
-            twin_kwargs.pop("pipe_axis", None)
-            init_model = create_model(
-                cfg.arch, num_classes=cfg.num_classes,
-                dtype=compute_dtype(cfg), **twin_kwargs)
-            self._init_model = init_model
-            self.state = create_train_state(jax.random.PRNGKey(seed),
-                                            init_model, cfg)
-        else:
-            self._init_model = self.model
-            self.state = create_train_state(jax.random.PRNGKey(seed),
-                                            self.model, cfg)
+        # SPMD collectives can't be traced by model.init outside shard_map:
+        # init with the unsharded twin (identical param tree — the SP model
+        # slices tokens after patchify/pos-embed; the EP twin runs experts
+        # dense/vmapped with the same stacked [E] weights).
+        self._init_model = self.model.clone(**{
+            axis: None for axis in ("seq_axis", "expert_axis", "pipe_axis")
+            if axis in takes})
+        self.state = create_train_state(jax.random.PRNGKey(seed),
+                                        self._init_model, cfg)
         mark(scopes.INIT_MODEL_STATE)
         if cfg.pretrained:
             # Reference: torchvision pretrained=True + "=> using pre-trained
@@ -789,73 +736,83 @@ class Trainer:
         if self.watchdog is not None:
             self.watchdog.kick()
 
-    def _resolve_flash_dispatch(self):
-        """Resolve --flash for the configured attention workload through
-        ``ops/attention_dispatch`` (host-side, before any step is traced).
-        Under `auto` the model is cloned with the resolved backend; forced
-        modes only record their decision. Returns the decision dict (None
-        when the arch's attention shape can't be derived — dispatch then
-        falls back to the model-level trace-safe lookup). A probe that
-        raises propagates."""
+    def _resolve_attention(self) -> dict:
+        """Resolve ``--flash`` for the attention the model says its step
+        runs (``model.attention_workloads``), host-side, before any step is
+        traced; set the model's ``flash`` to the verdict under `auto`, and
+        log and emit the decision. One ``fused`` workload is the call the
+        start-up probe can time: it goes through ``attention_dispatch.
+        decide`` (forced modes too; a probe that raises propagates: only a
+        measured loss or a static ineligibility may select the XLA path, a
+        kernel that fails to compile is a bug). Any other set of workloads
+        (a decoder's grouped-query, windowed or block-masked attention, one
+        a layer type) has no probe: what ``--flash`` says, `auto` read as
+        `off`, nothing measured and nothing cached; the shape keys carry
+        the window and the head grouping."""
         from tpudist.ops import attention_dispatch
         cfg = self.cfg
-        m = self.model
-        if not probes_flash(cfg.arch):
-            return self._forced_flash_decision()
-        patch = getattr(m, "patch_size", None)
-        heads = getattr(m, "num_heads", None)
-        hidden = getattr(m, "hidden_dim", None)
-        if not (patch and heads and hidden) or cfg.image_size % patch:
-            return None
-        tokens = (cfg.image_size // patch) ** 2
-        if getattr(m, "pool", "token") == "token":
-            tokens += 1
-        # Measure the shape a device ACTUALLY runs. Under GSPMD TP the
-        # nested manual region (flash_attention_spmd) shards heads over
-        # 'model' and batch over 'data' only — so per-shard attention is
-        # (per_device_batch × tp, heads / tp), not (per_device_batch,
-        # heads). Probing the wrong shape would re-open the hole this layer
-        # closes: a kernel that wins an unrun shape and loses the real one.
-        # (The pipe-path TP composition is dominated by forced modes and
-        # microbatching; its auto probe uses the unsharded shape.)
-        batch, local_heads = cfg.per_device_batch_size, heads
-        if self.uses_model_axis and not self.uses_pipe_axis:
-            tp = self.mesh.shape["model"]
-            if heads % tp == 0:
-                local_heads = heads // tp
-                batch = cfg.per_device_batch_size * tp
-        dt = compute_dtype(cfg)
+        dt, train = compute_dtype(cfg), not cfg.evaluate
+        # The shape a device ACTUALLY runs: under GSPMD TP the nested manual
+        # region (flash_attention_spmd) shards heads over 'model' and batch
+        # over 'data' only, so per-shard attention is (per_device_batch ×
+        # tp, heads / tp). (The pipe path's TP is dominated by forced modes
+        # and microbatching; its probe uses the unsharded shape.)
+        tp = (self.mesh.shape["model"]
+              if self.uses_model_axis and not self.uses_pipe_axis else 1)
+        shapes = []
+        for w in self.model.attention_workloads(
+                cfg.seq_len if self.trains_tokens else cfg.image_size):
+            split = tp if w["heads"] % tp == w["kv_heads"] % tp == 0 else 1
+            shapes.append(dict(w, batch=cfg.per_device_batch_size * split,
+                               heads=w["heads"] // split,
+                               kv_heads=w["kv_heads"] // split))
+        keys = [attention_dispatch.shape_key(
+                    w["batch"], w["seq"], w["heads"], w["head_dim"], dt,
+                    train, w["causal"], kv_heads=w["kv_heads"],
+                    window=w["window"],
+                    block_diffusion=w.get("block_diffusion"))
+                for w in shapes]
+        probed = len(shapes) == 1 and shapes[0]["fused"]
+        if probed:
+            w = shapes[0]
 
-        # A probe that RAISES (kernel refused by the compiler, runtime
-        # fault) propagates and ends the run: only a measured loss or a
-        # static ineligibility may select the XLA baseline — a kernel that
-        # fails to compile is a bug, not a dispatch decision.
-        def _decide():
-            return attention_dispatch.decide(
-                batch, tokens, local_heads, hidden // heads, dt,
-                train=not cfg.evaluate, mode=cfg.flash)
+            def _decide():
+                return attention_dispatch.decide(
+                    w["batch"], w["seq"], w["heads"], w["head_dim"], dt,
+                    train=train, causal=w["causal"], mode=cfg.flash)
 
-        if jax.process_count() > 1 and cfg.flash == "auto":
-            # One verdict for the gang: a per-host micro-benchmark at a
-            # near-tie shape could compile DIFFERENT attention backends
-            # into one SPMD program. Primary decides, peers read it
-            # from the shared run dir.
-            dec = attention_dispatch.shared_decision(
-                cfg.outpath, self.primary, _decide,
-                expect_key=attention_dispatch.shape_key(
-                    batch, tokens, local_heads, hidden // heads, dt,
-                    not cfg.evaluate, False),
-                log=self.log)
+            if jax.process_count() > 1 and cfg.flash == "auto":
+                # One verdict for the gang: a per-host micro-benchmark at a
+                # near-tie shape could compile DIFFERENT attention backends
+                # into one SPMD program. Primary decides, peers read it
+                # from the shared run dir.
+                dec = attention_dispatch.shared_decision(
+                    cfg.outpath, self.primary, _decide, expect_key=keys[0],
+                    log=self.log)
+            else:
+                dec = _decide()
         else:
-            dec = _decide()
+            kernel = "flash" if cfg.flash == "on" else "xla"
+            key = ",".join(keys)
+            why = ("no start-up probe for grouped-query or windowed "
+                   "attention: auto is the XLA path"
+                   if cfg.flash == "auto" else None)
+            dec = {"kernel": kernel, "mode": cfg.flash, "source": "forced",
+                   "key": key, "reason": "; ".join(filter(None, [why, key])),
+                   "kernel_rev": attention_dispatch.kernel_rev()
+                   if kernel == "flash" else None}
         if cfg.flash == "auto":
             self.model = self.model.clone(flash=dec["kernel"] == "flash")
         if dec["kernel"] == "flash":
-            # which of the kernel's schedules this shape takes, and how far
-            # it engages there
+            # which of the kernel's schedules each shape takes and how far
+            # it engages there; one entry a workload, in the keys' order
             dec["programs"] = [attention_dispatch.program(
-                tokens, local_heads, hidden // heads, dt, fused=True)]
-            dec["schedule"] = dec["programs"][0]["schedule"]
+                w["seq"], w["heads"], w["head_dim"], dt,
+                kv_heads=w["kv_heads"], causal=w["causal"],
+                window=w["window"], block_diffusion=w.get("block_diffusion"),
+                fused=w["fused"]) for w in shapes]
+            if dec["programs"]:       # a share may keep no attention
+                dec["schedule"] = dec["programs"][0]["schedule"]
         return self._announce_flash_decision(dec)
 
     def _announce_flash_decision(self, dec: dict) -> dict:
@@ -883,93 +840,18 @@ class Trainer:
                                 **attention_dispatch.event_fields(dec))
         return dec
 
-    def _announce_scan_plan(self, plan: Optional[dict]) -> None:
-        """The log line and telemetry event of the Mamba blocks' chunked
-        scan (``ssd.scan_plan``: read from the shape, nothing to decide)."""
-        if plan is None:
-            return
-        self.log(f"=> ssm scan: {plan['kernel']} (chunk {plan['chunk']}, "
-                 f"heads_per_program {plan['heads_per_program']}, programs "
-                 f"{plan['programs']} a block"
+    def _announce_plan(self, event: str, plan: dict) -> None:
+        """The log line and telemetry event of a plan the model read from
+        its shape (``model.plans``: which program runs, nothing to decide):
+        its ``kernel`` or ``form``, its other fields, and why (``reason``)
+        where a fallback runs."""
+        fields = ", ".join(f"{k} {v}" for k, v in plan.items()
+                           if k not in ("kernel", "form", "reason"))
+        self.log(f"=> {event}: {plan.get('kernel', plan.get('form'))} "
+                 f"({fields}"
                  + (f": {plan['reason']}" if "reason" in plan else "") + ")")
         if self.telemetry is not None:
-            self.telemetry.emit("ssm_scan", **plan)
-
-    def _announce_pass_plan(self, said: str, event: str, per: str,
-                            plan: dict) -> None:
-        """The log line and telemetry event of an elementwise pass's plan
-        (read from the shape, nothing to decide): ``said`` in the log,
-        ``programs`` a ``per``."""
-        self.log(f"=> {said}: {plan['kernel']} (" + (
-            plan["reason"] if "reason" in plan else
-            f"rows_per_program {plan['rows_per_program']}, programs "
-            f"{plan['programs']} a {per}") + ")")
-        if self.telemetry is not None:
             self.telemetry.emit(event, **plan)
-
-    def _announce_conv_plan(self, plan: Optional[dict]) -> None:
-        """The Mamba blocks' convolution and SiLU (``ssd.conv_plan``)."""
-        if plan is not None:
-            Trainer._announce_pass_plan(self, "ssm conv", "ssm_conv",
-                                        "block", plan)
-
-    def _announce_qk_plans(self, plans: list) -> None:
-        """q's and k's norm and rotation in the attention blocks (``qk_plan``
-        or, latent attention, ``latent_plan``), one a plan that differs."""
-        for plan in plans:
-            Trainer._announce_pass_plan(self, "attn q/k", "attn_qk", "layer",
-                                        plan)
-
-    def _announce_head_plan(self, plan: dict) -> None:
-        """Which form of the head's loss the step runs (``ops.lm_head_loss``:
-        it follows from the caller, nothing to decide)."""
-        def counted(n, what):
-            return f"{n} {what}{'s'[:n != 1]}"
-        runs = (f"(chunk {plan['chunk']}, "
-                f"{counted(plan['chunks'], 'chunk')} a call, "
-                f"{counted(plan['calls'], 'call')} a step")
-        self.log("=> lm head: " + (
-            f"gradients in the forward loop {runs})"
-            if plan["form"] == "forward_loop" else
-            f"rematerialised {runs} under the loop over passes)"))
-        if self.telemetry is not None:
-            self.telemetry.emit("lm_head", **plan)
-
-    def _forced_flash_decision(self) -> dict:
-        """The attention decision of a family without a start-up probe (a
-        decoder's grouped-query, windowed attention): what ``--flash`` says,
-        `auto` read as `off`, once for every attention shape the step runs
-        (``model.attention_workloads``). Nothing is measured, so nothing is
-        cached; the shape keys carry the window and the head grouping."""
-        from tpudist.ops import attention_dispatch
-        cfg = self.cfg
-        kernel = "flash" if cfg.flash == "on" else "xla"
-        workloads = self.model.attention_workloads(cfg.seq_len)
-        keys = [attention_dispatch.shape_key(
-                    cfg.per_device_batch_size, w["seq"], w["heads"],
-                    w["head_dim"], compute_dtype(cfg), not cfg.evaluate,
-                    w["causal"], kv_heads=w["kv_heads"], window=w["window"],
-                    block_diffusion=w.get("block_diffusion"))
-                for w in workloads]
-        dec = {"kernel": kernel, "mode": cfg.flash, "source": "forced",
-               "key": ",".join(keys),
-               "kernel_rev": attention_dispatch.kernel_rev()
-               if kernel == "flash" else None}
-        if cfg.flash == "auto":
-            dec["reason"] = ("no start-up probe for grouped-query or "
-                             "windowed attention: auto is the XLA path")
-        if kernel == "flash":
-            # split q / k / v stream; one entry a workload, in the keys' order
-            dec["programs"] = [attention_dispatch.program(
-                w["seq"], w["heads"], w["head_dim"], compute_dtype(cfg),
-                kv_heads=w["kv_heads"], causal=w["causal"],
-                window=w["window"],
-                block_diffusion=w.get("block_diffusion")) for w in workloads]
-            if dec["programs"]:       # a share may keep no attention
-                dec["schedule"] = dec["programs"][0]["schedule"]
-        dec["reason"] = "; ".join(filter(None, [dec.get("reason"),
-                                                dec["key"]]))
-        return self._announce_flash_decision(dec)
 
     def _resolve_comm_dispatch(self) -> dict:
         """Resolve ``--compress-grads`` through ``ops/comm_dispatch``
